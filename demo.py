@@ -200,13 +200,9 @@ def main(argv=None):
     p.add_argument("--out", default="prediction.png")
     args = p.parse_args(argv)
 
-    if args.device == "cpu":
-        import jax
+    from tmr_tpu.utils.cache import enable_compilation_cache, select_device
 
-        jax.config.update("jax_platforms", "cpu")
-
-    from tmr_tpu.utils.cache import enable_compilation_cache
-
+    select_device(args.device)
     enable_compilation_cache()
 
     engine = DemoEngine(demo_config(args))

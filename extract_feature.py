@@ -135,12 +135,9 @@ def main(argv=None):
     p.add_argument("--image_size", default=1024, type=int)
     p.add_argument("--device", default="tpu")
     args = p.parse_args(argv)
-    if args.device == "cpu":
-        import jax
+    from tmr_tpu.utils.cache import enable_compilation_cache, select_device
 
-        jax.config.update("jax_platforms", "cpu")
-    from tmr_tpu.utils.cache import enable_compilation_cache
-
+    select_device(args.device)
     enable_compilation_cache()
     run_extraction_and_analyze(
         args.image, args.output_dir, args.backbone, args.checkpoint,

@@ -168,13 +168,9 @@ def scrub_training_env(environ=None) -> list:
 def main(argv=None):
     args = config_parser(argv)
 
-    if args.device == "cpu":
-        import jax
+    from tmr_tpu.utils.cache import enable_compilation_cache, select_device
 
-        jax.config.update("jax_platforms", "cpu")
-
-    from tmr_tpu.utils.cache import enable_compilation_cache
-
+    select_device(args.device)
     enable_compilation_cache()
 
     # seed_everything (reference main.py:86)

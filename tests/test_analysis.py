@@ -395,7 +395,7 @@ def test_audit_jaxpr_f64_and_quant_widen_predicates():
 
     from tmr_tpu.analysis.program_audit import audit_jaxpr
 
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         j = jax.make_jaxpr(
             lambda a: a.astype(jnp.float64) * 2.0
         )(jax.ShapeDtypeStruct((8,), jnp.float32))
@@ -461,7 +461,6 @@ def test_program_audit_all_eight_gate_states_reduced_geometry():
         image_size=64, emb_dim=16, backbone="resnet50_layer1",
         gate_states=ALL_GATE_STATES, include_attention=False,
         programs=("match_heads",),
-        transfer_pins={"match_heads": 0},  # resnet stages no constants
     )
     assert rec["ok"], rec["problems"]
     assert len(rec["states"]) == 8

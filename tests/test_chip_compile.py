@@ -1,0 +1,319 @@
+"""Compiles for a *described* TPU v5e: the only file that describes the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is not
+attached (the `on-chip-measurement` guide §2.3). Nothing runs, so these
+cases say nothing about results or times; they say that the chip's compiler
+takes, at ViT-B/1024 shapes, every Pallas kernel the ``auto`` path can still
+select, and that the fused predict program fits one chip's 16 GB. A compile
+that passes here is not a chip run.
+
+The topology is described inside a module-scoped fixture — never at import,
+never ``autouse``, never from ``conftest.py`` — because only one process may
+load the TPU's library: every xdist worker imports this file, and only the
+worker that runs it may make the call. Code that asks
+``jax.default_backend()`` still sees the CPU here, so the test steers those
+branches itself (``monkeypatch``), not through an option of the program.
+"""
+
+import inspect
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tmr_tpu.ops.flash_attn import (
+    flash_decomposed_attention,
+    flash_windowed_attention,
+)
+from tmr_tpu.ops.pallas_attn import (
+    pallas_decomposed_attention,
+    pallas_windowed_attention,
+)
+
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on the described chip, with the persistent compile cache
+    off around the module: such a compile is written to the cache but
+    cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The program's ``jax.default_backend()`` branches take their TPU side
+    (no interpret mode, TPU defaults), and the gates the ``auto`` path asks
+    answer yes — their self-checks execute, which only a chip can."""
+    from tmr_tpu.diagnostics import mosaic_gate
+    from tmr_tpu.ops import flash_attn, pallas_nms
+
+    def admits(name):
+        def gate(*a, **k):
+            return True
+
+        gate.__name__ = name
+        # still a Mosaic gate: inside a partitioned trace it answers no
+        return mosaic_gate(gate)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for mod, name in ((flash_attn, "flash_attention_ok"),
+                      (flash_attn, "flash_window_ok"),
+                      (pallas_nms, "pallas_nms_compiled_ok")):
+        monkeypatch.setattr(mod, name, admits(name))
+
+
+# ViT-B at 1024: 64x64 token grid, 12 heads of 64; the 14x14 windows of a
+# 70x70-padded grid are 25 per image
+_G, _H, _D, _WIN, _NWIN = 64, 12, 64, 14, 25
+_SCALE = _D**-0.5
+
+
+def _attn_case(fn, grid, batch, grad=False):
+    """One attention formulation at its production shape; ``grad`` compiles
+    what the train step differentiates, as the gates' grad half does."""
+    def forward(q, k, v, rh, rw):
+        return fn(q, k, v, rh, rw, (grid, grid), _SCALE)
+
+    def loss(*args):
+        return jnp.sum(forward(*args).astype(jnp.float32) ** 2)
+
+    def case(sds):
+        q = sds((batch, _H, grid * grid, _D), jnp.bfloat16)
+        rel = sds((grid, grid, _D), jnp.float32)
+        run = jax.grad(loss, argnums=(0, 1, 2)) if grad else forward
+        return run, (q, q, q, rel, rel)
+
+    return case
+
+
+def _case_nms(sds):
+    """The decode tail's shape: 2 images x max_detections slots, vmapped as
+    postprocess.batched_nms does."""
+    from tmr_tpu.ops.pallas_nms import nms_keep_mask_pallas
+
+    fn = jax.vmap(lambda b, s, v: nms_keep_mask_pallas(b, s, 0.5, v))
+    return fn, (sds((2, 2000, 4), jnp.float32), sds((2, 2000), jnp.float32),
+                sds((2, 2000), jnp.bool_))
+
+
+def _case_int8_matmul(sds):
+    """A decoder-conv-as-matmul shape: the 128x128 grid's 16384 rows."""
+    from tmr_tpu.ops.pallas_int8 import int8_matmul
+
+    return int8_matmul, (sds((16384, 1024), jnp.int8),
+                         sds((1024, 1024), jnp.int8),
+                         sds((16384, 1), jnp.float32),
+                         sds((1, 1024), jnp.float32))
+
+
+def _case_predict_program(sds):
+    """The fused predict program of chip_smoke.py and bench.py: ViT-B/1024,
+    emb 512, 2x upsample, fusion, bf16, two images, parameters as shapes."""
+    import numpy as np
+
+    from tmr_tpu.config import preset
+    from tmr_tpu.inference import Predictor
+
+    cfg = preset("TMR_FSCD147", backbone="sam_vit_b", image_size=1024,
+                 compute_dtype="bfloat16")
+    pred = Predictor(cfg)
+    image = jnp.zeros((2, 1024, 1024, 3), jnp.float32)
+    ex = np.asarray([[[0.2, 0.2, 0.3, 0.3]]] * 2, np.float32)
+    params = jax.eval_shape(
+        pred.model.init, jax.random.key(0), image[:1], jnp.asarray(ex[:1])
+    )["params"]
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype), params)
+    fn = inspect.unwrap(pred._get_fn(pred.pick_capacity(ex, 1024)),
+                        stop=lambda f: hasattr(f, "lower"))
+    return fn, (params, None, sds(image.shape, image.dtype),
+                sds(ex.shape, ex.dtype))
+
+
+CASES = {
+    "pallas_global": _attn_case(pallas_decomposed_attention, _G, 1),
+    "pallas_window": _attn_case(pallas_windowed_attention, _WIN, _NWIN),
+    "flash_global": _attn_case(flash_decomposed_attention, _G, 1),
+    "flash_global_grad": _attn_case(flash_decomposed_attention, _G, 1,
+                                    grad=True),
+    "flash_window": _attn_case(flash_windowed_attention, _WIN, _NWIN),
+    "flash_window_grad": _attn_case(flash_windowed_attention, _WIN, _NWIN,
+                                    grad=True),
+    "nms": _case_nms,
+    "int8_matmul": _case_int8_matmul,
+    "predict_program": _case_predict_program,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_v5e(case, one_chip, as_tpu):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = CASES[case](sds)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{case}: the compiled program holds no Mosaic kernel"
+    )
+    if case == "predict_program":
+        mem = compiled.memory_analysis()
+        need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+        assert need < V5E_HBM_BYTES, f"{need} bytes do not fit 16 GB"
+
+
+def _shallow_vit_b(cfg):
+    """The configuration's model with ViT-B's widths and both of its block
+    kinds (one windowed, one global) at depth 2: what a partitioned
+    program lowers does not depend on how often the pair repeats, and the
+    compile stays short."""
+    from tmr_tpu.models import build_model
+
+    model = build_model(cfg)
+    return model.clone(template_capacity=9, backbone=model.backbone.clone(
+        depth=2, global_attn_indexes=(1,)))
+
+
+def _mesh_case_train_step(devices):
+    """The trainer's own jit (``Trainer._jit_step_under_mesh``) over a
+    two-chip data mesh: ``main.py --mesh_data -1 --multi_gpu`` on a host
+    with more than one chip."""
+    import types
+
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from tmr_tpu.config import preset
+    from tmr_tpu.parallel import make_mesh
+    from tmr_tpu.parallel.sharding import state_sharding
+    from tmr_tpu.train.loop import Trainer
+    from tmr_tpu.train.state import create_train_state, make_train_step
+
+    cfg = preset("TMR_FSCD147", backbone="sam_vit_b", image_size=1024,
+                 compute_dtype="bfloat16")
+    model = _shallow_vit_b(cfg)
+    mesh = make_mesh((2, 1), devices=devices)
+    image = jax.ShapeDtypeStruct((2, 1024, 1024, 3), jnp.float32)
+    boxes = jax.ShapeDtypeStruct((2, 1, 4), jnp.float32)
+    state = jax.eval_shape(
+        lambda im, ex: create_train_state(model, cfg, jax.random.key(0),
+                                          im[:1], ex[:1]), image, boxes)
+    sharding = state_sharding(state, mesh)
+    step = Trainer._jit_step_under_mesh(
+        types.SimpleNamespace(mesh=mesh), make_train_step(model, cfg),
+        sharding)
+
+    def place(tree, shardings):
+        return jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sh), tree, shardings)
+
+    data = NamedSharding(mesh, P("data"))
+    batch = {"image": image, "exemplars": boxes, "gt_boxes": boxes,
+             "gt_valid": jax.ShapeDtypeStruct((2, 1), jnp.bool_)}
+    return mesh, step, (place(state, sharding),
+                        place(batch, jax.tree.map(lambda _: data, batch)))
+
+
+def _mesh_case_serve_tp2(devices):
+    """A tensor-parallel serving target (``Predictor._get_sharded_fn``
+    through ``compile_sharded``) on one two-chip replica group."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from tmr_tpu.config import preset
+    from tmr_tpu.inference import Predictor
+    from tmr_tpu.serve.meshplan import MeshPlan
+
+    cfg = preset("TMR_FSCD147", backbone="sam_vit_b", image_size=1024,
+                 compute_dtype="bfloat16")
+    pred = Predictor(cfg, model=_shallow_vit_b(cfg))
+    image = jax.ShapeDtypeStruct((1, 1024, 1024, 3), jnp.float32)
+    boxes = jax.ShapeDtypeStruct((1, 1, 4), jnp.float32)
+    pred.params = jax.eval_shape(pred.model.init, jax.random.key(0), image,
+                                 boxes)["params"]
+    target = MeshPlan("tp2", devices=devices).group_targets[0]
+    pshard, repl = pred._sharded_shardings(target)
+    params = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        pred.params, pshard)
+    repl = NamedSharding(target.mesh, P())
+    return target.mesh, pred._get_sharded_fn(9, target), (
+        params, None,
+        jax.ShapeDtypeStruct(image.shape, image.dtype, sharding=repl),
+        jax.ShapeDtypeStruct(boxes.shape, boxes.dtype, sharding=repl))
+
+
+MESH_CASES = {"train_step_dp2": _mesh_case_train_step,
+              "serve_tp2": _mesh_case_serve_tp2}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_CASES))
+def test_partitioned_program_compiles_for_two_v5e_chips(
+    case, topo, one_chip, as_tpu
+):
+    """A program XLA partitions over more than one chip cannot hold a
+    Mosaic kernel ("Mosaic kernels cannot be automatically partitioned"),
+    and on a TPU the gates admit the kernels: such a program must be
+    traced with them off (``parallel.compat.partitioned``), record that
+    with cause ``partitioned``, and compile for the two chips."""
+    from tmr_tpu.diagnostics import drain_gate_refusals
+
+    drain_gate_refusals()
+    mesh, fn, args = MESH_CASES[case](topo.devices[:2])
+    jitted = inspect.unwrap(fn, stop=lambda f: hasattr(f, "lower"))
+    with jax.sharding.set_mesh(mesh):
+        compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text, "nothing was partitioned over the chips"
+    causes = {(r["gate"], r["cause"]) for r in drain_gate_refusals()}
+    assert ("flash_attention_ok", "partitioned") in causes
+    assert ("flash_window_ok", "partitioned") in causes
+
+
+@pytest.mark.parametrize("gate,args", [
+    ("pallas_fused_ok", (64, 64, 64, 512, 512)),
+    ("pallas_xcorr_ok", (512, 128, 128, 15)),
+])
+def test_retired_kernels_answer_no_with_the_compilers_words(
+    gate, args, as_tpu
+):
+    """The two kernels the chip's compiler refuses are not selectable on a
+    TPU: their gates answer no with a structured cause that carries the
+    compiler's message, never ``exception``."""
+    from tmr_tpu.diagnostics import drain_gate_refusals
+    from tmr_tpu.ops import pallas_attn, pallas_xcorr
+
+    drain_gate_refusals()
+    fn = {"pallas_fused_ok": pallas_attn.pallas_fused_ok,
+          "pallas_xcorr_ok": pallas_xcorr.pallas_xcorr_ok}[gate]
+    getattr(fn, "cache_clear", lambda: None)()
+    assert fn(*args) is False
+    getattr(fn, "cache_clear", lambda: None)()
+    (rec,) = drain_gate_refusals()
+    assert rec["gate"] == gate and rec["cause"] == "unsupported-shape"
+    assert "Mosaic" in rec["message"]

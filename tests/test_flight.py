@@ -496,10 +496,19 @@ def test_bench_trend_cli_one_line_and_rc(tmp_path):
     doc = json.loads(lines[0])
     assert validate_bench_trend(doc) == []
     assert doc["checks"]["regressed"] is True
-    # against the REAL repo history: must read without error and emit
-    # one valid line (rc 0 or 1 depending on the committed trajectory)
+    # a longer written history with an errored round in it: must read
+    # without error and emit one valid line (the driver's own records left
+    # the tree with the transport they were taken over)
+    hist = tmp_path / "history"
+    hist.mkdir()
+    for n, value in enumerate((1.2, 0.0, 10.1, 21.0, 21.1), start=1):
+        rec = {"n": n, "rc": 0, "parsed": {"value": value, "mfu": 0.1}}
+        if not value:
+            rec = {"n": n, "rc": 1, "parsed": None}
+        _write(hist / f"BENCH_r0{n}.json", rec)
     out = subprocess.run(
-        [sys.executable, os.path.join(repo, "scripts", "bench_trend.py")],
+        [sys.executable, os.path.join(repo, "scripts", "bench_trend.py"),
+         "--repo", str(hist)],
         capture_output=True, text=True, timeout=120,
     )
     doc = json.loads(out.stdout.strip().splitlines()[-1])

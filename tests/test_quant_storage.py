@@ -337,7 +337,13 @@ def test_mfu_report_weight_bytes_halved_and_roofline_flip():
     try:
         rng = np.random.default_rng(0)
         K = N = 512
-        M = 64
+        # M rows against a (K, N) weight: about 2M flops per weight
+        # element, over 4+2+2 bytes of it stored f32 (read, bf16 copy
+        # written, copy read: XLA:CPU does not fuse the convert into the
+        # dot) and 1+2+2 stored int8 — intensity ~M/4 against ~2M/5.
+        # The CPU row's ridge is 10 flops/byte, so 25 <= M < 40 puts the
+        # two storages on opposite sides of it.
+        M = 32
         w = jnp.asarray(rng.standard_normal((K, N)) * 0.05, jnp.float32)
         x = jnp.asarray(rng.standard_normal((M, K)), jnp.bfloat16)
 
@@ -376,6 +382,8 @@ def test_mfu_report_weight_bytes_halved_and_roofline_flip():
         # roofline verdict of this memory-bound shape
         assert pf["cost_source"] == "xla" and pi["cost_source"] == "xla"
         assert pi["bytes_per_call"] < pf["bytes_per_call"]
+        assert (pi["arithmetic_intensity"]
+                >= 1.4 * pf["arithmetic_intensity"])  # 8/5 less x and out
         assert pf["bound"] == "memory"
         assert pi["bound"] == "compute"
     finally:
@@ -530,7 +538,7 @@ def test_pallas_int8_matmul_interpret_matches_xla():
 def test_pallas_int8_gate_refuses_off_tpu_with_cause():
     from tmr_tpu.ops import pallas_int8 as pi8
 
-    pi8._OK_CACHE.clear()
+    pi8.pallas_int8_ok.cache_clear()
     assert not pi8.pallas_int8_ok()
     causes = drain_gate_refusals()
     assert causes and causes[-1]["gate"] == "pallas_int8_ok"

@@ -387,10 +387,9 @@ def test_devtime_divides_mfu_by_replica_group_size():
 # ----------------------------------------------- sharded program audit
 def test_program_audit_covers_sharded_backbone():
     """The shard_map dp serve variant is audited trace-only like every
-    production program: no f64, no host callbacks, and the per-platform
-    device_put pin (24 on the sam_vit_b trace — override via
-    analysis_baseline.json transfer_guard for an understood
-    constant-staging change)."""
+    production program: no f64, no host callbacks, and no device_put
+    equation (a host hop written into the program; override via
+    analysis_baseline.json transfer_guard for an understood one)."""
     from tmr_tpu.analysis.program_audit import audit_production_programs
 
     rec = audit_production_programs(
@@ -403,4 +402,36 @@ def test_program_audit_covers_sharded_backbone():
     assert audit["ok"], audit["problems"]
     assert audit["f64_eqns"] == 0
     assert audit["callbacks"] == 0
-    assert audit["transfer_pin"] == 24
+    assert audit["transfer_pin"] == 0
+
+
+def test_cached_subslice_tp_plan_is_refused_on_tpu(monkeypatch):
+    """On a TPU with the persistent compile cache on, a plan with a
+    multi-chip replica group that does not include the first device is an
+    error at engine construction (loaded from the cache, ``tp2`` on chips
+    2 and 3 halted them, on chips 0 and 1 it ran: PR 23); every other
+    case passes."""
+    import jax
+
+    from tmr_tpu.serve.meshplan import MeshPlan, refuse_cached_subslice_tp
+
+    devices = jax.devices()[:4]
+    both, dp_only = MeshPlan("dp2tp2", devices), MeshPlan("dp2", devices)
+    tp_first = MeshPlan("tp2", devices[:2])
+    tp_later = MeshPlan("tp2", devices[2:])
+    refuse_cached_subslice_tp(both)  # the CPU backend: nothing to refuse
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert jax.config.jax_compilation_cache_dir  # conftest turned it on
+    with pytest.raises(RuntimeError, match="group1 runs on chips"):
+        refuse_cached_subslice_tp(both)
+    with pytest.raises(RuntimeError, match="JAX_ENABLE_COMPILATION_CACHE"):
+        refuse_cached_subslice_tp(tp_later)
+    refuse_cached_subslice_tp(tp_first)
+    refuse_cached_subslice_tp(MeshPlan("tp4", devices))
+    refuse_cached_subslice_tp(dp_only)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        refuse_cached_subslice_tp(both)
+        refuse_cached_subslice_tp(tp_later)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)

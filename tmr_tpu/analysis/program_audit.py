@@ -20,8 +20,8 @@ no compile, no execute, no device memory):
   dequant arithmetic is pinned at f32 accumulation, and a stray f64
   dequant would both break the quant_ok bound and destroy the win.
 - **transfer-guard** — ``device_put`` equations per program are pinned
-  to the expected count (trace-time constant placement; a NEW one means
-  someone put a mid-program host hop into a hot path) and host
+  to the expected count (zero: one means someone put a mid-program host
+  hop into a hot path) and host
   callbacks (``pure_callback``/``io_callback``/``debug_callback``) must
   be ZERO — the rtt_floor regression mode. The device_put pin is
   per-platform (CPU constant staging differs from TPU), resolved
@@ -50,19 +50,16 @@ NO_S2_ATTN_IMPLS = ("blockwise", "blockfolded", "flash", "xlaflash",
 #: attention impls that trace without TPU hardware present; audited set
 DENSE_BY_DESIGN = ("densefolded",)
 
-#: expected trace-time ``device_put`` count per production program at
-#: the PRODUCTION backbone (sam_vit_b) — measured on the committed tree
-#: (they come from numpy constants the trace stages: the ViT rel-pos
-#: tables and norm stats; a resnet program stages none). Override per
-#: platform via analysis_baseline.json ``transfer_guard`` when a backend
-#: stages constants differently, or per call via ``transfer_pins`` when
-#: auditing a non-default backbone/geometry.
+#: expected trace-time ``device_put`` count per production program: none.
+#: JAX 0.9 embeds the numpy constants a trace stages (the ViT rel-pos
+#: tables, norm stats) as literals, so any ``device_put`` equation is a
+#: host hop somebody wrote into the program. Override per platform via
+#: analysis_baseline.json ``transfer_guard`` or per call via
+#: ``transfer_pins``.
 DEFAULT_TRANSFER_PINS: Dict[str, int] = {
-    "match_heads": 24,
-    "match_heads_dp": 24,  # the shard_map dp serve variant: same ViT
-    # constants staged inside the shard_map body — a drift from the
-    # unsharded pin means the sharded trace grew a host hop of its own
-    "backbone": 24,
+    "match_heads": 0,
+    "match_heads_dp": 0,
+    "backbone": 0,
     "heads_only": 0,
     "nms_topk": 0,
 }
@@ -162,9 +159,8 @@ def int8_reach_stats(jaxpr) -> dict:
     whole-body tainting elsewhere (scan/cond) — over-taint can only
     produce a false PASS for a program with int8 inputs feeding nothing,
     which ``int8_invars`` plus the dot counts make visible."""
-    from jax import core as _core
+    from jax.extend.core import Literal
 
-    Literal = _core.Literal
     top = getattr(jaxpr, "jaxpr", jaxpr)
     stats = {"int8_invars": 0, "dot_eqns": 0, "int8_fed_dots": 0,
              "int8_operand_dots": 0, "conv_eqns": 0,

@@ -55,6 +55,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from tmr_tpu.diagnostics import WINNER_BANK_SCHEMA, validate_winner_bank
+from tmr_tpu.utils.cache import STATE_DIR
 
 #: anomaly kinds that demote a live promotion (the HealthWatch /
 #: FleetHealthWatch vocabulary subset that reads "the formulation made
@@ -69,9 +70,7 @@ DEMOTE_ANOMALIES = (
 )
 
 #: default winner-bank location, next to the offline autotune cache
-BANK_PATH = os.path.join(
-    os.path.expanduser("~"), ".cache", "tmr_tpu", "winner_bank.json"
-)
+BANK_PATH = os.path.join(STATE_DIR, "winner_bank.json")
 
 #: ``Predictor._compiled`` program kinds each live-tunable knob can
 #: change: None = every program embeds the formulation (backbone attn,
@@ -135,7 +134,7 @@ def default_wins() -> int:
 # ------------------------------------------------------------ winner bank
 def bank_path() -> str:
     """Bank file location: ``TMR_LIVE_TUNE_BANK`` override, else
-    ``~/.cache/tmr_tpu/winner_bank.json``."""
+    ``<repo>/.tmr_cache/winner_bank.json``."""
     return os.environ.get("TMR_LIVE_TUNE_BANK") or BANK_PATH
 
 

@@ -217,8 +217,6 @@ ENV_KNOBS = {
     "TMR_BENCH_BATCH": "bench.py batch-size override",
     "TMR_BENCH_ALARM": "bench.py watchdog timeout seconds",
     "TMR_BENCH_STAGES": "bench.py per-stage tail timings (0 skips)",
-    "TMR_COMPILATION_CACHE": "persistent XLA compilation cache (0 opts "
-        "out)",
     # serving layer
     "TMR_SERVE_BATCH": "ServeEngine release-batch override",
     "TMR_SERVE_MAX_WAIT_MS": "ServeEngine micro-batch wait bound",
@@ -375,7 +373,7 @@ ENV_KNOBS = {
     "TMR_LIVE_TUNE_WINS": "continuous autotune: consecutive decisive "
         "(>10%) wins a candidate needs before promotion",
     "TMR_LIVE_TUNE_BANK": "continuous autotune: winner-bank file path "
-        "override (default ~/.cache/tmr_tpu/winner_bank.json)",
+        "override (default <repo>/.tmr_cache/winner_bank.json)",
     # bench.py driver knobs (consumed outside tmr_tpu/ but part of the
     # same surface; the parity test scans bench.py + scripts/ for these)
     "TMR_AUTOTUNE": "bench.py: run the autotune sweep (0 skips)",
@@ -384,8 +382,6 @@ ENV_KNOBS = {
     "TMR_AUTOTUNE_EXPORT": "bench.py: write elected winners as K=V lines",
     "TMR_BENCH_CHAIN": "bench.py: chained-iteration count override",
     "TMR_BENCH_CKPT": "bench.py: trained-checkpoint path to measure",
-    "TMR_BENCH_INIT_RETRIES": "bench.py: device-init retry count",
-    "TMR_BENCH_INIT_TIMEOUT": "bench.py: device-init timeout seconds",
     "TMR_BENCH_PROFILE": "bench.py: capture an xprof trace directory",
     "TMR_BENCH_SELFTEST_FAIL": "bench.py self-test: force a failed probe",
     "TMR_BENCH_SELFTEST_PRELIM": "bench.py self-test: force prelim emit",

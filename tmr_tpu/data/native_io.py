@@ -38,8 +38,13 @@ class _Item(ctypes.Structure):
 
 
 def ensure_built(quiet: bool = True) -> Optional[str]:
-    """Build libtmr_io.so if missing; returns its path or None (no g++)."""
-    if os.path.exists(_SO_PATH):
+    """Build libtmr_io.so when it is missing or older than tmr_io.cc;
+    returns its path, or None when the build fails (no g++, or a source
+    that no longer compiles — a stale library must not mask that)."""
+    src = os.path.join(_NATIVE_DIR, "tmr_io.cc")
+    if os.path.exists(_SO_PATH) and (
+        os.path.getmtime(_SO_PATH) >= os.path.getmtime(src)
+    ):
         return _SO_PATH
     try:
         subprocess.run(
@@ -49,7 +54,7 @@ def ensure_built(quiet: bool = True) -> Optional[str]:
         )
     except (OSError, subprocess.CalledProcessError):
         return None
-    return _SO_PATH if os.path.exists(_SO_PATH) else None
+    return _SO_PATH
 
 
 def _load():

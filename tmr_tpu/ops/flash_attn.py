@@ -35,6 +35,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from tmr_tpu.diagnostics import mosaic_gate
+
 
 def fold_rel_pos_into_qk(
     q: jnp.ndarray,
@@ -263,10 +265,7 @@ def xla_flash_decomposed_attention(
 
     Schedule: the q-band loop is a ROLLED lax.scan (blockwise's band
     structure — one compiled body); the k-block loop inside each band is a
-    STATIC UNROLL. Not an accident: a nested scan-in-scan whose inner xs
-    mix outer-trace constants with band tracers trips an UnexpectedTracer
-    bug under jax.ensure_compile_time_eval on jax 0.4.x (the gate's
-    execution context), and the unrolled inner body is also what lets XLA
+    STATIC UNROLL: the unrolled inner body is what lets XLA
     software-pipeline the next block's K/V fetch behind the current tile's
     compute — the measured TMR_GLOBAL_BANDS_UNROLL lesson applied here by
     construction.
@@ -302,10 +301,7 @@ def xla_flash_decomposed_attention(
         l = jnp.zeros((B, H, bq, 1), jnp.float32)
         acc = jnp.zeros((B, H, bq, v.shape[-1]), jnp.float32)
         for ikb in range(nkb):
-            # static slices of the RAW q/k/v arguments, not of a reshaped
-            # intermediate: a scan body may close over argument tracers
-            # (blockwise does), but closing over an intermediate leaks
-            # under the gate's ensure_compile_time_eval on jax 0.4.x
+            # static slices of the raw q/k/v arguments
             kb = k[:, :, ikb * bk:(ikb + 1) * bk]
             s = jnp.einsum(
                 "bhqd,bhkd->bhqk", qb, kb,
@@ -334,9 +330,7 @@ def xla_flash_decomposed_attention(
             m = m_new
         return (acc / l).astype(work)
 
-    # scan, not lax.map: same rolled schedule, but lax.map's internal
-    # dispatch leaks tracers under the gate's ensure_compile_time_eval on
-    # jax 0.4.x where this scan spelling (blockwise's) does not
+    # the same rolled scan spelling as blockwise
     out = jax.lax.scan(
         lambda c, x: (c, one_band(x)), (),
         (q_blocks, rel_h_blocks, rel_w_blocks),
@@ -374,9 +368,9 @@ def _self_check(
     apply — there is no kernel to kill, only numerics to pin.
 
     Callers invoke this while TRACING the model (Attention.__call__ only
-    ever runs under jit), so the whole check runs under
-    ``jax.ensure_compile_time_eval()`` — concrete values, real compiled
-    executions, no leakage into the ambient trace.
+    ever runs under jit), so the whole check runs outside the ambient trace
+    (``diagnostics.run_outside_trace``) — concrete values, real compiled
+    executions, the Pallas kernels' included.
 
     Every refusal records a STRUCTURED cause (diagnostics.record_gate_
     refusal: category, swallowed exception class + message, the gate's
@@ -418,88 +412,69 @@ def _self_check(
         if jax.default_backend() != "tpu":
             return _refused(f"backend {jax.default_backend()!r} != 'tpu'",
                             cause="backend")
-    import contextlib
-
     import numpy as np
 
+    from tmr_tpu.diagnostics import run_outside_trace
     from tmr_tpu.models.vit import blockwise_decomposed_attention
 
-    # ensure_compile_time_eval exists to keep the check's concrete values
-    # out of an AMBIENT trace (Attention.__call__ runs under jit). At top
-    # level (tests, gate_probe, the autotune sweeps between traces) it must
-    # NOT be entered: on jax 0.4.x it switches jit to eager trace-eval,
-    # where lax.scan's output stacking hits "Evaluation rule for 'empty'
-    # not implemented" — which silently turned EVERY scan-based gate
-    # (blockfolded/densefolded/xlaflash) into a constant False off-trace.
-    # When the introspection API is missing (future jax), default to
-    # entering it — the prior behavior, and harmless where the eval bug
-    # is fixed.
-    _clean = getattr(jax.core, "trace_state_clean", None)
-    ect = (
-        contextlib.nullcontext()
-        if _clean is not None and _clean()
-        else jax.ensure_compile_time_eval()
-    )
-    try:
-        with ect:
-            rng = np.random.default_rng(0)
-            S = gh * gw
-            q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.bfloat16)
-            k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.bfloat16)
-            v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.bfloat16)
-            rh = jnp.asarray(
-                rng.standard_normal((gh, gh, D)) * 0.2, jnp.float32
+    def check() -> bool:
+        rng = np.random.default_rng(0)
+        S = gh * gw
+        q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.bfloat16)
+        k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.bfloat16)
+        v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.bfloat16)
+        rh = jnp.asarray(rng.standard_normal((gh, gh, D)) * 0.2, jnp.float32)
+        rw = jnp.asarray(rng.standard_normal((gw, gw, D)) * 0.2, jnp.float32)
+        scale = D**-0.5
+        got = jax.jit(lambda *a: attn_fn(*a, (gh, gw), scale))(
+            q, k, v, rh, rw
+        )
+        want = jax.jit(
+            lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
+        )(q, k, v, rh, rw)
+        err = np.abs(
+            np.asarray(got, np.float32) - np.asarray(want, np.float32)
+        ).max()
+        scale_ref = np.abs(np.asarray(want, np.float32)).max() + 1e-6
+        # NOTE: comparisons are phrased as ``not (diff < tol)`` so a NaN
+        # (classic Mosaic-miscompile symptom) REJECTS — ``diff >= tol``
+        # would let NaN through, since both comparisons are False on NaN
+        if not (err / scale_ref < 0.05):
+            return _refused(
+                f"forward rel err {err / scale_ref:.4g} >= 0.05",
+                cause="forward-mismatch",
             )
-            rw = jnp.asarray(
-                rng.standard_normal((gw, gw, D)) * 0.2, jnp.float32
+
+        # the TRAIN step differentiates through whichever path is
+        # active, and a backward-pass Mosaic failure would otherwise
+        # surface unguarded inside the train trace — so the gate also
+        # compiles and compares gradients w.r.t. q/k/v
+        def loss_of(fn):
+            return lambda *a: jnp.sum(
+                fn(*a, rh, rw, (gh, gw), scale).astype(jnp.float32) ** 2
             )
-            scale = D**-0.5
-            got = jax.jit(lambda *a: attn_fn(*a, (gh, gw), scale))(
-                q, k, v, rh, rw
+
+        g_got = jax.jit(jax.grad(loss_of(attn_fn), argnums=(0, 1, 2)))(
+            q, k, v
+        )
+        g_want = jax.jit(
+            jax.grad(
+                loss_of(blockwise_decomposed_attention), argnums=(0, 1, 2)
             )
-            want = jax.jit(
-                lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-            )(q, k, v, rh, rw)
-            err = np.abs(
-                np.asarray(got, np.float32) - np.asarray(want, np.float32)
-            ).max()
-            scale_ref = np.abs(np.asarray(want, np.float32)).max() + 1e-6
-            # NOTE: comparisons are phrased as ``not (diff < tol)`` so a NaN
-            # (classic Mosaic-miscompile symptom) REJECTS — ``diff >= tol``
-            # would let NaN through, since both comparisons are False on NaN
-            if not (err / scale_ref < 0.05):
+        )(q, k, v)
+        for i, (a, b) in enumerate(zip(g_got, g_want)):
+            a = np.asarray(a, np.float32)
+            b = np.asarray(b, np.float32)
+            rel = np.abs(a - b).max() / (np.abs(b).max() + 1e-6)
+            if not (rel < 0.05):
                 return _refused(
-                    f"forward rel err {err / scale_ref:.4g} >= 0.05",
-                    cause="forward-mismatch",
+                    f"grad arg {i} rel err {rel:.4g} >= 0.05",
+                    cause="grad-mismatch",
                 )
+        return True
 
-            # the TRAIN step differentiates through whichever path is
-            # active, and a backward-pass Mosaic failure would otherwise
-            # surface unguarded inside the train trace — so the gate also
-            # compiles and compares gradients w.r.t. q/k/v
-            def loss_of(fn):
-                return lambda *a: jnp.sum(
-                    fn(*a, rh, rw, (gh, gw), scale).astype(jnp.float32) ** 2
-                )
-
-            g_got = jax.jit(jax.grad(loss_of(attn_fn), argnums=(0, 1, 2)))(
-                q, k, v
-            )
-            g_want = jax.jit(
-                jax.grad(
-                    loss_of(blockwise_decomposed_attention), argnums=(0, 1, 2)
-                )
-            )(q, k, v)
-            for i, (a, b) in enumerate(zip(g_got, g_want)):
-                a = np.asarray(a, np.float32)
-                b = np.asarray(b, np.float32)
-                rel = np.abs(a - b).max() / (np.abs(b).max() + 1e-6)
-                if not (rel < 0.05):
-                    return _refused(
-                        f"grad arg {i} rel err {rel:.4g} >= 0.05",
-                        cause="grad-mismatch",
-                    )
-            return True
+    try:
+        return run_outside_trace(check)
     except Exception as e:
         if os.environ.get("TMR_GATE_DEBUG"):
             import traceback
@@ -545,7 +520,7 @@ def densefolded_ok(
                        config={"scores": scores})
 
 
-@functools.lru_cache(maxsize=None)
+@mosaic_gate
 def flash_window_ok(gh: int, gw: int, head_dim: int) -> bool:
     """Per-geometry compiled self-check of the windowed flash path — the
     caller passes the ACTUAL window grid and head dim it is about to run
@@ -555,7 +530,7 @@ def flash_window_ok(gh: int, gw: int, head_dim: int) -> bool:
                        gate="flash_window_ok")
 
 
-@functools.lru_cache(maxsize=None)
+@mosaic_gate
 def flash_attention_ok(
     gh: int = 64, gw: int = 64, head_dim: int = 64
 ) -> bool:

@@ -43,17 +43,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tmr_tpu.diagnostics import mosaic_gate
+
 _NEG_INF = -1e30
-
-
-def _tpu_compiler_params(dimension_semantics: Tuple[str, ...]):
-    """pltpu.CompilerParams across jax versions: renamed from
-    TPUCompilerParams in newer releases. The old name must keep working —
-    on jax 0.4.x the new-name AttributeError made every pallas_call here
-    raise at trace time, which the gates dutifully (and silently, before
-    the structured diagnostics) converted into a permanent fallback."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=dimension_semantics)
 
 
 def _attn_kernel(
@@ -314,8 +306,8 @@ def _pallas_attn_fwd_impl(q, k, v, rh, rw, grid_hw, scale):
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=jax.default_backend() != "tpu",
     )(*inputs)
@@ -445,7 +437,9 @@ def _pallas_win_fwd_impl(q, k, v, rh, rw, grid_hw, scale):
         ],
         out_specs=pl.BlockSpec((g, s_pad, D), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, D), q.dtype),
-        compiler_params=_tpu_compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=jax.default_backend() != "tpu",
     )(
         qp.reshape(bh, s_pad, D), kp.reshape(bh, s_pad, D),
@@ -475,7 +469,7 @@ def _win_vjp_bwd(grid_hw, scale, res, g):
 _pallas_win_vjp.defvjp(_win_vjp_fwd, _win_vjp_bwd)
 
 
-@functools.lru_cache(maxsize=None)
+@mosaic_gate
 def pallas_window_ok(
     gh: int, gw: int, head_dim: int, group: int = 1
 ) -> bool:
@@ -495,7 +489,7 @@ def pallas_window_ok(
     )
 
 
-@functools.lru_cache(maxsize=None)
+@mosaic_gate
 def pallas_global_ok(
     gh: int, gw: int, head_dim: int, bq: int, bk: int
 ) -> bool:
@@ -570,6 +564,20 @@ _pallas_attn_vjp.defvjp(_vjp_fwd, _vjp_bwd)
 # exactly the rk bias columns this tile needs — the "(q, k) index offsets"
 # are the block indices themselves.
 # --------------------------------------------------------------------------
+#: Retired from what a TPU can select (PR 23): the chip's compiler takes
+#: neither the (1, bq, rk) bias strip ("the last two dimensions of your
+#: block shape [must be] divisible by 8 and 128 respectively, or be equal
+#: to the respective dimensions of the overall array": rk is 8 of gh 64)
+#: nor, with the strip laid out to satisfy that, the kernel's one idea —
+#: the (bq, bk) -> (bq, rk, gw) view that splits the lane axis below 128.
+#: The kernel stays for the interpreter tests; ROADMAP Design item 2 decides
+#: whether it is rewritten or deleted.
+_FUSED_MOSAIC_REFUSAL = (
+    "Mosaic (jaxlib 0.9.0, v5e): infer-vector-layout: unsupported shape "
+    "cast — tpu.reshape vector<512x512xf32> -> vector<512x8x64xf32>"
+)
+
+
 def _fused_attn_kernel(
     q_ref, k_ref, v_ref, rhq_ref, rwq_ref, out_ref,
     m_ref, l_ref, acc_ref,
@@ -686,8 +694,8 @@ def _pallas_fused_fwd_impl(q, k, v, rh, rw, grid_hw, scale):
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=jax.default_backend() != "tpu",
     )(
@@ -718,7 +726,7 @@ def _fused_vjp_bwd(grid_hw, scale, res, g):
 _pallas_fused_vjp.defvjp(_fused_vjp_fwd, _fused_vjp_bwd)
 
 
-@functools.lru_cache(maxsize=None)
+@mosaic_gate
 def pallas_fused_ok(
     gh: int, gw: int, head_dim: int, bq: int, bk: int
 ) -> bool:
@@ -730,5 +738,13 @@ def pallas_fused_ok(
     with a structured cause, not in the model trace."""
     from tmr_tpu.ops.flash_attn import _self_check
 
+    if jax.default_backend() == "tpu":
+        from tmr_tpu.diagnostics import gate_refused
+
+        return gate_refused(
+            "pallas_fused_ok", _FUSED_MOSAIC_REFUSAL, "unsupported-shape",
+            config={"gh": gh, "gw": gw, "head_dim": head_dim,
+                    "bq": bq, "bk": bk},
+        )
     return _self_check(pallas_fused_attention, 1, 2, gh, gw, head_dim,
                        gate="pallas_fused_ok", config={"bq": bq, "bk": bk})

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tmr_tpu.diagnostics import gate_refused, mosaic_gate, run_outside_trace
+
 #: MXU-shaped tiles: 128-lane aligned in every dimension. K tiles of 256
 #: keep the int8 operand blocks at 32 KB each; the int32 accumulator
 #: scratch is block_m x block_n x 4 bytes (64 KB at the defaults).
@@ -118,9 +120,7 @@ def int8_matmul(x_q, w_q, x_scale, w_scale,
     return out[:m, :n]
 
 
-_OK_CACHE: dict = {}
-
-
+@mosaic_gate
 def pallas_int8_ok(m: int = 256, k: int = 256, n: int = 256) -> bool:
     """Compiled self-check of the Mosaic int8 kernel against the XLA
     int8 dot at a small MXU-aligned shape. Any exception or disagreement
@@ -128,8 +128,6 @@ def pallas_int8_ok(m: int = 256, k: int = 256, n: int = 256) -> bool:
     rounding) refuses with a recorded cause; off-TPU refuses with
     cause "backend" like every Mosaic gate. TMR_NO_PALLAS_INT8=1
     force-disables."""
-    from tmr_tpu.diagnostics import gate_refused
-
     cfg = {"M": m, "K": k, "N": n}
     if os.environ.get("TMR_NO_PALLAS_INT8"):
         return gate_refused("pallas_int8_ok",
@@ -141,41 +139,35 @@ def pallas_int8_ok(m: int = 256, k: int = 256, n: int = 256) -> bool:
             f"backend {jax.default_backend()!r} != 'tpu'", "backend",
             config=cfg,
         )
-    key = (m, k, n)
-    if key in _OK_CACHE:
-        return _OK_CACHE[key]
     import numpy as np
 
-    ok = False
+    def check() -> float:
+        rng = np.random.default_rng(0)
+        xq = jnp.asarray(rng.integers(-127, 128, (m, k)), jnp.int8)
+        wq = jnp.asarray(rng.integers(-127, 128, (k, n)), jnp.int8)
+        sx = jnp.asarray(rng.random((m, 1)) * 0.01 + 1e-4, jnp.float32)
+        sw = jnp.asarray(rng.random((1, n)) * 0.01 + 1e-4, jnp.float32)
+        got = np.asarray(int8_matmul(xq, wq, sx, sw, interpret=False))
+        want = np.asarray(
+            jax.lax.dot_general(
+                xq, wq, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            ).astype(jnp.float32) * (sx * sw)
+        )
+        return float(np.abs(got - want).max() / (np.abs(want).max() + 1e-6))
+
     try:
-        with jax.ensure_compile_time_eval():
-            rng = np.random.default_rng(0)
-            xq = jnp.asarray(rng.integers(-127, 128, (m, k)), jnp.int8)
-            wq = jnp.asarray(rng.integers(-127, 128, (k, n)), jnp.int8)
-            sx = jnp.asarray(rng.random((m, 1)) * 0.01 + 1e-4, jnp.float32)
-            sw = jnp.asarray(rng.random((1, n)) * 0.01 + 1e-4, jnp.float32)
-            got = np.asarray(int8_matmul(xq, wq, sx, sw, interpret=False))
-            want = np.asarray(
-                jax.lax.dot_general(
-                    xq, wq, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                ).astype(jnp.float32) * (sx * sw)
-            )
-            rel = float(
-                np.abs(got - want).max() / (np.abs(want).max() + 1e-6)
-            )
-            ok = rel < 1e-6
-            if not ok:
-                gate_refused("pallas_int8_ok",
-                             f"rel err {rel:.4g} >= 1e-6",
-                             "forward-mismatch", config=cfg)
+        rel = run_outside_trace(check)
+        ok = rel < 1e-6
+        if not ok:
+            gate_refused("pallas_int8_ok", f"rel err {rel:.4g} >= 1e-6",
+                         "forward-mismatch", config=cfg)
     except Exception as e:
         if os.environ.get("TMR_GATE_DEBUG"):
             import traceback
 
             traceback.print_exc()
-        gate_refused("pallas_int8_ok", f"{type(e).__name__}: {e}",
-                     "exception", config=cfg, exception=type(e).__name__)
-        ok = False
-    _OK_CACHE[key] = ok
+        ok = gate_refused("pallas_int8_ok", f"{type(e).__name__}: {e}",
+                          "exception", config=cfg,
+                          exception=type(e).__name__)
     return ok

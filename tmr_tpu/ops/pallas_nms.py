@@ -28,54 +28,71 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tmr_tpu.diagnostics import mosaic_gate
 
-def _nms_kernel(boxes_ref, valid_ref, thr_ref, keep_ref):
-    """boxes (N, 4) score-sorted; valid (N,) int32; keep (N,) int32 out."""
-    n = boxes_ref.shape[0]
-    x1 = boxes_ref[:, 0]
-    y1 = boxes_ref[:, 1]
-    x2 = boxes_ref[:, 2]
-    y2 = boxes_ref[:, 3]
+
+def _nms_kernel(thr_ref, rows_ref, boxes_ref, valid_ref, keep_ref, *,
+                n_real: int):
+    """Score-sorted boxes twice: coordinate-major and lane-dense (4, R, 128)
+    for the N-wide vector work, and row-major (R * 128, 4) for the per-step
+    scalar reads; valid/keep (R, 128) int32.
+
+    Box i's liveness is a masked reduction over the keep vector: a dynamic
+    scalar load from a 1-D VMEM ref is what Mosaic refuses ("cannot
+    statically prove that index in dimension 0 is a multiple of 1024"),
+    while a dynamic row of a 2-D ref lowers.
+    """
+    r = valid_ref.shape[0]
+    x1 = boxes_ref[0]
+    y1 = boxes_ref[1]
+    x2 = boxes_ref[2]
+    y2 = boxes_ref[3]
     area = jnp.maximum(x2 - x1, 0.0) * jnp.maximum(y2 - y1, 0.0)
     thr = thr_ref[0]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
+    idx = (
+        jax.lax.broadcasted_iota(jnp.int32, (r, 128), 0) * 128
+        + jax.lax.broadcasted_iota(jnp.int32, (r, 128), 1)
+    )
 
-    keep_ref[:] = valid_ref[:]
+    keep_ref[...] = valid_ref[...]
 
     def body(i, _):
         # IoU of box i against every box (vectorized over lanes)
-        bx1 = boxes_ref[i, 0]
-        by1 = boxes_ref[i, 1]
-        bx2 = boxes_ref[i, 2]
-        by2 = boxes_ref[i, 3]
+        bx1 = rows_ref[i, 0]
+        by1 = rows_ref[i, 1]
+        bx2 = rows_ref[i, 2]
+        by2 = rows_ref[i, 3]
         barea = jnp.maximum(bx2 - bx1, 0.0) * jnp.maximum(by2 - by1, 0.0)
         iw = jnp.maximum(jnp.minimum(x2, bx2) - jnp.maximum(x1, bx1), 0.0)
         ih = jnp.maximum(jnp.minimum(y2, by2) - jnp.maximum(y1, by1), 0.0)
         inter = iw * ih
         iou = inter / jnp.maximum(area + barea - inter, 1e-12)
 
-        alive = keep_ref[i] > 0
+        keep = keep_ref[...]
+        alive = jnp.max(jnp.where(idx == i, keep, 0)) > 0
         suppress = alive & (idx > i) & (iou > thr)
-        keep_ref[:] = jnp.where(suppress, 0, keep_ref[:])
+        keep_ref[...] = jnp.where(suppress, 0, keep)
         return 0
 
-    jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, n_real, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _run_nms_kernel(boxes, valid, thr, interpret: bool = False):
-    n = boxes.shape[0]
+@functools.partial(jax.jit, static_argnames=("n_real", "interpret"))
+def _run_nms_kernel(boxes, valid, thr, n_real: int, interpret: bool = False):
+    """boxes (R * 128, 4) f32, valid (R, 128) int32 -> keep (R, 128) int32;
+    the first ``n_real`` slots are real, the rest padding."""
     return pl.pallas_call(
-        _nms_kernel,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        functools.partial(_nms_kernel, n_real=n_real),
+        out_shape=jax.ShapeDtypeStruct(valid.shape, jnp.int32),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(boxes, valid, thr)
+    )(thr, boxes, boxes.T.reshape(4, -1, 128), valid)
 
 
 def nms_keep_mask_pallas(
@@ -86,7 +103,8 @@ def nms_keep_mask_pallas(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Drop-in replacement for ops/nms.py nms_keep_mask (same semantics,
-    same original-order output). ``interpret`` defaults to True off-TPU."""
+    same original-order output). ``interpret`` defaults to True off-TPU
+    (the CPU tests) and is never chosen on a TPU backend."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = boxes.shape[0]
@@ -96,15 +114,18 @@ def nms_keep_mask_pallas(
     order = jnp.argsort(-sort_scores)
     b = boxes[order].astype(jnp.float32)
     v = valid[order].astype(jnp.int32)
-    # pad rows to a lane multiple (128): VMEM vectors with ragged trailing
-    # sizes are a classic Mosaic failure mode; padded slots are valid=0 so
+    # pad to whole (8, 128) int32/f32 vregs; padded slots are valid=0 so
     # they neither suppress nor survive
-    pad = (-n) % 128
+    pad = (-n) % 1024
     if pad:
         b = jnp.pad(b, ((0, pad), (0, 0)))
         v = jnp.pad(v, (0, pad))
     thr = jnp.asarray([iou_threshold], jnp.float32)
-    keep_sorted = _run_nms_kernel(b, v, thr, interpret=interpret)[:n] > 0
+    keep = _run_nms_kernel(
+        b, v.reshape(-1, 128), thr, n_real=n,
+        interpret=interpret,
+    )
+    keep_sorted = keep.reshape(-1)[:n] > 0
     return jnp.zeros((n,), bool).at[order].set(keep_sorted)
 
 
@@ -179,21 +200,29 @@ def nms_topk(
     }
 
 
-@functools.lru_cache(maxsize=1)
+@mosaic_gate
 def pallas_nms_compiled_ok() -> bool:
     """One-time self-check of the *compiled* kernel on this backend.
 
     Runs a small randomized case (N deliberately not a lane multiple) through
     the compiled Pallas kernel and the XLA fixpoint (ops/nms.py) and compares
-    keep decisions. Any exception (Mosaic lowering, VMEM indexing) or any
-    mismatch returns False so callers can fall back to the XLA path instead
-    of crashing — or silently mis-suppressing — the default TPU eval path.
+    keep decisions. A refusal — wrong backend, a Mosaic lowering error, a
+    mismatch — is recorded with its structured cause
+    (diagnostics.record_gate_refusal) and returns False, so ``auto`` callers
+    take the XLA path and the cause stays visible.
     """
     import numpy as np
 
+    from tmr_tpu.diagnostics import gate_refused, run_outside_trace
     from tmr_tpu.ops.nms import nms_keep_mask
 
-    try:
+    if jax.default_backend() != "tpu":
+        return gate_refused(
+            "pallas_nms_compiled_ok",
+            f"backend {jax.default_backend()!r} != 'tpu'", "backend",
+        )
+
+    def check() -> bool:
         rng = np.random.default_rng(0)
         n = 150  # not a multiple of 128 -> exercises the padding path
         xy = rng.uniform(0.0, 0.8, (n, 2)).astype(np.float32)
@@ -204,5 +233,16 @@ def pallas_nms_compiled_ok() -> bool:
         got = nms_keep_mask_pallas(boxes, scores, 0.5, valid, interpret=False)
         want = nms_keep_mask(boxes, scores, 0.5, valid)
         return bool(jnp.array_equal(got, want))
-    except Exception:
-        return False
+
+    try:
+        if run_outside_trace(check):
+            return True
+    except Exception as e:
+        return gate_refused(
+            "pallas_nms_compiled_ok", f"{type(e).__name__}: {e}",
+            "exception", exception=type(e).__name__,
+        )
+    return gate_refused(
+        "pallas_nms_compiled_ok",
+        "keep decisions differ from the XLA fixpoint", "forward-mismatch",
+    )

@@ -19,9 +19,10 @@ Scope: small-capacity buckets only (T <= MAX_UNROLL_T); the unroll count is
 T^2, and capacities above the cap fall back to the conv lowering in the
 dispatcher (the >65 buckets take the FFT path anyway, ops/xcorr.py).
 
-Runs compiled on TPU behind a per-geometry compiled self-check with
-fallback (the flash_attn.py pattern); ``interpret=True`` (automatic
-off-TPU) keeps CPU tests honest.
+Not selectable on a TPU today: the chip's compiler refuses the kernel
+(``_MOSAIC_REFUSAL`` below), so ``pallas_xcorr_ok`` answers no there with
+that cause. ``interpret=True`` (automatic off-TPU) keeps the CPU tests of
+the kernel's semantics honest.
 """
 
 from __future__ import annotations
@@ -42,6 +43,20 @@ MAX_UNROLL_T = 33
 #: the padded feature plus the CB*H*W f32 accumulator — 8 keeps the worst
 #: production shape (H=W=192, T=33) near 2.5 MB, well inside VMEM.
 _CB = 8
+
+
+#: Retired from what a TPU can select (PR 23): the chip's compiler refuses
+#: the per-channel template broadcast at every shape. Read per channel as
+#: scalars instead, the kernel lowers, but at the production shape
+#: (C 512, 128x128, T 15) its T^2 * CB unroll takes 234 s to compile and
+#: overruns VMEM ("Scoped allocation with size 21.68M and limit 16.00M").
+#: The kernel stays for the interpreter tests; ROADMAP Design item 2
+#: decides whether it is rewritten or deleted.
+_MOSAIC_REFUSAL = (
+    "Mosaic (jaxlib 0.9.0, v5e): Not implemented: Broadcast in both "
+    "sublanes and lanes — vector.broadcast vector<8x1x1xf32> -> "
+    "vector<8x128x128xf32>"
+)
 
 
 def _xcorr_kernel(fpad_ref, tmpl_ref, out_ref, *, T: int, H: int, W: int):
@@ -105,35 +120,17 @@ def xcorr_pallas(
     return _run_xcorr(fpad, template, interpret=interpret)
 
 
-_OK_CACHE: dict = {}
-
-
 def pallas_xcorr_ok(C: int, H: int, W: int, T: int) -> bool:
-    """Per-geometry compiled self-check with conv-path cross-check.
+    """Whether the dispatcher may run the kernel at (C, H, W, T): never
+    today. Every refusal records its structured cause — kill-switch
+    (TMR_NO_PALLAS_XCORR=1), capacity above the unroll cap, wrong backend,
+    and on a TPU the compiler's own refusal (``_MOSAIC_REFUSAL``) — and the
+    dispatcher falls back to the conv lowering."""
+    from tmr_tpu.diagnostics import gate_refused
 
-    Callers pass the actual (C, H, W, T) about to run. Reduced only in
-    batch/channels (block geometry is what Mosaic failures key on): the
-    check runs B=1 with one channel block. Any exception or disagreement
-    beyond f32 tolerance -> False (dispatcher falls back to the conv
-    lowering). TMR_NO_PALLAS_XCORR=1 force-disables.
-    """
-    def _refused(
-        reason: str, cause: str = "exception", exception=None
-    ) -> bool:
-        from tmr_tpu.diagnostics import record_gate_refusal
-
-        record_gate_refusal(
-            "pallas_xcorr_ok", cause, message=reason, exception=exception,
-            config={"C": C, "H": H, "W": W, "T": T},
-        )
-        if os.environ.get("TMR_GATE_DEBUG"):
-            import sys
-
-            print(
-                f"[gate] xcorr_pallas C{C} {H}x{W} T{T}: refused — {reason}",
-                file=sys.stderr,
-            )
-        return False
+    def _refused(reason: str, cause: str) -> bool:
+        return gate_refused("pallas_xcorr_ok", reason, cause,
+                            config={"C": C, "H": H, "W": W, "T": T})
 
     if os.environ.get("TMR_NO_PALLAS_XCORR"):
         return _refused("TMR_NO_PALLAS_XCORR kill-switch",
@@ -144,48 +141,4 @@ def pallas_xcorr_ok(C: int, H: int, W: int, T: int) -> bool:
     if jax.default_backend() != "tpu":
         return _refused(f"backend {jax.default_backend()!r} != 'tpu'",
                         cause="backend")
-    cb = _CB if C % _CB == 0 else 1
-    key = (cb, H, W, T)
-    if key in _OK_CACHE:
-        return _OK_CACHE[key]
-    import numpy as np
-
-    from jax import lax
-
-    try:
-        with jax.ensure_compile_time_eval():
-            rng = np.random.default_rng(0)
-            f = jnp.asarray(
-                rng.standard_normal((1, cb, H, W)), jnp.float32
-            )
-            t = jnp.asarray(
-                rng.standard_normal((1, cb, T, T)), jnp.float32
-            )
-            got = np.asarray(xcorr_pallas(f, t, interpret=False))
-            want = np.asarray(
-                lax.conv_general_dilated(
-                    f.reshape(1, cb, H, W),
-                    t.reshape(cb, 1, T, T),
-                    window_strides=(1, 1),
-                    padding=[(T // 2, T // 2), (T // 2, T // 2)],
-                    feature_group_count=cb,
-                    dimension_numbers=("NCHW", "OIHW", "NCHW"),
-                    precision=lax.Precision.HIGHEST,
-                )
-            )
-            scale = np.abs(want).max() + 1e-6
-            rel = np.abs(got - want).max() / scale
-            ok = bool(rel < 5e-5)
-            if not ok:
-                _refused(f"rel err {rel:.4g} >= 5e-5",
-                         cause="forward-mismatch")
-    except Exception as e:
-        if os.environ.get("TMR_GATE_DEBUG"):
-            import traceback
-
-            traceback.print_exc()
-        _refused(f"{type(e).__name__}: {e}", cause="exception",
-                 exception=type(e).__name__)
-        ok = False
-    _OK_CACHE[key] = ok
-    return ok
+    return _refused(_MOSAIC_REFUSAL, cause="unsupported-shape")

@@ -204,15 +204,6 @@ def _xcorr_int8dot(feature: jnp.ndarray,
     return acc.astype(jnp.float32) * (fs * ts)
 
 
-def _ambient_abstract_mesh():
-    """jax-version compat: ``jax.sharding.get_abstract_mesh`` is absent on
-    jax 0.4.x (the ``_tpu_compiler_params`` situation again). No accessor
-    means no ambient abstract mesh can exist — return None so the unsharded
-    compute path runs, exactly what new jax reports outside ``set_mesh``."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
-
-
 def _data_shard_map(fn, mesh):
     """Wrap the correlation compute in a per-device island over 'data'.
 
@@ -492,7 +483,7 @@ def cross_correlation(
             preferred_element_type=acc,
         ).reshape(b, C, H, W).astype(in_dtype)
 
-    am = _ambient_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if (
         impl != "fft"  # the FFT path has no group-merge; partitions cleanly
         and am is not None

@@ -1,39 +1,44 @@
-"""jax-version compat for the sharding surface (the
-``_tpu_compiler_params`` situation applied to ``shard_map``).
-
-jax moved ``shard_map`` out of ``jax.experimental`` into the top-level
-namespace and renamed its replication-check kwarg ``check_rep`` ->
-``check_vma`` along the way. The parallel modules and their tests target
-the new spelling; on a 0.4.x runtime the top-level import fails and the
-new kwarg is unknown — which is exactly how tests/test_ring.py carried a
-collection error from the seed until this shim. One definition here so
-every caller (ring, pipeline, xcorr's data island, the tests) resolves
-the API the same way on every installed jax.
-"""
+"""The sharding surface's helpers: ``shard_map`` with the argument order
+every parallel module here uses, :func:`partitioned` — the one place that
+decides a program is XLA's to partition and so can hold no Mosaic kernel —
+and the one compile seam of the sharded serving programs."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: the supported top-level export
-    from jax import shard_map as _shard_map
+import jax
 
-    _NEW_API = True
-except ImportError:  # jax 0.4.x/0.5.x: the experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NEW_API = False
+from tmr_tpu.diagnostics import mosaic_kernels_off
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the new-API signature on every jax.
+    """``jax.shard_map`` with ``mesh`` positional. ``check_vma`` toggles
+    the static varying-manual-axes check that several of our islands
+    disable (collectives whose replication the checker cannot prove)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
-    ``check_vma`` maps onto the old API's ``check_rep`` (same meaning,
-    renamed): both toggle the static replication/varying-manual-axes
-    check that several of our islands disable (collectives whose
-    replication the checker cannot prove).
-    """
-    kw = {"check_vma" if _NEW_API else "check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+
+def partitioned(f, mesh):
+    """``f`` as the body (or the jitted callable) of a program XLA
+    partitions over ``mesh`` by itself (GSPMD: a ``jax.jit`` whose
+    arguments or ``in_shardings``/``out_shardings`` span the mesh).
+
+    Over more than one device JAX refuses to lower a Pallas TPU kernel
+    into such a program ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map."), so ``f`` then
+    runs with the Mosaic gates answering no, cause "partitioned"
+    (``diagnostics.mosaic_kernels_off``), and its trace takes the XLA
+    formulations. On one device, or with no mesh, ``f`` comes back as it
+    is. Every GSPMD program of the repo — the tensor-parallel serving
+    targets below, the trainer's step and eval programs — is built
+    through here; a ``shard_map`` whose axes are all manual is not XLA's
+    to partition and keeps its kernels."""
+    if mesh is None or mesh.size == 1:
+        return f
+    return mosaic_kernels_off(
+        "GSPMD-partitioned program: Mosaic kernels cannot be "
+        "automatically partitioned"
+    )(f)
 
 
 def compile_sharded(f, mesh, *, in_shardings=None, out_shardings=None,
@@ -41,7 +46,8 @@ def compile_sharded(f, mesh, *, in_shardings=None, out_shardings=None,
     """One compile seam for the sharded serving programs (the SNIPPETS.md
     compile-helper pattern): explicit shardings -> ``jax.jit`` with
     ``in_shardings``/``out_shardings`` (the pjit/GSPMD path — XLA derives
-    the tensor-parallel collectives from the param specs), plain
+    the tensor-parallel collectives from the param specs; traced through
+    :func:`partitioned`), plain
     PartitionSpecs -> :func:`shard_map` over the mesh wrapped in jit (the
     pure data-parallel map path, whose per-shard trace IS the unsharded
     program body — the serving tier's bitwise-exactness lever).
@@ -49,8 +55,6 @@ def compile_sharded(f, mesh, *, in_shardings=None, out_shardings=None,
     Exactly one of the two spec families must be given; mixing them is a
     caller bug, refused loudly.
     """
-    import jax
-
     use_pjit = in_shardings is not None or out_shardings is not None
     use_smap = in_specs is not None or out_specs is not None
     if use_pjit == use_smap:
@@ -64,7 +68,7 @@ def compile_sharded(f, mesh, *, in_shardings=None, out_shardings=None,
                 "compile_sharded: the pjit path needs BOTH in_shardings "
                 "and out_shardings"
             )
-        return jax.jit(f, in_shardings=in_shardings,
+        return jax.jit(partitioned(f, mesh), in_shardings=in_shardings,
                        out_shardings=out_shardings,
                        donate_argnums=donate_argnums)
     if in_specs is None or out_specs is None:

@@ -42,11 +42,21 @@ def make_mesh(
     n = int(np.prod(sizes))
     if n > len(devices):
         raise ValueError(f"mesh {sizes} needs {n} devices, have {len(devices)}")
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
+    try:
         arr = mesh_utils.create_device_mesh(tuple(sizes), devices=devices[:n])
-    except Exception:
+    except (ValueError, NotImplementedError, AssertionError) as e:
+        # a shape the topology mapper cannot place (e.g. a sub-slice of a
+        # host): the flat device order still gives a correct mesh, only not
+        # an ICI-aware one — say so instead of hiding the layout
+        import warnings
+
+        warnings.warn(
+            f"make_mesh: create_device_mesh refused {tuple(sizes)} over "
+            f"{n} devices ({type(e).__name__}: {e}); using the flat "
+            "device order"
+        )
         arr = np.array(devices[:n]).reshape(sizes)
     return Mesh(arr, tuple(axis_names))
 

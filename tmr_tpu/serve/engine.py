@@ -57,7 +57,11 @@ from tmr_tpu.serve.admission import (
 from tmr_tpu.serve.batcher import MicroBatcher, Request
 from tmr_tpu.serve.caches import LRUCache, array_digest
 from tmr_tpu.serve.degrade import DegradeController, downscale_image
-from tmr_tpu.serve.meshplan import MeshPlan, resolve_plan
+from tmr_tpu.serve.meshplan import (
+    MeshPlan,
+    refuse_cached_subslice_tp,
+    resolve_plan,
+)
 from tmr_tpu.serve.staging import DeviceStager, StagedBatch, _PAD_BOX
 
 _DET_FIELDS = ("boxes", "scores", "refs", "valid")
@@ -161,6 +165,7 @@ class ServeEngine:
         )
         if self._plan is not None:
             self._validate_plan_tp()
+            refuse_cached_subslice_tp(self._plan)
             devices = [d for t in self._plan.group_targets
                        for d in t.devices]
         elif devices is None:

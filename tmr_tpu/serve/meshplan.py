@@ -182,6 +182,46 @@ class MeshPlan:
         }
 
 
+def refuse_cached_subslice_tp(plan: "MeshPlan") -> None:
+    """Raise when this plan would load from the persistent compile cache,
+    on a TPU, a multi-chip program for chips that do not include the
+    host's first.
+
+    Seen on a 2x2 v5e host (jaxlib 0.9.0 / libtpu 0.0.34, PR 23;
+    ``scripts/mesh_cache_probe.py`` is the reproduction: one process per
+    cell, all loading from a directory the first one filled). The ``tp2``
+    program on chips 2 and 3 runs when it is compiled in the process. The
+    same executable — same cache key, same fingerprint, same devices —
+    loaded from the persistent cache halts both cores on first execution
+    ("schecklt: Invalid logical z: enhanced-barrier-parent-phase-1 ...
+    Core halted unexpectedly") and the runtime terminates the process.
+    Loaded from the same cache, ``tp2`` on chips 0 and 1 runs, and so
+    does a one-chip program on chip 2. The fault therefore needs a
+    cross-chip barrier and chips that are not the slice's first; every
+    ``dp > 1, tp > 1`` plan has such a replica group, and so has a
+    ``tpN`` plan handed later devices. Such a plan needs the cache off:
+    an error here is better than a halted slice."""
+    import jax
+
+    if not (
+        jax.default_backend() == "tpu"
+        and jax.config.jax_enable_compilation_cache
+        and jax.config.jax_compilation_cache_dir
+    ):
+        return
+    first = jax.local_devices()[0]
+    halting = [t.name for t in plan.group_targets
+               if t.n_devices > 1 and first not in list(t.devices)]
+    if halting:
+        raise RuntimeError(
+            f"mesh {plan.spec!r}: the tensor-parallel program of "
+            f"{', '.join(halting)} runs on chips that do not include "
+            f"{first}, and loaded from the persistent compile cache such "
+            "a program halts the TPU (libtpu 0.0.34); start the process "
+            "with JAX_ENABLE_COMPILATION_CACHE=false"
+        )
+
+
 def resolve_plan(mesh: Optional[str],
                  devices: Optional[Sequence[Any]] = None,
                  tp_size: Optional[int] = None) -> Optional[MeshPlan]:

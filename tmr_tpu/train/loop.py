@@ -182,9 +182,13 @@ class Trainer:
             self.state = self.state.replace(
                 params=shard_params(self.state.params, self.mesh)
             )
-            self._train_step = self._jit_step_under_mesh(
-                step, state_sharding(self.state, self.mesh)
-            )
+            sharding = state_sharding(self.state, self.mesh)
+            # place the whole state as the step returns it: optimizer
+            # moments left on their init placement make the second call's
+            # input shardings differ from the first's, and the step
+            # compiles twice (seen on the chip: 55 s + 60 s)
+            self.state = jax.device_put(self.state, sharding)
+            self._train_step = self._jit_step_under_mesh(step, sharding)
         else:
             self._train_step = jax.jit(step, donate_argnums=0)
 
@@ -219,14 +223,19 @@ class Trainer:
         """jit with sharded output state + tracing under set_mesh — NOT a
         bare ``with mesh:``, which mesh-aware ops can't see: the matcher's
         data-axis shard_map island (ops/xcorr.py) discovers the mesh through
-        get_abstract_mesh at trace time."""
-        jitted = jax.jit(step, out_shardings=(sharding, None),
-                         donate_argnums=0)
+        get_abstract_mesh at trace time. The step is XLA's to partition
+        (``partitioned``): over more than one device it holds no Mosaic
+        kernel."""
+        from tmr_tpu.parallel.compat import partitioned
+
+        jitted = jax.jit(partitioned(step, self.mesh),
+                         out_shardings=(sharding, None), donate_argnums=0)
 
         def step_under_mesh(state, batch, _jit=jitted, _mesh=self.mesh):
             with jax.sharding.set_mesh(_mesh):
                 return _jit(state, batch)
 
+        step_under_mesh.__wrapped__ = jitted  # for .lower()
         return step_under_mesh
 
     def _init_state_pp(self, sample_batch, steps_per_epoch: int):
@@ -423,6 +432,11 @@ class Trainer:
     def eval_epoch(self, loader, stage: str, params) -> Dict[str, float]:
         cfg = self.cfg
         self.predictor.params = self._eval_params(params)
+        # the params live across the mesh, so every eval program is XLA's
+        # to partition over it, like the train step
+        from tmr_tpu.parallel.compat import partitioned
+
+        eval_batch = partitioned(self._eval_batch, self.mesh)
         sums = None  # device-scalar pytree, fetched once per epoch
         n = 0
         # one-batch software pipeline: batch k's detections are fetched only
@@ -461,7 +475,7 @@ class Trainer:
                 sub_batches = [full_batch]
             for batch in sub_batches:
                 with span("eval.batch", stage=stage):
-                    losses, dets = self._eval_batch(batch)  # async dispatch
+                    losses, dets = eval_batch(batch)  # async dispatch
                 if pending is not None:
                     collect(pending)
                 pending = (

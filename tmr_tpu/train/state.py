@@ -92,7 +92,7 @@ def make_optimizer(cfg, steps_per_epoch: int) -> optax.GradientTransformation:
 def create_train_state(
     model, cfg, rng, sample_image, sample_exemplars, steps_per_epoch: int = 1000
 ) -> TrainState:
-    # jitted init — eager init is op-by-op (slow on remote/tunneled devices)
+    # jitted init — eager init is op-by-op
     params = jax.jit(model.init)(rng, sample_image, sample_exemplars)["params"]
     tx = make_optimizer(cfg, steps_per_epoch)
     return TrainState.create(

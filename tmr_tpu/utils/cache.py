@@ -1,21 +1,23 @@
-"""Persistent XLA compilation cache.
+"""Process-level JAX set-up shared by the CLIs: device selection and the
+persistent XLA compilation cache.
 
 First compiles of the ViT-H/B programs cost tens of seconds to minutes;
-the jax persistent cache makes every later process on the same machine
-reuse them. Enabled uniformly by the CLIs and scripts that compile
-programs (main.py, bench.py, demo.py, extract_feature.py,
-scripts/bench_extra.py, scripts/serve_bench.py,
-scripts/profile_breakdown.py, scripts/gate_probe.py,
-scripts/chaos_probe.py, scripts/ckpt_probe.py,
-scripts/make_bench_ckpt.py) — library code never mutates global jax
-config.
+the jax persistent cache makes every later process that sees the same
+directory reuse them. Enabled uniformly by the CLIs and scripts that
+compile programs (main.py, bench.py, demo.py, extract_feature.py,
+chip_smoke.py, the scripts/ drivers) — library code never mutates global
+jax config.
 
-``TMR_COMPILATION_CACHE`` doubles as the knob: a directory path relocates
-the cache, and ``0``/``off``/``false`` opts out entirely (e.g. a CI job
-whose workdir must stay pristine, or when a corrupt cache is suspected).
-Failures to enable (read-only home, jax missing/ancient) degrade to a
-warning + None instead of raising, so the uniform call sites never turn a
-benchmark into a crash over a cache nicety.
+Where the cache lives is decided from outside: when
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself reads it and this module
+sets no directory (only the thresholds, so that every program is cached).
+Otherwise the cache goes to one fixed path inside the checkout,
+``<repo>/.jax_cache`` (git-ignored) — the path is part of the cache key's
+lookup, so it never depends on ``~``, a temp name, a pid or a time.
+``JAX_ENABLE_COMPILATION_CACHE=false`` is JAX's own opt-out.
+Failures to enable (read-only checkout) degrade to a warning + None
+instead of raising, so the uniform call sites never turn a run into a
+crash over a cache nicety.
 """
 
 from __future__ import annotations
@@ -23,30 +25,59 @@ from __future__ import annotations
 import os
 import warnings
 
-DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "tmr_tpu", "xla"
+#: the checkout's root: tmr_tpu/utils/cache.py -> three levels up
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-#: TMR_COMPILATION_CACHE values that mean "don't enable" rather than a path
-_OPT_OUT = ("0", "off", "false", "no")
+DEFAULT_DIR = os.path.join(REPO_ROOT, ".jax_cache")
+
+#: where the repo's own measured state lives by default (autotune cache,
+#: live winner bank): beside the compile cache, inside the checkout
+STATE_DIR = os.path.join(REPO_ROOT, ".tmr_cache")
 
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
+def select_device(device: str) -> None:
+    """Hold the process to the ``--device`` a CLI was given, or exit.
+
+    ``cpu`` pins JAX to the CPU platform (call before any device access).
+    ``tpu`` is checked, not assumed: when the default backend is anything
+    else the process exits with an error — a run that was asked for the
+    chip never carries on without one."""
+    import jax
+
+    if device == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+        return
+    if device != "tpu":
+        raise SystemExit(f"--device {device!r}: expected 'tpu' or 'cpu'")
+    try:
+        backend = jax.default_backend()
+    except RuntimeError as e:  # JAX_PLATFORMS names a platform not there
+        raise SystemExit(f"--device tpu: no TPU backend: {e}")
+    if backend != "tpu":
+        raise SystemExit(
+            f"--device tpu: the default JAX backend is {backend!r}, not "
+            "'tpu' (no chip attached, or JAX_PLATFORMS holds the process "
+            "elsewhere); pass --device cpu to run on the CPU"
+        )
+
+
+def enable_compilation_cache() -> str | None:
     """Turn on the persistent compilation cache (idempotent).
 
-    Returns the cache directory, or None when opted out
-    (``TMR_COMPILATION_CACHE=0``) or when enabling failed — failures warn
-    instead of raising so library/CLI callers can enable unconditionally.
+    Returns the cache directory in use, or None when enabling failed —
+    failures warn instead of raising so library/CLI callers can enable
+    unconditionally.
     """
-    env = os.environ.get("TMR_COMPILATION_CACHE", "")
-    if env.strip().lower() in _OPT_OUT:
-        return None
-    path = path or env or DEFAULT_DIR
     try:
         import jax
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not path:
+            path = DEFAULT_DIR
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
         # cache every program regardless of size/compile time
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
