@@ -426,6 +426,26 @@ def serve_through(engine, reqs: list, size: Size, seed: int):
     return results, (len(events), _backend_compiles() - jax0)
 
 
+def report_batch_stages(since: float, batches: int) -> None:
+    """The serve pipeline's always-on batch spans (obs/tracing.py) of the
+    engine started at ``since``: each of its ``batches`` batches left one
+    record of each stage, and what a stage cost the host a batch."""
+    from tmr_tpu import obs
+
+    stages = [r for r in obs.spans() if r["scope"] == "batch"
+              and r["name"].startswith("serve.") and r["ts"] >= since]
+    once = collections.Counter((r["attrs"]["batch"], r["name"])
+                               for r in stages)
+    check(len(once) == 4 * batches and set(once.values()) == {1}
+          and all(r["attrs"]["rows"] <= r["attrs"]["slots"] for r in stages),
+          f"each of {batches} batches left one span of each of the four "
+          f"batch stages ({len(stages)} spans)")
+    for name in sorted({r["name"] for r in stages}):
+        durs = [r["dur"] for r in stages if r["name"] == name]
+        say(f"  {name}: mean {1e3 * sum(durs) / len(durs):.3f} ms a batch "
+            f"over {len(durs)}")
+
+
 def phase_serve(pred, size: Size, seed: int) -> None:
     from tmr_tpu.serve import ServeEngine
 
@@ -433,11 +453,13 @@ def phase_serve(pred, size: Size, seed: int) -> None:
     reqs = serve_requests(size, seed)
     want, t_direct = _timed(lambda: direct_results(pred, reqs))
     say(f"  direct Predictor calls: first pass {t_direct:.2f}s")
+    t_engine = time.perf_counter()
     # bound 1: every dispatch runs the B=1 program the direct call runs —
     # the bitwise property tests/test_serve.py pins on CPU
     with ServeEngine(pred, batch=1, max_wait_ms=5, feature_cache=0) as eng:
         results, compiles = serve_through(eng, reqs, size, seed)
         stats = eng.stats()
+    report_batch_stages(t_engine, stats["batches"])
     check(len(results) == 8, "all 8 futures resolved")
     check(all(_bitwise(_np(r), w) for r, w in zip(results, want)),
           "serve results bitwise equal to the direct Predictor calls")

@@ -232,7 +232,9 @@ def _run(cancel_watchdog, argv=None) -> int:
         traced_s = _serve_closed_loop(engine, _requests(n_req, seed=3))
         serve_counters = engine.counters
         serve_metrics = engine.metrics_snapshot()
-    serve_spans = obs.spans()
+    # the per-request view: the always-on once-a-batch records of the
+    # four batch stages (scope "batch") would count each stage twice
+    serve_spans = [r for r in obs.spans() if r["scope"] == "request"]
 
     # per-request completeness: every stage name present under one trace id
     by_trace: dict = {}

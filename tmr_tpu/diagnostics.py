@@ -2168,7 +2168,7 @@ def mosaic_gate(fn):
     return gate
 
 
-def run_outside_trace(fn):
+def run_outside_trace(fn, gate: str = ""):
     """Run ``fn()`` with a clean JAX trace state and return its result.
 
     The gates are first asked while a model is being traced (under
@@ -2177,10 +2177,17 @@ def run_outside_trace(fn):
     thread starts outside every ambient trace: jitted calls there compile
     and run — Pallas kernels included, which
     ``jax.ensure_compile_time_eval`` cannot evaluate. Exceptions
-    propagate to the caller."""
+    propagate to the caller.
+
+    The wait is a ``gate.selfcheck`` set-up span named for ``gate``,
+    recorded on the calling thread: asked inside a program's first call,
+    it is that ``compile`` span's child (obs/tracing.py)."""
     from concurrent.futures import ThreadPoolExecutor
 
-    with ThreadPoolExecutor(max_workers=1) as pool:
+    from tmr_tpu.obs.tracing import span
+
+    with span("gate.selfcheck", scope="setup", gate=gate), \
+            ThreadPoolExecutor(max_workers=1) as pool:
         return pool.submit(fn).result()
 
 

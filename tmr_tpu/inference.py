@@ -27,7 +27,7 @@ import flax.linen as nn
 
 from tmr_tpu.models import build_model
 from tmr_tpu.models.matching_net import select_capacity_bucket
-from tmr_tpu.obs import track_compile, track_devtime
+from tmr_tpu.obs import span, track_compile, track_devtime
 from tmr_tpu.ops.postprocess import (
     batched_nms,
     compact_detections,
@@ -403,7 +403,7 @@ class Predictor:
         )
 
         @jit
-        def run(params, refiner_params, image, exemplars, *extra):
+        def run_single(params, refiner_params, image, exemplars, *extra):
             if chain_feedback:
                 image = image + extra[-1]
                 extra = extra[:-1]
@@ -422,8 +422,8 @@ class Predictor:
         # flight recorder's per-execution device-time attribution seam;
         # with TMR_FLIGHT=0 (default) it is one bool check.
         run = self._storage_entry(track_devtime(
-            track_compile(run, "single", key,
-                          bucket={"capacity": capacity}),
+            track_compile(run_single, "single", key,
+                          bucket={"capacity": capacity}, batch_arg=2),
             "single", key, bucket={"capacity": capacity},
         ), st)
         self._compiled[key] = run
@@ -474,14 +474,14 @@ class Predictor:
         Returns dict boxes/scores/refs/valid as fixed-shape device arrays."""
         if self.params is None:
             raise RuntimeError("call init_params() or load params first")
-        cap = self.pick_capacity(exemplars, int(image.shape[1]))
-        fn = self._get_fn(cap)
-        return fn(
-            self.exec_params(),
-            self.refiner_params,
-            jnp.asarray(image),
-            jnp.asarray(exemplars),
-        )
+        with span("predict.stage", scope="batch", program="run_single",
+                  rows=int(image.shape[0])) as sp:
+            cap = self.pick_capacity(exemplars, int(image.shape[1]))
+            sp.set_attr(capacity=cap)
+            fn = self._get_fn(cap)
+            args = (self.exec_params(), self.refiner_params,
+                    jnp.asarray(image), jnp.asarray(exemplars))
+        return fn(*args)
 
     #: static exemplar-count buckets for the multi-exemplar program: the
     #: compiled fn is keyed by bucket, real counts pad up and padded rows'
@@ -538,7 +538,8 @@ class Predictor:
         scales = st.scales if st is not None else None
 
         @jax.jit
-        def run(params, refiner_params, image, exemplars, k_real, *extra):
+        def run_multi(params, refiner_params, image, exemplars, k_real,
+                      *extra):
             # image (1, S, S, 3); exemplars (k_bucket, 4); k_real () int32
             feat = model.backbone.apply(
                 {"params": params["backbone"]}, image
@@ -593,9 +594,9 @@ class Predictor:
             return losses, final
 
         run = self._storage_entry(track_devtime(
-            track_compile(run, "multi", key,
+            track_compile(run_multi, "multi", key,
                           bucket={"capacity": capacity,
-                                  "k_bucket": k_bucket}),
+                                  "k_bucket": k_bucket}, batch_arg=2),
             "multi", key, bucket={"capacity": capacity,
                                   "k_bucket": k_bucket},
         ), st)
@@ -622,19 +623,22 @@ class Predictor:
             raise ValueError(
                 f"k_real={k} out of range for {len(exemplars)} exemplar rows"
             )
-        exemplars = exemplars[:k]
-        k_bucket = int(next((b for b in self.K_BUCKETS if b >= k), k))
-        pad = np.tile(exemplars[-1:], (k_bucket - k, 1))  # masked below
-        cap = self.pick_capacity(exemplars, int(image.shape[1]))
-        fn = self._get_multi_fn(cap, k_bucket, loss_fn=loss_fn)
-        return fn(
-            self.exec_params(),
-            self.refiner_params,
-            jnp.asarray(image),
-            jnp.asarray(np.concatenate([exemplars, pad], axis=0)),
-            jnp.asarray(k, jnp.int32),
-            *loss_args,
-        )
+        with span("predict.stage", scope="batch", program="run_multi",
+                  rows=int(image.shape[0])) as sp:
+            exemplars = exemplars[:k]
+            k_bucket = int(next((b for b in self.K_BUCKETS if b >= k), k))
+            pad = np.tile(exemplars[-1:], (k_bucket - k, 1))  # masked below
+            cap = self.pick_capacity(exemplars, int(image.shape[1]))
+            sp.set_attr(capacity=cap)
+            fn = self._get_multi_fn(cap, k_bucket, loss_fn=loss_fn)
+            args = (
+                self.exec_params(),
+                self.refiner_params,
+                jnp.asarray(image),
+                jnp.asarray(np.concatenate([exemplars, pad], axis=0)),
+                jnp.asarray(k, jnp.int32),
+            )
+        return fn(*args, *loss_args)
 
 
     # ---------------------------------------------------------------- serve
@@ -670,14 +674,20 @@ class Predictor:
             functools.partial(jax.jit, donate_argnums=(2,)) if donate
             else jax.jit
         )
-        run = jit(self._multi_batched_pipeline(
+        body = self._multi_batched_pipeline(
             model, heads, k_bucket, refine,
             scales=st.scales if st is not None else None,
-        ))
+        )
+
+        @jit
+        def run_multi_batched(params, refiner_params, image, exemplars,
+                              k_real):
+            return body(params, refiner_params, image, exemplars, k_real)
+
         run = self._storage_entry(track_devtime(
-            track_compile(run, "multi_batched", key,
+            track_compile(run_multi_batched, "multi_batched", key,
                           bucket={"capacity": capacity,
-                                  "k_bucket": k_bucket}),
+                                  "k_bucket": k_bucket}, batch_arg=2),
             "multi_batched", key, bucket={"capacity": capacity,
                                           "k_bucket": k_bucket},
         ), st)
@@ -691,15 +701,21 @@ class Predictor:
         counts. Returns fixed-slot dets with leading dim B."""
         if self.params is None:
             raise RuntimeError("call init_params() or load params first")
-        exemplars = jnp.asarray(exemplars)
-        fn = self._get_multi_batched_fn(
-            self.pick_capacity(exemplars, int(images.shape[1])),
-            int(exemplars.shape[1]), donate=donate,
-        )
-        return fn(
-            self.exec_params(), self.refiner_params, jnp.asarray(images),
-            exemplars, jnp.asarray(k_real, jnp.int32),
-        )
+        with span("predict.stage", scope="batch",
+                  program="run_multi_batched",
+                  rows=int(images.shape[0])) as sp:
+            exemplars = jnp.asarray(exemplars)
+            cap = self.pick_capacity(exemplars, int(images.shape[1]))
+            sp.set_attr(capacity=cap)
+            fn = self._get_multi_batched_fn(
+                cap, int(exemplars.shape[1]), donate=donate,
+            )
+            args = (
+                self.exec_params(), self.refiner_params,
+                jnp.asarray(images), exemplars,
+                jnp.asarray(k_real, jnp.int32),
+            )
+        return fn(*args)
 
     def _get_backbone_fn(self):
         """Encoder-only program: image (B, S, S, 3) -> pre-upsample backbone
@@ -710,7 +726,7 @@ class Predictor:
             return self._compiled[key]
 
         @jax.jit
-        def run(params, image):
+        def run_backbone(params, image):
             f = self.model.backbone.apply({"params": params["backbone"]},
                                           image)
             if isinstance(f, (list, tuple)):
@@ -722,8 +738,9 @@ class Predictor:
                 f = f[0]
             return f
 
-        run = track_devtime(track_compile(run, "backbone", key),
-                            "backbone", key)
+        run = track_devtime(
+            track_compile(run_backbone, "backbone", key, batch_arg=1),
+            "backbone", key)
         self._compiled[key] = run
         return run
 
@@ -754,7 +771,7 @@ class Predictor:
         scales = st.scales if st is not None else None
 
         @jax.jit
-        def run(params, refiner_params, features, exemplars):
+        def run_heads(params, refiner_params, features, exemplars):
             out = model.apply(
                 self._variables(params, scales),
                 jnp.zeros((features.shape[0], 1, 1, 3), jnp.float32),
@@ -767,9 +784,9 @@ class Predictor:
             )
 
         run = self._storage_entry(track_devtime(
-            track_compile(run, "heads", key,
+            track_compile(run_heads, "heads", key,
                           bucket={"capacity": capacity,
-                                  "image_size": image_size}),
+                                  "image_size": image_size}, batch_arg=2),
             "heads", key, bucket={"capacity": capacity,
                                   "image_size": image_size},
         ), st)
@@ -865,7 +882,8 @@ class Predictor:
         )
 
         @jit
-        def run(params, refiner_params, image, exemplars, k_real, n_real):
+        def run_gallery(params, refiner_params, image, exemplars, k_real,
+                        n_real):
             feat = model.backbone.apply(
                 {"params": params["backbone"]}, image
             )
@@ -882,7 +900,8 @@ class Predictor:
         bucket = {"capacity": capacity, "n_bucket": n_bucket,
                   "k_bucket": k_bucket}
         run = self._storage_entry(track_devtime(
-            track_compile(run, "gallery", key, bucket=bucket),
+            track_compile(run_gallery, "gallery", key, bucket=bucket,
+                          batch_arg=2),
             "gallery", key, bucket=bucket,
         ), st)
         self._compiled[key] = run
@@ -917,15 +936,16 @@ class Predictor:
         )
 
         @jax.jit
-        def run(params, refiner_params, features, exemplars, k_real,
-                n_real):
+        def run_gallery_heads(params, refiner_params, features, exemplars,
+                              k_real, n_real):
             return tail(params, refiner_params, features, exemplars,
                         k_real, n_real, (image_size, image_size))
 
         bucket = {"capacity": capacity, "n_bucket": n_bucket,
                   "k_bucket": k_bucket, "image_size": image_size}
         run = self._storage_entry(track_devtime(
-            track_compile(run, "gallery_heads", key, bucket=bucket),
+            track_compile(run_gallery_heads, "gallery_heads", key,
+                          bucket=bucket, batch_arg=2),
             "gallery_heads", key, bucket=bucket,
         ), st)
         self._compiled[key] = run
@@ -945,13 +965,14 @@ class Predictor:
             return self._compiled[key]
 
         @jax.jit
-        def run(features, exemplars, k_real, n_real):
+        def run_gallery_prefilter(features, exemplars, k_real, n_real):
             return coarse_prefilter_scores(features, exemplars, k_real,
                                            n_real)
 
         bucket = {"n_bucket": n_bucket, "k_bucket": k_bucket}
         run = track_devtime(
-            track_compile(run, "gallery_prefilter", key, bucket=bucket),
+            track_compile(run_gallery_prefilter, "gallery_prefilter", key,
+                          bucket=bucket, batch_arg=0),
             "gallery_prefilter", key, bucket=bucket,
         )
         self._compiled[key] = run
@@ -1009,20 +1030,27 @@ class Predictor:
                     "features-arm predict_gallery needs image_size"
                 )
             size = int(image_size)
-        rows = np.concatenate(
-            [exemplars[i, :int(k_real[i])] for i in range(n)], axis=0
-        )
-        cap = self.pick_capacity(rows, size)
-        args = (
-            self.exec_params(), self.refiner_params,
-            jnp.asarray(exemplars), jnp.asarray(k_real),
-            jnp.asarray(n, jnp.int32),
-        )
-        if features is None:
-            fn = self._get_gallery_fn(cap, n_bucket, k_bucket)
-            return fn(args[0], args[1], jnp.asarray(image), *args[2:])
-        fn = self._get_gallery_heads_fn(cap, n_bucket, k_bucket, size)
-        return fn(args[0], args[1], features, *args[2:])
+        with span("predict.stage", scope="batch", rows=1,
+                  program="run_gallery" if features is None
+                  else "run_gallery_heads") as sp:
+            rows = np.concatenate(
+                [exemplars[i, :int(k_real[i])] for i in range(n)], axis=0
+            )
+            cap = self.pick_capacity(rows, size)
+            sp.set_attr(capacity=cap)
+            if features is None:
+                fn = self._get_gallery_fn(cap, n_bucket, k_bucket)
+                frame = jnp.asarray(image)
+            else:
+                fn = self._get_gallery_heads_fn(cap, n_bucket, k_bucket,
+                                                size)
+                frame = features
+            args = (
+                self.exec_params(), self.refiner_params, frame,
+                jnp.asarray(exemplars), jnp.asarray(k_real),
+                jnp.asarray(n, jnp.int32),
+            )
+        return fn(*args)
 
     # ------------------------------------------------------- sharded serve
     # Mesh-sharded program variants for the serving tier (serve/meshplan):
@@ -1112,7 +1140,8 @@ class Predictor:
         bucket = {"capacity": capacity, "mode": target.mode,
                   "devices": target.n_devices}
         run = track_devtime(
-            track_compile(run, "single_sharded", key, bucket=bucket),
+            track_compile(run, "single_sharded", key, bucket=bucket,
+                          batch_arg=2),
             "single_sharded", key, bucket=bucket,
             devices=target.n_devices,
         )
@@ -1176,7 +1205,8 @@ class Predictor:
         bucket = {"capacity": capacity, "k_bucket": k_bucket,
                   "mode": target.mode, "devices": target.n_devices}
         run = track_devtime(
-            track_compile(run, "multi_sharded", key, bucket=bucket),
+            track_compile(run, "multi_sharded", key, bucket=bucket,
+                          batch_arg=2),
             "multi_sharded", key, bucket=bucket,
             devices=target.n_devices,
         )
@@ -1191,27 +1221,31 @@ def detections_to_numpy(dets: dict) -> list:
     Device-compacted detections (TMR_DECODE_TAIL=device: survivors in the
     leading ``count`` slots) take the prefix-slice fast path; the host
     form scans the validity mask. Both yield identical lists."""
-    boxes = np.asarray(dets["boxes"])
-    scores = np.asarray(dets["scores"])
-    refs = np.asarray(dets["refs"])
+    # predict.fetch waits for the device and copies back; predict.unpack
+    # is the host's ragged split (obs/tracing.py: always-on batch spans)
+    with span("predict.fetch", scope="batch") as sp:
+        boxes = np.asarray(dets["boxes"])
+        scores = np.asarray(dets["scores"])
+        refs = np.asarray(dets["refs"])
+        compacted = "count" in dets
+        keep = np.asarray(dets["count" if compacted else "valid"])
+        rows = int(boxes.shape[0])
+        sp.set_attr(rows=rows)
     out = []
-    if "count" in dets:
-        count = np.asarray(dets["count"])
-        for b in range(boxes.shape[0]):
-            n = int(count[b])
-            # .copy(): a prefix-slice VIEW would pin the whole padded
-            # (B, max_detections, ...) batch alive for as long as the
-            # caller keeps the per-image dict — the retention hazard
-            # serve/engine.py's _finish documents; the host path's
-            # boolean indexing below copies inherently
-            out.append({"boxes": boxes[b][:n].copy(),
-                        "scores": scores[b][:n].copy(),
-                        "refs": refs[b][:n].copy()})
-        return out
-    valid = np.asarray(dets["valid"])
-    for b in range(boxes.shape[0]):
-        v = valid[b]
-        out.append(
-            {"boxes": boxes[b][v], "scores": scores[b][v], "refs": refs[b][v]}
-        )
+    with span("predict.unpack", scope="batch", rows=rows):
+        for b in range(rows):
+            if compacted:
+                # .copy(): a prefix-slice VIEW would pin the whole padded
+                # (B, max_detections, ...) batch alive for as long as the
+                # caller keeps the per-image dict — the retention hazard
+                # serve/engine.py's _finish documents; the host path's
+                # boolean indexing below copies inherently
+                n = int(keep[b])
+                out.append({"boxes": boxes[b][:n].copy(),
+                            "scores": scores[b][:n].copy(),
+                            "refs": refs[b][:n].copy()})
+            else:
+                v = keep[b]
+                out.append({"boxes": boxes[b][v], "scores": scores[b][v],
+                            "refs": refs[b][v]})
     return out

@@ -1,8 +1,9 @@
 """Unified telemetry: span tracing, metrics registry, compile accounting.
 
 The observability layer every serving/map/train component records into —
-see tracing.py (request-scoped spans -> Chrome trace JSON + xprof
-TraceAnnotations, zero-cost under ``TMR_TRACE=0``), metrics.py (named
+see tracing.py (always-on coarse set-up and batch spans, request-scoped
+spans under ``TMR_TRACE=1`` -> Chrome trace JSON + xprof
+TraceAnnotations), metrics.py (named
 counters/gauges/histograms, ``metrics_report/v1`` snapshots),
 compile.py (per-trace/compile events with cold vs key-change causes),
 devtime.py (per-program device-time attribution + MFU/roofline
@@ -71,6 +72,7 @@ from tmr_tpu.obs.tracing import (
     save_chrome_trace,
     span,
     spans,
+    spans_ns,
     tracing_enabled,
 )
 
@@ -113,6 +115,7 @@ __all__ = [
     "save_chrome_trace",
     "span",
     "spans",
+    "spans_ns",
     "stitch_chrome_traces",
     "tracing_enabled",
     "track_compile",

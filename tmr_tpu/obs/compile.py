@@ -13,12 +13,20 @@ a cause —
   the signature of a storm (numpy-int key drift, an unexpected new
   bucket, a fresh donate/loss_fn flavor) that should be a cache hit.
 
-Events land in three places at once: a bounded in-process log
+Events land in two places at once: a bounded in-process log
 (:func:`compile_events` / :func:`drain_compile_events`, the gate-refusal
-registry pattern), the process-wide metrics registry (``compile.total``,
-``compile.cold``, ``compile.key_change`` counters + ``compile.wall_s``
-histogram), and — when tracing is on — a ``compile`` span on the thread
-that paid the wall time.
+registry pattern) and the process-wide metrics registry
+(``compile.total``, ``compile.cold``, ``compile.key_change`` counters +
+``compile.wall_s`` histogram). A program's first call is also a
+``compile`` span (``scope="setup"``, always recorded: obs/tracing.py) on
+the thread that paid the wall time, open while the call runs, so a gate
+self-check asked inside the model's trace
+(``diagnostics.run_outside_trace``) is its child.
+
+:func:`track_compile`'s wrapper is also the one seam every Predictor
+program is called through, so it records each call as a
+``predict.dispatch`` span (``scope="batch"``): ``Predictor.__call__``, the
+trainer's eval step and ``ServeEngine._run_batch`` all get it from here.
 
 The wall time is measured on the wrapped program's FIRST call, not at
 cache-insert: jit wrappers are lazy, and the first call is where trace +
@@ -55,7 +63,9 @@ _SEEN_KEYS: dict = {}
 
 def record_compile_event(kind: str, key: Any, t0: float, t1: float,
                          bucket: Optional[dict] = None) -> dict:
-    """Record one trace/compile occurrence; returns the event record."""
+    """Record one trace/compile occurrence in the log and the registry;
+    returns the event record. The ``compile`` span is
+    :func:`track_compile`'s, which holds it open over the call."""
     global _SEQ
     key_repr = repr(key)
     with _LOCK:
@@ -79,8 +89,6 @@ def record_compile_event(kind: str, key: Any, t0: float, t1: float,
     reg.counter("compile.cold" if cause == "cold"
                 else "compile.key_change").inc()
     reg.histogram("compile.wall_s").observe(rec["wall_s"])
-    _tracing.add_span("compile", t0, t1, kind=kind, key=rec["key"],
-                      cause=cause)
     return rec
 
 
@@ -118,25 +126,37 @@ def drain_compile_events() -> List[dict]:
 
 
 def track_compile(fn, kind: str, key: Any,
-                  bucket: Optional[dict] = None):
+                  bucket: Optional[dict] = None,
+                  batch_arg: Optional[int] = None):
     """Wrap a freshly built jitted program so its first call records a
-    compile event. Later calls pay one list check. The wrapped callable
-    is what goes into the ``_compiled`` cache, so every consumer sees
-    the same accounting exactly once per cache entry."""
+    compile event, and every call a ``predict.dispatch`` span with the
+    program's name, its ``bucket`` and, where ``batch_arg`` names the
+    positional argument that carries the batch, its ``rows``. The
+    wrapped callable is what goes into the ``_compiled`` cache, so every
+    consumer sees the same accounting exactly once per cache entry."""
     done: List[bool] = []
     lock = threading.Lock()
+    static = dict(bucket or {}, program=getattr(fn, "__name__", kind))
 
     def wrapped(*args, **kw):
-        if done:
-            return fn(*args, **kw)
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        t1 = time.perf_counter()
-        with lock:
-            if not done:
-                done.append(True)
-                record_compile_event(kind, key, t0, t1, bucket=bucket)
-        return out
+        attrs = dict(static)
+        if batch_arg is not None:
+            attrs["rows"] = int(args[batch_arg].shape[0])
+        with _tracing.span("predict.dispatch", scope="batch", **attrs):
+            if done:
+                return fn(*args, **kw)
+            with _tracing.span("compile", scope="setup", kind=kind,
+                               key=repr(key)) as sp:
+                t0 = time.perf_counter()
+                out = fn(*args, **kw)
+                t1 = time.perf_counter()
+                with lock:
+                    if not done:
+                        done.append(True)
+                        rec = record_compile_event(kind, key, t0, t1,
+                                                   bucket=bucket)
+                        sp.set_attr(cause=rec["cause"])
+            return out
 
     wrapped.__wrapped__ = fn
     return wrapped

@@ -1,11 +1,22 @@
-"""Request-scoped host-side span tracing.
+"""Host-side span tracing: always-on coarse spans, request spans on demand.
 
 ``span("stage", **attrs)`` is a context manager that records one complete
-event (name, start, duration, thread, trace/span/parent IDs, attributes)
-into a per-thread ring buffer; ``add_span`` records an event with explicit
-timestamps for stages whose boundaries were stamped elsewhere (the
-batcher's queue-wait window, a batch-level stage attributed to each
-request in it). Exports:
+event (name, scope, start, duration, thread, trace/span/parent IDs,
+attributes) into a per-thread ring buffer; ``add_span`` records an event
+with explicit timestamps for stages whose boundaries were stamped
+elsewhere (the batcher's queue-wait window, a batch's staging window).
+
+Every span has a ``scope``, and the scope decides whether it is recorded:
+
+- ``"setup"`` (a handful a process: a gate's self-check, a program's
+  first call) and ``"batch"`` (a few a batch: the Predictor's stage /
+  dispatch / fetch / unpack, the serve pipeline's four batch stages) are
+  *coarse* and ALWAYS recorded. They are what a benchmark's traced run
+  reads (``benchmarks/reducers/program_span_ms.py``), and being on in the
+  untraced run too, its end-to-end comparison prices them.
+- ``"request"`` (the default) is recorded only under ``TMR_TRACE=1``.
+
+Exports:
 
 - :func:`chrome_trace` — Chrome trace-event JSON (open in Perfetto /
   ``chrome://tracing``): one ``ph: "X"`` event per span plus thread-name
@@ -16,15 +27,22 @@ request in it). Exports:
 
 Cost model (the load-bearing contract, pinned by tests/test_obs.py):
 
-- ``TMR_TRACE=0`` (the default): ``span()`` is one module-global bool
-  check returning a shared no-op context manager — a few hundred ns per
-  enter/exit, nothing allocated, nothing locked. Hot paths that would pay
-  even for building kwargs guard on :func:`tracing_enabled` first.
-- ``TMR_TRACE=1``: each thread appends to its OWN ring buffer (no
-  cross-thread locking on the record path; the global lock is touched
-  once per thread lifetime, at ring registration) and the ring overwrites
-  its oldest events rather than growing — a long-lived traced server is
-  memory-bounded by ``TMR_TRACE_RING`` events per thread.
+- ``TMR_TRACE=0`` (the default): a request-scope ``span()`` is one
+  module-global bool check returning a shared no-op context manager — a
+  few hundred ns per enter/exit, nothing allocated, nothing locked. A
+  request-scope span is paid thousands of times a second, so hot paths
+  that would pay even for building kwargs guard on
+  :func:`tracing_enabled` first.
+- coarse spans, at either setting: one small object, two clock readings
+  and one dict appended to the thread's own ring — a few microseconds.
+  A batch is 0.5-1 s of device work and leaves about five of them, a
+  process a handful of set-up ones, so they need no switch. They mirror
+  into ``TraceAnnotation`` only under ``TMR_TRACE=1``.
+- each thread appends to its OWN ring buffer (no cross-thread locking on
+  the record path; the global lock is touched once per thread lifetime,
+  at ring registration) and the ring overwrites its oldest events rather
+  than growing — a long-lived server is memory-bounded by
+  ``TMR_TRACE_RING`` events per thread, traced or not.
 
 Trace IDs: a request's trace id is minted at submit
 (:func:`new_trace_id`), travels WITH the request object through queueing,
@@ -229,13 +247,14 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
-                 "t0", "_ann", "_b")
+    __slots__ = ("name", "scope", "attrs", "trace_id", "span_id",
+                 "parent_id", "t0", "_ann", "_b")
 
-    def __init__(self, name: str, trace_id: Optional[str],
+    def __init__(self, name: str, trace_id: Optional[str], scope: str,
                  attrs: Dict[str, Any]) -> None:
         self.name = name
         self.trace_id = trace_id
+        self.scope = scope
         self.attrs = attrs
 
     def __enter__(self) -> "_Span":
@@ -243,11 +262,14 @@ class _Span:
         self._b = b
         parent = b.stack[-1] if b.stack else None
         if self.trace_id is None:
-            self.trace_id = parent[1] if parent else new_trace_id()
+            # a coarse root belongs to no request: no id is minted for it
+            self.trace_id = parent[1] if parent else (
+                new_trace_id() if self.scope == "request" else "")
         self.span_id = next(_SPAN_IDS)
         self.parent_id = parent[0] if parent else 0
         b.stack.append((self.span_id, self.trace_id))
-        ann_cls = _annotation_cls()
+        # a coarse span of an untraced process stays off the profiler
+        ann_cls = _annotation_cls() if _ENABLED else None
         self._ann = ann_cls(self.name) if ann_cls else None
         if self._ann is not None:
             self._ann.__enter__()
@@ -266,6 +288,7 @@ class _Span:
             b.stack.pop()
         b.record({
             "name": self.name,
+            "scope": self.scope,
             "ts": self.t0,
             "dur": t1 - self.t0,
             "tid": b.tid,
@@ -277,33 +300,39 @@ class _Span:
         return False
 
 
-def span(name: str, trace_id: Optional[str] = None, **attrs):
-    """Context manager timing one named stage. No-op (shared singleton)
-    when tracing is disabled; otherwise records a complete event on exit
-    and mirrors the region into ``jax.profiler.TraceAnnotation``."""
+def span(name: str, trace_id: Optional[str] = None,
+         scope: str = "request", **attrs):
+    """Context manager timing one named stage. A request-scope span is a
+    no-op (shared singleton) when tracing is disabled; a coarse one
+    (``scope="setup"`` / ``"batch"``) always records. Records a complete
+    event on exit; under ``TMR_TRACE=1`` also mirrors the region into
+    ``jax.profiler.TraceAnnotation``."""
     if _ENABLED is None:
         _resolve_env()
-    if not _ENABLED:
+    if not _ENABLED and scope == "request":
         return _NOOP
-    return _Span(name, trace_id, attrs)
+    return _Span(name, trace_id, scope, attrs)
 
 
 def add_span(name: str, t0: float, t1: float,
              trace_id: Optional[str] = None, parent: int = 0,
-             span_id: Optional[int] = None, **attrs) -> None:
+             span_id: Optional[int] = None, scope: str = "request",
+             **attrs) -> None:
     """Record a complete event whose boundaries were stamped elsewhere
-    (``time.perf_counter`` values) — queue-wait windows, batch-level
-    stages attributed per request. Does not touch the nesting stack.
+    (``time.perf_counter`` values) — queue-wait windows, a batch's
+    staging and execute windows. Does not touch the nesting stack.
+    ``scope`` decides as in :func:`span` whether it is recorded.
     ``span_id`` records under a pre-minted id (:func:`next_span_id`);
     ``parent`` may be a remote process's span id (cross-process context
     propagation parents receiver spans under the sender's id)."""
     if _ENABLED is None:
         _resolve_env()
-    if not _ENABLED:
+    if not _ENABLED and scope == "request":
         return
     b = _buf()
     b.record({
         "name": name,
+        "scope": scope,
         "ts": t0,
         "dur": max(t1 - t0, 0.0),
         "tid": b.tid,
@@ -323,6 +352,17 @@ def spans() -> List[dict]:
         out.extend(b.snapshot())
     out.sort(key=lambda r: r["ts"])
     return out
+
+
+def spans_ns(names=None) -> List[list]:
+    """Recorded spans as ``[name, t0_ns, t1_ns]`` rows on
+    ``time.perf_counter_ns``'s clock, oldest first; ``names`` keeps only
+    those names. The shape of ``benchmarks/trace.py:Spans.records``, so a
+    driver can hand the program's spans to ``trace.gaps_add`` as they
+    are."""
+    return [[r["name"], round(r["ts"] * 1e9),
+             round((r["ts"] + r["dur"]) * 1e9)]
+            for r in spans() if names is None or r["name"] in names]
 
 
 def dropped_spans() -> int:
@@ -355,7 +395,7 @@ def chrome_trace() -> dict:
         })
     for rec in spans():
         args = {"trace": rec["trace"], "span": rec["span"],
-                "parent": rec["parent"]}
+                "parent": rec["parent"], "scope": rec["scope"]}
         args.update(rec["attrs"])
         events.append({
             "ph": "X",

@@ -474,7 +474,7 @@ def _self_check(
         return True
 
     try:
-        return run_outside_trace(check)
+        return run_outside_trace(check, gate=gate_name)
     except Exception as e:
         if os.environ.get("TMR_GATE_DEBUG"):
             import traceback

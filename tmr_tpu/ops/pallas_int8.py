@@ -157,7 +157,7 @@ def pallas_int8_ok(m: int = 256, k: int = 256, n: int = 256) -> bool:
         return float(np.abs(got - want).max() / (np.abs(want).max() + 1e-6))
 
     try:
-        rel = run_outside_trace(check)
+        rel = run_outside_trace(check, gate="pallas_int8_ok")
         ok = rel < 1e-6
         if not ok:
             gate_refused("pallas_int8_ok", f"rel err {rel:.4g} >= 1e-6",

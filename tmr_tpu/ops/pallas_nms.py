@@ -235,7 +235,7 @@ def pallas_nms_compiled_ok() -> bool:
         return bool(jnp.array_equal(got, want))
 
     try:
-        if run_outside_trace(check):
+        if run_outside_trace(check, gate="pallas_nms_compiled_ok"):
             return True
     except Exception as e:
         return gate_refused(

@@ -18,6 +18,7 @@ shape per bucket.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import Counter, OrderedDict, deque
@@ -42,6 +43,7 @@ class Request:
     features: Any = None  # cached device features (heads path, hit)
     needs_features: bool = False  # heads path, promotion fill
     trace_id: str = ""  # per-request span correlation (obs.tracing)
+    batch: int = 0  # id of the batch that carried it (set at release)
     group: Any = None  # replica-group queue id (mesh serving; None = the
     # single ungrouped pipeline, the pre-mesh behavior)
     priority: int = 0  # class-weighted scheduling (higher = sooner)
@@ -105,6 +107,10 @@ class MicroBatcher:
         self._maxp: Dict[tuple, int] = {}
         self._cond = threading.Condition()
         self._closed = False
+        #: batch ids, local to this batcher's engine: minted where a batch
+        #: is formed, carried by its requests and named by every span the
+        #: batch causes (``batch=<id>``)
+        self._batch_ids = itertools.count(1)
         #: released-batch size histogram {occupied_slots: count} — the
         #: serve report's batch-occupancy evidence
         self.occupancy: Counter = Counter()
@@ -196,6 +202,9 @@ class MicroBatcher:
         self.occupancy[len(out)] += 1
         if self.groups is not None:
             self.occupancy_by_group[key[0]][len(out)] += 1
+        batch = next(self._batch_ids)
+        for r in out:
+            r.batch = batch
         if obs.tracing_enabled():
             # queue wait = submit -> release, per request: the window was
             # stamped at submit, so it is recorded retroactively here.
@@ -207,7 +216,7 @@ class MicroBatcher:
                 for r in out:
                     obs.add_span("serve.queue_wait", r.t_submit, now,
                                  trace_id=r.trace_id or None,
-                                 bucket=str(bucket))
+                                 bucket=str(bucket), batch=batch)
             except Exception:
                 pass
         return bucket, out

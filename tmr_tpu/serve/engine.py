@@ -30,10 +30,14 @@ Contracts:
   constructor argument override it.
 - **Observable**: every counter lives in a per-engine obs metrics
   registry (``stats()`` keeps its original shape; ``metrics_snapshot()``
-  is the metrics_report/v1 view), and with ``TMR_TRACE=1`` each request's
+  is the metrics_report/v1 view); the four batch stages (batch_assemble,
+  stage, execute, postprocess) are always recorded once a batch as
+  ``scope="batch"`` spans with the batch's id, rows and padded slots
+  (``StagedBatch.record_stage``), and with ``TMR_TRACE=1`` each request's
   trace id follows it through spans for all seven pipeline stages
   (submit, queue_wait, batch_assemble, stage, execute, postprocess,
-  resolve) — scripts/obs_probe.py is the measured proof.
+  resolve), each naming its batch — scripts/obs_probe.py is the measured
+  proof.
 """
 
 from __future__ import annotations
@@ -947,13 +951,8 @@ class ServeEngine:
             try:
                 t0 = time.perf_counter()
                 out, fill_feats = self._run_batch(staged)
-                if obs.tracing_enabled():
-                    t1 = time.perf_counter()
-                    for r in staged.requests:
-                        obs.add_span("serve.execute", t0, t1,
-                                     trace_id=r.trace_id or None,
-                                     bucket=str(staged.bucket),
-                                     device=str(staged.device))
+                staged.record_stage("serve.execute", t0,
+                                    time.perf_counter())
                 self._done_q.put((staged, out, fill_feats))
             except Exception as e:
                 self._isolate(staged.requests, e, batch_level=True)
@@ -1091,6 +1090,7 @@ class ServeEngine:
         # span at its own resolve time instead would fold every EARLIER
         # rider's unpad+resolve into the later riders' spans
         t_fetch1 = time.perf_counter()
+        staged.record_stage("serve.postprocess", t_post0, t_fetch1)
         kind, size = staged.bucket[0], staged.bucket[1]
         traced = obs.tracing_enabled()
         now = time.perf_counter()
@@ -1137,11 +1137,10 @@ class ServeEngine:
                 req.resolve(result)
                 t_res1 = time.perf_counter()
                 if traced:
-                    tid = req.trace_id or None
-                    obs.add_span("serve.postprocess", t_post0, t_fetch1,
-                                 trace_id=tid)
                     obs.add_span("serve.resolve", t_res0, t_res1,
-                                 trace_id=tid, futures=len(req.futures))
+                                 trace_id=req.trace_id or None,
+                                 futures=len(req.futures),
+                                 batch=req.batch)
                 self._lat.observe(t_res1 - req.t_submit)
                 if obs.flight_enabled():  # one bool check when off
                     obs.flight_record(

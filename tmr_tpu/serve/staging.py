@@ -46,6 +46,23 @@ class StagedBatch:
     padded_slots: int = 0
     t_staged: float = 0.0
 
+    def record_stage(self, name: str, t0: float, t1: float) -> None:
+        """One batch stage's window: once for the batch, always
+        (``scope="batch"``; ``rows`` over ``slots`` is the occupancy, and
+        ``batch`` the batcher's id of it, ``Request.batch``), and under
+        ``TMR_TRACE`` once more a rider under the trace id it carried
+        from submit, naming the batch."""
+        rows = len(self.requests)
+        batch = self.requests[0].batch if rows else 0
+        obs.add_span(name, t0, t1, scope="batch", batch=batch,
+                     bucket=str(self.bucket), rows=rows,
+                     slots=rows + self.padded_slots,
+                     device=str(self.device))
+        if obs.tracing_enabled():
+            for r in self.requests:
+                obs.add_span(name, t0, t1, trace_id=r.trace_id or None,
+                             batch=batch)
+
     @property
     def target(self):
         """The MeshTarget this batch stages onto (None on the legacy
@@ -215,17 +232,9 @@ class DeviceStager:
             if kind == "multi":
                 staged.k_real = jax.device_put(k_real, placement)
         staged.t_staged = time.perf_counter()
-        if obs.tracing_enabled():
-            # batch-level windows attributed to each rider: host pad/stack
-            # (assemble) then the H2D transfers (stage), same trace id the
-            # request carried from submit
-            for r in requests:
-                tid = r.trace_id or None
-                obs.add_span("serve.batch_assemble", t_assemble, t_put,
-                             trace_id=tid, bucket=str(bucket),
-                             batch=len(requests), padded=staged.padded_slots)
-                obs.add_span("serve.stage", t_put, staged.t_staged,
-                             trace_id=tid, device=str(device))
+        # host pad/stack (assemble), then the H2D transfers (stage)
+        staged.record_stage("serve.batch_assemble", t_assemble, t_put)
+        staged.record_stage("serve.stage", t_put, staged.t_staged)
         return staged
 
     def _stage_heads(self, staged: StagedBatch, bound: int, size: int,
