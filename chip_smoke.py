@@ -192,7 +192,8 @@ def decide_gates(cfg, size: Size) -> dict:
     """Ask, outside any trace, every gate the ``auto`` path of this
     configuration consults; the model's traces then hit their caches."""
     from tmr_tpu.models.vit import VIT_CONFIGS
-    from tmr_tpu.ops.flash_attn import flash_attention_ok, flash_window_ok
+    from tmr_tpu.ops.flash_attn import flash_attention_ok
+    from tmr_tpu.ops.pallas_attn import packed_window_ok
     from tmr_tpu.ops.pallas_nms import pallas_nms_compiled_ok
 
     vc = VIT_CONFIGS["vit_b" if size.backbone == "sam_vit_b" else "vit_h"]
@@ -200,7 +201,8 @@ def decide_gates(cfg, size: Size) -> dict:
     grid = size.image_size // 16
     verdicts = {
         "flash_attention_ok": flash_attention_ok(grid, grid, head_dim),
-        "flash_window_ok": flash_window_ok(14, 14, head_dim),
+        "packed_window_ok": packed_window_ok(14, 14, head_dim,
+                                             vc["num_heads"]),
         "pallas_nms_compiled_ok": pallas_nms_compiled_ok(),
     }
     for gate, ok in verdicts.items():
@@ -218,7 +220,7 @@ def report_formulations(verdicts: dict) -> None:
 
     env = os.environ.get
     win = _WIN_ATTN_IMPL()
-    if win == "flash" and not verdicts["flash_window_ok"]:
+    if win == "packed" and not verdicts["packed_window_ok"]:
         win = "dense"
     glob = env("TMR_GLOBAL_ATTN", "auto")
     if glob == "auto":
@@ -236,6 +238,21 @@ def report_formulations(verdicts: dict) -> None:
         "decode_tail(TMR_DECODE_TAIL)": decode_tail_mode(),
         "nms": "pallas" if verdicts["pallas_nms_compiled_ok"] else "xla",
     }))
+
+
+def report_window_formulation() -> None:
+    """What the programs compiled so far traced their windowed blocks
+    with: the ``vit.win_attn.<formulation>`` counters (one count a block a
+    trace) and each ``compile`` span's own share of them."""
+    from tmr_tpu import obs
+
+    say("  windowed blocks traced, by formulation: " + json.dumps(
+        obs.get_registry().counters("vit.win_attn.")))
+    for rec in obs.spans():
+        if rec["name"] == "compile":
+            a = rec["attrs"]
+            say(f"  compile {a.get('kind')} {a.get('key')}: win_attn="
+                f"{a.get('win_attn')} x{a.get('win_attn_blocks')}")
 
 
 def report_autotune(cfg, size: Size, batch: int) -> None:
@@ -301,6 +318,7 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
     _, t_run = _timed(lambda: pred(images, ex1))
     say(f"  __call__ 2x1: first call (compile+run) {t_first:.2f}s, "
         f"run {t_run:.3f}s, valid={np.asarray(dets['valid']).sum(1)}")
+    report_window_formulation()
     multi, t_first = _timed(
         lambda: pred.predict_multi_exemplar(images[:1], ex3))
     _, t_run = _timed(lambda: pred.predict_multi_exemplar(images[:1], ex3))

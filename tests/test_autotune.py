@@ -571,9 +571,10 @@ def test_autotune_cached_hit_respects_explicit_knobs(
 
 def test_measured_tpu_defaults(monkeypatch):
     """VERDICT r3 #2 'measured winners become the defaults': with no knobs
-    set, TPU processes default to the BENCH_LIVE.json-measured winners
-    (TMR_WIN_ATTN=flash, TMR_XCORR_IMPL_SMALL=vmap); other backends keep
-    the portable defaults; explicit env always wins."""
+    set, TPU processes default to the measured winners (TMR_WIN_ATTN=packed,
+    on the benchmark's two cells: PERF.md section 6, PR 28;
+    TMR_XCORR_IMPL_SMALL=vmap, BENCH_LIVE.json); other backends keep the
+    portable defaults; explicit env always wins."""
     from tmr_tpu.models import vit as vit_mod
     from tmr_tpu.ops import xcorr as xcorr_mod
 
@@ -585,7 +586,7 @@ def test_measured_tpu_defaults(monkeypatch):
         assert vit_mod._WIN_ATTN_IMPL() == "dense"
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert vit_mod._WIN_ATTN_IMPL() == "flash"
+    assert vit_mod._WIN_ATTN_IMPL() == "packed"
     monkeypatch.setenv("TMR_WIN_ATTN", "folded")
     assert vit_mod._WIN_ATTN_IMPL() == "folded"
 

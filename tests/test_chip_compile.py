@@ -28,6 +28,7 @@ from tmr_tpu.ops.flash_attn import (
     flash_windowed_attention,
 )
 from tmr_tpu.ops.pallas_attn import (
+    packed_windowed_attention,
     pallas_decomposed_attention,
     pallas_windowed_attention,
 )
@@ -69,7 +70,7 @@ def as_tpu(monkeypatch):
     (no interpret mode, TPU defaults), and the gates the ``auto`` path asks
     answer yes — their self-checks execute, which only a chip can."""
     from tmr_tpu.diagnostics import mosaic_gate
-    from tmr_tpu.ops import flash_attn, pallas_nms
+    from tmr_tpu.ops import flash_attn, pallas_attn, pallas_nms
 
     def admits(name):
         def gate(*a, **k):
@@ -82,6 +83,7 @@ def as_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for mod, name in ((flash_attn, "flash_attention_ok"),
                       (flash_attn, "flash_window_ok"),
+                      (pallas_attn, "packed_window_ok"),
                       (pallas_nms, "pallas_nms_compiled_ok")):
         monkeypatch.setattr(mod, name, admits(name))
 
@@ -106,6 +108,26 @@ def _attn_case(fn, grid, batch, grad=False):
         rel = sds((grid, grid, _D), jnp.float32)
         run = jax.grad(loss, argnums=(0, 1, 2)) if grad else forward
         return run, (q, q, q, rel, rel)
+
+    return case
+
+
+def _packed_case(windows, heads, head_dim, grad=False):
+    """The windowed blocks' packed path on the ``qkv`` product's own
+    output, 14 x 16 rows a window, at a cell's production shape; ``grad``
+    compiles what the train step differentiates."""
+    def forward(qkv, rh, rw):
+        return packed_windowed_attention(qkv, rh, rw, (_WIN, _WIN), heads,
+                                         head_dim**-0.5)
+
+    def loss(*args):
+        return jnp.sum(forward(*args).astype(jnp.float32) ** 2)
+
+    def case(sds):
+        qkv = sds((windows * _WIN * 16, 3 * heads * head_dim), jnp.bfloat16)
+        rel = sds((_WIN, _WIN, head_dim), jnp.float32)
+        return (jax.grad(loss, argnums=(0, 1, 2)) if grad else forward), (
+            qkv, rel, rel)
 
     return case
 
@@ -162,6 +184,11 @@ CASES = {
     "flash_window": _attn_case(flash_windowed_attention, _WIN, _NWIN),
     "flash_window_grad": _attn_case(flash_windowed_attention, _WIN, _NWIN,
                                     grad=True),
+    # vitb_fscd147.eval: 16 images of 25 windows, 12 heads of 64;
+    # vith_rpine.eval: 8 images, 16 heads of 80
+    "packed_window_vitb": _packed_case(16 * _NWIN, 12, 64),
+    "packed_window_vith": _packed_case(8 * _NWIN, 16, 80),
+    "packed_window_grad": _packed_case(2 * _NWIN, 12, 64, grad=True),
     "nms": _case_nms,
     "int8_matmul": _case_int8_matmul,
     "predict_program": _case_predict_program,
@@ -183,6 +210,44 @@ def test_compiles_for_v5e(case, one_chip, as_tpu):
         need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
         assert need < V5E_HBM_BYTES, f"{need} bytes do not fit 16 GB"
+
+
+def test_windowed_block_moves_its_operands_once(one_chip, as_tpu):
+    """One windowed ViT-B block at the cell's batch, compiled for the v5e:
+    between the ``qkv`` product and ``proj`` the program holds the kernel
+    and no ``concatenate``, and no ``pad`` whose result is larger than q on
+    the rows the kernel reads (the one pad there is those rows' own, a
+    window row of x from 14 to 16 tokens, ahead of ``qkv``); no ``copy`` or
+    ``transpose`` under the scope has a q-sized result either."""
+    import re
+
+    from tmr_tpu.models.vit import Block
+
+    blk = Block(num_heads=_H, window_size=_WIN, rel_pos_size=(_G, _G),
+                dtype=jnp.bfloat16, name="blocks_0")
+    x = jax.ShapeDtypeStruct((16, _G, _G, _H * _D), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(blk.init, jax.random.key(0), x))
+    text = jax.jit(blk.apply).lower(params, x).compile().as_text()
+    q_elems = 16 * _NWIN * _WIN * 16 * _H * _D
+    seen = set()
+    # the entry computation's instructions are what the device runs
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(.*op_name=\"[^\"]*blocks_0/attn/", line)
+        if not m:
+            continue
+        elems = 1
+        for d in filter(None, m.group(1).split(",")):
+            elems *= int(d)
+        seen.add(m.group(2))
+        assert m.group(2) != "concatenate" or elems < q_elems, line
+        assert m.group(2) != "pad" or elems <= q_elems, line
+        assert m.group(2) not in ("copy", "transpose") or elems < q_elems, \
+            line
+    assert "custom-call" in seen and "fusion" in seen, seen
 
 
 def _shallow_vit_b(cfg):
@@ -292,7 +357,7 @@ def test_partitioned_program_compiles_for_two_v5e_chips(
     assert "all-reduce" in text, "nothing was partitioned over the chips"
     causes = {(r["gate"], r["cause"]) for r in drain_gate_refusals()}
     assert ("flash_attention_ok", "partitioned") in causes
-    assert ("flash_window_ok", "partitioned") in causes
+    assert ("packed_window_ok", "partitioned") in causes
 
 
 @pytest.mark.parametrize("gate,args", [

@@ -145,6 +145,23 @@ def test_a_self_check_asked_in_a_first_call_is_the_compile_spans_child():
     assert "rows" not in got["predict.dispatch"][0]["attrs"]
 
 
+def test_the_compile_span_names_the_windowed_formulation_it_traced():
+    """What ``vit.win_attn.*`` counted during a program's first call is on
+    its ``compile`` span, and a later call adds nothing to it."""
+    def program(x):
+        for _ in range(8):
+            obs.counter("vit.win_attn.packed").inc()
+        return x
+
+    obs.counter("vit.win_attn.packed").inc()  # another program's block
+    fn = obs.track_compile(program, "test_kind_win_attn", ("k", 2))
+    assert fn(1) == 1 and fn(2) == 2
+    (span,) = [r for r in obs.spans() if r["name"] == "compile"
+               and r["attrs"]["kind"] == "test_kind_win_attn"]
+    assert span["attrs"]["win_attn"] == "packed"
+    assert span["attrs"]["win_attn_blocks"] == 8
+
+
 def _serve(pred, n: int, seed: int):
     from tmr_tpu.serve import ServeEngine
 

@@ -766,6 +766,125 @@ def test_pallas_windowed_attention_matches_blockwise(group, D, monkeypatch):
                                    rtol=2e-4, atol=2e-4)
 
 
+def _packed_case(windows, heads, head_dim, dtype, seed=28):
+    """qkv as ``nn.Dense(3 * dim)`` writes it on padded window rows, and
+    the rel-pos tables, at the real 14 x 14 window."""
+    from tmr_tpu.ops.pallas_attn import pad_window_rows
+
+    rng = np.random.default_rng(seed)
+    qkv = pad_window_rows(jnp.asarray(
+        rng.standard_normal((windows, 14, 14, 3 * heads * head_dim)), dtype))
+    rh = jnp.asarray(rng.standard_normal((14, 14, head_dim)) * 0.2,
+                     jnp.float32)
+    rw = jnp.asarray(rng.standard_normal((14, 14, head_dim)) * 0.2,
+                     jnp.float32)
+    return qkv, rh, rw
+
+
+@pytest.mark.parametrize("windows,heads,head_dim,dtype", [
+    (25, 12, 64, "float32"),     # one ViT-B image
+    (25, 16, 80, "float32"),     # one ViT-H image: heads off the lane tile
+    (7, 8, 80, "float32"),       # a count no group of windows divides
+    (400, 12, 64, "bfloat16"),   # ViT-B at batch 16, the deployed dtype
+])
+def test_packed_windowed_attention_matches_blockwise(
+    windows, heads, head_dim, dtype
+):
+    """TMR_WIN_ATTN=packed (ops/pallas_attn.packed_windowed_attention, the
+    interpreter here) against the exact blockwise oracle on the unpacked
+    heads, at window (14, 14). One window is one grid step, so every count
+    of windows divides; float32 holds the oracle to its own rounding
+    (the bias's three-part split is exact), bfloat16 to ``_self_check``'s
+    tolerance, on the first, a middle and the last windows of the 400."""
+    from tmr_tpu.ops.pallas_attn import (
+        _packed_oracle,
+        drop_window_pad,
+        packed_windowed_attention,
+    )
+
+    qkv, rh, rw = _packed_case(windows, heads, head_dim, jnp.dtype(dtype))
+    scale = head_dim**-0.5
+    rows = 14 * 16  # a window's rows, pad tokens included
+    got = jax.jit(lambda *a: packed_windowed_attention(
+        *a, (14, 14), heads, scale))(qkv, rh, rw)
+    assert got.shape == (windows * rows, heads * head_dim)
+    pick = sorted({0, windows // 2, windows - 1}) if windows > 25 \
+        else range(windows)
+    take = np.concatenate([np.arange(w * rows, (w + 1) * rows) for w in pick])
+    want = jax.jit(lambda *a: _packed_oracle(
+        *a, (14, 14), heads, scale))(qkv[take], rh, rw)
+    got, want = (np.asarray(drop_window_pad(x, (14, 14)), np.float32)
+                 for x in (got[take], want))
+    if dtype == "float32":
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    else:
+        assert np.abs(got - want).max() / np.abs(want).max() < 0.05
+
+
+def test_packed_windowed_attention_grads_are_the_oracles():
+    """The packed path's custom_vjp recomputes through the blockwise
+    oracle: gradients w.r.t. qkv and both tables equal the oracle's own,
+    and the pad tokens' rows get none."""
+    from tmr_tpu.ops.pallas_attn import (
+        _packed_oracle,
+        drop_window_pad,
+        packed_windowed_attention,
+    )
+
+    qkv, rh, rw = _packed_case(3, 4, 64, jnp.float32, seed=29)
+    scale = 64**-0.5
+
+    def loss(fn):
+        return lambda *a: jnp.sum(drop_window_pad(
+            fn(*a, (14, 14), 4, scale), (14, 14)) ** 2)
+
+    g_got = jax.jit(jax.grad(loss(packed_windowed_attention),
+                             argnums=(0, 1, 2)))(qkv, rh, rw)
+    g_want = jax.jit(jax.grad(loss(_packed_oracle),
+                              argnums=(0, 1, 2)))(qkv, rh, rw)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+    pad_rows = np.asarray(g_got[0]).reshape(3, 14, 16, -1)[:, :, 14:]
+    assert not pad_rows.any()
+
+
+@pytest.mark.parametrize("backend,taken", [("cpu", "dense"),
+                                           ("tpu", "packed")])
+def test_windowed_blocks_are_counted_by_formulation(
+    backend, taken, monkeypatch
+):
+    """``vit.win_attn.<formulation>`` counts the windowed blocks of a
+    trace: 8 for a depth-12 encoder with ViT-B's pattern of global blocks,
+    under the formulation taken and 0 under every other. Here the CPU
+    takes ``dense``; where the backend reads ``tpu`` and the gate admits
+    the kernel (its self-check only a chip can run) a bfloat16 trace takes
+    ``packed``, and a float32 one still ``dense``."""
+    from tmr_tpu.models.vit import SamViT
+    from tmr_tpu.obs import metrics
+    from tmr_tpu.ops import pallas_attn
+
+    monkeypatch.delenv("TMR_WIN_ATTN", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(pallas_attn, "packed_window_ok", lambda *a: True)
+    model = SamViT(embed_dim=128, depth=12, num_heads=4,
+                   global_attn_indexes=(2, 5, 8, 11), window_size=14,
+                   out_chans=8, pretrain_img_size=448, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, 448, 448, 3), jnp.float32)
+    params = jax.eval_shape(model.init, jax.random.key(0), x)
+    names = ("dense", "folded", "flash", "pallas", "packed")
+
+    def counts(m):
+        metrics.get_registry().reset("vit.win_attn.")
+        jax.eval_shape(m.apply, params, x)
+        return {n: 0 for n in names} | metrics.get_registry().counters(
+            "vit.win_attn.")
+
+    assert counts(model) == {n: 8 * (n == taken) for n in names}
+    assert counts(model.clone(dtype=jnp.float32)) == {
+        n: 8 * (n == "dense") for n in names}
+
+
 @pytest.mark.slow
 def test_win_attn_env_dispatch_pallas(monkeypatch):
     """A windowed Attention module under TMR_WIN_ATTN=pallas must equal the

@@ -174,8 +174,8 @@ ENV_KNOBS = {
     # formulation dispatch (trace-time; autotune exports winners here)
     "TMR_GLOBAL_ATTN": "global ViT attention formulation: auto|blockwise|"
         "blockfolded|densefolded|flash|xlaflash|pallas|fused",
-    "TMR_WIN_ATTN": "windowed ViT attention formulation: auto|dense|"
-        "flash|pallas",
+    "TMR_WIN_ATTN": "windowed ViT attention formulation: dense|folded|"
+        "flash|pallas|packed (the TPU bf16 default)",
     "TMR_XCORR_IMPL": "template-correlation formulation: auto|conv|"
         "convnhwc|vmap|fft|pallas",
     "TMR_XCORR_IMPL_SMALL": "small-bucket override of TMR_XCORR_IMPL",

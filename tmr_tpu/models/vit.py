@@ -36,17 +36,20 @@ def _WIN_ATTN_IMPL() -> str:
     """Windowed-attention formulation, read at trace time: "dense" (separate
     f32 bias einsums + adds), "folded" (bias inside the QK contraction),
     "flash" (stock Pallas kernel over 256-padded folded QK, bf16/TPU only),
-    or "pallas" (the custom decomposed-bias kernel, ops/pallas_attn.py).
-    A/B knob for hardware profiling — see Attention below.
+    "pallas" (the custom decomposed-bias kernel on head-major operands) or
+    "packed" (the kernel that reads the ``qkv`` product's output as it lies
+    and writes what ``proj`` reads, ops/pallas_attn.py).
 
-    Default: "flash" on TPU, "dense" elsewhere. Measured, not assumed: the
-    on-device autotune sweep picked flash at the production ViT-B/1024
-    shapes on TPU v5 lite (BENCH_LIVE.json, 2026-07-31, the repo's first
-    driver-grade measurement) — the VERDICT r3 "measured winners become the
-    defaults" mandate. Safe as a default: the flash path runs behind a
-    per-geometry compiled self-check with dense fallback (Attention below),
-    and the bf16/geometry gates mean non-TPU or f32 traces never take it."""
-    dflt = "flash" if jax.default_backend() == "tpu" else "dense"
+    Default: "packed" on TPU, "dense" elsewhere. On the v5e the windowed
+    blocks' attention took 11.1 ms an image of ViT-B/1024 under "flash",
+    7.9 of them pads, concatenates and transposes around the kernel
+    (PERF.md section 5, from PR 25's chip trace); "packed" has none of
+    them, and PERF.md section 6 (PR 28) has both cells' numbers for all
+    five. Safe as a default: the path runs behind a per-geometry compiled
+    self-check with dense fallback (Attention below), and the bf16, backend
+    and partitioning gates mean non-TPU, float32 or GSPMD-partitioned
+    traces never take it."""
+    dflt = "packed" if jax.default_backend() == "tpu" else "dense"
     return os.environ.get("TMR_WIN_ATTN", dflt)
 
 
@@ -64,6 +67,15 @@ def _pallas_window_available(
     from tmr_tpu.ops.pallas_attn import _win_group, pallas_window_ok
 
     return pallas_window_ok(gh, gw, head_dim, _win_group(bh))
+
+
+def _packed_window_available(
+    gh: int, gw: int, head_dim: int, num_heads: int
+) -> bool:
+    from tmr_tpu.ops.pallas_attn import packed_supported, packed_window_ok
+
+    return packed_supported((gh, gw), num_heads, head_dim) and \
+        packed_window_ok(gh, gw, head_dim, num_heads)
 
 
 def window_partition(x: jnp.ndarray, window: int):
@@ -367,19 +379,63 @@ class Attention(nn.Module):
     seq_mesh: Optional[object] = None  # jax.sharding.Mesh with a 'seq' axis
     seq_axis: str = "seq"
     batch_axis: Optional[str] = "data"
+    # set by Block for its windowed blocks: their traces are counted by
+    # formulation (obs counter ``vit.win_attn.<formulation>``)
+    windowed: bool = False
+
+    def _window_formulation(self, h: int, w: int, head_dim: int,
+                            bh: int) -> str:
+        """The formulation a block under 1024 tokens traces with: the
+        requested one (``_WIN_ATTN_IMPL``) where its dtype precondition and
+        its gate admit it at this geometry, else "dense"."""
+        want = _WIN_ATTN_IMPL()
+        bf16 = self.dtype == jnp.bfloat16
+        if not self.use_rel_pos:
+            got = "dense"
+        elif want == "packed" and bf16 and _packed_window_available(
+            h, w, head_dim, self.num_heads
+        ):
+            got = "packed"
+        elif want == "flash" and bf16 and _flash_window_available(
+            h, w, head_dim
+        ):
+            got = "flash"
+        elif want == "pallas" and _pallas_window_available(
+            h, w, head_dim, bh
+        ):
+            got = "pallas"
+        elif want == "folded":
+            got = "folded"
+        else:
+            got = "dense"
+        if got == "dense" and os.environ.get("TMR_WIN_ATTN") in (
+            "flash", "pallas", "packed"
+        ):
+            # an EXPLICIT kernel request landed here only because its
+            # gate (or dtype precondition) refused — warn, or an A/B
+            # records dense timings under the requested label. The
+            # TPU default ("packed" with no env set) falls back silently
+            # by design.
+            import warnings
+
+            warnings.warn(FormulationFallbackWarning(
+                "TMR_WIN_ATTN",
+                f"TMR_WIN_ATTN={os.environ['TMR_WIN_ATTN']}: gate or "
+                f"dtype refused window grid ({h}, {w}, head_dim "
+                f"{head_dim}, dtype {self.dtype}); running dense "
+                "fallback"
+            ))
+        if self.windowed:
+            from tmr_tpu.obs import metrics
+
+            metrics.counter(f"vit.win_attn.{got}").inc()
+        return got
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         b, h, w, dim = x.shape
         head_dim = dim // self.num_heads
         scale = head_dim**-0.5
-
-        qkv = nn.Dense(dim * 3, dtype=self.dtype, name="qkv")(x)
-        qkv = qkv.reshape(b, h * w, 3, self.num_heads, head_dim)
-        q, k, v = jnp.moveaxis(qkv, 2, 0)  # each (b, hw, heads, hd)
-        q = q.transpose(0, 2, 1, 3)  # (b, heads, hw, hd)
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
 
         rh = rw = None
         if self.use_rel_pos:
@@ -395,6 +451,36 @@ class Attention(nn.Module):
             )
             rh = get_rel_pos(h, h, rel_pos_h)  # (h, h, hd) f32
             rw = get_rel_pos(w, w, rel_pos_w)  # (w, w, hd) f32
+
+        win = None
+        if self.seq_mesh is None and h * w < 1024:
+            win = self._window_formulation(
+                h, w, head_dim, b * self.num_heads)
+        if win == "packed":
+            # the windowed blocks' TPU bf16 path: the kernel takes qkv as
+            # the product wrote it and writes what proj reads — no
+            # per-head operand, concatenate or transpose between the two
+            # products, which run on rows of tokens (a window's row padded
+            # 14 -> 16: ops/pallas_attn._padded_width has the why)
+            from tmr_tpu.ops.pallas_attn import (
+                drop_window_pad,
+                packed_windowed_attention,
+                pad_window_rows,
+            )
+
+            qkv = nn.Dense(dim * 3, dtype=self.dtype, name="qkv")(
+                pad_window_rows(x))
+            x = packed_windowed_attention(
+                qkv, rh, rw, (h, w), self.num_heads, scale)
+            x = nn.Dense(dim, dtype=self.dtype, name="proj")(x)
+            return drop_window_pad(x, (h, w))
+
+        qkv = nn.Dense(dim * 3, dtype=self.dtype, name="qkv")(x)
+        qkv = qkv.reshape(b, h * w, 3, self.num_heads, head_dim)
+        q, k, v = jnp.moveaxis(qkv, 2, 0)  # each (b, hw, heads, hd)
+        q = q.transpose(0, 2, 1, 3)  # (b, heads, hw, hd)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
 
         if self.seq_mesh is not None:
             x = self._ring_attn(q, k, v, rh, rw, (b, h, w, dim), head_dim)
@@ -582,12 +668,7 @@ class Attention(nn.Module):
                 (h, w), scale,
             )
             x = x.transpose(0, 2, 1, 3).reshape(b, h, w, dim)
-        elif (
-            self.use_rel_pos
-            and _WIN_ATTN_IMPL() == "flash"
-            and self.dtype == jnp.bfloat16
-            and _flash_window_available(h, w, head_dim)
-        ):
+        elif win == "flash":
             # A/B variant (TMR_WIN_ATTN=flash): the stock Pallas kernel over
             # 256-padded windows with a pad segment — zero per-window score
             # materialization. bf16-only (the kernel's compute dtype); gated
@@ -596,11 +677,7 @@ class Attention(nn.Module):
 
             x = flash_windowed_attention(q, k, v, rh, rw, (h, w), scale)
             x = x.transpose(0, 2, 1, 3).reshape(b, h, w, dim)
-        elif (
-            self.use_rel_pos
-            and _WIN_ATTN_IMPL() == "pallas"
-            and _pallas_window_available(h, w, head_dim, b * self.num_heads)
-        ):
+        elif win == "pallas":
             # A/B variant (TMR_WIN_ATTN=pallas): the custom decomposed-bias
             # kernel (ops/pallas_attn.py) on 128-padded window tiles with
             # in-kernel pad-column masking — native head-dim contraction,
@@ -611,22 +688,7 @@ class Attention(nn.Module):
             x = pallas_windowed_attention(q, k, v, rh, rw, (h, w), scale)
             x = x.transpose(0, 2, 1, 3).reshape(b, h, w, dim)
         else:
-            if os.environ.get("TMR_WIN_ATTN") in ("flash", "pallas"):
-                # an EXPLICIT kernel request landed here only because its
-                # gate (or dtype precondition) refused — warn, or an A/B
-                # records dense timings under the requested label. The
-                # TPU default ("flash" with no env set) falls back silently
-                # by design.
-                import warnings
-
-                warnings.warn(FormulationFallbackWarning(
-                    "TMR_WIN_ATTN",
-                    f"TMR_WIN_ATTN={os.environ['TMR_WIN_ATTN']}: gate or "
-                    f"dtype refused window grid ({h}, {w}, head_dim "
-                    f"{head_dim}, dtype {self.dtype}); running dense "
-                    "fallback"
-                ))
-            if self.use_rel_pos and _WIN_ATTN_IMPL() == "folded":
+            if win == "folded":
                 # A/B variant for the windowed blocks (TMR_WIN_ATTN=folded):
                 # the decomposed bias rides inside the QK contraction via the
                 # flash_attn augmentation (q'=[q*scale|q.RH|q.RW],
@@ -747,6 +809,7 @@ class Block(nn.Module):
             # parallelism applies to the quadratic global blocks only
             seq_mesh=self.seq_mesh if self.window_size == 0 else None,
             batch_axis=self.batch_axis,
+            windowed=self.window_size > 0,
             name="attn",
         )(x)
         if self.window_size > 0:

@@ -21,7 +21,9 @@ registry pattern) and the process-wide metrics registry
 ``compile`` span (``scope="setup"``, always recorded: obs/tracing.py) on
 the thread that paid the wall time, open while the call runs, so a gate
 self-check asked inside the model's trace
-(``diagnostics.run_outside_trace``) is its child.
+(``diagnostics.run_outside_trace``) is its child; it carries the windowed
+attention formulation the program traced with (``win_attn``) and how many
+blocks took it (``win_attn_blocks``), from the ``vit.win_attn.*`` counters.
 
 :func:`track_compile`'s wrapper is also the one seam every Predictor
 program is called through, so it records each call as a
@@ -42,6 +44,9 @@ from typing import Any, List, Optional
 
 from tmr_tpu.obs import metrics as _metrics
 from tmr_tpu.obs import tracing as _tracing
+
+#: prefix of the counters of windowed blocks traced, by formulation
+_WIN_ATTN = "vit.win_attn."
 
 #: bounded like diagnostics._GATE_REFUSALS: a long-lived server that
 #: never drains must not grow without bound
@@ -147,9 +152,20 @@ def track_compile(fn, kind: str, key: Any,
                 return fn(*args, **kw)
             with _tracing.span("compile", scope="setup", kind=kind,
                                key=repr(key)) as sp:
+                # models/vit.py counts each windowed block it traces by
+                # the formulation taken: the difference over the first
+                # call is this program's own
+                counts = _metrics.get_registry().counters
+                before = counts(_WIN_ATTN)
                 t0 = time.perf_counter()
                 out = fn(*args, **kw)
                 t1 = time.perf_counter()
+                traced = {n: v - before.get(n, 0)
+                          for n, v in counts(_WIN_ATTN).items()
+                          if v > before.get(n, 0)}
+                if traced:
+                    sp.set_attr(win_attn="+".join(sorted(traced)),
+                                win_attn_blocks=sum(traced.values()))
                 with lock:
                     if not done:
                         done.append(True)

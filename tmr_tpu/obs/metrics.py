@@ -210,6 +210,14 @@ class MetricsRegistry:
         with self._lock:
             return sorted(self._instruments)
 
+    def counters(self, prefix: str = "") -> Dict[str, object]:
+        """The counters whose name starts with ``prefix``, by the rest of
+        the name: one family's counts without a whole snapshot."""
+        with self._lock:
+            return {n[len(prefix):]: i.value
+                    for n, i in sorted(self._instruments.items())
+                    if n.startswith(prefix) and isinstance(i, Counter)}
+
     def reset(self, prefix: str = "") -> None:
         """Drop instruments whose name starts with ``prefix`` (all, when
         empty) — test/harness hygiene between measurements."""

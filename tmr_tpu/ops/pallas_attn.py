@@ -469,6 +469,366 @@ def _win_vjp_bwd(grid_hw, scale, res, g):
 _pallas_win_vjp.defvjp(_win_vjp_fwd, _win_vjp_bwd)
 
 
+# --------------------------------------------------------------------------
+# Packed windowed attention (TMR_WIN_ATTN=packed, the TPU bf16 default).
+#
+# The kernels above take q/k/v head-major, (B', H, S, D): between the
+# ``qkv`` product, which writes (B', S, 3*dim) token-major, and ``proj``,
+# which reads (B', S, dim), that costs a transpose of every operand, and
+# pads and concatenates besides: of the 11.1 ms an image that the 8
+# windowed blocks' attention took on ViT-B/1024 (PR 25's chip trace), 7.9
+# were HBM passes that compute nothing. This kernel reads what ``qkv``
+# wrote and writes what ``proj`` reads. One grid step is one window, all
+# of its heads:
+#
+# - head h's q, k, v are lanes [h*D, (h+1)*D) of their third of the row.
+#   The kernel never slices lanes off a 128 boundary (head dim 80 is not a
+#   lane multiple): it loads the aligned slab, or pair of slabs, that
+#   covers the head and zeroes q's other lanes, so the contraction over
+#   the slab is the head's own q.k; the a.v product is taken against the
+#   same lanes of v and the head's lanes selected into the output.
+# - the decomposed bias enters as one more product on the MXU: the small
+#   float32 projections q.RH / q.RW (gh + gw numbers a token and head, 32
+#   lanes a head, so four heads a slab) are split in VMEM into three
+#   parts of the operand dtype that sum to the float32 value exactly, laid
+#   side by side in the slab (lane rolls) and multiplied by a constant 0/1
+#   selector; accumulated in float32 like the scores.
+# - a window is 14 rows of 16 tokens (``_padded_width``), a whole number
+#   of tiles, so the products write and read the kernel's rows directly.
+# --------------------------------------------------------------------------
+_PROJ_LANES = 32  # lanes a head's bias projections take: gh + gw <= 32
+_PROJ_HEADS = 128 // _PROJ_LANES  # heads a 128-lane slab of them holds
+
+
+def _split3(x: jnp.ndarray, dtype) -> Tuple[jnp.ndarray, ...]:
+    """Three float32 arrays, each exact in ``dtype``, that sum to float32
+    ``x``: exactly for bfloat16 (3 x 8 mantissa bits), trivially for
+    float32 (x, 0, 0)."""
+    hi = x.astype(dtype).astype(jnp.float32)
+    r1 = x - hi
+    mid = r1.astype(dtype).astype(jnp.float32)
+    lo = (r1 - mid).astype(dtype).astype(jnp.float32)
+    return hi, mid, lo
+
+
+def _packed_win_kernel(
+    qkv_ref, proj_ref, sel_ref, out_ref,
+    *, num_heads: int, head_dim: int, scale: float,
+):
+    """One window, all of its heads. Refs: qkv (S, 3*dim), proj (S, P)
+    float32, sel (4, 128, S), out (S, dim); S counts the window's pad
+    tokens too (``_padded_width``). The heads are a loop, not an unroll:
+    the chip compiles a Mosaic kernel every time a program that holds it
+    is loaded, compile cache or not, in proportion to its code, and a
+    model has one instance a windowed block."""
+    dim = num_heads * head_dim
+    dtype = qkv_ref.dtype
+    width = _head_lanes(head_dim)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    group = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1) // _PROJ_LANES
+
+    def head(h, carry):
+        a = h * head_dim
+        lo = pl.multiple_of(
+            jnp.minimum(a // 128 * 128, dim - width), 128)
+        mine = (lanes >= a - lo) & (lanes < a - lo + head_dim)
+        q = qkv_ref[:, pl.ds(lo, width)]
+        q = jnp.where(mine, q, jnp.zeros_like(q))
+        s = jax.lax.dot_general(
+            q, qkv_ref[:, pl.ds(dim + lo, width)], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+
+        # the head's bias projections: three parts that sum to the float32
+        # value, side by side (its own 32 lanes, then rolled into the two
+        # groups after it); ones in the fourth group, where sel[c] holds
+        # the pad keys' -1e30
+        c = h % _PROJ_HEADS
+        p0 = pl.multiple_of(h // _PROJ_HEADS * 128, 128)
+        parts = _split3(proj_ref[:, pl.ds(p0, 128)], dtype)
+        parts = (parts[0], pltpu.roll(parts[1], _PROJ_LANES, 1),
+                 pltpu.roll(parts[2], 2 * _PROJ_LANES, 1))
+        x = jnp.ones_like(parts[0])
+        for n in (2, 1, 0):
+            x = jnp.where(group == (c + n) % _PROJ_HEADS, parts[n], x)
+        s += jax.lax.dot_general(
+            x.astype(dtype), sel_ref[c], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+        p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+        inv = 1.0 / jnp.sum(p, axis=1, keepdims=True)
+        o = jax.lax.dot_general(
+            p.astype(dtype), qkv_ref[:, pl.ds(2 * dim + lo, width)],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * inv
+        # the slabs are shared with other heads: write this head's lanes
+        out = out_ref[:, pl.ds(lo, width)]
+        out_ref[:, pl.ds(lo, width)] = jnp.where(
+            mine, o.astype(out.dtype), out)
+        return carry
+
+    jax.lax.fori_loop(0, num_heads, head, 0)
+
+
+def _padded_width(gw: int) -> int:
+    """Tokens a window row takes in the packed operands: the grid's
+    columns rounded up to the 16-row tile of a 16-bit operand (14 -> 16).
+    With whole tiles a row, (B', gh, gwp, C) and (B'*gh*gwp, C) are the
+    same bytes, so the products on either side write and read the kernel's
+    rows with no relayout, and a window is a legal block. (At 196 tokens a
+    window XLA puts the windows second-minor, and every reshape between
+    that and the kernel's row-major order is a pass over the operand.) The
+    pad tokens are keys no query may see: ``_packed_selector`` masks
+    them; as queries they cost their share of the tile and are dropped by
+    the caller."""
+    return -(-gw // 16) * 16
+
+
+def _proj_width(num_heads: int) -> int:
+    return -(-num_heads // _PROJ_HEADS) * 128
+
+
+def _packed_projections(
+    qkv: jnp.ndarray, rh: jnp.ndarray, rw: jnp.ndarray,
+    grid_hw: Tuple[int, int], num_heads: int,
+) -> jnp.ndarray:
+    """(B'*S, 3*dim) qkv + (gh, gh, D)/(gw, gw, D) tables -> (B'*S, P)
+    float32: for token t = (y, x) of its window and head h, lanes
+    [32h, 32h + gh) hold q.RH[y] and the next gw q.RW[x], the numbers of
+    ``_bias_projections``.
+
+    The tables become block-diagonal weights over the heads, (dim, P) for
+    each grid row and each grid column, so that the two products contract
+    q's whole row where it lies: a third of the ``qkv`` product's
+    operations, no per-head operand, and q read in the rows it has. As
+    there, a float32 operand is rounded to the operand dtype by a TPU's
+    default precision and the sum is float32."""
+    gh, gw = grid_hw
+    dim = qkv.shape[1] // 3
+    D = dim // num_heads
+    P = _proj_width(num_heads)
+    # w[g, h*D + d, 32*h + first + j] = table[g, j, d], zero elsewhere.
+    # Spread over rows and columns by two products with 0/1 matrices (each
+    # entry a single term, so exact) and cut to the diagonal blocks by a
+    # mask: cheap where an outer product with eye(H) reshaped to 2-D is a
+    # lane shuffle.
+    row, col = jnp.arange(dim)[:, None], jnp.arange(P)[None, :]
+    rows = (row % D == jnp.arange(D)[None, :]).astype(jnp.float32)
+    cols = (col % _PROJ_LANES == jnp.arange(_PROJ_LANES)[:, None]).astype(
+        jnp.float32)
+    blocks = row // D == col // _PROJ_LANES
+
+    def weights(table, first):  # (g, k, D) -> (g, dim, P)
+        table = jnp.pad(table.astype(jnp.float32), (
+            (0, 0), (first, _PROJ_LANES - first - table.shape[1]), (0, 0)))
+        w = jnp.einsum("rd,gjd,jn->grn", rows, table, cols,
+                       precision=jax.lax.Precision.HIGHEST)
+        return jnp.where(blocks, w, 0.0).astype(qkv.dtype)
+
+    gwp = _padded_width(gw)
+    q = qkv[:, :dim].reshape(-1, gh, gwp, dim)
+    if jax.default_backend() == "cpu":
+        # XLA:CPU has no bfloat16 x bfloat16 -> float32 product
+        q = q.astype(jnp.float32)
+    w_cols = jnp.pad(weights(rw, gh), ((0, gwp - gw), (0, 0), (0, 0)))
+    proj = sum(
+        jnp.einsum(spec, q, w.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+        for spec, w in (("byxc,ycn->byxn", weights(rh, 0)),
+                        ("byxc,xcn->byxn", w_cols)))
+    return row_major(proj).reshape(-1, P)
+
+
+def row_major(x: jnp.ndarray) -> jnp.ndarray:
+    """Pin ``x`` to the row-major layout. Of a product over windows XLA
+    would rather put the windows second-minor and copy the result to the
+    order the kernel reads; told so, the product writes that order."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def _packed_selector(grid_hw: Tuple[int, int], dtype) -> jnp.ndarray:
+    """(4, 128, S) constants. Head c of a slab has its three parts in lane
+    groups c, c+1, c+2 (mod 4); in each, lane j < gh selects the keys of
+    grid row j and lane gh + j the keys of grid column j: the product with
+    the parts is bias[t, u] = proj[t, ky(u)] + proj[t, gh + kx(u)]. The
+    fourth group's first lane holds 1 in the kernel and here -1e30 for the
+    pad keys (kx >= gw)."""
+    import numpy as np
+
+    gh, gw = grid_hw
+    gwp = _padded_width(gw)
+    u = np.arange(gh * gwp)
+    ky, kx = u // gwp, u % gwp
+    j = np.arange(128)[:, None] % _PROJ_LANES
+    one = ((j == ky) | ((j - gh == kx) & (j < gh + gw))) & (kx < gw)
+    grp = np.arange(128)[:, None] // _PROJ_LANES
+    sel = np.zeros((_PROJ_HEADS, 128, u.size), np.float32)
+    for c in range(_PROJ_HEADS):
+        sel[c] = one & (((grp - c) % _PROJ_HEADS) < 3)
+        sel[c, ((c + 3) % _PROJ_HEADS) * _PROJ_LANES, kx >= gw] = _NEG_INF
+    return jnp.asarray(sel, dtype)
+
+
+def _head_lanes(head_dim: int) -> int:
+    """The lanes one head's slices take: its own slab where heads tile the
+    128 lanes (64), else the two slabs that cover it (80)."""
+    return 128 if 128 % head_dim == 0 else 256
+
+
+def packed_supported(
+    grid_hw: Tuple[int, int], num_heads: int, head_dim: int
+) -> bool:
+    """Whole 128-lane slabs of heads, a head within the lanes its slices
+    take, and room for its projections in their 32 lanes."""
+    dim = num_heads * head_dim
+    return (dim % 128 == 0 and head_dim <= 128
+            and dim >= _head_lanes(head_dim)
+            and sum(grid_hw) <= _PROJ_LANES)
+
+
+def packed_windowed_attention(
+    qkv: jnp.ndarray,
+    rh: jnp.ndarray,
+    rw: jnp.ndarray,
+    grid_hw: Tuple[int, int],
+    num_heads: int,
+    scale: float,
+) -> jnp.ndarray:
+    """Windowed attention on the ``qkv`` product's own output: qkv
+    (B'*S, 3*dim), a row a token (``pad_window_rows``: windows of
+    S = gh*gwp rows one after the other), q | k | v along the row and
+    heads within each, as ``nn.Dense(3 * dim)`` writes it; rh (gh, gh, D) /
+    rw (gw, gw, D) the get_rel_pos tables. Returns (B'*S, dim), what
+    ``proj`` reads; the pad tokens' rows hold nothing of use
+    (``drop_window_pad``). Same math as ``blockwise_decomposed_attention``
+    on the real tokens' unpacked heads; differentiable by recomputing
+    through it."""
+    return _packed_win_vjp(qkv, rh, rw, grid_hw, num_heads, scale)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _packed_win_vjp(qkv, rh, rw, grid_hw, num_heads, scale):
+    return _packed_win_fwd_impl(qkv, rh, rw, grid_hw, num_heads, scale)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _packed_win_fwd_impl(qkv, rh, rw, grid_hw, num_heads, scale):
+    # a jit of its own: a model's 8 or 28 windowed blocks are one shape,
+    # and share one traced and lowered function in the enclosing program
+    rows, c3 = qkv.shape
+    S = grid_hw[0] * _padded_width(grid_hw[1])
+    dim = c3 // 3
+    if not packed_supported(grid_hw, num_heads, dim // num_heads):
+        raise ValueError(
+            f"window grid {grid_hw} at {num_heads} heads of "
+            f"{dim // num_heads} has no packed layout; gate callers on "
+            "packed_supported()"
+        )
+    P = _proj_width(num_heads)
+    kernel = functools.partial(
+        _packed_win_kernel, num_heads=num_heads, head_dim=dim // num_heads,
+        scale=scale,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=(rows // S,),
+        in_specs=[
+            pl.BlockSpec((S, c3), lambda b: (b, 0)),
+            pl.BlockSpec((S, P), lambda b: (b, 0)),
+            pl.BlockSpec((_PROJ_HEADS, 128, S), lambda b: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((S, dim), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, dim), qkv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
+        interpret=jax.default_backend() != "tpu",
+    )(
+        qkv, _packed_projections(qkv, rh, rw, grid_hw, num_heads),
+        _packed_selector(grid_hw, qkv.dtype),
+    )
+
+
+def pad_window_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """(B', gh, gw, C) -> (B'*gh*gwp, C), a window row padded with zero
+    tokens to ``_padded_width``: the rows ``packed_windowed_attention``
+    takes."""
+    B, gh, gw, C = x.shape
+    gwp = _padded_width(gw)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, gwp - gw), (0, 0)))
+    return x.reshape(B * gh * gwp, C)
+
+
+def drop_window_pad(x: jnp.ndarray, grid_hw: Tuple[int, int]) -> jnp.ndarray:
+    """The inverse: (B'*gh*gwp, C) -> (B', gh, gw, C)."""
+    gh, gw = grid_hw
+    return x.reshape(-1, gh, _padded_width(gw), x.shape[-1])[:, :, :gw]
+
+
+def _packed_oracle(qkv, rh, rw, grid_hw, num_heads, scale):
+    """The same on the exact blockwise path: the real tokens' heads
+    unpacked, head-major; zeros in the pad tokens' rows."""
+    from tmr_tpu.models.vit import blockwise_decomposed_attention
+
+    gh, gw = grid_hw
+    t = drop_window_pad(qkv, grid_hw).reshape(
+        -1, gh * gw, 3, num_heads, qkv.shape[1] // 3 // num_heads)
+    q, k, v = jnp.moveaxis(t, 2, 0).transpose(0, 1, 3, 2, 4)
+    out = blockwise_decomposed_attention(q, k, v, rh, rw, grid_hw, scale)
+    out = out.transpose(0, 2, 1, 3).reshape(-1, gh, gw, qkv.shape[1] // 3)
+    return pad_window_rows(out)
+
+
+def _packed_vjp_fwd(qkv, rh, rw, grid_hw, num_heads, scale):
+    return _packed_win_fwd_impl(qkv, rh, rw, grid_hw, num_heads, scale), (
+        qkv, rh, rw,
+    )
+
+
+def _packed_vjp_bwd(grid_hw, num_heads, scale, res, g):
+    _, pull = jax.vjp(
+        lambda a, b, c: _packed_oracle(a, b, c, grid_hw, num_heads, scale),
+        *res,
+    )
+    return pull(g)
+
+
+_packed_win_vjp.defvjp(_packed_vjp_fwd, _packed_vjp_bwd)
+
+
+def _packed_on_heads(q, k, v, rh, rw, grid_hw, scale):
+    """The packed path behind the head-major signature ``_self_check``
+    drives: q/k/v (B', H, S, D) packed as ``qkv`` lays them out."""
+    B, H, S, D = q.shape
+    qkv = jnp.stack([q, k, v], axis=2)  # (B', H, 3, S, D)
+    qkv = qkv.transpose(0, 3, 2, 1, 4).reshape(B, *grid_hw, 3 * H * D)
+    out = packed_windowed_attention(
+        pad_window_rows(qkv), rh, rw, grid_hw, H, scale)
+    return drop_window_pad(out, grid_hw).reshape(B, S, H, D).transpose(
+        0, 2, 1, 3)
+
+
+@mosaic_gate
+def packed_window_ok(
+    gh: int, gw: int, head_dim: int, num_heads: int
+) -> bool:
+    """Per-geometry compiled self-check of the packed windowed kernel
+    against the exact blockwise oracle, forward and gradients, at the
+    window grid and — the head count and head dim fix every lane offset
+    the kernel uses — at the model's own heads (two windows of them)."""
+    from tmr_tpu.ops.flash_attn import _self_check
+
+    return _self_check(
+        _packed_on_heads, 2, num_heads, gh, gw, head_dim,
+        gate="packed_window_ok",
+    )
+
+
 @mosaic_gate
 def pallas_window_ok(
     gh: int, gw: int, head_dim: int, group: int = 1
