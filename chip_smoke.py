@@ -237,6 +237,73 @@ def check_grouped_products(size: Size, seed: int, batch: int = 2) -> None:
                       "the backbone's sizes")
 
 
+def check_kda_kernel(size: Size, seed: int, batch: int = 2) -> bool:
+    """The recurrence's Pallas kernel (``ops/kda.py:kda_chunk_kernel``),
+    which off the chip runs only in the interpreter: held here to
+    ``kda_chunked`` at the backbone's own shape and to the token recurrence
+    on 256 tokens, a decay near 0 and one near 1 among the heads. Returns
+    whether a trace of the backbone takes the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
+    from tmr_tpu.ops import kda
+
+    z = TRUNK_CONFIGS[size.backbone]
+    h, d, bf = z["num_heads"], z["kda_head_dim"], jnp.bfloat16
+    seq = (size.image_size // 16) ** 2
+    formulation = kda.kda_formulation(seq, d, d, bf, h)
+    say(f"  recurrence: kda_formulation({seq}, {d}, {d}, bfloat16, {h}) = "
+        f"{formulation}, {kda._heads_per_step(h)} heads a grid step")
+    if formulation != "chunk_kernel":
+        return False
+
+    def inputs(key, tokens):
+        ks = jax.random.split(key, 6)
+        unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+        shape = (batch, tokens, h, d)
+        # log-decays as the mixer makes them: -exp(A_log) softplus(. + bias)
+        rate = jnp.exp(jax.random.uniform(ks[5], (h, 1), maxval=2.8))
+        g = -rate * jax.nn.softplus(
+            2.0 * jax.random.normal(ks[3], shape) - 4.0)
+        return (unit(jax.random.normal(ks[0], shape)) * d ** -0.5,
+                unit(jax.random.normal(ks[1], shape)),
+                jax.random.normal(ks[2], shape).astype(bf), g,
+                jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+
+    kernel = jax.jit(lambda *a: kda.kda_chunk_kernel(*a, bf))
+    chunked = jax.jit(lambda *a: kda.kda_chunked(*a, dtype=bf))
+    args = inputs(jax.random.key(seed), seq)
+    want = np.asarray(chunked(*args))
+    gap = float(np.abs(np.asarray(kernel(*args)) - want).max()
+                / np.abs(want).max())
+    say(f"  recurrence, {batch} x {seq} tokens x {h} heads of {d}: the "
+        f"kernel against kda_chunked, widest gap {gap:.5f} of the range")
+    check(gap < 0.01, "the recurrence's kernel equals kda_chunked at the "
+                      "backbone's shape")
+    # as the mixer calls it: q and k as the convolutions left them, the
+    # norms on either side of the recurrence inside the kernel
+    _, _, v, g, beta = inputs(jax.random.key(seed + 1), 256)
+    keys = jax.random.split(jax.random.key(seed + 2), 3)
+    q, k = (jax.random.normal(key, v.shape).astype(bf) for key in keys[:2])
+    weight = 1.0 + 0.1 * jax.random.normal(keys[2], (d,))
+    g = g.at[:, :, 0].set(np.log(1e-9)).at[:, :, 1].set(np.log(1 - 1e-6))
+    want = np.asarray(jax.jit(lambda q, k, v, g, beta, w: kda.rms_norm(
+        kda.kda_recurrent(kda.l2norm(q) * d ** -0.5, kda.l2norm(k), v, g,
+                          beta), w, 1e-5))(q, k, v, g, beta, weight))
+    got = np.asarray(jax.jit(lambda q, k, v, g, beta, w: kda.kda_chunk_kernel(
+        q, k, v, g, beta, bf, d ** -0.5, (w, 1e-5)))(q, k, v, g, beta,
+                                                     weight))
+    gap = float(np.abs(got - want).max() / np.abs(want).max())
+    say(f"  recurrence with its norms, 256 tokens, decays 1e-9 and 1 - 1e-6 "
+        f"among them: the kernel against the token recurrence, widest gap "
+        f"{gap:.5f} of the range (bfloat16 operands against float32)")
+    check(np.isfinite(got).all() and gap < 0.03,
+          "the recurrence's kernel, the norms in it, equals the token "
+          "recurrence on 256 tokens")
+    return True
+
+
 def decide_gates(cfg, size: Size) -> dict:
     """Ask, outside any trace, every gate the ``auto`` path of this
     configuration consults; the model's traces then hit their caches."""
@@ -246,12 +313,13 @@ def decide_gates(cfg, size: Size) -> dict:
     from tmr_tpu.ops.pallas_nms import pallas_nms_compiled_ok
 
     if _is_trunk(size):
-        # a trunk of typed layers asks no attention gate: its formulations
-        # are on its compile span (report_window_formulation)
+        # a trunk of typed layers asks no attention gate of the ViT's: its
+        # formulations are on its compile span (report_window_formulation)
         verdicts = {"flash_attention_ok": False, "packed_window_ok": False,
                     "pallas_nms_compiled_ok": pallas_nms_compiled_ok()}
         say(f"  gate pallas_nms_compiled_ok: "
             f"{'pass' if verdicts['pallas_nms_compiled_ok'] else 'refused'}")
+        verdicts["kda_chunk_ok"] = check_kda_kernel(size, seed=0)
         report_gates("decide")
         check_grouped_products(size, seed=0)
         return verdicts
@@ -419,6 +487,14 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
                              "predict program contains a tpu_custom_call")
 
     if _is_trunk(size):
+        from tmr_tpu import obs
+
+        traced = [r["attrs"].get("trunk_kda") for r in obs.spans()
+                  if r["name"] == "compile" and "trunk_kda" in r["attrs"]]
+        want = "chunk_kernel" if verdicts.get("kda_chunk_ok") else "chunked_xla"
+        check(traced and all(t.startswith(want + " x") for t in traced),
+              f"every compiled program traced its recurrence as {want}: "
+              f"{traced}")
         # a float32 copy left to route by itself breaks ties otherwise and
         # sends those tokens through other experts: that comparison is the
         # benchmark cell's, where the reference follows ties (PERF.md)

@@ -70,7 +70,7 @@ def as_tpu(monkeypatch):
     (no interpret mode, TPU defaults), and the gates the ``auto`` path asks
     answer yes — their self-checks execute, which only a chip can."""
     from tmr_tpu.diagnostics import mosaic_gate
-    from tmr_tpu.ops import flash_attn, pallas_attn, pallas_nms
+    from tmr_tpu.ops import flash_attn, kda, pallas_attn, pallas_nms
 
     def admits(name):
         def gate(*a, **k):
@@ -84,6 +84,7 @@ def as_tpu(monkeypatch):
     for mod, name in ((flash_attn, "flash_attention_ok"),
                       (flash_attn, "flash_window_ok"),
                       (pallas_attn, "packed_window_ok"),
+                      (kda, "kda_chunk_ok"),
                       (pallas_nms, "pallas_nms_compiled_ok")):
         monkeypatch.setattr(mod, name, admits(name))
 
@@ -130,6 +131,21 @@ def _packed_case(windows, heads, head_dim, grad=False):
             qkv, rel, rel)
 
     return case
+
+
+# kimilinear_fscd147.eval: 4 images of 4,096 tokens, 32 heads of 128
+_KDA = dict(batch=4, seq=4096, heads=32, head_dim=128, hidden=2304)
+
+
+def _case_kda_chunk(sds):
+    """The chunked delta rule's kernel on what ``KDAMixer`` holds: q, k, g
+    float32 and v bfloat16 as (B, S, H, d), beta (B, S, H)."""
+    from tmr_tpu.ops.kda import kda_chunk_kernel
+
+    shape = (_KDA["batch"], _KDA["seq"], _KDA["heads"], _KDA["head_dim"])
+    f32 = sds(shape, jnp.float32)
+    return (lambda *a: kda_chunk_kernel(*a, jnp.bfloat16)), (
+        f32, f32, sds(shape, jnp.bfloat16), f32, sds(shape[:3], jnp.float32))
 
 
 def _case_nms(sds):
@@ -189,6 +205,7 @@ CASES = {
     "packed_window_vitb": _packed_case(16 * _NWIN, 12, 64),
     "packed_window_vith": _packed_case(8 * _NWIN, 16, 80),
     "packed_window_grad": _packed_case(2 * _NWIN, 12, 64, grad=True),
+    "kda_chunk_kimi": _case_kda_chunk,
     "nms": _case_nms,
     "int8_matmul": _case_int8_matmul,
     "predict_program": _case_predict_program,
@@ -248,6 +265,40 @@ def test_windowed_block_moves_its_operands_once(one_chip, as_tpu):
         assert m.group(2) not in ("copy", "transpose") or elems < q_elems, \
             line
     assert "custom-call" in seen and "fusion" in seen, seen
+
+
+def _kda_layer(sharding, batch=_KDA["batch"], seq=_KDA["seq"]):
+    """A KDA mixer at the cell's widths, named as a trunk layer names it,
+    its parameters and input as shapes under ``sharding``."""
+    from tmr_tpu.models.lm_trunk import KDAMixer
+
+    mixer = KDAMixer(_KDA["heads"], _KDA["head_dim"], dtype=jnp.bfloat16,
+                     name="attn")
+    x = jax.ShapeDtypeStruct((batch, seq, _KDA["hidden"]), jnp.bfloat16,
+                             sharding=sharding)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        jax.eval_shape(mixer.init, jax.random.key(0), x))
+    return mixer, params, x
+
+
+def test_kda_layer_keeps_a_chunk_on_the_chip(one_chip, as_tpu):
+    """One KDA layer at the cell's batch, compiled for the v5e: the
+    recurrence is one Mosaic kernel, and nothing under ``attn/scan/`` moves
+    its operands (no ``transpose``, ``concatenate`` or ``pad``, in the entry
+    computation or inside a fusion): q, k, v, g are read where the
+    projections left them."""
+    import re
+
+    mixer, params, x = _kda_layer(one_chip)
+    text = jax.jit(mixer.apply).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    under_scan = [
+        m.group(1) for m in re.finditer(
+            r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*attn/scan/", text)]
+    assert "custom-call" in under_scan, under_scan
+    assert not {"transpose", "concatenate", "pad"} & set(under_scan), \
+        sorted(set(under_scan))
 
 
 def _shallow_vit_b(cfg):
@@ -332,8 +383,29 @@ def _mesh_case_serve_tp2(devices):
         jax.ShapeDtypeStruct(boxes.shape, boxes.dtype, sharding=repl))
 
 
-MESH_CASES = {"train_step_dp2": _mesh_case_train_step,
-              "serve_tp2": _mesh_case_serve_tp2}
+def _mesh_case_kda_layer(devices):
+    """A KDA layer of the trunk alone (``MoEFFN`` refuses a partitioned
+    trace, so no whole trunk can be traced here), its input split along the
+    hidden width over two chips: the projections' contraction is
+    partitioned."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from tmr_tpu.parallel.compat import partitioned
+
+    mesh = Mesh(np.asarray(devices).reshape(1, 2), ("data", "model"))
+    mixer, params, x = _kda_layer(NamedSharding(mesh, P()), batch=1,
+                                  seq=1024)
+    x = jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=NamedSharding(mesh, P(None, None, "model")))
+    return mesh, jax.jit(partitioned(mixer.apply, mesh)), (params, x)
+
+
+_VIT_GATES = ("flash_attention_ok", "packed_window_ok")
+MESH_CASES = {"train_step_dp2": (_mesh_case_train_step, _VIT_GATES),
+              "serve_tp2": (_mesh_case_serve_tp2, _VIT_GATES),
+              "kda_layer_tp2": (_mesh_case_kda_layer, ("kda_chunk_ok",))}
 
 
 @pytest.mark.parametrize("case", sorted(MESH_CASES))
@@ -348,7 +420,8 @@ def test_partitioned_program_compiles_for_two_v5e_chips(
     from tmr_tpu.diagnostics import drain_gate_refusals
 
     drain_gate_refusals()
-    mesh, fn, args = MESH_CASES[case](topo.devices[:2])
+    build, gates = MESH_CASES[case]
+    mesh, fn, args = build(topo.devices[:2])
     jitted = inspect.unwrap(fn, stop=lambda f: hasattr(f, "lower"))
     with jax.sharding.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
@@ -356,8 +429,8 @@ def test_partitioned_program_compiles_for_two_v5e_chips(
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text, "nothing was partitioned over the chips"
     causes = {(r["gate"], r["cause"]) for r in drain_gate_refusals()}
-    assert ("flash_attention_ok", "partitioned") in causes
-    assert ("packed_window_ok", "partitioned") in causes
+    for gate in gates:
+        assert (gate, "partitioned") in causes, (gate, causes)
 
 
 @pytest.mark.parametrize("gate,args", [

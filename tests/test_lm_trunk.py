@@ -17,7 +17,9 @@ from tmr_tpu import obs
 from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, MoEFFN, build_lm_trunk
 from tmr_tpu.ops import moe
 from tmr_tpu.ops.causal_attn import causal_attention_blocked
-from tmr_tpu.ops.kda import causal_conv, kda_chunked, kda_recurrent
+from tmr_tpu.ops import kda
+from tmr_tpu.ops.kda import (causal_conv, kda_chunk_kernel, kda_chunked,
+                             kda_formulation, kda_recurrent)
 
 TINY = "kimi_linear_tiny"
 SIZE = 64
@@ -63,6 +65,92 @@ def test_chunk_and_sub_block_sizes_do_not_change_the_result():
     a = kda_chunked(*args, chunk=32, sub=8)
     b = kda_chunked(*args, chunk=64, sub=16)
     np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("seq", [64, 128, 256])
+@pytest.mark.parametrize("decay", [1e-9, 0.5, 0.97, 1.0 - 1e-6])
+def test_chunk_kernel_equals_the_token_recurrence(seq, decay):
+    """The Pallas kernel (in the interpreter here) at the published head
+    width, two heads a grid step, a batch of two so that the state is reset
+    at a sequence's first chunk, up to four chunks so that it is carried."""
+    args = _kda_inputs(seq, decay, seed=seq, d=128)
+    assert kda._heads_per_step(args[0].shape[2]) == 2
+    want = kda_recurrent(*args)
+    got = kda_chunk_kernel(*args, jnp.float32)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_chunk_kernel_takes_the_norms_on_either_side_of_the_recurrence():
+    """With ``q_scale`` the kernel takes q and k unnormalised and does what
+    the mixer's ``l2norm`` does; with ``out_norm`` it returns what the
+    mixer's ``o_norm`` would: a chunk at a time, and differentiable."""
+    _, _, v, g, beta = _kda_inputs(128, 0.9, d=128)
+    q, k = (3.0 * jax.random.normal(jax.random.key(n), v.shape)
+            for n in (5, 6))
+    weight = 1.0 + 0.1 * jax.random.normal(jax.random.key(7), (128,))
+
+    def plain(fn, q, k, weight):
+        return kda.rms_norm(fn(kda.l2norm(q) * 128 ** -0.5, kda.l2norm(k), v,
+                               g, beta), weight, 1e-5)
+
+    def fused(q, k, weight):
+        return kda_chunk_kernel(q, k, v, g, beta, jnp.float32, 128 ** -0.5,
+                                (weight, 1e-5))
+
+    np.testing.assert_allclose(fused(q, k, weight),
+                               plain(kda_recurrent, q, k, weight),
+                               atol=1e-4, rtol=1e-4)  # o's rms is 0.01
+    grads = jax.grad(lambda *a: jnp.sum(fused(*a) ** 2),
+                     argnums=(0, 1, 2))(q, k, weight)
+    wants = jax.grad(lambda *a: jnp.sum(plain(kda_chunked, *a) ** 2),
+                     argnums=(0, 1, 2))(q, k, weight)
+    for a, b in zip(grads, wants):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def test_chunk_kernel_differentiates_through_the_chunked_form():
+    args = _kda_inputs(128, 0.9, d=128)
+    weigh = jax.random.normal(jax.random.key(9), args[2].shape)
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * weigh)
+    got = jax.grad(loss(lambda *a: kda_chunk_kernel(*a, jnp.float32)),
+                   argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(loss(kda_chunked), argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("case,want", [
+    ("the_cell", "chunk_kernel"), ("odd_length", "chunked_xla"),
+    ("narrow_heads", "chunked_xla"), ("float32", "chunked_xla"),
+    ("cpu_backend", "chunked_xla"), ("partitioned", "chunked_xla")])
+def test_kda_formulation_by_what_it_observes(case, want, monkeypatch):
+    """The kernel where a TPU, the type, the length and the head width
+    allow it and its gate says yes; ``kda_chunked`` everywhere else, and a
+    trace XLA partitions says why."""
+    from tmr_tpu import diagnostics
+
+    if case != "partitioned":  # there the gate's own wrapper answers
+        monkeypatch.setattr(kda, "kda_chunk_ok", lambda dk, hb: True)
+    if case != "cpu_backend":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seq, d, dtype = {"odd_length": (4000, 128, jnp.bfloat16),
+                     "narrow_heads": (4096, 16, jnp.bfloat16),
+                     "float32": (4096, 128, jnp.float32)}.get(
+                         case, (4096, 128, jnp.bfloat16))
+    diagnostics.drain_gate_refusals()
+    if case == "partitioned":
+        with diagnostics.mosaic_kernels_off("a two-chip mesh"):
+            got = kda_formulation(seq, d, d, dtype, 32)
+        causes = {(r["gate"], r["cause"])
+                  for r in diagnostics.drain_gate_refusals()}
+        assert ("kda_chunk_ok", "partitioned") in causes
+    else:
+        got = kda_formulation(seq, d, d, dtype, 32)
+        assert not diagnostics.drain_gate_refusals()
+    assert got == want
 
 
 def test_causal_conv_is_left_padded_and_depthwise():

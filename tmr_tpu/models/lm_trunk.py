@@ -30,7 +30,8 @@ from tmr_tpu.models.vit import apply_neck, neck_modules, patch_embed_conv
 from tmr_tpu.obs import metrics
 from tmr_tpu.ops import moe as moe_ops
 from tmr_tpu.ops.causal_attn import causal_attention_blocked
-from tmr_tpu.ops.kda import HI, causal_conv, kda_chunked
+from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
+                             kda_formulation, l2norm, rms_norm)
 
 #: the collection the expert layers leave their group sizes and their
 #: choice of experts in; a program that makes it mutable gets them back
@@ -38,9 +39,11 @@ from tmr_tpu.ops.kda import HI, causal_conv, kda_chunked
 STATS = "trunk_stats"
 
 #: what each mechanism traces with (counters ``trunk.<kind>.<formulation>``,
-#: copied onto the ``compile`` span). The recurrence and latent attention
-#: have one formulation each today; the experts' grouped products choose
-#: theirs by device, type and sizes (``ops/moe.py:grouped_formulation``)
+#: copied onto the ``compile`` span). Latent attention has one formulation
+#: today; the recurrence and the experts' grouped products choose theirs by
+#: device, type and sizes (``ops/kda.py:kda_formulation``,
+#: ``ops/moe.py:grouped_formulation``). ``KDA_FORMULATION`` is the
+#: recurrence's fallback, kept as a name the benchmark's driver imports
 KDA_FORMULATION = "chunked_xla"
 MLA_FORMULATION = "blocked_xla"
 
@@ -61,12 +64,13 @@ class RMSNorm(nn.Module):
     param_dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, weight_only: bool = False):
+        """``weight_only``: the weight and eps for a caller whose kernel
+        normalises ``x``'s like itself."""
         weight = _weight(self, "weight", (x.shape[-1],), nn.initializers.ones)
-        x32 = x.astype(jnp.float32)
-        x32 = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (x32 * weight.astype(jnp.float32)).astype(x.dtype)
+        if weight_only:
+            return weight, self.eps
+        return rms_norm(x, weight, self.eps).astype(x.dtype)
 
 
 class GatedMLP(nn.Module):
@@ -106,13 +110,7 @@ class KDAMixer(nn.Module):
             kernel = _weight(self, f"{name}_conv", (self.conv_size, h * d))
             return jax.nn.silu(causal_conv(y, kernel)).reshape(b, s, h, d)
 
-        def l2norm(t):
-            t32 = t.astype(f32)
-            return t32 * jax.lax.rsqrt(
-                jnp.sum(t32 * t32, -1, keepdims=True) + 1e-6)
-
         q, k, v = conv_branch("q"), conv_branch("k"), conv_branch("v")
-        q, k = l2norm(q) * d ** -0.5, l2norm(k)
         # the decay and beta: float32 arithmetic
         a_log = _weight(self, "A_log", (h,),
                         nn.initializers.constant(1.386))
@@ -122,10 +120,19 @@ class KDAMixer(nn.Module):
         g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
             g + dt_bias.astype(f32)).reshape(b, s, h, d)
         beta = jax.nn.sigmoid(lin32(h, "b_proj")(x))
-        metrics.counter(f"trunk.kda.{KDA_FORMULATION}").inc()
-        with jax.named_scope("scan"):
-            o = kda_chunked(q, k, v, g, beta, dtype=self.dtype)
-        o = RMSNorm(param_dtype=self.param_dtype, name="o_norm")(o)
+        formulation = kda_formulation(s, d, d, self.dtype, h)
+        metrics.counter(f"trunk.kda.{formulation}").inc()
+        o_norm = RMSNorm(param_dtype=self.param_dtype, name="o_norm")
+        if formulation == "chunk_kernel":
+            # the norms on either side of the recurrence ride in the kernel
+            with jax.named_scope("scan"):
+                o = kda_chunk_kernel(q, k, v, g, beta, self.dtype, d ** -0.5,
+                                     o_norm(v, weight_only=True))
+        else:
+            q, k = l2norm(q) * d ** -0.5, l2norm(k)
+            with jax.named_scope("scan"):
+                o = kda_chunked(q, k, v, g, beta, dtype=self.dtype)
+            o = o_norm(o)
         gate = jax.nn.sigmoid(
             lin(h * d, "g_b")(lin(rank, "g_a")(x)).astype(f32))
         o = o.reshape(b, s, h * d) * gate
