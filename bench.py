@@ -363,7 +363,7 @@ def _run(cancel_watchdog) -> None:
         )
         from tmr_tpu.utils.autotune import autotune
 
-        snap_keys = ("TMR_GLOBAL_ATTN", "TMR_WIN_ATTN", "TMR_XCORR_IMPL",
+        snap_keys = ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL",
                      "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
                      "TMR_GLOBAL_SCORES_DTYPE", "TMR_DECODER_IMPL",
                      "TMR_QUANT", "TMR_QUANT_STORAGE", "TMR_QUANT_KERNEL")
@@ -638,12 +638,11 @@ def _build_and_measure(cfg, tune) -> dict:
         # picks, so env-pinned A/B runs need this to be readable
         "knobs": {
             k: os.environ[k]
-            for k in ("TMR_GLOBAL_ATTN", "TMR_WIN_ATTN",
+            for k in ("TMR_GLOBAL_ATTN",
                       "TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL",
                       "TMR_XCORR_PRECISION", "TMR_PALLAS_ATTN_BQ",
-                      "TMR_PALLAS_ATTN_BK", "TMR_PALLAS_WIN_GROUP",
-                      "TMR_GLOBAL_BANDS_UNROLL",
-                      "TMR_GLOBAL_SCORES_DTYPE", "TMR_WIN_SCORES_DTYPE",
+                      "TMR_PALLAS_ATTN_BK", "TMR_GLOBAL_BANDS_UNROLL",
+                      "TMR_GLOBAL_SCORES_DTYPE",
                       "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK",
                       "TMR_DECODER_IMPL", "TMR_QUANT", "TMR_DECODE_TAIL")
             if k in os.environ

@@ -59,7 +59,6 @@ TP_REL_TOL = 0.05
 #: the plain-XLA oracle formulations of the float32 reference run
 ORACLE_ENV = {
     "TMR_GLOBAL_ATTN": "blockwise",
-    "TMR_WIN_ATTN": "dense",
     "TMR_XCORR_IMPL": "conv",
     "TMR_DECODER_IMPL": "xla",
 }
@@ -304,10 +303,17 @@ def check_kda_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     return True
 
 
+def _vit_heads(size: Size) -> tuple:
+    """(heads, head dim) of the SAM encoder ``size`` names."""
+    from tmr_tpu.models.vit import VIT_CONFIGS
+
+    vc = VIT_CONFIGS["vit_b" if size.backbone == "sam_vit_b" else "vit_h"]
+    return vc["num_heads"], vc["embed_dim"] // vc["num_heads"]
+
+
 def decide_gates(cfg, size: Size) -> dict:
     """Ask, outside any trace, every gate the ``auto`` path of this
     configuration consults; the model's traces then hit their caches."""
-    from tmr_tpu.models.vit import VIT_CONFIGS
     from tmr_tpu.ops.flash_attn import flash_attention_ok
     from tmr_tpu.ops.pallas_attn import packed_window_ok
     from tmr_tpu.ops.pallas_nms import pallas_nms_compiled_ok
@@ -323,13 +329,11 @@ def decide_gates(cfg, size: Size) -> dict:
         report_gates("decide")
         check_grouped_products(size, seed=0)
         return verdicts
-    vc = VIT_CONFIGS["vit_b" if size.backbone == "sam_vit_b" else "vit_h"]
-    head_dim = vc["embed_dim"] // vc["num_heads"]
+    num_heads, head_dim = _vit_heads(size)
     grid = size.image_size // 16
     verdicts = {
         "flash_attention_ok": flash_attention_ok(grid, grid, head_dim),
-        "packed_window_ok": packed_window_ok(14, 14, head_dim,
-                                             vc["num_heads"]),
+        "packed_window_ok": packed_window_ok(14, 14, head_dim, num_heads),
         "pallas_nms_compiled_ok": pallas_nms_compiled_ok(),
     }
     for gate, ok in verdicts.items():
@@ -338,23 +342,25 @@ def decide_gates(cfg, size: Size) -> dict:
     return verdicts
 
 
-def report_formulations(verdicts: dict) -> None:
+def report_formulations(cfg, size: Size, verdicts: dict) -> None:
     """The formulation each layer runs under the current environment: the
-    knob when set, else what ``auto`` resolves to given the gates."""
+    knob when set, else what ``auto`` resolves to given the gates; for the
+    windowed blocks, which have no knob, ``window_formulation``'s answer."""
+    import jax.numpy as jnp
+
     from tmr_tpu.inference import decode_tail_mode
-    from tmr_tpu.models.vit import _WIN_ATTN_IMPL
+    from tmr_tpu.ops.pallas_attn import window_formulation
     from tmr_tpu.ops.xcorr import small_impl_default
 
     env = os.environ.get
-    win = _WIN_ATTN_IMPL()
-    if win == "packed" and not verdicts["packed_window_ok"]:
-        win = "dense"
+    win = "none" if _is_trunk(size) else window_formulation(
+        (14, 14), *_vit_heads(size), jnp.dtype(cfg.compute_dtype))
     glob = env("TMR_GLOBAL_ATTN", "auto")
     if glob == "auto":
         glob = "flash" if verdicts["flash_attention_ok"] else "blockwise"
     say("  formulations: " + json.dumps({
         "global_attention(TMR_GLOBAL_ATTN)": glob,
-        "windowed_attention(TMR_WIN_ATTN)": win,
+        "windowed_attention(window_formulation)": win,
         "xcorr_small(TMR_XCORR_IMPL[_SMALL])": env(
             "TMR_XCORR_IMPL", env("TMR_XCORR_IMPL_SMALL",
                                   small_impl_default())),
@@ -932,7 +938,7 @@ def main(argv=None) -> int:
     pred = build_predictor(size, args.seed)
     report_autotune(pred.cfg, size, batch=2 if args.chips == 1 else 1)
     verdicts = decide_gates(pred.cfg, size)
-    report_formulations(verdicts)
+    report_formulations(pred.cfg, size, verdicts)
     if args.chips == 4:
         phase_mesh(pred, size, args.seed)
     else:

@@ -145,14 +145,13 @@ def main(argv=None) -> int:
         effective_global_tiles,
         pallas_fused_ok,
         pallas_global_ok,
-        pallas_window_ok,
     )
     from tmr_tpu.ops.pallas_xcorr import pallas_xcorr_ok
     from tmr_tpu.models.vit import _scores_dtype
 
     for gate_fn in (blockfolded_ok, densefolded_ok, flash_attention_ok,
                     flash_window_ok, xlaflash_ok, pallas_fused_ok,
-                    pallas_global_ok, pallas_window_ok, pallas_xcorr_ok):
+                    pallas_global_ok, pallas_xcorr_ok):
         clear = getattr(gate_fn, "cache_clear", None)  # not all are cached
         if clear is not None:
             clear()
@@ -172,8 +171,6 @@ def main(argv=None) -> int:
             lambda: pallas_global_ok(64, 64, 64, bq, bk),
         f"pallas_fused_64x64_d64_bq{fbq}_bk{fbk}":
             lambda: pallas_fused_ok(64, 64, 64, fbq, fbk),
-        "pallas_window_14x14_d64_g8":
-            lambda: pallas_window_ok(14, 14, 64, 8),
         "pallas_xcorr_c256_64_t17": lambda: pallas_xcorr_ok(256, 64, 64, 17),
     }
     drain_gate_refusals()  # discard causes from the direct probes above

@@ -212,14 +212,14 @@ def _phase_fraction() -> dict:
         return dets, 0.010 if arm == "xla" else 0.004
 
     tuner = autotune_live.LiveTuner(
-        "TMR_WIN_ATTN", ["flash"], "dense", runner=runner,
+        "TMR_GLOBAL_ATTN", ["flash"], "blockwise", runner=runner,
         device_kind="cpu", geometry="frac",
         sample=None,            # the DEFAULT rate — the pin under test
         budget_s=5.0, wins_needed=10 ** 6,  # never promote here
     )
-    # dense/flash arms reuse the runner's xla/other split
+    # blockwise/flash arms reuse the runner's xla/other split
     tuner._runner = lambda arm, payload: runner(
-        "xla" if arm == "dense" else "flash", payload
+        "xla" if arm == "blockwise" else "flash", payload
     )
     tuner.start()
     offers = 3000
@@ -244,9 +244,9 @@ def _phase_bank_isolation(path: str) -> dict:
     """Per-generation isolation + stale-revision fallback on one file."""
     entries = {}
     for kind in ("cpu", "TPU v5e", "TPU v6e"):
-        key = autotune_live.bank_key(kind, "TMR_WIN_ATTN", "g1")
+        key = autotune_live.bank_key(kind, "TMR_GLOBAL_ATTN", "g1")
         entries[key] = autotune_live.make_entry(
-            kind, "TMR_WIN_ATTN", "g1", "flash", source="offline")
+            kind, "TMR_GLOBAL_ATTN", "g1", "flash", source="offline")
     stale_key = autotune_live.bank_key("cpu", "TMR_QUANT", "g1")
     stale = autotune_live.make_entry("cpu", "TMR_QUANT", "g1", "int8",
                                      source="offline")

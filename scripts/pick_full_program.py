@@ -3,16 +3,15 @@
 
 The autotune sweep times one isolated block per formulation; round 4 showed
 that granularity can disagree with the production program (the sweep
-crowned TMR_WIN_ATTN=flash while the one-block profile measured flash
-slower than dense). Per the verdict, the resolution is to record BOTH
+crowned a formulation that the one-block profile measured slower than the
+one it displaced). Per the verdict, the resolution is to record BOTH
 granularities and let the FULL-PROGRAM number decide: the watch2 battery
 benches the complete fused eval program under env-pinned formulation
-combos (bench_pallas/windense/combined/allpallas) plus the autotuned
-headline; this script reads those records and, when an env-pinned combo
-beats the autotuned headline decisively (>3% img/s), pins its knobs into
-AUTOTUNE_SEED.json so every later process (including the driver's
-round-end bench) defaults to the full-program winner instead of re-running
-the one-block sweep ranking.
+combos (bench_pallas) plus the autotuned headline; this script reads those
+records and, when an env-pinned combo beats the autotuned headline
+decisively (>3% img/s), pins its knobs into AUTOTUNE_SEED.json so every
+later process (including the driver's round-end bench) defaults to the
+full-program winner instead of re-running the one-block sweep ranking.
 
 Offline: operates purely on the battery's JSON outputs.
 Prints one JSON summary line; exit 0 = seed updated, 3 = no update needed
@@ -32,17 +31,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_FILES = (
     "bench_live.json",      # autotuned headline (sweep-ranked winners)
     "bench_pallas.json",    # TMR_GLOBAL_ATTN=pallas
-    "bench_windense.json",  # TMR_WIN_ATTN=dense
-    "bench_combined.json",  # both
-    "bench_allpallas.json",  # + windowed kernel grouped
 )
-#: knobs a full-program winner may pin (formulations + their tile/group
+#: knobs a full-program winner may pin (the global formulation + its tile
 #: sub-knobs; batch is handled by bench_extra's own sweep)
 PINNABLE = (
-    "TMR_GLOBAL_ATTN", "TMR_WIN_ATTN", "TMR_PALLAS_ATTN_BQ",
-    "TMR_PALLAS_ATTN_BK", "TMR_PALLAS_WIN_GROUP",
+    "TMR_GLOBAL_ATTN", "TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
     "TMR_GLOBAL_BANDS_UNROLL", "TMR_GLOBAL_SCORES_DTYPE",
-    "TMR_WIN_SCORES_DTYPE", "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK",
+    "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK",
 )
 #: decisive-win margin: below this the sweep ranking stands (same
 #: philosophy as the precision stage's >10% bar, scaled to whole-program
@@ -184,13 +179,14 @@ def main(argv=None) -> int:
                 # every versioned knob needs a fresh stamp or the loader
                 # drops the pin as stale on the very next run
                 entry[f"_variants_{k}"] = _variants_sig(k)
-        # full-program A/Bs supersede the one-block sweep for BOTH
-        # formulation knobs: a knob the winner left at its autotuned value
+        # full-program A/Bs supersede the one-block sweep for the
+        # formulation knob: left at its autotuned value by the winner, it
         # is also full-program-endorsed (it was part of the winning run)
-        for k in ("TMR_WIN_ATTN", "TMR_GLOBAL_ATTN"):
-            if k not in pinned and k in best.get("autotuned", {}):
-                entry[k] = best["autotuned"][k]
-                entry[f"_variants_{k}"] = _variants_sig(k)
+        auto = best.get("autotuned", {})
+        if "TMR_GLOBAL_ATTN" not in pinned and "TMR_GLOBAL_ATTN" in auto:
+            entry["TMR_GLOBAL_ATTN"] = auto["TMR_GLOBAL_ATTN"]
+            entry["_variants_TMR_GLOBAL_ATTN"] = _variants_sig(
+                "TMR_GLOBAL_ATTN")
         if "TMR_GLOBAL_SCORES_DTYPE" in entry:
             # the scores-dtype evidence is paired to the global formulation
             # of the winning run — record it or the loader's pairing check

@@ -126,17 +126,16 @@ def main():
     report["devtime"]["backbone"] = attributed(step2, image, rtt=rtt)
     _progress(f"backbone: {report['backbone']*1000:.2f} ms")
 
-    # 3. one global vs one windowed transformer block (768-d, real grid),
-    # plus the A/B windowed variant with the bias folded into QK
-    # (TMR_WIN_ATTN, read at trace time — models/vit.py)
+    # 3. one global vs one windowed transformer block (768-d, real grid)
     grid = SIZE // 16
     tokens = jnp.asarray(
         rng.standard_normal((BATCH, grid, grid, 768)), jnp.bfloat16
     )
     cases = (
-        # (label, window, {knob: value}): global blocks read TMR_GLOBAL_ATTN,
-        # windowed blocks TMR_WIN_ATTN (all trace-time); the pallas rows also
-        # sweep the kernel's tile sizes (TMR_PALLAS_ATTN_BQ/BK)
+        # (label, window, {knob: value}): global blocks read TMR_GLOBAL_ATTN
+        # at trace time; the pallas rows also sweep the kernel's tile sizes
+        # (TMR_PALLAS_ATTN_BQ/BK). The windowed block has no knob: it runs
+        # what ops/pallas_attn.window_formulation answers here
         ("one_global_block_blockwise", 0, {"TMR_GLOBAL_ATTN": "blockwise"}),
         ("one_global_block_flash", 0, {"TMR_GLOBAL_ATTN": "flash"}),
         ("one_global_block_blockfolded", 0,
@@ -171,14 +170,7 @@ def main():
         ("one_global_block_xlaflash", 0, {"TMR_GLOBAL_ATTN": "xlaflash"}),
         ("one_global_block_xlaflash_bk1024", 0,
          {"TMR_GLOBAL_ATTN": "xlaflash", "TMR_XLA_FLASH_BK": "1024"}),
-        ("one_windowed_block", 14, {"TMR_WIN_ATTN": "dense"}),
-        ("one_windowed_block_folded", 14, {"TMR_WIN_ATTN": "folded"}),
-        ("one_windowed_block_folded_scores16", 14,
-         {"TMR_WIN_ATTN": "folded", "TMR_WIN_SCORES_DTYPE": "bf16"}),
-        ("one_windowed_block_flash", 14, {"TMR_WIN_ATTN": "flash"}),
-        ("one_windowed_block_pallas", 14, {"TMR_WIN_ATTN": "pallas"}),
-        ("one_windowed_block_pallas_g8", 14,
-         {"TMR_WIN_ATTN": "pallas", "TMR_PALLAS_WIN_GROUP": "8"}),
+        ("one_windowed_block", 14, {}),
     )
     # restore the user's knobs afterwards (autotune's _restore): the
     # full-program timing in section 1 honoured them, and later sections /
@@ -187,30 +179,13 @@ def main():
 
     prev = {
         k: os.environ.get(k)
-        for k in ("TMR_WIN_ATTN", "TMR_GLOBAL_ATTN", "TMR_PALLAS_ATTN_BQ",
-                  "TMR_PALLAS_ATTN_BK", "TMR_PALLAS_WIN_GROUP",
-                  "TMR_GLOBAL_BANDS_UNROLL", "TMR_GLOBAL_SCORES_DTYPE",
-                  "TMR_WIN_SCORES_DTYPE", "TMR_XLA_FLASH_BQ",
+        for k in ("TMR_GLOBAL_ATTN", "TMR_PALLAS_ATTN_BQ",
+                  "TMR_PALLAS_ATTN_BK", "TMR_GLOBAL_BANDS_UNROLL",
+                  "TMR_GLOBAL_SCORES_DTYPE", "TMR_XLA_FLASH_BQ",
                   "TMR_XLA_FLASH_BK")
     }
     try:
         for label, win, knobs in cases:
-            if "TMR_PALLAS_WIN_GROUP" in knobs:
-                # skip when the preference clamps to a different effective
-                # group at this batch (same mislabeling hazard as the tile
-                # rows): bh = batch * windows * heads for one block
-                from tmr_tpu.ops.pallas_attn import _win_group
-
-                n_win = ((grid + win - 1) // win) ** 2 if win else 1
-                bh_blk = BATCH * n_win * 12
-                want_g = int(knobs["TMR_PALLAS_WIN_GROUP"])
-                os.environ["TMR_PALLAS_WIN_GROUP"] = str(want_g)
-                eff_g = _win_group(bh_blk)
-                os.environ.pop("TMR_PALLAS_WIN_GROUP", None)
-                if eff_g != want_g:
-                    _progress(f"stage 3: {label} skipped (group clamps to "
-                              f"{eff_g} at bh={bh_blk})")
-                    continue
             if "TMR_PALLAS_ATTN_BQ" in knobs or "TMR_PALLAS_ATTN_BK" in knobs:
                 # skip tile rows whose preference clamps back to the default
                 # tile at this S — they would re-measure the plain pallas
@@ -230,10 +205,9 @@ def main():
                     continue
             _progress(f"stage 3: {label}")
             for k in ("TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-                      "TMR_PALLAS_WIN_GROUP", "TMR_GLOBAL_BANDS_UNROLL",
-                      "TMR_GLOBAL_SCORES_DTYPE", "TMR_WIN_SCORES_DTYPE",
+                      "TMR_GLOBAL_BANDS_UNROLL", "TMR_GLOBAL_SCORES_DTYPE",
                       "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK"):
-                os.environ.pop(k, None)  # tile/group overrides are per-case
+                os.environ.pop(k, None)  # tile overrides are per-case
             os.environ.update(knobs)
             blk = Block(num_heads=12, window_size=win,
                         rel_pos_size=(grid, grid), dtype=jnp.bfloat16)

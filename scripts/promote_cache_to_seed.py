@@ -53,7 +53,7 @@ def main(argv=None) -> int:
     #: Once a _SWEEP_REV bump stales the pin, runtime drops it and
     #: re-sweeps anyway, so the fresh sweep winner must promote or every
     #: fresh container re-sweeps forever.
-    FULL_PROGRAM_KNOBS = ("TMR_WIN_ATTN", "TMR_GLOBAL_ATTN")
+    FULL_PROGRAM_KNOBS = ("TMR_GLOBAL_ATTN",)
 
     promoted = {}
     for key, entry in cache.items():

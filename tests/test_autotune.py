@@ -10,7 +10,7 @@ import pytest
 from tmr_tpu.config import preset
 from tmr_tpu.utils import autotune as at
 
-KNOBS = ("TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL", "TMR_WIN_ATTN",
+KNOBS = ("TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL",
          "TMR_XCORR_PRECISION", "TMR_GLOBAL_ATTN",
          "TMR_GLOBAL_SCORES_DTYPE", "TMR_DECODER_IMPL", "TMR_QUANT")
 
@@ -65,10 +65,6 @@ def test_autotune_picks_min_and_exports_env(clean_knobs, monkeypatch):
         lambda *a, **k: {"conv": 0.03, "vmap": 0.05, "fft": 0.01},
     )
     monkeypatch.setattr(
-        at, "pick_win_attn_impl",
-        lambda *a, **k: {"dense": 0.02, "folded": 0.01, "flash": 0.03},
-    )
-    monkeypatch.setattr(
         at, "pick_global_attn_impl",
         lambda *a, **k: {"blockwise": 0.03, "flash": 0.02},
     )
@@ -76,12 +72,47 @@ def test_autotune_picks_min_and_exports_env(clean_knobs, monkeypatch):
     # the xcorr winner exports through the SMALL-scoped knob only: the
     # 127/191 buckets must keep their FFT auto path
     assert report["TMR_XCORR_IMPL_SMALL"]["picked"] == "fft"
-    assert report["TMR_WIN_ATTN"]["picked"] == "folded"
     assert report["TMR_GLOBAL_ATTN"]["picked"] == "flash"
     assert os.environ["TMR_XCORR_IMPL_SMALL"] == "fft"
     assert "TMR_XCORR_IMPL" not in os.environ
-    assert os.environ["TMR_WIN_ATTN"] == "folded"
     assert os.environ["TMR_GLOBAL_ATTN"] == "flash"
+
+
+def test_autotune_exports_nothing_the_windowed_blocks_read(
+    clean_knobs, monkeypatch
+):
+    """An ``autotune()`` run on a TPU backend must leave the windowed
+    blocks' formulation where ``ops/pallas_attn.window_formulation`` put
+    it: it sets no environment variable that models/vit.py or
+    ops/pallas_attn.py reads on a windowed block's path (the parent swept
+    the windows' knob among four variants without ``packed`` and exported
+    the fastest, displacing PR 28's path), and a bfloat16 trace on that
+    backend still takes ``packed`` afterwards."""
+    import inspect
+
+    from tmr_tpu.models import vit
+    from tmr_tpu.ops import pallas_attn
+
+    monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.03, "fft": 0.01})
+    monkeypatch.setattr(
+        at, "pick_global_attn_impl",
+        lambda *a, **k: {"blockwise": 0.03, "flash": 0.02})
+    before = dict(os.environ)
+    report = at.autotune(_cfg(), 1024, 4)
+    exported = {k for k, v in os.environ.items() if before.get(k) != v}
+    assert exported and exported <= set(report), (exported, set(report))
+    assert not any("WIN" in k for k in exported | set(report))
+    # and the choice's own code reads no environment at all
+    for fn in (vit.Attention._window_formulation,
+               pallas_attn.window_formulation, pallas_attn.packed_supported):
+        src = inspect.getsource(fn)
+        assert "environ" not in src and "TMR_" not in src, fn.__name__
+    monkeypatch.setattr(pallas_attn, "packed_window_ok", lambda *a: True)
+    assert pallas_attn.window_formulation(
+        (14, 14), 12, 64, jnp.bfloat16) == "packed"
 
 
 def test_fallback_annotated_entries_never_win(clean_knobs, monkeypatch):
@@ -96,22 +127,16 @@ def test_fallback_annotated_entries_never_win(clean_knobs, monkeypatch):
         lambda *a, **k: {"conv": 0.01, "pallas" + at.FALLBACK_SUFFIX: 1e-5},
     )
     monkeypatch.setattr(
-        at, "pick_win_attn_impl",
-        lambda *a, **k: {"dense": 0.02, "pallas (fallback)": 0.001},
-    )
-    monkeypatch.setattr(
         at, "pick_global_attn_impl",
         lambda *a, **k: {"blockwise": 0.03, "flash (fallback)": 0.001},
     )
     report = at.autotune(_cfg(), 1024, 4, tune_precision=False)
     assert report["TMR_XCORR_IMPL_SMALL"]["picked"] == "conv"
     assert os.environ["TMR_XCORR_IMPL_SMALL"] == "conv"
-    assert report["TMR_WIN_ATTN"]["picked"] == "dense"
     assert report["TMR_GLOBAL_ATTN"]["picked"] == "blockwise"
-    assert os.environ["TMR_WIN_ATTN"] == "dense"
     assert os.environ["TMR_GLOBAL_ATTN"] == "blockwise"
     # the annotated evidence is preserved in the report
-    assert "pallas (fallback)" in report["TMR_WIN_ATTN"]["times"]
+    assert "flash (fallback)" in report["TMR_GLOBAL_ATTN"]["times"]
     assert "pallas" + at.FALLBACK_SUFFIX in (
         report["TMR_XCORR_IMPL_SMALL"]["times"]
     )
@@ -130,7 +155,6 @@ def test_autotune_sweep_false_exports_cached_and_reports_pending(
         AssertionError(f"{tag} swept under sweep=False")
     )
     monkeypatch.setattr(at, "pick_xcorr_impl", boom("x"))
-    monkeypatch.setattr(at, "pick_win_attn_impl", boom("w"))
     monkeypatch.setattr(at, "pick_global_attn_impl", boom("g"))
     monkeypatch.setattr(at, "pick_xcorr_precision", boom("p"))
     monkeypatch.setattr(at, "measure_rtt_floor", boom("rtt"))
@@ -159,14 +183,13 @@ def test_autotune_sweep_false_exports_cached_and_reports_pending(
     assert report["TMR_GLOBAL_SCORES_DTYPE"] == {"picked": "f32",
                                                  "times": {}}
     assert set(report["_pending"]) == {
-        "TMR_WIN_ATTN", "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
+        "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
         "TMR_DECODER_IMPL", "TMR_QUANT",
     }
 
 
 def test_autotune_respects_explicit_knobs(clean_knobs, monkeypatch):
     monkeypatch.setenv("TMR_XCORR_IMPL", "conv")
-    monkeypatch.setenv("TMR_WIN_ATTN", "dense")
     monkeypatch.setenv("TMR_XCORR_PRECISION", "highest")
     monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
     monkeypatch.setenv("TMR_DECODER_IMPL", "xla")
@@ -176,9 +199,6 @@ def test_autotune_respects_explicit_knobs(clean_knobs, monkeypatch):
     called = []
     monkeypatch.setattr(
         at, "pick_xcorr_impl", lambda *a, **k: called.append("x") or {}
-    )
-    monkeypatch.setattr(
-        at, "pick_win_attn_impl", lambda *a, **k: called.append("w") or {}
     )
     monkeypatch.setattr(
         at, "pick_xcorr_precision", lambda *a, **k: called.append("p") or {}
@@ -236,13 +256,14 @@ def test_microbenchmarks_run_and_time_all_variants(clean_knobs):
     )
     assert "pallas" + at.FALLBACK_SUFFIX in tx and "pallas" not in tx
     assert all(v > 0 for v in tx.values())
-    # windowed block: flash falls back unavailable off-TPU but must not
-    # crash the sweep; dense/folded always time
-    tw = at.pick_win_attn_impl(1, 14, 16, 2, rtt=0.0)
-    assert {"dense", "folded"} <= set(tw)
-    assert all(v > 0 for v in tw.values())
+    # global block (1024 tokens, the smallest grid that dispatches on the
+    # knob): the kernels fall back off-TPU but must not crash the sweep;
+    # the XLA formulations always time
+    tg = at.pick_global_attn_impl(1, 32, 16, 2, rtt=0.0)
+    assert {"blockwise", "blockfolded"} <= set(tg)
+    assert all(v > 0 for v in tg.values())
     assert "TMR_XCORR_IMPL" not in os.environ  # knobs restored
-    assert "TMR_WIN_ATTN" not in os.environ
+    assert "TMR_GLOBAL_ATTN" not in os.environ
 
 
 def test_autotune_precision_stage_flips_only_on_decisive_win(
@@ -258,7 +279,6 @@ def test_autotune_precision_stage_flips_only_on_decisive_win(
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.01, "vmap": 0.05, "fft": 0.03},
     )
-    monkeypatch.setattr(at, "pick_win_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     swept = []
     monkeypatch.setattr(
@@ -306,7 +326,6 @@ def test_autotune_tune_precision_false_skips_sweep(clean_knobs, monkeypatch):
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.01, "vmap": 0.05, "fft": 0.03},
     )
-    monkeypatch.setattr(at, "pick_win_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     boom = lambda *a, **k: (_ for _ in ()).throw(AssertionError("swept"))
     monkeypatch.setattr(at, "pick_xcorr_precision", boom)
@@ -325,7 +344,6 @@ def test_autotune_cached_precision_is_impl_specific(clean_knobs, monkeypatch):
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.01, "vmap": 0.05, "fft": 0.03},
     )
-    monkeypatch.setattr(at, "pick_win_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(
         at, "pick_xcorr_precision",
@@ -355,7 +373,6 @@ def test_autotune_cached_precision_is_impl_specific(clean_knobs, monkeypatch):
     # (attention pinned: its sweep returned {} above so it was never cached)
     for k in KNOBS:
         os.environ.pop(k, None)
-    monkeypatch.setenv("TMR_WIN_ATTN", "dense")
     monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
     boom = lambda *a, **k: (_ for _ in ()).throw(AssertionError("swept"))
     monkeypatch.setattr(at, "pick_xcorr_precision", boom)
@@ -380,40 +397,36 @@ def test_autotune_cache_persists_winners_across_processes(
         lambda *a, **k: calls.append("x") or {"conv": 0.03, "fft": 0.01},
     )
     monkeypatch.setattr(
-        at, "pick_win_attn_impl",
-        lambda *a, **k: calls.append("w") or {"dense": 0.02, "folded": 0.01},
-    )
-    monkeypatch.setattr(
         at, "pick_global_attn_impl",
         lambda *a, **k: calls.append("g") or {"blockwise": 0.02,
                                               "flash": 0.01},
     )
     r1 = at.autotune(_cfg(), 1024, 4)
-    assert calls == ["x", "w", "g"]
-    assert r1["TMR_WIN_ATTN"]["picked"] == "folded"
+    assert calls == ["x", "g"]
+    assert r1["TMR_GLOBAL_ATTN"]["picked"] == "flash"
 
     # fresh process simulation: knobs cleared, cache file remains
     for k in KNOBS:
         os.environ.pop(k, None)
     r2 = at.autotune(_cfg(), 1024, 4)
-    assert calls == ["x", "w", "g"], "cached hit must not re-measure"
+    assert calls == ["x", "g"], "cached hit must not re-measure"
     assert r2["TMR_XCORR_IMPL_SMALL"] == {"picked": "fft", "cached": True}
-    assert r2["TMR_WIN_ATTN"] == {"picked": "folded", "cached": True}
+    assert r2["TMR_GLOBAL_ATTN"] == {"picked": "flash", "cached": True}
     assert os.environ["TMR_XCORR_IMPL_SMALL"] == "fft"
-    assert os.environ["TMR_WIN_ATTN"] == "folded"
+    assert os.environ["TMR_GLOBAL_ATTN"] == "flash"
 
     # a different shape key measures fresh
     for k in KNOBS:
         os.environ.pop(k, None)
     at.autotune(_cfg(), 1536, 1)
-    assert calls == ["x", "w", "g", "x", "w", "g"]
+    assert calls == ["x", "g", "x", "g"]
 
     # force bypasses the cache
     for k in KNOBS:
         os.environ.pop(k, None)
     monkeypatch.setenv("TMR_AUTOTUNE_FORCE", "1")
     at.autotune(_cfg(), 1024, 4)
-    assert calls == ["x", "w", "g", "x", "w", "g", "x", "w", "g"]
+    assert calls == ["x", "g", "x", "g", "x", "g"]
 
 
 def test_train_autotune_uses_separate_key_and_grad_sweep(
@@ -435,33 +448,32 @@ def test_train_autotune_uses_separate_key_and_grad_sweep(
 
     def fake_sweep(*a, train=False, **k):
         seen_train.append(("a", train))
-        return ({"dense": 0.02, "folded": 0.01} if train
-                else {"dense": 0.01, "folded": 0.02})
+        return ({"blockwise": 0.02, "flash": 0.01} if train
+                else {"blockwise": 0.01, "flash": 0.02})
 
-    monkeypatch.setattr(at, "pick_win_attn_impl", fake_sweep)
     monkeypatch.setattr(at, "pick_global_attn_impl", fake_sweep)
 
     r_eval = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert r_eval["TMR_WIN_ATTN"]["picked"] == "dense"
-    assert seen_train == [("x", False), ("a", False), ("a", False)]
+    assert r_eval["TMR_GLOBAL_ATTN"]["picked"] == "blockwise"
+    assert seen_train == [("x", False), ("a", False)]
 
     for k in KNOBS:
         os.environ.pop(k, None)
     r_train = at.autotune(_cfg(), 1024, 4, tune_precision=False, train=True)
     # the eval cache entry must NOT satisfy the train run, and every sweep
     # (xcorr included) must time with gradients
-    assert seen_train[3:] == [("x", True), ("a", True), ("a", True)]
-    assert r_train["TMR_WIN_ATTN"]["picked"] == "folded"
+    assert seen_train[2:] == [("x", True), ("a", True)]
+    assert r_train["TMR_GLOBAL_ATTN"]["picked"] == "flash"
 
     # both keys now cached independently
     for k in KNOBS:
         os.environ.pop(k, None)
     r2 = at.autotune(_cfg(), 1024, 4, tune_precision=False, train=True)
-    assert r2["TMR_WIN_ATTN"] == {"picked": "folded", "cached": True}
+    assert r2["TMR_GLOBAL_ATTN"] == {"picked": "flash", "cached": True}
     for k in KNOBS:
         os.environ.pop(k, None)
     r3 = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert r3["TMR_WIN_ATTN"] == {"picked": "dense", "cached": True}
+    assert r3["TMR_GLOBAL_ATTN"] == {"picked": "blockwise", "cached": True}
 
 
 @pytest.mark.slow
@@ -469,14 +481,14 @@ def test_block_sweep_train_mode_times_grad(clean_knobs, monkeypatch):
     """The real harness under train=True must build a differentiable step
     (value_and_grad through the block) and produce a time for every
     variant that can differentiate — on CPU every variant falls back to a
-    differentiable path, so all four windowed variants report. Off-TPU the
+    differentiable path, so all seven global variants report. Off-TPU the
     flash/pallas gates refuse, so those entries come back ANNOTATED
     ("<impl> (fallback)"): the harness must label what it measured, never
     record a fallback timing under the requested name (ADVICE r4)."""
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
-    times = at.pick_win_attn_impl(1, 8, 16, 2, rtt=0.0, train=True)
+    times = at.pick_global_attn_impl(1, 32, 16, 2, rtt=0.0, train=True)
     base = {k.replace(at.FALLBACK_SUFFIX, "") for k in times}
-    assert base == set(at.WIN_ATTN_VARIANTS)
+    assert base == set(at.GLOBAL_ATTN_VARIANTS)
     # CPU: the kernel gates refuse -> their rows must carry the annotation
     for impl in ("flash", "pallas"):
         assert impl + at.FALLBACK_SUFFIX in times and impl not in times
@@ -496,22 +508,18 @@ def test_cached_winner_stale_when_variant_set_grows(clean_knobs, monkeypatch):
         lambda *a, **k: calls.append("x") or {"conv": 0.03, "fft": 0.01},
     )
     monkeypatch.setattr(
-        at, "pick_win_attn_impl",
-        lambda *a, **k: calls.append("w") or {"dense": 0.02, "folded": 0.01},
-    )
-    monkeypatch.setattr(
         at, "pick_global_attn_impl",
         lambda *a, **k: calls.append("g") or {"blockwise": 0.02,
                                               "flash": 0.01},
     )
     r1 = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "w", "g"]
+    assert calls == ["x", "g"]
 
     # cached entries were stamped: a rerun re-measures nothing
     for k in KNOBS:
         os.environ.pop(k, None)
     at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "w", "g"]
+    assert calls == ["x", "g"]
 
     # the global-attn variant set grows (new kernel lands): ONLY that knob
     # re-sweeps; the stamped siblings stay cached
@@ -522,9 +530,8 @@ def test_cached_winner_stale_when_variant_set_grows(clean_knobs, monkeypatch):
         at.GLOBAL_ATTN_VARIANTS + ("newkernel",),
     )
     r3 = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "w", "g", "g"]
+    assert calls == ["x", "g", "g"]
     assert r3["TMR_XCORR_IMPL_SMALL"].get("cached") is True
-    assert r3["TMR_WIN_ATTN"].get("cached") is True
     assert "cached" not in r3["TMR_GLOBAL_ATTN"]
 
     # legacy stamp-less entries (pre-versioning caches/seeds) also re-sweep
@@ -539,7 +546,7 @@ def test_cached_winner_stale_when_variant_set_grows(clean_knobs, monkeypatch):
     for k in KNOBS:
         os.environ.pop(k, None)
     at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "w", "g", "g", "x", "w", "g"]
+    assert calls == ["x", "g", "g", "x", "g"]
 
 
 def test_autotune_cached_hit_respects_explicit_knobs(
@@ -551,10 +558,6 @@ def test_autotune_cached_hit_respects_explicit_knobs(
         at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.03, "fft": 0.01}
     )
     monkeypatch.setattr(
-        at, "pick_win_attn_impl", lambda *a, **k: {"dense": 0.02,
-                                                  "folded": 0.01}
-    )
-    monkeypatch.setattr(
         at, "pick_global_attn_impl",
         lambda *a, **k: {"blockwise": 0.02, "flash": 0.01},
     )
@@ -562,33 +565,35 @@ def test_autotune_cached_hit_respects_explicit_knobs(
     for k in KNOBS:
         os.environ.pop(k, None)
     # user pins the attention knob: the cached hit must not override it
-    monkeypatch.setenv("TMR_WIN_ATTN", "dense")
+    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
     r = at.autotune(_cfg(), 1024, 4)
-    assert "TMR_WIN_ATTN" not in r
-    assert os.environ["TMR_WIN_ATTN"] == "dense"
+    assert "TMR_GLOBAL_ATTN" not in r
+    assert os.environ["TMR_GLOBAL_ATTN"] == "blockwise"
     assert r["TMR_XCORR_IMPL_SMALL"]["cached"] is True
 
 
 def test_measured_tpu_defaults(monkeypatch):
     """VERDICT r3 #2 'measured winners become the defaults': with no knobs
-    set, TPU processes default to the measured winners (TMR_WIN_ATTN=packed,
-    on the benchmark's two cells: PERF.md section 6, PR 28;
-    TMR_XCORR_IMPL_SMALL=vmap, BENCH_LIVE.json); other backends keep the
-    portable defaults; explicit env always wins."""
-    from tmr_tpu.models import vit as vit_mod
+    set, TPU processes default to the measured winners (windowed blocks
+    ``packed``, on the benchmark's two cells: PERF.md section 6, PR 28,
+    by ``window_formulation`` and no knob; TMR_XCORR_IMPL_SMALL=vmap,
+    BENCH_LIVE.json); other backends keep the portable defaults; for
+    the knobs that remain, explicit env always wins."""
+    from tmr_tpu.ops import pallas_attn
     from tmr_tpu.ops import xcorr as xcorr_mod
 
-    monkeypatch.delenv("TMR_WIN_ATTN", raising=False)
     monkeypatch.delenv("TMR_XCORR_IMPL", raising=False)
     monkeypatch.delenv("TMR_XCORR_IMPL_SMALL", raising=False)
+    monkeypatch.setattr(pallas_attn, "packed_window_ok", lambda *a: True)
+    vit_b = ((14, 14), 12, 64, jnp.bfloat16)
 
     if jax.default_backend() != "tpu":  # portable default off-TPU
-        assert vit_mod._WIN_ATTN_IMPL() == "dense"
+        assert pallas_attn.window_formulation(*vit_b) == "dense"
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert vit_mod._WIN_ATTN_IMPL() == "packed"
-    monkeypatch.setenv("TMR_WIN_ATTN", "folded")
-    assert vit_mod._WIN_ATTN_IMPL() == "folded"
+    assert pallas_attn.window_formulation(*vit_b) == "packed"
+    assert pallas_attn.window_formulation((14, 14), 16, 80,
+                                          jnp.bfloat16) == "packed"
 
     # xcorr: small-bucket default resolves to vmap on TPU. Observable via
     # the dispatch: identity-template correlation through a capacity-5
@@ -665,25 +670,20 @@ def test_autotune_seed_file_partial_sweep(clean_knobs, monkeypatch, tmp_path):
     key = "|".join(str(p) for p in (
         jax.devices()[0].device_kind, 1024, 128, 4, 512, "vit_b"))
     seed.write_text(json.dumps({key: {
-        "TMR_XCORR_IMPL_SMALL": "vmap", "TMR_WIN_ATTN": "flash",
+        "TMR_XCORR_IMPL_SMALL": "vmap", "TMR_GLOBAL_ATTN": "flash",
         # seeds carry the variant sets their winners beat (an unstamped
         # entry is treated as stale — covered by
         # test_cached_winner_stale_when_variant_set_grows)
         "_variants_TMR_XCORR_IMPL_SMALL": at._variants_sig(
             "TMR_XCORR_IMPL_SMALL"),
-        "_variants_TMR_WIN_ATTN": at._variants_sig("TMR_WIN_ATTN"),
+        "_variants_TMR_GLOBAL_ATTN": at._variants_sig("TMR_GLOBAL_ATTN"),
     }}))
     monkeypatch.setenv("TMR_AUTOTUNE_SEED", str(seed))
 
     calls = []
     boom = lambda tag: lambda *a, **k: calls.append(tag) or {}
     monkeypatch.setattr(at, "pick_xcorr_impl", boom("x"))
-    monkeypatch.setattr(at, "pick_win_attn_impl", boom("w"))
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: calls.append("g") or {"blockwise": 0.02,
-                                              "flash": 0.01},
-    )
+    monkeypatch.setattr(at, "pick_global_attn_impl", boom("g"))
     monkeypatch.setattr(
         at, "pick_xcorr_precision",
         lambda *a, **k: calls.append("p") or {
@@ -691,12 +691,11 @@ def test_autotune_seed_file_partial_sweep(clean_knobs, monkeypatch, tmp_path):
     )
     r = at.autotune(_cfg(), 1024, 4)
     # seeded knobs exported without their sweeps; unseeded ones measured
-    assert "x" not in calls and "w" not in calls
-    assert "g" in calls and "p" in calls
+    assert "x" not in calls and "g" not in calls
+    assert "p" in calls
     assert r["TMR_XCORR_IMPL_SMALL"] == {"picked": "vmap", "cached": True}
-    assert r["TMR_WIN_ATTN"] == {"picked": "flash", "cached": True}
-    assert os.environ["TMR_WIN_ATTN"] == "flash"
-    assert r["TMR_GLOBAL_ATTN"]["picked"] == "flash"
+    assert r["TMR_GLOBAL_ATTN"] == {"picked": "flash", "cached": True}
+    assert os.environ["TMR_GLOBAL_ATTN"] == "flash"
     # precision measured on the seeded vmap winner, decisive win -> default
     assert r["TMR_XCORR_PRECISION"]["picked"] == "default"
 
@@ -708,7 +707,7 @@ def test_autotune_seed_file_partial_sweep(clean_knobs, monkeypatch, tmp_path):
     at._cache_store(key, {"TMR_XCORR_IMPL_SMALL": {"picked": "conv"}})
     cached = at._cache_load()[key]
     assert cached["TMR_XCORR_IMPL_SMALL"] == "conv"
-    assert cached["TMR_WIN_ATTN"] == "flash"
+    assert cached["TMR_GLOBAL_ATTN"] == "flash"
 
     # and with the user cache absent, the seed alone still serves
     os.environ["TMR_AUTOTUNE_CACHE"] = str(tmp_path / "fresh_cache.json")
@@ -724,7 +723,6 @@ def test_cached_precision_dropped_when_impl_sweep_pending(
     fresh sweep picks, so relaxed numerics never outlive their pairing."""
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(at, "pick_win_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(
         at, "pick_xcorr_precision",
@@ -768,8 +766,6 @@ def test_scores_dtype_sweep_decisive_win_policy(clean_knobs, monkeypatch):
         at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.01})
     monkeypatch.setattr(
         at, "pick_xcorr_precision", lambda *a, **k: {"highest": 0.01})
-    monkeypatch.setattr(
-        at, "pick_win_attn_impl", lambda *a, **k: {"folded": 0.01})
     monkeypatch.setattr(
         at, "pick_global_attn_impl",
         lambda *a, **k: {"blockwise": 0.03, "blockfolded": 0.01},
@@ -851,8 +847,9 @@ def test_stale_winners_returns_only_stale_stamped_entries(
         "cpu|1024|128|4|512|vit_b": {
             "TMR_GLOBAL_ATTN": "blockfolded",
             "_variants_TMR_GLOBAL_ATTN": "old,set|old-rev",  # stale
-            "TMR_WIN_ATTN": "folded",
-            "_variants_TMR_WIN_ATTN": at._variants_sig("TMR_WIN_ATTN"),
+            "TMR_XCORR_IMPL_SMALL": "vmap",
+            "_variants_TMR_XCORR_IMPL_SMALL": at._variants_sig(
+                "TMR_XCORR_IMPL_SMALL"),
             "TMR_XCORR_PRECISION": "bf16",
             "_variants_TMR_XCORR_PRECISION": "also,old",  # stale
         }
@@ -922,7 +919,7 @@ def test_block_sweep_fallback_rows_carry_structured_refusals(
     diagnostics (verdict r5 #1)."""
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     times = at._sweep_block_env(
-        "TMR_GLOBAL_ATTN", ("blockwise", "pallas", "fused"), 0,
+        "TMR_GLOBAL_ATTN", ("blockwise", "pallas", "fused"),
         1, 32, 16, 2, 0.0, lambda s: None,
     )
     assert "blockwise" in times
@@ -957,8 +954,6 @@ def test_autotune_report_attaches_sweep_refusals(clean_knobs, monkeypatch):
                 "pallas" + at.FALLBACK_SUFFIX: 0.001}
 
     monkeypatch.setattr(at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.01})
-    monkeypatch.setattr(at, "pick_win_attn_impl",
-                        lambda *a, **k: {"dense": 0.01})
     monkeypatch.setattr(at, "pick_global_attn_impl", fake_global_sweep)
     report = at.autotune(_cfg(), 1024, 4, tune_precision=False)
     assert report["TMR_GLOBAL_ATTN"]["picked"] == "blockwise"
@@ -978,9 +973,6 @@ def _stub_non_tail_picks(monkeypatch):
     )
     monkeypatch.setattr(
         at, "pick_xcorr_precision", lambda *a, **k: {"highest": 0.01}
-    )
-    monkeypatch.setattr(
-        at, "pick_win_attn_impl", lambda *a, **k: {"dense": 0.01}
     )
     monkeypatch.setattr(
         at, "pick_global_attn_impl", lambda *a, **k: {"blockwise": 0.01}

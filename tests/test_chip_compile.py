@@ -30,7 +30,6 @@ from tmr_tpu.ops.flash_attn import (
 from tmr_tpu.ops.pallas_attn import (
     packed_windowed_attention,
     pallas_decomposed_attention,
-    pallas_windowed_attention,
 )
 
 V5E_HBM_BYTES = 16 * 1024**3
@@ -193,7 +192,6 @@ def _case_predict_program(sds):
 
 CASES = {
     "pallas_global": _attn_case(pallas_decomposed_attention, _G, 1),
-    "pallas_window": _attn_case(pallas_windowed_attention, _WIN, _NWIN),
     "flash_global": _attn_case(flash_decomposed_attention, _G, 1),
     "flash_global_grad": _attn_case(flash_decomposed_attention, _G, 1,
                                     grad=True),

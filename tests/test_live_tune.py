@@ -83,8 +83,8 @@ def test_winner_bank_device_generation_isolation(tmp_path):
     path = str(tmp_path / "bank.json")
     entries = {}
     for kind in GENS:
-        key = bank_key(kind, "TMR_WIN_ATTN", "g1")
-        entries[key] = make_entry(kind, "TMR_WIN_ATTN", "g1", "flash",
+        key = bank_key(kind, "TMR_GLOBAL_ATTN", "g1")
+        entries[key] = make_entry(kind, "TMR_GLOBAL_ATTN", "g1", "flash",
                                   source="offline")
     assert store_bank(entries, path)
     with open(path) as f:
@@ -107,15 +107,15 @@ def test_winner_bank_stale_rev_falls_back(tmp_path, monkeypatch):
     from tmr_tpu.utils.autotune import _variants_sig
 
     path = str(tmp_path / "bank.json")
-    fresh = make_entry("cpu", "TMR_WIN_ATTN", "g1", "flash",
+    fresh = make_entry("cpu", "TMR_GLOBAL_ATTN", "g1", "flash",
                        source="live")
     stale = make_entry("cpu", "TMR_QUANT", "g1", "int8",
                        source="offline")
     stale["sweep_rev"] = "pre-history"
-    store_bank({bank_key("cpu", "TMR_WIN_ATTN", "g1"): fresh,
+    store_bank({bank_key("cpu", "TMR_GLOBAL_ATTN", "g1"): fresh,
                 bank_key("cpu", "TMR_QUANT", "g1"): stale}, path)
     got = load_bank(path, device_kind="cpu")
-    assert set(got) == {bank_key("cpu", "TMR_WIN_ATTN", "g1")}
+    assert set(got) == {bank_key("cpu", "TMR_GLOBAL_ATTN", "g1")}
 
     # offline-cache seeding: fresh variants stamp seeds, stale stamp and
     # fallback-annotated winners do not, other generations do not, and
@@ -159,13 +159,13 @@ def test_winner_bank_rejects_invalid(tmp_path):
     (tmp_path / "bank.json").write_text("not json")
     assert load_bank(path) == {}
     # fallback-annotated winner: never electable
-    bad = make_entry("cpu", "TMR_WIN_ATTN", "g1", "dense (fallback)",
+    bad = make_entry("cpu", "TMR_GLOBAL_ATTN", "g1", "blockwise (fallback)",
                      source="live")
     # key/entry mismatch: a hand-edit, dropped
-    moved = make_entry("cpu", "TMR_WIN_ATTN", "g2", "flash",
+    moved = make_entry("cpu", "TMR_GLOBAL_ATTN", "g2", "flash",
                        source="live")
-    store_bank({bank_key("cpu", "TMR_WIN_ATTN", "g1"): bad,
-                bank_key("cpu", "TMR_WIN_ATTN", "g3"): moved}, path)
+    store_bank({bank_key("cpu", "TMR_GLOBAL_ATTN", "g1"): bad,
+                bank_key("cpu", "TMR_GLOBAL_ATTN", "g3"): moved}, path)
     assert load_bank(path) == {}
     # validator-level: source outside the vocabulary / boolean wins
     doc = {"schema": WINNER_BANK_SCHEMA, "sweep_rev": "r", "ts": 1.0,
@@ -338,11 +338,11 @@ def test_apply_winner_env_and_kinds(monkeypatch):
     assert apply_winner(_Pred(), "TMR_DECODER_IMPL", "fused") == 7
     assert os.environ["TMR_DECODER_IMPL"] == "fused"
     assert calls == [autotune_live.KNOB_PROGRAM_KINDS["TMR_DECODER_IMPL"]]
-    monkeypatch.setenv("TMR_WIN_ATTN", "dense")
-    assert apply_winner(_Pred(), "TMR_WIN_ATTN", "flash") == 7
+    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
+    assert apply_winner(_Pred(), "TMR_GLOBAL_ATTN", "flash") == 7
     assert calls[-1] is None  # attention knobs invalidate EVERYTHING
     # a predictor without the hook (the fleet stub): env-only, 0 drops
-    assert apply_winner(object(), "TMR_WIN_ATTN", "dense") == 0
+    assert apply_winner(object(), "TMR_GLOBAL_ATTN", "blockwise") == 0
 
 
 # ----------------------------------------------------------- engine wiring

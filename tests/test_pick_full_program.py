@@ -37,9 +37,7 @@ def seed_file(tmp_path, monkeypatch):
     path.write_text(json.dumps({
         "TPU v5 lite|1024|128|4|512|vit_b": {
             "TMR_GLOBAL_ATTN": "blockwise",
-            "TMR_WIN_ATTN": "flash",
             "_variants_TMR_GLOBAL_ATTN": "stale",
-            "_variants_TMR_WIN_ATTN": "stale",
         }
     }))
     monkeypatch.setenv("TMR_AUTOTUNE_SEED", str(path))
@@ -55,14 +53,15 @@ def test_decisive_full_program_winner_pins_seed(tmp_path, seed_file, capsys):
     # autotuned (nothing externally pinned)
     (tmp_path / "bench_live.json").write_text(json.dumps(_rec(
         10.1,
-        knobs={"TMR_GLOBAL_ATTN": "blockwise", "TMR_WIN_ATTN": "flash"},
-        autotuned={"TMR_GLOBAL_ATTN": "blockwise", "TMR_WIN_ATTN": "flash"},
+        knobs={"TMR_GLOBAL_ATTN": "blockwise"},
+        autotuned={"TMR_GLOBAL_ATTN": "blockwise"},
     )))
-    # pinned run: TMR_GLOBAL_ATTN forced in the env (absent from autotuned)
+    # pinned run: the kernel's query tile forced in the env (absent from
+    # autotuned); the sweep of that run picked the formulation itself
     (tmp_path / "bench_pallas.json").write_text(json.dumps(_rec(
         27.4,
-        knobs={"TMR_GLOBAL_ATTN": "pallas", "TMR_WIN_ATTN": "flash"},
-        autotuned={"TMR_WIN_ATTN": "flash"},
+        knobs={"TMR_GLOBAL_ATTN": "pallas", "TMR_PALLAS_ATTN_BQ": "256"},
+        autotuned={"TMR_GLOBAL_ATTN": "pallas"},
     )))
     rc = arb.main([str(tmp_path / "bench_live.json"),
                    str(tmp_path / "bench_pallas.json")])
@@ -74,9 +73,9 @@ def test_decisive_full_program_winner_pins_seed(tmp_path, seed_file, capsys):
 
     seed = json.loads(seed_file.read_text())
     entry = seed["TPU v5 lite|1024|128|4|512|vit_b"]
+    assert entry["TMR_PALLAS_ATTN_BQ"] == "256"
+    # the winning run's autotuned formulation is full-program-endorsed
     assert entry["TMR_GLOBAL_ATTN"] == "pallas"
-    # the winning run's autotuned windowed pick is full-program-endorsed
-    assert entry["TMR_WIN_ATTN"] == "flash"
     assert entry["_variants_TMR_GLOBAL_ATTN"] == _variants_sig(
         "TMR_GLOBAL_ATTN"
     )
@@ -186,7 +185,7 @@ def test_error_records_and_missing_files_are_skipped(tmp_path, seed_file,
 
 
 def test_pinned_tile_knobs_round_trip_the_cache(tmp_path, monkeypatch):
-    """Tile/group pins written by the arbiter must survive cache validation
+    """Tile/unroll pins written by the arbiter must survive cache validation
     and be exported to the env by autotune() as cached hits — the pallas
     kernels read them at trace time."""
     import jax
@@ -200,7 +199,7 @@ def test_pinned_tile_knobs_round_trip_the_cache(tmp_path, monkeypatch):
             "_variants_TMR_GLOBAL_ATTN": at._variants_sig("TMR_GLOBAL_ATTN"),
             "TMR_PALLAS_ATTN_BQ": "256",
             "TMR_PALLAS_ATTN_BK": "1024",
-            "TMR_PALLAS_WIN_GROUP": "8",
+            "TMR_GLOBAL_BANDS_UNROLL": "2",
             "TMR_PALLAS_ATTN_BQ_bad": "300",  # not pow2: must be dropped
         }
     }))
@@ -209,21 +208,18 @@ def test_pinned_tile_knobs_round_trip_the_cache(tmp_path, monkeypatch):
     loaded = at._load_validated(str(seed))
     entry = loaded["cpu|1024|128|4|512|vit_b"]
     assert entry["TMR_PALLAS_ATTN_BQ"] == "256"
-    assert entry["TMR_PALLAS_WIN_GROUP"] == "8"
+    assert entry["TMR_GLOBAL_BANDS_UNROLL"] == "2"
     assert "TMR_PALLAS_ATTN_BQ_bad" not in entry
 
-    for k in ("TMR_GLOBAL_ATTN", "TMR_WIN_ATTN", "TMR_XCORR_IMPL",
+    for k in ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL",
               "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
               "TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-              "TMR_PALLAS_WIN_GROUP"):
+              "TMR_GLOBAL_BANDS_UNROLL"):
         monkeypatch.delenv(k, raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     monkeypatch.setattr(
         at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.01}
-    )
-    monkeypatch.setattr(
-        at, "pick_win_attn_impl", lambda *a, **k: {"dense": 0.01}
     )
     monkeypatch.setattr(
         at, "pick_global_attn_impl", lambda *a, **k: {"blockwise": 0.01}
@@ -253,9 +249,9 @@ def test_pinned_tile_knobs_round_trip_the_cache(tmp_path, monkeypatch):
                                              "cached": True}
         assert os.environ["TMR_PALLAS_ATTN_BQ"] == "256"
         assert os.environ["TMR_PALLAS_ATTN_BK"] == "1024"
-        assert os.environ["TMR_PALLAS_WIN_GROUP"] == "8"
+        assert os.environ["TMR_GLOBAL_BANDS_UNROLL"] == "2"
     finally:
-        for k in ("TMR_GLOBAL_ATTN", "TMR_WIN_ATTN", "TMR_XCORR_IMPL_SMALL",
+        for k in ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL_SMALL",
                   "TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-                  "TMR_PALLAS_WIN_GROUP", "TMR_XCORR_PRECISION"):
+                  "TMR_GLOBAL_BANDS_UNROLL", "TMR_XCORR_PRECISION"):
             os.environ.pop(k, None)

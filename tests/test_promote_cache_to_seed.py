@@ -40,15 +40,15 @@ def test_fresh_winners_promote_and_stale_do_not(paths, capsys):
     cache.write_text(json.dumps({KEY: {
         "TMR_GLOBAL_ATTN": "pallas",
         "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
-        "TMR_WIN_ATTN": "flash",
-        "_variants_TMR_WIN_ATTN": "stale,old,set",  # stale: must not move
+        "TMR_XCORR_IMPL_SMALL": "vmap",
+        "_variants_TMR_XCORR_IMPL_SMALL": "stale,old,set",  # must not move
         "TMR_BENCH_BATCH": "8",
     }}))
     seed.write_text(json.dumps({KEY: {
         "TMR_GLOBAL_ATTN": "blockwise",
         "_variants_TMR_GLOBAL_ATTN": "old",
-        "TMR_WIN_ATTN": "dense",
-        "_variants_TMR_WIN_ATTN": "old",
+        "TMR_XCORR_IMPL_SMALL": "conv",
+        "_variants_TMR_XCORR_IMPL_SMALL": "old",
     }}))
     rc = _promoter().main([])
     assert rc == 0
@@ -57,9 +57,9 @@ def test_fresh_winners_promote_and_stale_do_not(paths, capsys):
     assert out["_variants_TMR_GLOBAL_ATTN"] == _variants_sig(
         "TMR_GLOBAL_ATTN"
     )
-    # the stale-stamped windowed winner did NOT launder into the seed
-    assert out["TMR_WIN_ATTN"] == "dense"
-    assert out["_variants_TMR_WIN_ATTN"] == "old"
+    # the stale-stamped correlation winner did NOT launder into the seed
+    assert out["TMR_XCORR_IMPL_SMALL"] == "conv"
+    assert out["_variants_TMR_XCORR_IMPL_SMALL"] == "old"
     # measured batch rides along
     assert out["TMR_BENCH_BATCH"] == "8"
 
@@ -69,24 +69,24 @@ def test_full_program_pins_outrank_sweep_winners(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_WIN_ATTN": "flash",
-        "_variants_TMR_WIN_ATTN": _variants_sig("TMR_WIN_ATTN"),
+        "TMR_GLOBAL_ATTN": "flash",
+        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
         "TMR_XCORR_IMPL_SMALL": "vmap",
         "_variants_TMR_XCORR_IMPL_SMALL": _variants_sig(
             "TMR_XCORR_IMPL_SMALL"
         ),
     }}))
-    # seed entry written by pick_full_program: dense won the WHOLE-program
-    # A/B — the sweep's one-block flash pick must not overwrite it
+    # seed entry written by pick_full_program: blockwise won the WHOLE-
+    # program A/B — the sweep's one-block flash pick must not overwrite it
     seed.write_text(json.dumps({KEY: {
-        "TMR_WIN_ATTN": "dense",
-        "_variants_TMR_WIN_ATTN": _variants_sig("TMR_WIN_ATTN"),
+        "TMR_GLOBAL_ATTN": "blockwise",
+        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
         "_full_program_ab": "{}",
     }}))
     rc = _promoter().main([])
     assert rc == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_WIN_ATTN"] == "dense"          # preserved
+    assert out["TMR_GLOBAL_ATTN"] == "blockwise"         # preserved
     assert out["_full_program_ab"] == "{}"         # marker intact
     assert out["TMR_XCORR_IMPL_SMALL"] == "vmap"   # non-block knob promoted
 
@@ -100,18 +100,18 @@ def test_stale_full_program_pin_does_not_block_promotion(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_WIN_ATTN": "flash",
-        "_variants_TMR_WIN_ATTN": _variants_sig("TMR_WIN_ATTN"),
+        "TMR_GLOBAL_ATTN": "flash",
+        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
     }}))
     seed.write_text(json.dumps({KEY: {
-        "TMR_WIN_ATTN": "dense",
-        "_variants_TMR_WIN_ATTN": "pre-revision,stale",
+        "TMR_GLOBAL_ATTN": "blockwise",
+        "_variants_TMR_GLOBAL_ATTN": "pre-revision,stale",
         "_full_program_ab": "{}",
     }}))
     rc = _promoter().main([])
     assert rc == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_WIN_ATTN"] == "flash"
+    assert out["TMR_GLOBAL_ATTN"] == "flash"
 
 
 def test_overwritten_stale_pin_loses_its_marker(paths, capsys):
@@ -123,17 +123,17 @@ def test_overwritten_stale_pin_loses_its_marker(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_WIN_ATTN": "flash",
-        "_variants_TMR_WIN_ATTN": _variants_sig("TMR_WIN_ATTN"),
+        "TMR_GLOBAL_ATTN": "flash",
+        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
     }}))
     seed.write_text(json.dumps({KEY: {
-        "TMR_WIN_ATTN": "dense",
-        "_variants_TMR_WIN_ATTN": "pre-revision,stale",
+        "TMR_GLOBAL_ATTN": "blockwise",
+        "_variants_TMR_GLOBAL_ATTN": "pre-revision,stale",
         "_full_program_ab": "{}",
     }}))
     assert _promoter().main([]) == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_WIN_ATTN"] == "flash"
+    assert out["TMR_GLOBAL_ATTN"] == "flash"
     assert "_full_program_ab" not in out
 
 
@@ -180,10 +180,10 @@ def test_corrupt_seed_entry_degrades_gracefully(paths, capsys):
 def test_nothing_to_promote(paths, capsys):
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_WIN_ATTN": "flash",
-        "_variants_TMR_WIN_ATTN": "stale",
+        "TMR_GLOBAL_ATTN": "flash",
+        "_variants_TMR_GLOBAL_ATTN": "stale",
     }}))
-    before = json.dumps({KEY: {"TMR_WIN_ATTN": "dense"}})
+    before = json.dumps({KEY: {"TMR_GLOBAL_ATTN": "blockwise"}})
     seed.write_text(before)
     rc = _promoter().main([])
     assert rc == 3

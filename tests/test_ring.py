@@ -719,53 +719,6 @@ def test_pallas_global_gate_keys_on_effective_tiles(monkeypatch):
     assert info1.hits - info0.hits == 1
 
 
-@pytest.mark.parametrize("group,D", [(None, 8), ("3", 8), (None, 80)])
-@pytest.mark.slow
-def test_pallas_windowed_attention_matches_blockwise(group, D, monkeypatch):
-    """TMR_WIN_ATTN=pallas (ops/pallas_attn.pallas_windowed_attention) vs
-    the exact blockwise oracle at the REAL 14x14 window grid (196 tokens
-    padded to a 256 tile with in-kernel masking), values and grads —
-    grouped (TMR_PALLAS_WIN_GROUP=3 -> G=3 at bh=6) and ungrouped, plus
-    vit_h's non-lane-aligned head_dim 80."""
-    import numpy as np
-
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops.pallas_attn import pallas_windowed_attention
-
-    if group is not None:
-        monkeypatch.setenv("TMR_PALLAS_WIN_GROUP", group)
-    rng = np.random.default_rng(15)
-    B, H, gh, gw = 3, 2, 14, 14  # B = batch*windows
-    S = gh * gw
-    q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    rh = jnp.asarray(rng.standard_normal((gh, gh, D)), jnp.float32) * 0.2
-    rw = jnp.asarray(rng.standard_normal((gw, gw, D)), jnp.float32) * 0.2
-    scale = D**-0.5
-
-    got = jax.jit(
-        lambda *a: pallas_windowed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    want = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-    def loss(fn):
-        return lambda a, b, c: jnp.sum(
-            fn(a, b, c, rh, rw, (gh, gw), scale) ** 2)
-
-    g_got = jax.jit(jax.grad(loss(pallas_windowed_attention),
-                             argnums=(0, 1, 2)))(q, k, v)
-    g_want = jax.jit(jax.grad(loss(blockwise_decomposed_attention),
-                              argnums=(0, 1, 2)))(q, k, v)
-    for a, b in zip(g_got, g_want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
 def _packed_case(windows, heads, head_dim, dtype, seed=28):
     """qkv as ``nn.Dense(3 * dim)`` writes it on padded window rows, and
     the rel-pos tables, at the real 14 x 14 window."""
@@ -790,7 +743,7 @@ def _packed_case(windows, heads, head_dim, dtype, seed=28):
 def test_packed_windowed_attention_matches_blockwise(
     windows, heads, head_dim, dtype
 ):
-    """TMR_WIN_ATTN=packed (ops/pallas_attn.packed_windowed_attention, the
+    """``packed`` (ops/pallas_attn.packed_windowed_attention, the
     interpreter here) against the exact blockwise oracle on the unpacked
     heads, at window (14, 14). One window is one grid step, so every count
     of windows divides; float32 holds the oracle to its own rounding
@@ -849,62 +802,71 @@ def test_packed_windowed_attention_grads_are_the_oracles():
     assert not pad_rows.any()
 
 
-@pytest.mark.parametrize("backend,taken", [("cpu", "dense"),
-                                           ("tpu", "packed")])
+@pytest.mark.parametrize("backend,dtype,gate,rel_pos,env,taken", [
+    ("cpu", "bfloat16", "admits", True, None, "dense"),
+    ("tpu", "bfloat16", "admits", True, None, "packed"),
+    ("tpu", "float32", "admits", True, None, "dense"),
+    ("tpu", "bfloat16", "partitioned", True, None, "dense"),
+    ("tpu", "bfloat16", "refuses", True, None, "dense"),
+    ("tpu", "bfloat16", "admits", False, None, "dense"),
+    ("tpu", "bfloat16", "admits", True, "folded", "packed"),
+], ids=["cpu-dense", "tpu-packed", "tpu-float32-dense",
+        "tpu-partitioned-dense", "tpu-gate-refuses-dense",
+        "tpu-no-rel-pos-dense", "tpu-dead-variable-packed"])
 def test_windowed_blocks_are_counted_by_formulation(
-    backend, taken, monkeypatch
+    backend, dtype, gate, rel_pos, env, taken, monkeypatch
 ):
     """``vit.win_attn.<formulation>`` counts the windowed blocks of a
-    trace: 8 for a depth-12 encoder with ViT-B's pattern of global blocks,
-    under the formulation taken and 0 under every other. Here the CPU
-    takes ``dense``; where the backend reads ``tpu`` and the gate admits
-    the kernel (its self-check only a chip can run) a bfloat16 trace takes
-    ``packed``, and a float32 one still ``dense``."""
-    from tmr_tpu.models.vit import SamViT
+    trace under what ``ops/pallas_attn.window_formulation`` answers: 8 for
+    a depth-12 encoder with ViT-B's pattern of global blocks, 0 under the
+    other name. ``packed`` only where the backend reads ``tpu``, the trace
+    is bfloat16, the block has rel-pos tables and the gate admits the
+    kernel (its self-check only a chip can run); the gate itself refuses
+    with cause ``partitioned`` inside a program XLA partitions. No
+    environment variable has a say: the old knob, set, changes nothing."""
+    from tmr_tpu import diagnostics
+    from tmr_tpu.models.vit import Attention, SamViT
     from tmr_tpu.obs import metrics
     from tmr_tpu.ops import pallas_attn
+    from tmr_tpu.parallel.compat import partitioned
 
-    monkeypatch.delenv("TMR_WIN_ATTN", raising=False)
+    if env is not None:
+        monkeypatch.setenv("TMR_WIN_ATTN", env)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    monkeypatch.setattr(pallas_attn, "packed_window_ok", lambda *a: True)
-    model = SamViT(embed_dim=128, depth=12, num_heads=4,
-                   global_attn_indexes=(2, 5, 8, 11), window_size=14,
-                   out_chans=8, pretrain_img_size=448, dtype=jnp.bfloat16)
-    x = jax.ShapeDtypeStruct((1, 448, 448, 3), jnp.float32)
+    if gate != "partitioned":  # there the real gate answers, unasked
+        monkeypatch.setattr(pallas_attn, "packed_window_ok",
+                            lambda *a: gate == "admits")
+    dtype = jnp.dtype(dtype)
+    if rel_pos:
+        blocks = 8
+        model = SamViT(embed_dim=128, depth=12, num_heads=4,
+                       global_attn_indexes=(2, 5, 8, 11), window_size=14,
+                       out_chans=8, pretrain_img_size=448, dtype=dtype)
+        x = jax.ShapeDtypeStruct((1, 448, 448, 3), jnp.float32)
+    else:
+        blocks = 1
+        model = Attention(num_heads=4, use_rel_pos=False, windowed=True,
+                          dtype=dtype)
+        x = jax.ShapeDtypeStruct((4, 14, 14, 128), dtype)
     params = jax.eval_shape(model.init, jax.random.key(0), x)
-    names = ("dense", "folded", "flash", "pallas", "packed")
 
-    def counts(m):
-        metrics.get_registry().reset("vit.win_attn.")
-        jax.eval_shape(m.apply, params, x)
-        return {n: 0 for n in names} | metrics.get_registry().counters(
-            "vit.win_attn.")
+    def trace():
+        jax.eval_shape(model.apply, params, x)
+        return pallas_attn.window_formulation(
+            (14, 14), 4, 32, dtype, rel_pos)
 
-    assert counts(model) == {n: 8 * (n == taken) for n in names}
-    assert counts(model.clone(dtype=jnp.float32)) == {
-        n: 8 * (n == "dense") for n in names}
-
-
-@pytest.mark.slow
-def test_win_attn_env_dispatch_pallas(monkeypatch):
-    """A windowed Attention module under TMR_WIN_ATTN=pallas must equal the
-    dense default (off-TPU the gate refuses -> dense fallback, which is the
-    point: the dispatch chain must stay numerically safe either way)."""
-    import numpy as np
-
-    from tmr_tpu.models.vit import Attention
-
-    rng = np.random.default_rng(16)
-    x = jnp.asarray(rng.standard_normal((2, 14, 14, 16)), jnp.float32)
-    attn = Attention(num_heads=2, rel_pos_size=(14, 14))
-    params = attn.init(jax.random.key(0), x)
-
-    monkeypatch.setenv("TMR_WIN_ATTN", "dense")
-    want = jax.jit(attn.apply)(params, x)
-    monkeypatch.setenv("TMR_WIN_ATTN", "pallas")
-    got = jax.jit(attn.apply)(params, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    if gate == "partitioned":
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("model",))
+        trace = partitioned(trace, mesh)
+    diagnostics.drain_gate_refusals()
+    metrics.get_registry().reset("vit.win_attn.")
+    assert trace() == taken
+    assert metrics.get_registry().counters("vit.win_attn.") == {
+        taken: blocks}
+    causes = {(r["gate"], r["cause"])
+              for r in diagnostics.drain_gate_refusals()}
+    assert causes == ({("packed_window_ok", "partitioned")}
+                      if gate == "partitioned" else set())
 
 
 @pytest.mark.slow
@@ -995,35 +957,6 @@ def test_flash_attention_ok_callable_under_trace():
 
 
 @pytest.mark.slow
-def test_windowed_attention_folded_matches_dense(monkeypatch):
-    """TMR_WIN_ATTN=folded routes the windowed blocks' bias through the QK
-    contraction (ops/flash_attn.fold_rel_pos_into_qk); in f32 the algebra is
-    exact, so the Attention module must agree with its default dense path."""
-    from tmr_tpu.models.vit import Attention
-
-    rng = np.random.default_rng(11)
-    b, win, dim, heads = 3, 14, 32, 4
-    x = jnp.asarray(rng.standard_normal((b, win, win, dim)), jnp.float32)
-    attn = Attention(num_heads=heads, rel_pos_size=(win, win))
-    params = attn.init(jax.random.key(0), x)
-    # zero-init rel-pos tables make the bias trivial; randomize them
-    params = jax.tree.map(
-        lambda p: jnp.asarray(
-            np.random.default_rng(3).standard_normal(p.shape) * 0.1, p.dtype
-        ),
-        params,
-    )
-
-    monkeypatch.delenv("TMR_WIN_ATTN", raising=False)
-    want = attn.apply(params, x)
-    monkeypatch.setenv("TMR_WIN_ATTN", "folded")
-    got = attn.apply(params, x)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
-    )
-
-
-@pytest.mark.slow
 def test_flash_windowed_padding_and_segments(monkeypatch):
     """flash_windowed_attention pads 196-token windows to 256 and masks the
     pad via a second segment. The Pallas kernel itself needs a TPU, but its
@@ -1058,39 +991,6 @@ def test_flash_windowed_padding_and_segments(monkeypatch):
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
     assert got.shape == (b, hds, s, d)
-
-
-@pytest.mark.slow
-def test_windowed_attention_folded_grads_match_dense(monkeypatch):
-    """Training differentiates through whatever attention formulation is
-    active; the folded QK path must carry the same gradients as dense."""
-    from tmr_tpu.models.vit import Attention
-
-    rng = np.random.default_rng(13)
-    b, win, dim, heads = 2, 7, 16, 2
-    x = jnp.asarray(rng.standard_normal((b, win, win, dim)), jnp.float32)
-    attn = Attention(num_heads=heads, rel_pos_size=(win, win))
-    params = attn.init(jax.random.key(0), x)
-    params = jax.tree.map(
-        lambda p: jnp.asarray(
-            np.random.default_rng(5).standard_normal(p.shape) * 0.1, p.dtype
-        ),
-        params,
-    )
-
-    def loss(p, x):
-        return jnp.sum(attn.apply(p, x) ** 2)
-
-    monkeypatch.delenv("TMR_WIN_ATTN", raising=False)
-    want_g = jax.grad(loss)(params, x)
-    monkeypatch.setenv("TMR_WIN_ATTN", "folded")
-    got_g = jax.grad(loss)(params, x)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5
-        ),
-        got_g, want_g,
-    )
 
 
 @pytest.mark.slow
@@ -1186,55 +1086,3 @@ def test_ring_at_1536_bucket_scale():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
-
-
-@pytest.mark.slow
-def test_win_scores_dtype_bf16_matches_dense(monkeypatch):
-    """TMR_WIN_SCORES_DTYPE=bf16 (experiment knob: per-window folded score
-    tensors materialize in bf16) must stay within bf16 tolerance of the
-    dense windowed oracle on the bf16 deployment dtype, change the
-    rounding vs f32 scores (liveness), and be inert for f32 models."""
-    from tmr_tpu.models.vit import Attention
-
-    rng = np.random.default_rng(17)
-    # drive the Attention module directly at the window grid (14x14
-    # tokens — the windowed folded branch)
-    xw = jnp.asarray(rng.standard_normal((4, 14, 14, 32)), jnp.bfloat16)
-    attn16 = Attention(num_heads=2, rel_pos_size=(14, 14),
-                       dtype=jnp.bfloat16)
-    params = attn16.init(jax.random.key(0), xw)
-
-    monkeypatch.setenv("TMR_WIN_ATTN", "dense")
-    monkeypatch.delenv("TMR_WIN_SCORES_DTYPE", raising=False)
-    ref = np.asarray(jax.jit(attn16.apply)(params, xw), np.float32)
-
-    monkeypatch.setenv("TMR_WIN_ATTN", "folded")
-    f32s = np.asarray(jax.jit(attn16.apply)(params, xw), np.float32)
-    monkeypatch.setenv("TMR_WIN_SCORES_DTYPE", "bf16")
-    b16s = np.asarray(jax.jit(attn16.apply)(params, xw), np.float32)
-
-    scale = np.abs(ref).max() + 1e-6
-    assert np.abs(f32s - ref).max() / scale < 0.05
-    assert np.abs(b16s - ref).max() / scale < 0.05
-    # liveness at the trace level: the lowered programs must differ (the
-    # bf16-rounded scores can coincide with f32 scores after the final
-    # bf16 output cast at this tiny scale, so output inequality is not a
-    # reliable signal here — unlike the global-path test)
-    monkeypatch.delenv("TMR_WIN_SCORES_DTYPE")
-    h_f32 = jax.jit(attn16.apply).lower(params, xw).as_text()
-    monkeypatch.setenv("TMR_WIN_SCORES_DTYPE", "bf16")
-    h_b16 = jax.jit(attn16.apply).lower(params, xw).as_text()
-    assert h_f32 != h_b16
-
-    # f32 model: knob inert (bit-equal to the unset run)
-    attn32 = Attention(num_heads=2, rel_pos_size=(14, 14))
-    xw32 = jnp.asarray(rng.standard_normal((4, 14, 14, 32)), jnp.float32)
-    p32 = attn32.init(jax.random.key(0), xw32)
-    with_knob = np.asarray(jax.jit(attn32.apply)(p32, xw32), np.float32)
-    monkeypatch.delenv("TMR_WIN_SCORES_DTYPE")
-    without = np.asarray(jax.jit(attn32.apply)(p32, xw32), np.float32)
-    np.testing.assert_array_equal(with_knob, without)
-
-    monkeypatch.setenv("TMR_WIN_SCORES_DTYPE", "int8")
-    with pytest.raises(ValueError, match="TMR_WIN_SCORES_DTYPE"):
-        jax.jit(attn16.apply)(params, xw)

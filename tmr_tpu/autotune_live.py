@@ -78,7 +78,6 @@ BANK_PATH = os.path.join(STATE_DIR, "winner_bank.json")
 #: promotion's invalidation scope — too narrow would serve a stale
 #: formulation, too wide only costs recompiles.
 KNOB_PROGRAM_KINDS: Dict[str, Optional[Tuple[str, ...]]] = {
-    "TMR_WIN_ATTN": None,
     "TMR_GLOBAL_ATTN": None,
     "TMR_XCORR_IMPL_SMALL": None,
     "TMR_QUANT": None,
@@ -163,7 +162,6 @@ def _winner_ok(knob: str, value: str) -> bool:
         return False
     sets = {
         "TMR_XCORR_IMPL_SMALL": set(_at.XCORR_VARIANTS) | {"auto"},
-        "TMR_WIN_ATTN": set(_at.WIN_ATTN_VARIANTS),
         "TMR_GLOBAL_ATTN": set(_at.GLOBAL_ATTN_VARIANTS) | {"auto"},
         "TMR_DECODER_IMPL": set(_at.DECODER_IMPL_VARIANTS) | {"auto"},
         "TMR_QUANT": set(_at.QUANT_VARIANTS) | {"auto"},

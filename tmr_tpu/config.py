@@ -174,8 +174,6 @@ ENV_KNOBS = {
     # formulation dispatch (trace-time; autotune exports winners here)
     "TMR_GLOBAL_ATTN": "global ViT attention formulation: auto|blockwise|"
         "blockfolded|densefolded|flash|xlaflash|pallas|fused",
-    "TMR_WIN_ATTN": "windowed ViT attention formulation: dense|folded|"
-        "flash|pallas|packed (the TPU bf16 default)",
     "TMR_XCORR_IMPL": "template-correlation formulation: auto|conv|"
         "convnhwc|vmap|fft|pallas",
     "TMR_XCORR_IMPL_SMALL": "small-bucket override of TMR_XCORR_IMPL",
@@ -183,7 +181,6 @@ ENV_KNOBS = {
         "bf16 (decisive-win elected)",
     "TMR_GLOBAL_SCORES_DTYPE": "global-attention score-tile dtype: "
         "f32|bf16 (decisive-win elected)",
-    "TMR_WIN_SCORES_DTYPE": "windowed-attention score-tile dtype: f32|bf16",
     "TMR_DECODER_IMPL": "decoder-tail formulation: auto|xla|fused "
         "(ops/fused_heads.py, oracle-gated)",
     "TMR_QUANT": "int8-weight quantized tail: off|int8|auto "
@@ -199,7 +196,6 @@ ENV_KNOBS = {
     # kernel tile / schedule parameters (validated, pinnable)
     "TMR_PALLAS_ATTN_BQ": "Pallas global-attention query-tile rows",
     "TMR_PALLAS_ATTN_BK": "Pallas global-attention key-tile rows",
-    "TMR_PALLAS_WIN_GROUP": "Pallas windowed-attention window group size",
     "TMR_XLA_FLASH_BQ": "XLA flash-attention query-block rows",
     "TMR_XLA_FLASH_BK": "XLA flash-attention key-block rows",
     "TMR_GLOBAL_BANDS_UNROLL": "global-attention band-scan unroll factor",

@@ -15,7 +15,7 @@ The gate-refusal REGISTRY is the machine-readable side of the same story
 (round-5 verdict #1: on the live TPU every require_tpu kernel fell back
 and the gates swallowed WHY). Every refusal inside the compiled
 self-checks (ops/flash_attn._self_check and the gates built on it —
-pallas_global_ok, pallas_fused_ok, pallas_window_ok, flash_attention_ok,
+pallas_global_ok, pallas_fused_ok, packed_window_ok, flash_attention_ok,
 …) records a ``gate_probe.json``-schema cause here: refusal category,
 exception class + message when one was swallowed, the tile/geometry
 config the verdict keys on, and the device kind. Consumers drain it:
@@ -2044,8 +2044,7 @@ class FormulationFallbackWarning(UserWarning):
     """An explicitly requested kernel formulation fell back at trace time.
 
     ``env_var`` names the knob whose request was refused (e.g.
-    "TMR_GLOBAL_ATTN", "TMR_WIN_ATTN", "TMR_XCORR_IMPL",
-    "TMR_XCORR_IMPL_SMALL")."""
+    "TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL")."""
 
     def __init__(self, env_var: str, message: str):
         super().__init__(message)

@@ -43,7 +43,10 @@ STATS = "trunk_stats"
 #: today; the recurrence and the experts' grouped products choose theirs by
 #: device, type and sizes (``ops/kda.py:kda_formulation``,
 #: ``ops/moe.py:grouped_formulation``). ``KDA_FORMULATION`` is the
-#: recurrence's fallback, kept as a name the benchmark's driver imports
+#: recurrence's fallback, kept for the benchmark's driver alone
+#: (``benchmarks/drivers/offline_predict_lm_trunk.py:_say_gates`` prints
+#: it): it goes when a ``benchmark`` PR drops that read (ROADMAP.md
+#: Design 3a)
 KDA_FORMULATION = "chunked_xla"
 MLA_FORMULATION = "blocked_xla"
 

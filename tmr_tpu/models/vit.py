@@ -32,52 +32,6 @@ from tmr_tpu.diagnostics import FormulationFallbackWarning  # noqa: F401
 from tmr_tpu.models.common import LayerNorm2d, MLPBlock
 
 
-def _WIN_ATTN_IMPL() -> str:
-    """Windowed-attention formulation, read at trace time: "dense" (separate
-    f32 bias einsums + adds), "folded" (bias inside the QK contraction),
-    "flash" (stock Pallas kernel over 256-padded folded QK, bf16/TPU only),
-    "pallas" (the custom decomposed-bias kernel on head-major operands) or
-    "packed" (the kernel that reads the ``qkv`` product's output as it lies
-    and writes what ``proj`` reads, ops/pallas_attn.py).
-
-    Default: "packed" on TPU, "dense" elsewhere. On the v5e the windowed
-    blocks' attention took 11.1 ms an image of ViT-B/1024 under "flash",
-    7.9 of them pads, concatenates and transposes around the kernel
-    (PERF.md section 5, from PR 25's chip trace); "packed" has none of
-    them, and PERF.md section 6 (PR 28) has both cells' numbers for all
-    five. Safe as a default: the path runs behind a per-geometry compiled
-    self-check with dense fallback (Attention below), and the bf16, backend
-    and partitioning gates mean non-TPU, float32 or GSPMD-partitioned
-    traces never take it."""
-    dflt = "packed" if jax.default_backend() == "tpu" else "dense"
-    return os.environ.get("TMR_WIN_ATTN", dflt)
-
-
-def _flash_window_available(gh: int, gw: int, head_dim: int) -> bool:
-    from tmr_tpu.ops.flash_attn import flash_window_ok
-
-    return flash_window_ok(gh, gw, head_dim)
-
-
-def _pallas_window_available(
-    gh: int, gw: int, head_dim: int, bh: int
-) -> bool:
-    """``bh`` = windows*batch*heads of the ACTUAL trace: the self-check
-    must validate the same effective window group production will run."""
-    from tmr_tpu.ops.pallas_attn import _win_group, pallas_window_ok
-
-    return pallas_window_ok(gh, gw, head_dim, _win_group(bh))
-
-
-def _packed_window_available(
-    gh: int, gw: int, head_dim: int, num_heads: int
-) -> bool:
-    from tmr_tpu.ops.pallas_attn import packed_supported, packed_window_ok
-
-    return packed_supported((gh, gw), num_heads, head_dim) and \
-        packed_window_ok(gh, gw, head_dim, num_heads)
-
-
 def window_partition(x: jnp.ndarray, window: int):
     """(B, H, W, C) -> (B*nW, window, window, C), padding to multiples.
 
@@ -139,19 +93,6 @@ def _scores_dtype() -> str:
     if val not in ("f32", "bf16"):
         raise ValueError(
             f"TMR_GLOBAL_SCORES_DTYPE={val!r}: expected f32|bf16"
-        )
-    return val
-
-
-def _win_scores_dtype() -> str:
-    """TMR_WIN_SCORES_DTYPE: _scores_dtype()'s sibling for the folded
-    windowed score tensors. Same contract: 'f32' (default, exact) or
-    'bf16' (halved score-tile traffic; opt-in via env / full-program
-    pin)."""
-    val = os.environ.get("TMR_WIN_SCORES_DTYPE", "f32")
-    if val not in ("f32", "bf16"):
-        raise ValueError(
-            f"TMR_WIN_SCORES_DTYPE={val!r}: expected f32|bf16"
         )
     return val
 
@@ -383,48 +324,14 @@ class Attention(nn.Module):
     # formulation (obs counter ``vit.win_attn.<formulation>``)
     windowed: bool = False
 
-    def _window_formulation(self, h: int, w: int, head_dim: int,
-                            bh: int) -> str:
-        """The formulation a block under 1024 tokens traces with: the
-        requested one (``_WIN_ATTN_IMPL``) where its dtype precondition and
-        its gate admit it at this geometry, else "dense"."""
-        want = _WIN_ATTN_IMPL()
-        bf16 = self.dtype == jnp.bfloat16
-        if not self.use_rel_pos:
-            got = "dense"
-        elif want == "packed" and bf16 and _packed_window_available(
-            h, w, head_dim, self.num_heads
-        ):
-            got = "packed"
-        elif want == "flash" and bf16 and _flash_window_available(
-            h, w, head_dim
-        ):
-            got = "flash"
-        elif want == "pallas" and _pallas_window_available(
-            h, w, head_dim, bh
-        ):
-            got = "pallas"
-        elif want == "folded":
-            got = "folded"
-        else:
-            got = "dense"
-        if got == "dense" and os.environ.get("TMR_WIN_ATTN") in (
-            "flash", "pallas", "packed"
-        ):
-            # an EXPLICIT kernel request landed here only because its
-            # gate (or dtype precondition) refused — warn, or an A/B
-            # records dense timings under the requested label. The
-            # TPU default ("packed" with no env set) falls back silently
-            # by design.
-            import warnings
+    def _window_formulation(self, h: int, w: int, head_dim: int) -> str:
+        """The formulation a block under 1024 tokens traces with
+        (ops/pallas_attn.window_formulation), counted where the block is
+        one of the windowed ones."""
+        from tmr_tpu.ops.pallas_attn import window_formulation
 
-            warnings.warn(FormulationFallbackWarning(
-                "TMR_WIN_ATTN",
-                f"TMR_WIN_ATTN={os.environ['TMR_WIN_ATTN']}: gate or "
-                f"dtype refused window grid ({h}, {w}, head_dim "
-                f"{head_dim}, dtype {self.dtype}); running dense "
-                "fallback"
-            ))
+        got = window_formulation(
+            (h, w), self.num_heads, head_dim, self.dtype, self.use_rel_pos)
         if self.windowed:
             from tmr_tpu.obs import metrics
 
@@ -454,8 +361,7 @@ class Attention(nn.Module):
 
         win = None
         if self.seq_mesh is None and h * w < 1024:
-            win = self._window_formulation(
-                h, w, head_dim, b * self.num_heads)
+            win = self._window_formulation(h, w, head_dim)
         if win == "packed":
             # the windowed blocks' TPU bf16 path: the kernel takes qkv as
             # the product wrote it and writes what proj reads — no
@@ -487,8 +393,8 @@ class Attention(nn.Module):
         elif h * w >= 1024:
             # global-attention blocks (4096+ tokens): never materialize the
             # S x S scores or the (B, H, h, w, h, w) bias. TMR_GLOBAL_ATTN
-            # (trace-time A/B knob, measured by the autotune sweep like
-            # TMR_WIN_ATTN) picks the formulation:
+            # (trace-time A/B knob, measured by the autotune sweep) picks
+            # the formulation:
             #   blockwise    exact XLA band scan (the f32-parity default)
             #   blockfolded  band scan, bias folded into the QK contraction
             #                (exact in f32; bf16 is numerics-self-checked
@@ -668,79 +574,27 @@ class Attention(nn.Module):
                 (h, w), scale,
             )
             x = x.transpose(0, 2, 1, 3).reshape(b, h, w, dim)
-        elif win == "flash":
-            # A/B variant (TMR_WIN_ATTN=flash): the stock Pallas kernel over
-            # 256-padded windows with a pad segment — zero per-window score
-            # materialization. bf16-only (the kernel's compute dtype); gated
-            # by a per-geometry compiled self-check with fallback to dense.
-            from tmr_tpu.ops.flash_attn import flash_windowed_attention
-
-            x = flash_windowed_attention(q, k, v, rh, rw, (h, w), scale)
-            x = x.transpose(0, 2, 1, 3).reshape(b, h, w, dim)
-        elif win == "pallas":
-            # A/B variant (TMR_WIN_ATTN=pallas): the custom decomposed-bias
-            # kernel (ops/pallas_attn.py) on 128-padded window tiles with
-            # in-kernel pad-column masking — native head-dim contraction,
-            # per-tile bias from the small q-projections. Self-check gated
-            # with dense fallback.
-            from tmr_tpu.ops.pallas_attn import pallas_windowed_attention
-
-            x = pallas_windowed_attention(q, k, v, rh, rw, (h, w), scale)
-            x = x.transpose(0, 2, 1, 3).reshape(b, h, w, dim)
         else:
-            if win == "folded":
-                # A/B variant for the windowed blocks (TMR_WIN_ATTN=folded):
-                # the decomposed bias rides inside the QK contraction via the
-                # flash_attn augmentation (q'=[q*scale|q.RH|q.RW],
-                # k'=[k|onehot_row|onehot_col]), so the per-window score
-                # tensor is written once with the bias already in — no
-                # separate bias einsums + broadcast-add passes. Algebraically
-                # exact in f32; in bf16 the bias terms round to bf16 (the
-                # dense path keeps them f32) — kept opt-in until measured on
-                # hardware.
-                from tmr_tpu.ops.flash_attn import fold_rel_pos_into_qk
-
-                q_aug, k_aug = fold_rel_pos_into_qk(
-                    q, k, rh, rw, (h, w), scale
+            # everything else under 1024 tokens: the dense einsums, the
+            # only windowed path in float32, off a TPU and inside a
+            # partitioned trace, and the tests' oracle
+            attn = jnp.einsum(
+                "bnqc,bnkc->bnqk", q, k, preferred_element_type=jnp.float32
+            ) * scale
+            if self.use_rel_pos:
+                r_q = q.astype(jnp.float32).reshape(
+                    b, self.num_heads, h, w, head_dim
                 )
-                # TMR_WIN_SCORES_DTYPE=bf16 (experiment knob, folded-only
-                # like its global sibling): materialize the per-window
-                # score tensors in bf16 — f32 MXU accumulate, softmax
-                # upcasts on the fused read. Opt-in via env/A-B pin only
-                # (no autotune stage yet); the folded formulation itself
-                # is already the opt-in measured variant.
-                win_pet = (
-                    jnp.bfloat16
-                    if self.dtype == jnp.bfloat16
-                    and _win_scores_dtype() == "bf16"
-                    else jnp.float32
+                rel_h = jnp.einsum(
+                    "bnhwc,hkc->bnhwk", r_q, rh.astype(jnp.float32)
                 )
-                attn = jnp.einsum(
-                    "bnqc,bnkc->bnqk", q_aug, k_aug,
-                    preferred_element_type=win_pet,
+                rel_w = jnp.einsum(
+                    "bnhwc,wkc->bnhwk", r_q, rw.astype(jnp.float32)
                 )
-            else:
-                attn = jnp.einsum(
-                    "bnqc,bnkc->bnqk", q, k, preferred_element_type=jnp.float32
-                ) * scale
-                if self.use_rel_pos:
-                    r_q = q.astype(jnp.float32).reshape(
-                        b, self.num_heads, h, w, head_dim
-                    )
-                    rel_h = jnp.einsum(
-                        "bnhwc,hkc->bnhwk", r_q, rh.astype(jnp.float32)
-                    )
-                    rel_w = jnp.einsum(
-                        "bnhwc,wkc->bnhwk", r_q, rw.astype(jnp.float32)
-                    )
-                    attn = attn.reshape(b, self.num_heads, h, w, h, w)
-                    attn = attn + rel_h[..., :, None] + rel_w[..., None, :]
-                    attn = attn.reshape(b, self.num_heads, h * w, h * w)
-            # softmax always in f32 (a fused convert on the read path when
-            # the folded score tensor materialized in bf16; no-op otherwise)
-            attn = jax.nn.softmax(
-                attn.astype(jnp.float32), axis=-1
-            ).astype(self.dtype)
+                attn = attn.reshape(b, self.num_heads, h, w, h, w)
+                attn = attn + rel_h[..., :, None] + rel_w[..., None, :]
+                attn = attn.reshape(b, self.num_heads, h * w, h * w)
+            attn = jax.nn.softmax(attn, axis=-1).astype(self.dtype)
             x = jnp.einsum(
                 "bnqk,bnkc->bnqc", attn, v,
                 preferred_element_type=jnp.float32,

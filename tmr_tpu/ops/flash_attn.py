@@ -159,6 +159,10 @@ def flash_windowed_attention(
 ) -> jnp.ndarray:
     """Stock Pallas flash kernel over 196-token attention windows.
 
+    No model path calls this any more (``pallas_attn.window_formulation``
+    answers ``packed`` or ``dense``): it is kept as ``flash_window_ok``'s
+    subject, which is kept for the benchmark's driver alone (see there).
+
     The ViT's windowed blocks attend within 14x14=196-token windows — below
     the kernel's 128 block granularity and not a power-of-two multiple. The
     windows are therefore zero-padded to the next 128 multiple (256) and the
@@ -522,10 +526,15 @@ def densefolded_ok(
 
 @mosaic_gate
 def flash_window_ok(gh: int, gw: int, head_dim: int) -> bool:
-    """Per-geometry compiled self-check of the windowed flash path — the
-    caller passes the ACTUAL window grid and head dim it is about to run
-    (14x14/64 in production; any other geometry gets its own checked entry,
-    so an unvalidated shape can never bypass the fallback-to-dense gate)."""
+    """Per-geometry compiled self-check of the windowed flash path at the
+    window grid and head dim given.
+
+    Kept for the benchmark's driver alone: nothing in the program asks
+    this gate since the windowed blocks lost their ``flash`` formulation,
+    but ``benchmarks/drivers/offline_predict.py:_say_gates`` calls it in
+    every SAM set-up and ``benchmarks/compile_check.py`` patches it by
+    name. When a ``benchmark`` PR drops those two reads, this gate and
+    ``flash_windowed_attention`` go (ROADMAP.md Design 3a)."""
     return _self_check(flash_windowed_attention, 2, 2, gh, gw, head_dim,
                        gate="flash_window_ok")
 

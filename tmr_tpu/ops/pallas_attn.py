@@ -314,163 +314,8 @@ def _pallas_attn_fwd_impl(q, k, v, rh, rw, grid_hw, scale):
     return out.reshape(B, H, S, D)
 
 
-def pallas_windowed_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    rh: jnp.ndarray,
-    rw: jnp.ndarray,
-    grid_hw: Tuple[int, int],
-    scale: float,
-) -> jnp.ndarray:
-    """The same VMEM-resident kernel for WINDOWED attention
-    (TMR_WIN_ATTN=pallas): q/k/v (B*num_windows, H, S, D) with S = the
-    window token count (196 for SAM's 14x14), padded to the next multiple
-    of 128 and masked in-kernel (pad key columns get -inf scores; pad query
-    rows are sliced off here). One (s_pad, s_pad) tile per (window, head)
-    program — no online-softmax chaining needed, the whole window fits.
-    Differentiable via the same recompute-through-blockwise backward as
-    the global kernel."""
-    return _pallas_win_vjp(q, k, v, rh, rw, grid_hw, scale)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _pallas_win_vjp(q, k, v, rh, rw, grid_hw, scale):
-    return _pallas_win_fwd_impl(q, k, v, rh, rw, grid_hw, scale)
-
-
-def _win_kernel(
-    q_ref, k_ref, v_ref, rhq_ref, rwq_ref, out_ref,
-    *, scale: float, gw: int, valid_len: int,
-):
-    """Whole-window attention, one (s_pad, s_pad) score tile per window —
-    nk == 1, so plain in-register softmax (no online rescaling, no
-    scratch). The leading block dim groups G windows per program
-    (TMR_PALLAS_WIN_GROUP) to amortize program dispatch; the loop is a
-    static unroll."""
-    G, s_pad, _ = q_ref.shape
-    gh = rhq_ref.shape[-1]
-    # selector one-hots depend only on the token layout — identical for
-    # every window, built once per program
-    k_tok = jax.lax.broadcasted_iota(jnp.int32, (1, s_pad), 1)
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (gh, 1), 0)
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, (gw, 1), 0)
-    sel_h = (row_ids == k_tok // gw).astype(jnp.float32)  # (gh, s_pad)
-    sel_w = (col_ids == k_tok % gw).astype(jnp.float32)  # (gw, s_pad)
-    pad_mask = k_tok < valid_len  # (1, s_pad)
-    for g in range(G):
-        s = jax.lax.dot_general(
-            q_ref[g], k_ref[g], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        s += jax.lax.dot_general(
-            rhq_ref[g].astype(jnp.float32), sel_h, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # pad KEY columns still receive a partial bias (kx = k_tok % gw
-        # wraps back into the grid, so sel_w matches even past valid_len);
-        # the -inf mask below is what keeps them out of the softmax — do
-        # not treat it as redundant
-        s += jax.lax.dot_general(
-            rwq_ref[g].astype(jnp.float32), sel_w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s = jnp.where(pad_mask, s, _NEG_INF)
-        m = jnp.max(s, axis=1, keepdims=True)
-        p = jnp.exp(s - m)
-        p = p / jnp.sum(p, axis=1, keepdims=True)
-        out_ref[g] = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[g], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(out_ref.dtype)
-
-
-def _win_group(bh: int) -> int:
-    """Windows per program: the largest divisor of ``bh`` at or below the
-    TMR_PALLAS_WIN_GROUP preference (default 1 — grouping is a measured
-    knob, not an assumed win)."""
-    import os
-
-    raw = os.environ.get("TMR_PALLAS_WIN_GROUP", "1")
-    try:
-        pref = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"TMR_PALLAS_WIN_GROUP={raw!r}: expected a positive integer"
-        )
-    if pref < 1:
-        raise ValueError(
-            f"TMR_PALLAS_WIN_GROUP={pref}: expected a positive integer"
-        )
-    g = min(pref, bh)
-    while bh % g:
-        g -= 1
-    return g
-
-
-def _pallas_win_fwd_impl(q, k, v, rh, rw, grid_hw, scale):
-    B, H, S, D = q.shape
-    gh, gw = grid_hw
-    s_pad = max(128, -(-S // 128) * 128)
-    pad = s_pad - S
-    qp, kp, vp = (
-        jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) for t in (q, k, v)
-    )
-    rel_h_q, rel_w_q = _bias_projections(q, rh, rw, grid_hw)
-    rel_h_q = jnp.pad(rel_h_q, ((0, 0), (0, pad), (0, 0)))
-    rel_w_q = jnp.pad(rel_w_q, ((0, 0), (0, pad), (0, 0)))
-
-    bh = B * H
-    g = _win_group(bh)
-    kernel = functools.partial(
-        _win_kernel, scale=scale, gw=gw, valid_len=S
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(bh // g,),
-        in_specs=[
-            pl.BlockSpec((g, s_pad, D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((g, s_pad, D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((g, s_pad, D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((g, s_pad, gh), lambda b: (b, 0, 0)),
-            pl.BlockSpec((g, s_pad, gw), lambda b: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((g, s_pad, D), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s_pad, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)
-        ),
-        interpret=jax.default_backend() != "tpu",
-    )(
-        qp.reshape(bh, s_pad, D), kp.reshape(bh, s_pad, D),
-        vp.reshape(bh, s_pad, D), rel_h_q, rel_w_q,
-    )
-    return out[:, :S].reshape(B, H, S, D)
-
-
-def _win_vjp_fwd(q, k, v, rh, rw, grid_hw, scale):
-    return _pallas_win_fwd_impl(q, k, v, rh, rw, grid_hw, scale), (
-        q, k, v, rh, rw,
-    )
-
-
-def _win_vjp_bwd(grid_hw, scale, res, g):
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-
-    q, k, v, rh, rw = res
-    _, pull = jax.vjp(
-        lambda a, b, c, d, e: blockwise_decomposed_attention(
-            a, b, c, d, e, grid_hw, scale),
-        q, k, v, rh, rw,
-    )
-    return pull(g)
-
-
-_pallas_win_vjp.defvjp(_win_vjp_fwd, _win_vjp_bwd)
-
-
 # --------------------------------------------------------------------------
-# Packed windowed attention (TMR_WIN_ATTN=packed, the TPU bf16 default).
+# Packed windowed attention (``window_formulation``: a TPU's bf16 path).
 #
 # The kernels above take q/k/v head-major, (B', H, S, D): between the
 # ``qkv`` product, which writes (B', S, 3*dim) token-major, and ``proj``,
@@ -829,24 +674,25 @@ def packed_window_ok(
     )
 
 
-@mosaic_gate
-def pallas_window_ok(
-    gh: int, gw: int, head_dim: int, group: int = 1
-) -> bool:
-    """Per-geometry compiled self-check of the windowed kernel against the
-    exact blockwise oracle at the window grid (14x14 in production).
-
-    ``group`` must be the PRODUCTION effective window group (the caller
-    computes ``_win_group(b*H)``): the check builds B=group, H=1 inputs so
-    its bh == group and ``_win_group`` resolves to exactly that G — a
-    group-specific Mosaic failure or VMEM overflow trips here, inside the
-    gate, not in the model trace. The lru_cache keys on it."""
-    from tmr_tpu.ops.flash_attn import _self_check
-
-    return _self_check(
-        pallas_windowed_attention, group, 1, gh, gw, head_dim,
-        gate="pallas_window_ok", config={"group": group},
-    )
+def window_formulation(
+    grid_hw: Tuple[int, int], num_heads: int, head_dim: int, dtype,
+    use_rel_pos: bool = True,
+) -> str:
+    """What a block under 1024 tokens traces with, by what can be observed:
+    on a TPU in bfloat16, with rel-pos tables, at heads that have a packed
+    layout and where the kernel's self-check says yes (it says no inside a
+    trace XLA partitions and under ``diagnostics.mosaic_kernels_off``), the
+    kernel above (``packed``); else ``dense``, the einsums of
+    models/vit.py:Attention. On the v5e ``packed`` read 9.47 / 55.48 ms an
+    image of ``backbone_rest.ms`` on ViT-B / ViT-H where ``dense`` read
+    22.96 / 116.99 (PERF.md section 6, PR 28); nothing else selects it."""
+    gh, gw = grid_hw
+    if (use_rel_pos and dtype == jnp.bfloat16
+            and jax.default_backend() == "tpu"
+            and packed_supported(grid_hw, num_heads, head_dim)
+            and packed_window_ok(gh, gw, head_dim, num_heads)):
+        return "packed"
+    return "dense"
 
 
 @mosaic_gate
@@ -865,7 +711,7 @@ def pallas_global_ok(
     exactly those tiles; the lru_cache keys on them so a verdict reached
     under one tile config is never reused for another (a tile-specific
     Mosaic lowering failure or VMEM overflow must trip here, inside the
-    gate — mirroring pallas_window_ok's ``group`` parameter)."""
+    gate)."""
     from tmr_tpu.ops.flash_attn import _self_check
 
     # (bq, bk) are cache key only — the env the caller resolved them from
@@ -930,7 +776,7 @@ _pallas_attn_vjp.defvjp(_vjp_fwd, _vjp_bwd)
 #: to the respective dimensions of the overall array": rk is 8 of gh 64)
 #: nor, with the strip laid out to satisfy that, the kernel's one idea —
 #: the (bq, bk) -> (bq, rk, gw) view that splits the lane axis below 128.
-#: The kernel stays for the interpreter tests; ROADMAP Design item 2 decides
+#: The kernel stays for the interpreter tests; ROADMAP Design item 3b decides
 #: whether it is rewritten or deleted.
 _FUSED_MOSAIC_REFUSAL = (
     "Mosaic (jaxlib 0.9.0, v5e): infer-vector-layout: unsupported shape "

@@ -2,7 +2,9 @@
 style): some ops have several semantically identical lowerings whose relative
 speed depends on the hardware/compiler pair — the matcher correlation
 (ops/xcorr.py: grouped conv / vmap'd depthwise conv / FFT) and the ViT
-windowed attention (models/vit.py: dense / folded-QK / Pallas flash).
+global attention (models/vit.py: blockwise / folded-QK / Pallas flash).
+(Windowed attention is not swept: ops/pallas_attn.window_formulation
+decides it from what it observes.)
 Rather than hardcoding a winner, ``autotune(cfg, ...)`` microbenchmarks each
 variant ON DEVICE at the production shapes derived from the config and
 exports the winners via the env knobs the modules read at trace time:
@@ -10,7 +12,7 @@ exports the winners via the env knobs the modules read at trace time:
 - ``TMR_XCORR_IMPL_SMALL`` — the small-bucket correlation winner. Scoped:
   ops/xcorr.py consults it only below FFT_CAPACITY_THRESHOLD, so the
   capacity-17 winner can never drag the 127/191 buckets off the FFT path.
-- ``TMR_WIN_ATTN`` — the windowed-attention formulation.
+- ``TMR_GLOBAL_ATTN`` — the global-attention formulation.
 
 The microbenchmarks are small isolated programs (one correlation, one
 transformer block) timed with the bench.py methodology via the shared
@@ -29,7 +31,6 @@ from tmr_tpu.utils.cache import REPO_ROOT, STATE_DIR
 from tmr_tpu.utils.profiling import chained_seconds_per_iter, measure_rtt_floor
 
 XCORR_VARIANTS = ("conv", "convnhwc", "vmap", "fft", "pallas")
-WIN_ATTN_VARIANTS = ("dense", "folded", "flash", "pallas")
 GLOBAL_ATTN_VARIANTS = (
     "blockwise", "flash", "blockfolded", "densefolded", "pallas",
     "fused", "xlaflash",
@@ -278,17 +279,18 @@ def pick_xcorr_precision(
 
 
 def _sweep_block_env(
-    env_var: str, variants, window_size: int,
+    env_var: str, variants,
     batch: int, grid: int, embed_dim: int, num_heads: int,
     rtt: Optional[float], log: Callable[[str], None],
     train: bool = False,
     also_fallback_envs: tuple = (),
 ) -> Dict[str, float]:
     """Shared microbenchmark harness for the trace-time transformer-block
-    knobs: pin ``env_var`` to each variant, jit one Block at the production
-    grid (bf16, the deployment dtype), time it chained. One harness for the
-    windowed and global sweeps so staging / step / failure handling can
-    never diverge between them (the _sweep_xcorr_env principle).
+    knobs: pin ``env_var`` to each variant, jit one global Block (window 0,
+    the full grid as keys) at the production grid (bf16, the deployment
+    dtype), time it chained. One harness for the formulation and the
+    score-dtype sweeps so staging / step / failure handling can never
+    diverge between them (the _sweep_xcorr_env principle).
 
     ``train=True`` times forward + backward (value_and_grad through the
     block): the Pallas kernels' backward RECOMPUTES through the blockwise
@@ -320,7 +322,7 @@ def _sweep_block_env(
         for impl in variants:
             os.environ[env_var] = impl
             drain_gate_refusals()  # discard causes from earlier traces
-            blk = Block(num_heads=num_heads, window_size=window_size,
+            blk = Block(num_heads=num_heads, window_size=0,
                         rel_pos_size=(grid, grid), dtype=jnp.bfloat16)
 
             # a gate-refused request silently traces the fallback
@@ -558,20 +560,6 @@ def pick_quant(
     return combined
 
 
-def pick_win_attn_impl(
-    batch: int, grid: int, embed_dim: int, num_heads: int,
-    rtt: Optional[float] = None,
-    log: Callable[[str], None] = lambda s: None,
-    train: bool = False,
-) -> Dict[str, float]:
-    """Time one windowed transformer block (window 14, bf16 — the deployment
-    dtype) per attention formulation. Returns {variant: sec/iter}."""
-    return _sweep_block_env(
-        "TMR_WIN_ATTN", WIN_ATTN_VARIANTS, 14,
-        batch, grid, embed_dim, num_heads, rtt, log, train=train,
-    )
-
-
 def pick_global_attn_impl(
     batch: int, grid: int, embed_dim: int, num_heads: int,
     rtt: Optional[float] = None,
@@ -585,7 +573,7 @@ def pick_global_attn_impl(
     program (harmless; selection only runs on TPU). Returns
     {variant: sec/iter}."""
     return _sweep_block_env(
-        "TMR_GLOBAL_ATTN", GLOBAL_ATTN_VARIANTS, 0,
+        "TMR_GLOBAL_ATTN", GLOBAL_ATTN_VARIANTS,
         batch, grid, embed_dim, num_heads, rtt, log, train=train,
     )
 
@@ -603,7 +591,7 @@ def pick_global_scores_dtype(
     annotated as a fallback row so a blockwise timing can never masquerade
     as bf16-scores evidence. Returns {dtype: sec/iter}."""
     return _sweep_block_env(
-        "TMR_GLOBAL_SCORES_DTYPE", GLOBAL_SCORES_DTYPES, 0,
+        "TMR_GLOBAL_SCORES_DTYPE", GLOBAL_SCORES_DTYPES,
         batch, grid, embed_dim, num_heads, rtt, log, train=train,
         also_fallback_envs=("TMR_GLOBAL_ATTN",),
     )
@@ -836,7 +824,7 @@ def _cache_load() -> Dict[str, dict]:
 #: new kernel) must get its chance at the next hardware sweep instead of
 #: being silently locked out by an older pick.
 _VERSIONED_KNOBS = (
-    "TMR_XCORR_IMPL_SMALL", "TMR_WIN_ATTN", "TMR_GLOBAL_ATTN",
+    "TMR_XCORR_IMPL_SMALL", "TMR_GLOBAL_ATTN",
     "TMR_XCORR_PRECISION", "TMR_GLOBAL_SCORES_DTYPE",
     "TMR_DECODER_IMPL", "TMR_QUANT", "TMR_QUANT_STORAGE",
 )
@@ -845,7 +833,6 @@ _VERSIONED_KNOBS = (
 def _variants_sig(knob: str) -> str:
     sets = {
         "TMR_XCORR_IMPL_SMALL": XCORR_VARIANTS,
-        "TMR_WIN_ATTN": WIN_ATTN_VARIANTS,
         "TMR_GLOBAL_ATTN": GLOBAL_ATTN_VARIANTS,
         "TMR_XCORR_PRECISION": XCORR_PRECISIONS,
         "TMR_GLOBAL_SCORES_DTYPE": GLOBAL_SCORES_DTYPES,
@@ -854,7 +841,7 @@ def _variants_sig(knob: str) -> str:
         "TMR_QUANT_STORAGE": STORAGE_VARIANTS,
     }
     sig = ",".join(sets[knob])
-    if knob in ("TMR_WIN_ATTN", "TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL_SMALL",
+    if knob in ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL_SMALL",
                 "TMR_DECODER_IMPL", "TMR_QUANT", "TMR_QUANT_STORAGE"):
         # formulation-sweep winners are additionally versioned by the
         # harness revision: a winner picked by a pre-revision sweep may be
@@ -868,11 +855,9 @@ def _variants_sig(knob: str) -> str:
 def _validate_cache_obj(obj: dict) -> Dict[str, dict]:
     valid = {
         "TMR_XCORR_IMPL_SMALL": set(XCORR_VARIANTS) | {"auto"},
-        "TMR_WIN_ATTN": set(WIN_ATTN_VARIANTS),
         "TMR_GLOBAL_ATTN": set(GLOBAL_ATTN_VARIANTS) | {"auto"},
         "TMR_XCORR_PRECISION": set(XCORR_PRECISIONS),
         "TMR_GLOBAL_SCORES_DTYPE": set(GLOBAL_SCORES_DTYPES),
-        "TMR_WIN_SCORES_DTYPE": set(GLOBAL_SCORES_DTYPES),
         # metadata, not an env knob: which global formulation the scores-
         # dtype winner was measured under (evidence is impl-specific).
         # "auto" is a legal pairing — a TMR_GLOBAL_ATTN=auto run records
@@ -891,10 +876,10 @@ def _validate_cache_obj(obj: dict) -> Dict[str, dict]:
         "_quant_decoder_impl": set(DECODER_IMPL_VARIANTS) | {"auto"},
     }
     # measured throughput-optimal eval batch (bench_extra's batch sweep),
-    # the Pallas windowed-kernel group, the band-scan unroll, and the XLA
-    # flash block targets — positive ints as strings
+    # the band-scan unroll, and the XLA flash block targets — positive
+    # ints as strings
     digit_keys = {
-        "TMR_BENCH_BATCH", "TMR_PALLAS_WIN_GROUP",
+        "TMR_BENCH_BATCH",
         "TMR_GLOBAL_BANDS_UNROLL", "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK",
         # gallery sweep winners (scripts/gallery_bench.py writes them,
         # serve/gallery.py reads): the N-bucket ladder cap + the
@@ -1063,8 +1048,7 @@ def autotune(
     # (pallas kernels / the blockwise-family band scan), so exporting
     # alongside a different winner is inert.
     for knob in ("TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-                 "TMR_PALLAS_WIN_GROUP", "TMR_GLOBAL_BANDS_UNROLL",
-                 "TMR_WIN_SCORES_DTYPE", "TMR_XLA_FLASH_BQ",
+                 "TMR_GLOBAL_BANDS_UNROLL", "TMR_XLA_FLASH_BQ",
                  "TMR_XLA_FLASH_BK", "TMR_QUANT_STORAGE"):
         if knob in cached and knob not in os.environ:
             os.environ[knob] = cached[knob]
@@ -1077,8 +1061,6 @@ def autotune(
         and "TMR_XCORR_IMPL_SMALL" not in os.environ
     ):
         wanted.add("TMR_XCORR_IMPL_SMALL")
-    if "TMR_WIN_ATTN" not in os.environ and vit_kind is not None:
-        wanted.add("TMR_WIN_ATTN")
     if "TMR_GLOBAL_ATTN" not in os.environ and vit_kind is not None:
         wanted.add("TMR_GLOBAL_ATTN")
     if tune_precision and "TMR_XCORR_PRECISION" not in os.environ:
@@ -1237,24 +1219,19 @@ def autotune(
                                                  "times": times}
                 _attach_refusals(report, "TMR_XCORR_PRECISION")
 
-    for knob, picker in (
-        ("TMR_WIN_ATTN", pick_win_attn_impl),
-        ("TMR_GLOBAL_ATTN", pick_global_attn_impl),
-    ):
-        if knob not in wanted:
-            continue
+    if "TMR_GLOBAL_ATTN" in wanted:
         vc = VIT_CONFIGS[vit_kind]
-        times = picker(
+        times = pick_global_attn_impl(
             batch, grid, vc["embed_dim"], vc["num_heads"], rtt=rtt, log=log,
             train=train,
         )
         pickable = _electable(times)
         if pickable:
             best = min(pickable, key=pickable.get)
-            os.environ[knob] = best
-            report[knob] = {"picked": best, "times": times}
-            _attach_refusals(report, knob)
-            log(f"autotune: {knob}={best} {times}")
+            os.environ["TMR_GLOBAL_ATTN"] = best
+            report["TMR_GLOBAL_ATTN"] = {"picked": best, "times": times}
+            _attach_refusals(report, "TMR_GLOBAL_ATTN")
+            log(f"autotune: TMR_GLOBAL_ATTN={best} {times}")
 
     if "TMR_GLOBAL_SCORES_DTYPE" in wanted:
         # sweep AFTER the formulation pick (the knob only matters to the
