@@ -315,13 +315,14 @@ def decide_gates(cfg, size: Size) -> dict:
     """Ask, outside any trace, every gate the ``auto`` path of this
     configuration consults; the model's traces then hit their caches."""
     from tmr_tpu.ops.flash_attn import flash_attention_ok
-    from tmr_tpu.ops.pallas_attn import packed_window_ok
+    from tmr_tpu.ops.pallas_attn import packed_global_ok, packed_window_ok
     from tmr_tpu.ops.pallas_nms import pallas_nms_compiled_ok
 
     if _is_trunk(size):
         # a trunk of typed layers asks no attention gate of the ViT's: its
         # formulations are on its compile span (report_window_formulation)
-        verdicts = {"flash_attention_ok": False, "packed_window_ok": False,
+        verdicts = {"flash_attention_ok": False, "packed_global_ok": False,
+                    "packed_window_ok": False,
                     "pallas_nms_compiled_ok": pallas_nms_compiled_ok()}
         say(f"  gate pallas_nms_compiled_ok: "
             f"{'pass' if verdicts['pallas_nms_compiled_ok'] else 'refused'}")
@@ -333,6 +334,7 @@ def decide_gates(cfg, size: Size) -> dict:
     grid = size.image_size // 16
     verdicts = {
         "flash_attention_ok": flash_attention_ok(grid, grid, head_dim),
+        "packed_global_ok": packed_global_ok(grid, grid, head_dim, num_heads),
         "packed_window_ok": packed_window_ok(14, 14, head_dim, num_heads),
         "pallas_nms_compiled_ok": pallas_nms_compiled_ok(),
     }
@@ -345,21 +347,27 @@ def decide_gates(cfg, size: Size) -> dict:
 def report_formulations(cfg, size: Size, verdicts: dict) -> None:
     """The formulation each layer runs under the current environment: the
     knob when set, else what ``auto`` resolves to given the gates; for the
-    windowed blocks, which have no knob, ``window_formulation``'s answer."""
+    ViT's blocks, ``global_formulation``'s answer (with ``TMR_GLOBAL_ATTN``
+    unset or ``auto``) and ``window_formulation``'s (no knob)."""
     import jax.numpy as jnp
 
     from tmr_tpu.inference import decode_tail_mode
-    from tmr_tpu.ops.pallas_attn import window_formulation
+    from tmr_tpu.ops.pallas_attn import global_formulation, window_formulation
     from tmr_tpu.ops.xcorr import small_impl_default
 
     env = os.environ.get
-    win = "none" if _is_trunk(size) else window_formulation(
-        (14, 14), *_vit_heads(size), jnp.dtype(cfg.compute_dtype))
-    glob = env("TMR_GLOBAL_ATTN", "auto")
-    if glob == "auto":
-        glob = "flash" if verdicts["flash_attention_ok"] else "blockwise"
+    win = glob = "none"
+    glob_by = "global_formulation"
+    if not _is_trunk(size):
+        dtype = jnp.dtype(cfg.compute_dtype)
+        win = window_formulation((14, 14), *_vit_heads(size), dtype)
+        if env("TMR_GLOBAL_ATTN", "auto") != "auto":
+            glob, glob_by = env("TMR_GLOBAL_ATTN"), "TMR_GLOBAL_ATTN"
+        else:
+            grid = size.image_size // 16
+            glob = global_formulation((grid, grid), *_vit_heads(size), dtype)
     say("  formulations: " + json.dumps({
-        "global_attention(TMR_GLOBAL_ATTN)": glob,
+        f"global_attention({glob_by})": glob,
         "windowed_attention(window_formulation)": win,
         "xcorr_small(TMR_XCORR_IMPL[_SMALL])": env(
             "TMR_XCORR_IMPL", env("TMR_XCORR_IMPL_SMALL",
@@ -374,20 +382,24 @@ def report_formulations(cfg, size: Size, verdicts: dict) -> None:
 
 
 def report_window_formulation() -> None:
-    """What the programs compiled so far traced their windowed blocks
-    with: the ``vit.win_attn.<formulation>`` counters (one count a block a
-    trace) and each ``compile`` span's own share of them."""
+    """What the programs compiled so far traced their windowed and their
+    global blocks with: the ``vit.win_attn.<formulation>`` and
+    ``vit.global_attn.<formulation>`` counters (one count a block a trace)
+    and each ``compile`` span's own share of them."""
     from tmr_tpu import obs
 
     say("  windowed blocks traced, by formulation: " + json.dumps(
         obs.get_registry().counters("vit.win_attn.")))
+    say("  global blocks traced, by formulation: " + json.dumps(
+        obs.get_registry().counters("vit.global_attn.")))
     for rec in obs.spans():
         if rec["name"] == "compile":
             a = rec["attrs"]
             trunk = {k: v for k, v in a.items()
                      if k.startswith("trunk_") or k == "experts_held"}
             say(f"  compile {a.get('kind')} {a.get('key')}: win_attn="
-                f"{a.get('win_attn')} x{a.get('win_attn_blocks')}"
+                f"{a.get('win_attn')} x{a.get('win_attn_blocks')} global_attn="
+                f"{a.get('global_attn')} x{a.get('global_attn_blocks')}"
                 + (f" {json.dumps(trunk)}" if trunk else ""))
 
 

@@ -170,6 +170,9 @@ def main():
         ("one_global_block_xlaflash", 0, {"TMR_GLOBAL_ATTN": "xlaflash"}),
         ("one_global_block_xlaflash_bk1024", 0,
          {"TMR_GLOBAL_ATTN": "xlaflash", "TMR_XLA_FLASH_BK": "1024"}),
+        # the kernel on qkv where the product wrote it (what ``auto``
+        # answers on a TPU in bfloat16 since PR 32)
+        ("one_global_block_packed", 0, {"TMR_GLOBAL_ATTN": "packed"}),
         ("one_windowed_block", 14, {}),
     )
     # restore the user's knobs afterwards (autotune's _restore): the

@@ -495,6 +495,24 @@ def test_block_sweep_train_mode_times_grad(clean_knobs, monkeypatch):
     assert all(t > 0 for t in times.values())
 
 
+def test_the_sweep_holds_every_formulation_auto_can_answer():
+    """``pick_global_attn_impl`` exports the fastest of
+    ``GLOBAL_ATTN_VARIANTS`` whenever ``TMR_GLOBAL_ATTN`` is unset, which
+    is when ``ops/pallas_attn.global_formulation`` decides: a formulation
+    it can answer and the sweep lacks would be displaced by a slower one
+    the first time ``--autotune`` runs (PERF.md section 6, PR 31, finding
+    2). Each is also a legal explicit value of the knob."""
+    from tmr_tpu.ops.pallas_attn import GLOBAL_FORMULATIONS
+
+    assert "packed" in GLOBAL_FORMULATIONS
+    assert set(GLOBAL_FORMULATIONS) <= set(at.GLOBAL_ATTN_VARIANTS)
+    legal = at._validate_cache_obj({"k": {
+        "TMR_GLOBAL_ATTN": "packed",
+        "_variants_TMR_GLOBAL_ATTN": at._variants_sig("TMR_GLOBAL_ATTN"),
+    }})
+    assert legal["k"]["TMR_GLOBAL_ATTN"] == "packed"
+
+
 def test_cached_winner_stale_when_variant_set_grows(clean_knobs, monkeypatch):
     """A cached winner is versioned by the variant set it beat
     (_variants_<knob>): growing the set (a new kernel) or a stamp-less

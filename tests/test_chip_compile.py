@@ -28,6 +28,7 @@ from tmr_tpu.ops.flash_attn import (
     flash_windowed_attention,
 )
 from tmr_tpu.ops.pallas_attn import (
+    packed_global_attention,
     packed_windowed_attention,
     pallas_decomposed_attention,
 )
@@ -83,6 +84,7 @@ def as_tpu(monkeypatch):
     for mod, name in ((flash_attn, "flash_attention_ok"),
                       (flash_attn, "flash_window_ok"),
                       (pallas_attn, "packed_window_ok"),
+                      (pallas_attn, "packed_global_ok"),
                       (kda, "kda_chunk_ok"),
                       (pallas_nms, "pallas_nms_compiled_ok")):
         monkeypatch.setattr(mod, name, admits(name))
@@ -126,6 +128,26 @@ def _packed_case(windows, heads, head_dim, grad=False):
     def case(sds):
         qkv = sds((windows * _WIN * 16, 3 * heads * head_dim), jnp.bfloat16)
         rel = sds((_WIN, _WIN, head_dim), jnp.float32)
+        return (jax.grad(loss, argnums=(0, 1, 2)) if grad else forward), (
+            qkv, rel, rel)
+
+    return case
+
+
+def _packed_global_case(images, heads, head_dim, grad=False):
+    """The global blocks' packed path on the ``qkv`` product's own output,
+    4,096 rows an image, at a cell's production shape; ``grad`` compiles
+    what the train step differentiates."""
+    def forward(qkv, rh, rw):
+        return packed_global_attention(qkv, rh, rw, (_G, _G), heads,
+                                       head_dim**-0.5)
+
+    def loss(*args):
+        return jnp.sum(forward(*args).astype(jnp.float32) ** 2)
+
+    def case(sds):
+        qkv = sds((images * _G * _G, 3 * heads * head_dim), jnp.bfloat16)
+        rel = sds((_G, _G, head_dim), jnp.float32)
         return (jax.grad(loss, argnums=(0, 1, 2)) if grad else forward), (
             qkv, rel, rel)
 
@@ -203,6 +225,9 @@ CASES = {
     "packed_window_vitb": _packed_case(16 * _NWIN, 12, 64),
     "packed_window_vith": _packed_case(8 * _NWIN, 16, 80),
     "packed_window_grad": _packed_case(2 * _NWIN, 12, 64, grad=True),
+    "packed_global_vitb": _packed_global_case(16, 12, 64),
+    "packed_global_vith": _packed_global_case(8, 16, 80),
+    "packed_global_grad": _packed_global_case(1, 12, 64, grad=True),
     "kda_chunk_kimi": _case_kda_chunk,
     "nms": _case_nms,
     "int8_matmul": _case_int8_matmul,
@@ -262,6 +287,42 @@ def test_windowed_block_moves_its_operands_once(one_chip, as_tpu):
         assert m.group(2) != "pad" or elems <= q_elems, line
         assert m.group(2) not in ("copy", "transpose") or elems < q_elems, \
             line
+    assert "custom-call" in seen and "fusion" in seen, seen
+
+
+def test_global_block_moves_its_operands_once(one_chip, as_tpu):
+    """One global ViT-B block at the cell's batch, compiled for the v5e:
+    between the ``qkv`` product and ``proj`` the program holds the kernel
+    and nothing that moves an operand: no ``concatenate``, ``pad``,
+    ``transpose`` or ``copy`` under the scope has a result as large as q,
+    and no stock ``flash_attention`` kernel is left."""
+    import re
+
+    from tmr_tpu.models.vit import Block
+
+    blk = Block(num_heads=_H, window_size=0, rel_pos_size=(_G, _G),
+                dtype=jnp.bfloat16, name="blocks_2")
+    x = jax.ShapeDtypeStruct((16, _G, _G, _H * _D), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(blk.init, jax.random.key(0), x))
+    lowered = jax.jit(blk.apply).lower(params, x)
+    assert "flash_attention" not in lowered.as_text()
+    text = lowered.compile().as_text()
+    q_elems = 16 * _G * _G * _H * _D
+    seen = set()
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(.*op_name=\"[^\"]*blocks_2/attn/", line)
+        if not m:
+            continue
+        elems = 1
+        for d in filter(None, m.group(1).split(",")):
+            elems *= int(d)
+        seen.add(m.group(2))
+        assert m.group(2) not in (
+            "concatenate", "pad", "copy", "transpose") or elems < q_elems, line
     assert "custom-call" in seen and "fusion" in seen, seen
 
 
@@ -400,7 +461,7 @@ def _mesh_case_kda_layer(devices):
     return mesh, jax.jit(partitioned(mixer.apply, mesh)), (params, x)
 
 
-_VIT_GATES = ("flash_attention_ok", "packed_window_ok")
+_VIT_GATES = ("flash_attention_ok", "packed_window_ok", "packed_global_ok")
 MESH_CASES = {"train_step_dp2": (_mesh_case_train_step, _VIT_GATES),
               "serve_tp2": (_mesh_case_serve_tp2, _VIT_GATES),
               "kda_layer_tp2": (_mesh_case_kda_layer, ("kda_chunk_ok",))}

@@ -145,21 +145,28 @@ def test_a_self_check_asked_in_a_first_call_is_the_compile_spans_child():
     assert "rows" not in got["predict.dispatch"][0]["attrs"]
 
 
-def test_the_compile_span_names_the_windowed_formulation_it_traced():
-    """What ``vit.win_attn.*`` counted during a program's first call is on
-    its ``compile`` span, and a later call adds nothing to it."""
+@pytest.mark.parametrize("attr,name,blocks", [
+    ("win_attn", "packed", 8), ("global_attn", "packed", 4)])
+def test_the_compile_span_names_the_formulations_it_traced(
+    attr, name, blocks
+):
+    """What ``vit.win_attn.*`` and ``vit.global_attn.*`` counted during a
+    program's first call is on its ``compile`` span, and a later call adds
+    nothing to it."""
     def program(x):
-        for _ in range(8):
-            obs.counter("vit.win_attn.packed").inc()
+        for _ in range(blocks):
+            obs.counter(f"vit.{attr}.{name}").inc()
         return x
 
-    obs.counter("vit.win_attn.packed").inc()  # another program's block
-    fn = obs.track_compile(program, "test_kind_win_attn", ("k", 2))
+    obs.counter(f"vit.{attr}.{name}").inc()  # another program's block
+    fn = obs.track_compile(program, f"test_kind_{attr}", ("k", 2))
     assert fn(1) == 1 and fn(2) == 2
     (span,) = [r for r in obs.spans() if r["name"] == "compile"
-               and r["attrs"]["kind"] == "test_kind_win_attn"]
-    assert span["attrs"]["win_attn"] == "packed"
-    assert span["attrs"]["win_attn_blocks"] == 8
+               and r["attrs"]["kind"] == f"test_kind_{attr}"]
+    assert span["attrs"][attr] == name
+    assert span["attrs"][f"{attr}_blocks"] == blocks
+    other = "global_attn" if attr == "win_attn" else "win_attn"
+    assert other not in span["attrs"]
 
 
 def _serve(pred, n: int, seed: int):

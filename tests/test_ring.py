@@ -869,6 +869,283 @@ def test_windowed_blocks_are_counted_by_formulation(
                       if gate == "partitioned" else set())
 
 
+def _packed_global_case(images, heads, head_dim, dtype, grid=32, seed=32):
+    """qkv as ``nn.Dense(3 * dim)`` writes it on an image's rows of tokens,
+    and rel-pos tables as ``get_rel_pos`` makes them (Toeplitz)."""
+    from tmr_tpu.models.vit import get_rel_pos
+
+    rng = np.random.default_rng(seed)
+    qkv = jnp.asarray(rng.standard_normal(
+        (images * grid * grid, 3 * heads * head_dim)), dtype)
+    rh, rw = (get_rel_pos(grid, grid, jnp.asarray(
+        rng.standard_normal((2 * grid - 1, head_dim)) * 0.2, jnp.float32))
+        for _ in range(2))
+    return qkv, rh, rw
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads,head_dim", [
+    (2, 64),    # two heads share a 128-lane slab, told apart by a mask
+    (8, 80),    # heads off the lane tile: all eight offsets of a group
+])
+def test_packed_global_attention_matches_blockwise(heads, head_dim, dtype):
+    """``packed`` for the global blocks
+    (ops/pallas_attn.packed_global_attention, the interpreter here) against
+    the exact blockwise oracle on the unpacked heads, forward and gradient,
+    on two images of a 32 x 32 grid (two query blocks an image; a smaller
+    key block, so that there are four of eight grid rows each, is the next
+    test's). float32 holds the oracle to its own rounding; bfloat16, where
+    q * scale and q.RH are rounded to the operand dtype, to
+    ``_self_check``'s tolerance. The gradient is the oracle's own
+    (``custom_vjp``)."""
+    from tmr_tpu.ops.pallas_attn import (
+        _packed_global_oracle,
+        packed_global_attention,
+    )
+
+    qkv, rh, rw = _packed_global_case(2, heads, head_dim, jnp.dtype(dtype))
+    scale = head_dim**-0.5
+
+    def loss(fn):
+        def run(*a):
+            out = fn(*a, (32, 32), heads, scale)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+        return jax.jit(jax.value_and_grad(run, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, got), g_got = loss(packed_global_attention)(qkv, rh, rw)
+    (_, want), g_want = loss(_packed_global_oracle)(qkv, rh, rw)
+    assert got.shape == (2 * 1024, heads * head_dim)
+    pairs = [(got, want)] + list(zip(g_got, g_want))
+    for a, b in pairs:
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+        else:
+            assert np.abs(a - b).max() / np.abs(b).max() < 0.05
+
+
+@pytest.mark.parametrize("heads,head_dim", [(2, 64), (8, 80)])
+def test_packed_global_attention_rides_q_rh_a_key_block_at_a_time(
+    heads, head_dim, monkeypatch
+):
+    """With four key blocks an image, each holds eight grid rows, and only
+    their eight entries of q.RH ride in a block's contraction, in the lanes
+    the head leaves free, against the one-hots ``_global_key_rows`` puts
+    beside k: the kernel still equals the oracle, and the one-hots sit
+    where the layout says."""
+    from tmr_tpu.ops import pallas_attn
+
+    monkeypatch.setattr(pallas_attn, "_GLOBAL_KEY_BLOCK", 256)
+    monkeypatch.setattr(pallas_attn, "_GLOBAL_QUERY_BLOCK", 256)
+    assert pallas_attn._global_blocks((32, 32)) == (256, 256)
+    rows = np.asarray(pallas_attn._global_key_rows(
+        (32, 32), head_dim, 256, jnp.float32))
+    assert rows.shape == (1024, 128)
+    u = np.arange(1024)
+    lane = u // 32 % 8  # a key's grid row within its block of eight
+    if head_dim == 64:  # in every head's lanes; q' is zero but in one
+        assert (rows.sum(1) == 2).all()
+        assert rows[u, lane].all() and rows[u, 64 + lane].all()
+    else:               # past the head aligned to lane 0
+        assert (rows.sum(1) == 1).all() and rows[u, 80 + lane].all()
+    qkv, rh, rw = _packed_global_case(1, heads, head_dim, jnp.float32)
+    scale = head_dim**-0.5
+    pallas_attn._packed_global_fwd_impl.clear_cache()
+    try:
+        got = pallas_attn.packed_global_attention(
+            qkv, rh, rw, (32, 32), heads, scale)
+    finally:
+        pallas_attn._packed_global_fwd_impl.clear_cache()
+    want = pallas_attn._packed_global_oracle(
+        qkv, rh, rw, (32, 32), heads, scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_packed_global_attention_reads_toeplitz_tables_only():
+    """The kernel takes the 2g - 1 distinct rows of a ``get_rel_pos``
+    table from its first row and first column, by the lane they are read
+    from: ``_toeplitz_lanes`` puts table[y, ky] in lane (ky - y) mod 128,
+    and ``_global_tables`` lays the two tables side by side, a copy for
+    every head of a slab."""
+    from tmr_tpu.models.vit import get_rel_pos
+    from tmr_tpu.ops.pallas_attn import _global_tables, _toeplitz_lanes
+
+    rng = np.random.default_rng(5)
+    param = jnp.asarray(rng.standard_normal((63, 64)), jnp.float32)
+    table = get_rel_pos(32, 32, param)
+    lanes = np.asarray(_toeplitz_lanes(table))
+    assert lanes.shape == (128, 64)
+    for y, ky in ((0, 0), (0, 31), (31, 0), (7, 19), (19, 7)):
+        np.testing.assert_array_equal(lanes[(ky - y) % 128], table[y, ky])
+    assert not lanes[32:97].any()
+    both = np.asarray(_global_tables(table, 2 * table, 64, jnp.float32))
+    assert both.shape == (128, 256)
+    np.testing.assert_array_equal(both[:64, :128], lanes.T)
+    np.testing.assert_array_equal(both[64:, 128:], 2 * lanes.T)
+    lone = _global_tables(get_rel_pos(32, 32, param[:, :48]), table[..., :48],
+                          48, jnp.float32)  # a head aligned to lane 0
+    assert not np.asarray(lone[48:]).any()
+
+
+@pytest.mark.parametrize("toeplitz,verdict,causes", [
+    (True, True, []), (False, False, ["forward-mismatch"])])
+def test_packed_global_gate_checks_one_program_a_side(
+    toeplitz, verdict, causes
+):
+    """``packed_global_ok``'s self-check as it runs on a chip, here with
+    the backend requirement lifted (the interpreter): output and gradients
+    of a side from one program, tables as ``get_rel_pos`` makes them. With
+    every table entry drawn on its own the kernel, which reads the tables
+    as Toeplitz, is refused on its forward: the check can tell."""
+    from tmr_tpu import diagnostics
+    from tmr_tpu.ops import flash_attn, pallas_attn
+
+    diagnostics.drain_gate_refusals()
+    assert flash_attn._self_check(
+        pallas_attn._packed_global_on_heads, 1, 2, 32, 32, 64,
+        require_tpu=False, gate="packed_global_ok", toeplitz=toeplitz,
+        one_program=True) is verdict
+    assert [r["cause"] for r in diagnostics.drain_gate_refusals()] == causes
+
+
+def test_global_block_with_packed_equals_blockwise_module():
+    """``Attention`` at 1024 tokens with ``global_formulation`` answering
+    ``packed`` (the interpreter here, float32) equals the module's
+    blockwise output on the same parameter tree: ``qkv`` and ``proj`` on
+    rows of tokens are the same leaves."""
+    from tmr_tpu.models.vit import Attention
+    from tmr_tpu.obs import metrics
+    from tmr_tpu.ops import pallas_attn
+
+    attn = Attention(num_heads=2, rel_pos_size=(32, 32), dtype=jnp.float32)
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((2, 32, 32, 128)), jnp.float32)
+    params = attn.init(jax.random.key(0), x)
+    params = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape) * 0.1, a.dtype),
+        params)
+    want = jax.jit(attn.apply)(params, x)
+    metrics.get_registry().reset("vit.global_attn.")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pallas_attn, "global_formulation", lambda *a: "packed")
+        got = jax.jit(attn.apply)(params, x)
+    assert metrics.get_registry().counters("vit.global_attn.") == {
+        "packed": 1}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("backend,dtype,gate,rel_pos,grid,taken", [
+    ("cpu", "bfloat16", "admits", True, 32, "blockwise"),
+    ("tpu", "bfloat16", "admits", True, 32, "packed"),
+    ("tpu", "float32", "admits", True, 32, "blockwise"),
+    ("tpu", "bfloat16", "partitioned", True, 32, "blockwise"),
+    ("tpu", "bfloat16", "refuses", True, 32, "flash"),
+    ("tpu", "bfloat16", "admits", False, 32, "flash"),
+    ("tpu", "bfloat16", "admits", True, 96, "flash"),
+], ids=["cpu-blockwise", "tpu-packed", "tpu-float32-blockwise",
+        "tpu-partitioned-blockwise", "tpu-gate-refuses-flash",
+        "tpu-no-rel-pos-flash", "tpu-192-projections-flash"])
+def test_global_blocks_are_counted_by_formulation(
+    backend, dtype, gate, rel_pos, grid, taken, monkeypatch
+):
+    """``ops/pallas_attn.global_formulation``'s truth table, and its
+    record: ``vit.global_attn.<formulation>`` counts a trace's global
+    blocks under what it answers and the program's ``compile`` span names
+    it. ``packed`` only where the backend reads ``tpu``, the trace is
+    bfloat16, the block has rel-pos tables, gh + gw fits the 128 lanes and
+    the gate admits the kernel; everything else keeps the path ``auto``
+    answered before: ``flash`` where that gate passes (a chip's answer,
+    given here; off a TPU it refuses for itself), else ``blockwise``. Both
+    gates refuse with cause ``partitioned`` inside a program XLA
+    partitions. No environment variable is read."""
+    from tmr_tpu import diagnostics, obs
+    from tmr_tpu.models.vit import Attention, SamViT
+    from tmr_tpu.obs import metrics
+    from tmr_tpu.ops import flash_attn, pallas_attn
+    from tmr_tpu.parallel.compat import partitioned
+
+    monkeypatch.delenv("TMR_GLOBAL_ATTN", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if gate != "partitioned":  # there the real gates answer, unasked
+        monkeypatch.setattr(pallas_attn, "packed_global_ok",
+                            lambda *a: gate == "admits")
+        monkeypatch.setattr(pallas_attn, "packed_window_ok",
+                            lambda *a: False)
+        if backend == "tpu":
+            monkeypatch.setattr(flash_attn, "flash_attention_ok",
+                                lambda *a: True)
+    dtype = jnp.dtype(dtype)
+    px = grid * 16
+    if rel_pos:
+        blocks = 4
+        model = SamViT(embed_dim=128, depth=12, num_heads=2,
+                       global_attn_indexes=(2, 5, 8, 11), window_size=14,
+                       out_chans=8, pretrain_img_size=px, dtype=dtype)
+        x = jax.ShapeDtypeStruct((1, px, px, 3), jnp.float32)
+    else:
+        blocks = 1
+        model = Attention(num_heads=2, use_rel_pos=False, dtype=dtype)
+        x = jax.ShapeDtypeStruct((1, grid, grid, 128), dtype)
+    with diagnostics.mosaic_kernels_off("shapes only"):  # asks no gate
+        params = jax.eval_shape(model.init, jax.random.key(0), x)
+
+    def trace():
+        jax.eval_shape(model.apply, params, x)
+        return pallas_attn.global_formulation(
+            (grid, grid), 2, 64, dtype, rel_pos)
+
+    if gate == "partitioned":
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("model",))
+        trace = partitioned(trace, mesh)
+    diagnostics.drain_gate_refusals()
+    metrics.get_registry().reset("vit.global_attn.")
+    program = obs.track_compile(trace, "test_kind_global_attn",
+                                (backend, str(dtype), gate, rel_pos, grid))
+    assert program() == taken
+    assert metrics.get_registry().counters("vit.global_attn.") == {
+        taken: blocks}
+    span = [r for r in obs.spans() if r["name"] == "compile"
+            and r["attrs"]["kind"] == "test_kind_global_attn"][-1]
+    assert span["attrs"]["global_attn"] == taken
+    assert span["attrs"]["global_attn_blocks"] == blocks
+    causes = {(r["gate"], r["cause"])
+              for r in diagnostics.drain_gate_refusals()}
+    if gate == "partitioned":
+        assert causes == {("packed_global_ok", "partitioned"),
+                          ("flash_attention_ok", "partitioned"),
+                          ("packed_window_ok", "partitioned")}
+    elif backend == "cpu":  # the flash gate's own no, unless cached
+        assert causes <= {("flash_attention_ok", "backend")}
+    else:
+        assert causes == set()
+
+
+def test_explicit_packed_refused_warns_and_runs_blockwise(monkeypatch):
+    """``TMR_GLOBAL_ATTN=packed`` is a legal value of the knob; where the
+    gate refuses (any backend but a TPU) the block says so once, at trace
+    time, runs blockwise and is counted as that. (The gate's no is given
+    here: its own depends on nothing but the backend.)"""
+    from tmr_tpu.diagnostics import FormulationFallbackWarning
+    from tmr_tpu.models.vit import Attention
+    from tmr_tpu.obs import metrics
+
+    from tmr_tpu.ops import pallas_attn
+
+    monkeypatch.setenv("TMR_GLOBAL_ATTN", "packed")
+    monkeypatch.setattr(pallas_attn, "packed_global_ok", lambda *a: False)
+    attn = Attention(num_heads=2, rel_pos_size=(32, 32), dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, 32, 32, 128), jnp.bfloat16)
+    params = jax.eval_shape(attn.init, jax.random.key(0), x)
+    metrics.get_registry().reset("vit.global_attn.")
+    with pytest.warns(FormulationFallbackWarning, match="packed"):
+        jax.eval_shape(attn.apply, params, x)
+    assert metrics.get_registry().counters("vit.global_attn.") == {
+        "blockwise": 1}
+
+
 @pytest.mark.slow
 def test_fold_rel_pos_into_qk_exact():
     """The augmented-QK trick (ops/flash_attn.py) must reproduce the biased

@@ -45,7 +45,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 #: S² materialization is its design point (the gate elects it only
 #: where that fits VMEM).
 NO_S2_ATTN_IMPLS = ("blockwise", "blockfolded", "flash", "xlaflash",
-                    "pallas", "fused")
+                    "pallas", "fused", "packed")
 
 #: attention impls that trace without TPU hardware present; audited set
 DENSE_BY_DESIGN = ("densefolded",)
@@ -294,11 +294,13 @@ def _attention_impl_fns() -> Dict[str, callable]:
         xla_flash_decomposed_attention,
     )
     from tmr_tpu.ops.pallas_attn import (
+        _packed_global_on_heads,
         pallas_decomposed_attention,
         pallas_fused_attention,
     )
 
     return {
+        "packed": _packed_global_on_heads,
         "blockwise": blockwise_decomposed_attention,
         "blockfolded": blockfolded_decomposed_attention,
         "densefolded": densefolded_decomposed_attention,

@@ -173,7 +173,7 @@ def preset(name: str, **overrides) -> Config:
 ENV_KNOBS = {
     # formulation dispatch (trace-time; autotune exports winners here)
     "TMR_GLOBAL_ATTN": "global ViT attention formulation: auto|blockwise|"
-        "blockfolded|densefolded|flash|xlaflash|pallas|fused",
+        "blockfolded|densefolded|flash|xlaflash|pallas|fused|packed",
     "TMR_XCORR_IMPL": "template-correlation formulation: auto|conv|"
         "convnhwc|vmap|fft|pallas",
     "TMR_XCORR_IMPL_SMALL": "small-bucket override of TMR_XCORR_IMPL",

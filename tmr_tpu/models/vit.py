@@ -338,6 +338,235 @@ class Attention(nn.Module):
             metrics.counter(f"vit.win_attn.{got}").inc()
         return got
 
+    def _global_formulation(self, h: int, w: int, head_dim: int):
+        """(name, attention function) a block of 1024 tokens or more
+        traces with, counted under ``vit.global_attn.<name>``; the function
+        takes head-major operands and is None for ``packed``, which takes
+        ``qkv`` itself."""
+        # global-attention blocks (4096+ tokens): never materialize the
+        # S x S scores or the (B, H, h, w, h, w) bias. TMR_GLOBAL_ATTN
+        # (trace-time A/B knob, measured by the autotune sweep) picks
+        # the formulation:
+        #   blockwise    exact XLA band scan (the f32-parity default)
+        #   blockfolded  band scan, bias folded into the QK contraction
+        #                (exact in f32; bf16 is numerics-self-checked
+        #                with blockwise fallback)
+        #   densefolded  folded QK with NO band scan — one dense
+        #                einsum/softmax/einsum, XLA picks the tiling
+        #                (same fold, same bf16 gate as blockfolded)
+        #   flash        stock Pallas flash over the 256-padded folded
+        #                QK (bf16 only; self-check gate -> blockwise)
+        #   pallas       custom decomposed-bias kernel, VMEM-resident
+        #                tiles at native head dim (ops/pallas_attn.py;
+        #                self-check gate -> blockwise)
+        #   fused        the rewritten fused-bias kernel: row+lane-
+        #                aligned v5e tiles, bias rebuilt per tile from
+        #                the (q, k) block offsets by broadcast alone —
+        #                no selector matmuls (ops/pallas_attn.py;
+        #                self-check gate -> blockwise)
+        #   xlaflash     pure-XLA online-softmax flash with the same
+        #                fused-bias tiling (ops/flash_attn.py) — the
+        #                Mosaic-independent form; largest live score
+        #                tile is (band, block_k), not (band, S)
+        #   packed       the kernel on ``qkv`` where the product wrote
+        #                it: no per-head operand between the two products
+        #                (ops/pallas_attn.py; bf16 with rel-pos tables;
+        #                self-check gate -> blockwise)
+        #   auto         what ops/pallas_attn.global_formulation observes:
+        #                packed, else flash, else blockwise
+        impl = os.environ.get("TMR_GLOBAL_ATTN", "auto")
+        if impl not in (
+            "auto", "blockwise", "flash", "blockfolded", "densefolded",
+            "pallas", "fused", "xlaflash", "packed",
+        ):
+            raise ValueError(
+                f"TMR_GLOBAL_ATTN={impl!r}: expected "
+                "auto|blockwise|flash|blockfolded|densefolded|pallas|"
+                "fused|xlaflash|packed"
+            )
+        attn_fn = blockwise_decomposed_attention
+        if impl in ("blockfolded", "densefolded"):
+            # exact in f32; under bf16 the folded bias rounds to bf16,
+            # so the selection is self-check-gated like every other
+            # formulation (PARITY.md contract). The gate is pure XLA
+            # (runs on any backend, Pallas kill-switch exempt).
+            attn_fn = (
+                blockfolded_decomposed_attention
+                if impl == "blockfolded"
+                else densefolded_decomposed_attention
+            )
+            if self.dtype == jnp.bfloat16:
+                from tmr_tpu.ops.flash_attn import (
+                    blockfolded_ok,
+                    densefolded_ok,
+                )
+
+                ok = (
+                    blockfolded_ok
+                    if impl == "blockfolded"
+                    else densefolded_ok
+                )
+                if not ok(h, w, head_dim, _scores_dtype()):
+                    import warnings
+
+                    warnings.warn(FormulationFallbackWarning(
+                        "TMR_GLOBAL_ATTN",
+                        f"TMR_GLOBAL_ATTN={impl}: bf16 numerics "
+                        f"self-check failed at grid ({h}, {w}, "
+                        f"head_dim {head_dim}); running blockwise "
+                        "fallback"
+                    ))
+                    attn_fn = blockwise_decomposed_attention
+        elif impl == "pallas":
+            # the custom decomposed-bias kernel (ops/pallas_attn.py):
+            # VMEM-resident online-softmax tiles, native head-dim
+            # contraction; self-checked per geometry with fallback
+            from tmr_tpu.ops.pallas_attn import (
+                effective_global_tiles,
+                pallas_decomposed_attention,
+                pallas_global_ok,
+                pallas_supported,
+            )
+
+            bq, bk = effective_global_tiles(h * w)
+            if pallas_supported(h * w) and pallas_global_ok(
+                h, w, head_dim, bq, bk
+            ):
+                attn_fn = pallas_decomposed_attention
+            else:
+                # explicit request refused by the gate: an A/B number
+                # measured now would silently be blockwise — say so
+                # once, at trace time
+                import warnings
+
+                warnings.warn(FormulationFallbackWarning(
+                    "TMR_GLOBAL_ATTN",
+                    "TMR_GLOBAL_ATTN=pallas: self-check gate refused "
+                    f"grid ({h}, {w}, head_dim {head_dim}); running "
+                    "blockwise fallback"
+                ))
+        elif impl == "fused":
+            # the fused-bias kernel: row+lane-aligned tiles, bias
+            # rebuilt per tile from the (q, k) block offsets —
+            # self-checked per (geometry, tile config) with fallback
+            from tmr_tpu.ops.pallas_attn import (
+                effective_fused_tiles,
+                fused_supported,
+                pallas_fused_attention,
+                pallas_fused_ok,
+            )
+
+            bq, bk = effective_fused_tiles(h * w, w)
+            if fused_supported(h * w, w) and pallas_fused_ok(
+                h, w, head_dim, bq, bk
+            ):
+                attn_fn = pallas_fused_attention
+            else:
+                import warnings
+
+                warnings.warn(FormulationFallbackWarning(
+                    "TMR_GLOBAL_ATTN",
+                    "TMR_GLOBAL_ATTN=fused: self-check gate refused "
+                    f"grid ({h}, {w}, head_dim {head_dim}); running "
+                    "blockwise fallback"
+                ))
+        elif impl == "xlaflash":
+            # pure-XLA online-softmax flash, fused bias tiles: exact
+            # in f32 up to reassociation (ungated there, like the
+            # folded formulations); bf16 is numerics-self-checked
+            # with blockwise fallback
+            from tmr_tpu.ops.flash_attn import (
+                xla_flash_decomposed_attention,
+                xlaflash_ok,
+            )
+
+            attn_fn = xla_flash_decomposed_attention
+            if self.dtype == jnp.bfloat16 and not xlaflash_ok(
+                h, w, head_dim
+            ):
+                import warnings
+
+                warnings.warn(FormulationFallbackWarning(
+                    "TMR_GLOBAL_ATTN",
+                    "TMR_GLOBAL_ATTN=xlaflash: bf16 numerics "
+                    f"self-check failed at grid ({h}, {w}, head_dim "
+                    f"{head_dim}); running blockwise fallback"
+                ))
+                attn_fn = blockwise_decomposed_attention
+        elif impl == "packed":
+            # the kernel on ``qkv`` where the product wrote it
+            # (ops/pallas_attn.packed_global_attention): bf16 with rel-pos
+            # tables on a TPU, self-check gate -> blockwise
+            from tmr_tpu.ops.pallas_attn import (
+                packed_global_ok,
+                packed_global_supported,
+            )
+
+            if (self.use_rel_pos and self.dtype == jnp.bfloat16
+                    and packed_global_supported(
+                        (h, w), self.num_heads, head_dim)
+                    and packed_global_ok(h, w, head_dim, self.num_heads)):
+                attn_fn = None
+            else:
+                import warnings
+
+                warnings.warn(FormulationFallbackWarning(
+                    "TMR_GLOBAL_ATTN",
+                    "TMR_GLOBAL_ATTN=packed: no packed layout or gate "
+                    f"refused grid ({h}, {w}, head_dim {head_dim}, dtype "
+                    f"{jnp.dtype(self.dtype).name}); running blockwise "
+                    "fallback"
+                ))
+        elif impl == "auto":
+            from tmr_tpu.ops.pallas_attn import global_formulation
+
+            impl = global_formulation(
+                (h, w), self.num_heads, head_dim, self.dtype,
+                self.use_rel_pos)
+            if impl == "packed":
+                attn_fn = None
+            elif impl == "flash":
+                from tmr_tpu.ops.flash_attn import flash_decomposed_attention
+
+                attn_fn = flash_decomposed_attention
+        elif impl == "flash" and self.dtype == jnp.bfloat16:
+            from tmr_tpu.ops.flash_attn import (
+                flash_attention_ok,
+                flash_decomposed_attention,
+                flash_supported,
+            )
+
+            if flash_supported(h * w) and flash_attention_ok(
+                h, w, head_dim
+            ):
+                attn_fn = flash_decomposed_attention
+            else:
+                import warnings
+
+                warnings.warn(FormulationFallbackWarning(
+                    "TMR_GLOBAL_ATTN",
+                    "TMR_GLOBAL_ATTN=flash: gate refused grid "
+                    f"({h}, {w}, head_dim {head_dim}); running "
+                    "blockwise fallback"
+                ))
+        elif impl == "flash":
+            # explicit flash on a non-bf16 model: the kernel is
+            # bf16-only, so the request silently lands on blockwise —
+            # say so or an A/B records blockwise timings labeled flash
+            import warnings
+
+            warnings.warn(FormulationFallbackWarning(
+                "TMR_GLOBAL_ATTN",
+                f"TMR_GLOBAL_ATTN=flash needs bf16 (model dtype "
+                f"{self.dtype}); running blockwise fallback"
+            ))
+        if attn_fn is blockwise_decomposed_attention:
+            impl = "blockwise"
+        from tmr_tpu.obs import metrics
+
+        metrics.counter(f"vit.global_attn.{impl}").inc()
+        return impl, attn_fn
+
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         b, h, w, dim = x.shape
@@ -359,9 +588,11 @@ class Attention(nn.Module):
             rh = get_rel_pos(h, h, rel_pos_h)  # (h, h, hd) f32
             rw = get_rel_pos(w, w, rel_pos_w)  # (w, w, hd) f32
 
-        win = None
+        win = glob = attn_fn = None
         if self.seq_mesh is None and h * w < 1024:
             win = self._window_formulation(h, w, head_dim)
+        elif self.seq_mesh is None:
+            glob, attn_fn = self._global_formulation(h, w, head_dim)
         if win == "packed":
             # the windowed blocks' TPU bf16 path: the kernel takes qkv as
             # the product wrote it and writes what proj reads — no
@@ -381,6 +612,18 @@ class Attention(nn.Module):
             x = nn.Dense(dim, dtype=self.dtype, name="proj")(x)
             return drop_window_pad(x, (h, w))
 
+        if glob == "packed":
+            # the global blocks' TPU bf16 path, the same way: 4,096 tokens
+            # an image are whole tiles, so the rows need no pad either
+            from tmr_tpu.ops.pallas_attn import packed_global_attention
+
+            qkv = nn.Dense(dim * 3, dtype=self.dtype, name="qkv")(
+                x.reshape(b * h * w, dim))
+            x = packed_global_attention(
+                qkv, rh, rw, (h, w), self.num_heads, scale)
+            x = nn.Dense(dim, dtype=self.dtype, name="proj")(x)
+            return x.reshape(b, h, w, dim)
+
         qkv = nn.Dense(dim * 3, dtype=self.dtype, name="qkv")(x)
         qkv = qkv.reshape(b, h * w, 3, self.num_heads, head_dim)
         q, k, v = jnp.moveaxis(qkv, 2, 0)  # each (b, hw, heads, hd)
@@ -391,182 +634,8 @@ class Attention(nn.Module):
         if self.seq_mesh is not None:
             x = self._ring_attn(q, k, v, rh, rw, (b, h, w, dim), head_dim)
         elif h * w >= 1024:
-            # global-attention blocks (4096+ tokens): never materialize the
-            # S x S scores or the (B, H, h, w, h, w) bias. TMR_GLOBAL_ATTN
-            # (trace-time A/B knob, measured by the autotune sweep) picks
-            # the formulation:
-            #   blockwise    exact XLA band scan (the f32-parity default)
-            #   blockfolded  band scan, bias folded into the QK contraction
-            #                (exact in f32; bf16 is numerics-self-checked
-            #                with blockwise fallback)
-            #   densefolded  folded QK with NO band scan — one dense
-            #                einsum/softmax/einsum, XLA picks the tiling
-            #                (same fold, same bf16 gate as blockfolded)
-            #   flash        stock Pallas flash over the 256-padded folded
-            #                QK (bf16 only; self-check gate -> blockwise)
-            #   pallas       custom decomposed-bias kernel, VMEM-resident
-            #                tiles at native head dim (ops/pallas_attn.py;
-            #                self-check gate -> blockwise)
-            #   fused        the rewritten fused-bias kernel: row+lane-
-            #                aligned v5e tiles, bias rebuilt per tile from
-            #                the (q, k) block offsets by broadcast alone —
-            #                no selector matmuls (ops/pallas_attn.py;
-            #                self-check gate -> blockwise)
-            #   xlaflash     pure-XLA online-softmax flash with the same
-            #                fused-bias tiling (ops/flash_attn.py) — the
-            #                Mosaic-independent form; largest live score
-            #                tile is (band, block_k), not (band, S)
-            #   auto         flash when its gate passes, else blockwise
-            impl = os.environ.get("TMR_GLOBAL_ATTN", "auto")
-            if impl not in (
-                "auto", "blockwise", "flash", "blockfolded", "densefolded",
-                "pallas", "fused", "xlaflash",
-            ):
-                raise ValueError(
-                    f"TMR_GLOBAL_ATTN={impl!r}: expected "
-                    "auto|blockwise|flash|blockfolded|densefolded|pallas|"
-                    "fused|xlaflash"
-                )
-            attn_fn = blockwise_decomposed_attention
-            if impl in ("blockfolded", "densefolded"):
-                # exact in f32; under bf16 the folded bias rounds to bf16,
-                # so the selection is self-check-gated like every other
-                # formulation (PARITY.md contract). The gate is pure XLA
-                # (runs on any backend, Pallas kill-switch exempt).
-                attn_fn = (
-                    blockfolded_decomposed_attention
-                    if impl == "blockfolded"
-                    else densefolded_decomposed_attention
-                )
-                if self.dtype == jnp.bfloat16:
-                    from tmr_tpu.ops.flash_attn import (
-                        blockfolded_ok,
-                        densefolded_ok,
-                    )
-
-                    ok = (
-                        blockfolded_ok
-                        if impl == "blockfolded"
-                        else densefolded_ok
-                    )
-                    if not ok(h, w, head_dim, _scores_dtype()):
-                        import warnings
-
-                        warnings.warn(FormulationFallbackWarning(
-                            "TMR_GLOBAL_ATTN",
-                            f"TMR_GLOBAL_ATTN={impl}: bf16 numerics "
-                            f"self-check failed at grid ({h}, {w}, "
-                            f"head_dim {head_dim}); running blockwise "
-                            "fallback"
-                        ))
-                        attn_fn = blockwise_decomposed_attention
-            elif impl == "pallas":
-                # the custom decomposed-bias kernel (ops/pallas_attn.py):
-                # VMEM-resident online-softmax tiles, native head-dim
-                # contraction; self-checked per geometry with fallback
-                from tmr_tpu.ops.pallas_attn import (
-                    effective_global_tiles,
-                    pallas_decomposed_attention,
-                    pallas_global_ok,
-                    pallas_supported,
-                )
-
-                bq, bk = effective_global_tiles(h * w)
-                if pallas_supported(h * w) and pallas_global_ok(
-                    h, w, head_dim, bq, bk
-                ):
-                    attn_fn = pallas_decomposed_attention
-                else:
-                    # explicit request refused by the gate: an A/B number
-                    # measured now would silently be blockwise — say so
-                    # once, at trace time
-                    import warnings
-
-                    warnings.warn(FormulationFallbackWarning(
-                        "TMR_GLOBAL_ATTN",
-                        "TMR_GLOBAL_ATTN=pallas: self-check gate refused "
-                        f"grid ({h}, {w}, head_dim {head_dim}); running "
-                        "blockwise fallback"
-                    ))
-            elif impl == "fused":
-                # the fused-bias kernel: row+lane-aligned tiles, bias
-                # rebuilt per tile from the (q, k) block offsets —
-                # self-checked per (geometry, tile config) with fallback
-                from tmr_tpu.ops.pallas_attn import (
-                    effective_fused_tiles,
-                    fused_supported,
-                    pallas_fused_attention,
-                    pallas_fused_ok,
-                )
-
-                bq, bk = effective_fused_tiles(h * w, w)
-                if fused_supported(h * w, w) and pallas_fused_ok(
-                    h, w, head_dim, bq, bk
-                ):
-                    attn_fn = pallas_fused_attention
-                else:
-                    import warnings
-
-                    warnings.warn(FormulationFallbackWarning(
-                        "TMR_GLOBAL_ATTN",
-                        "TMR_GLOBAL_ATTN=fused: self-check gate refused "
-                        f"grid ({h}, {w}, head_dim {head_dim}); running "
-                        "blockwise fallback"
-                    ))
-            elif impl == "xlaflash":
-                # pure-XLA online-softmax flash, fused bias tiles: exact
-                # in f32 up to reassociation (ungated there, like the
-                # folded formulations); bf16 is numerics-self-checked
-                # with blockwise fallback
-                from tmr_tpu.ops.flash_attn import (
-                    xla_flash_decomposed_attention,
-                    xlaflash_ok,
-                )
-
-                attn_fn = xla_flash_decomposed_attention
-                if self.dtype == jnp.bfloat16 and not xlaflash_ok(
-                    h, w, head_dim
-                ):
-                    import warnings
-
-                    warnings.warn(FormulationFallbackWarning(
-                        "TMR_GLOBAL_ATTN",
-                        "TMR_GLOBAL_ATTN=xlaflash: bf16 numerics "
-                        f"self-check failed at grid ({h}, {w}, head_dim "
-                        f"{head_dim}); running blockwise fallback"
-                    ))
-                    attn_fn = blockwise_decomposed_attention
-            elif impl != "blockwise" and self.dtype == jnp.bfloat16:
-                from tmr_tpu.ops.flash_attn import (
-                    flash_attention_ok,
-                    flash_decomposed_attention,
-                    flash_supported,
-                )
-
-                if flash_supported(h * w) and flash_attention_ok(
-                    h, w, head_dim
-                ):
-                    attn_fn = flash_decomposed_attention
-                elif impl == "flash":
-                    import warnings
-
-                    warnings.warn(FormulationFallbackWarning(
-                        "TMR_GLOBAL_ATTN",
-                        "TMR_GLOBAL_ATTN=flash: gate refused grid "
-                        f"({h}, {w}, head_dim {head_dim}); running "
-                        "blockwise fallback"
-                    ))
-            elif impl == "flash":
-                # explicit flash on a non-bf16 model: the kernel is
-                # bf16-only, so the request silently lands on blockwise —
-                # say so or an A/B records blockwise timings labeled flash
-                import warnings
-
-                warnings.warn(FormulationFallbackWarning(
-                    "TMR_GLOBAL_ATTN",
-                    f"TMR_GLOBAL_ATTN=flash needs bf16 (model dtype "
-                    f"{self.dtype}); running blockwise fallback"
-                ))
+            # global-attention blocks (4096+ tokens): the formulation
+            # ``_global_formulation`` chose, on head-major operands
             x = attn_fn(
                 q, k, v,
                 rh if self.use_rel_pos else None,

@@ -24,8 +24,10 @@ self-check asked inside the model's trace
 (``diagnostics.run_outside_trace``) is its child; it carries the windowed
 attention formulation the program traced with (``win_attn``) and how many
 blocks took it (``win_attn_blocks``), from the ``vit.win_attn.*`` counters,
-and for a trunk of typed layers (``models/lm_trunk.py``) ``trunk_kda``,
-``trunk_mla``, ``trunk_moe`` and ``experts_held`` from ``trunk.*``.
+the same of the global blocks (``global_attn``, ``global_attn_blocks``, from
+``vit.global_attn.*``), and for a trunk of typed layers
+(``models/lm_trunk.py``) ``trunk_kda``, ``trunk_mla``, ``trunk_moe`` and
+``experts_held`` from ``trunk.*``.
 
 :func:`track_compile`'s wrapper is also the one seam every Predictor
 program is called through, so it records each call as a
@@ -49,6 +51,8 @@ from tmr_tpu.obs import tracing as _tracing
 
 #: prefix of the counters of windowed blocks traced, by formulation
 _WIN_ATTN = "vit.win_attn."
+#: the same of the global blocks (models/vit.py:_global_formulation)
+_GLOBAL_ATTN = "vit.global_attn."
 
 #: prefix of the counters of trunk layers traced (models/lm_trunk.py):
 #: ``trunk.<kda|mla|moe>.<formulation>`` and ``trunk.experts_held``
@@ -181,17 +185,21 @@ def track_compile(fn, kind: str, key: Any,
                 # the formulation taken: the difference over the first
                 # call is this program's own
                 counts = _metrics.get_registry().counters
-                before = counts(_WIN_ATTN)
+                before = {"win_attn": counts(_WIN_ATTN),
+                          "global_attn": counts(_GLOBAL_ATTN)}
                 before_trunk = counts(_TRUNK)
                 t0 = time.perf_counter()
                 out = fn(*args, **kw)
                 t1 = time.perf_counter()
-                traced = {n: v - before.get(n, 0)
-                          for n, v in counts(_WIN_ATTN).items()
-                          if v > before.get(n, 0)}
-                if traced:
-                    sp.set_attr(win_attn="+".join(sorted(traced)),
-                                win_attn_blocks=sum(traced.values()))
+                for attr, prefix in (("win_attn", _WIN_ATTN),
+                                     ("global_attn", _GLOBAL_ATTN)):
+                    traced = {n: v - before[attr].get(n, 0)
+                              for n, v in counts(prefix).items()
+                              if v > before[attr].get(n, 0)}
+                    if traced:
+                        sp.set_attr(**{
+                            attr: "+".join(sorted(traced)),
+                            f"{attr}_blocks": sum(traced.values())})
                 sp.set_attr(**_trunk_attrs(before_trunk, counts(_TRUNK)))
                 with lock:
                     if not done:

@@ -31,9 +31,13 @@ from tmr_tpu.utils.cache import REPO_ROOT, STATE_DIR
 from tmr_tpu.utils.profiling import chained_seconds_per_iter, measure_rtt_floor
 
 XCORR_VARIANTS = ("conv", "convnhwc", "vmap", "fft", "pallas")
+#: every formulation ``ops/pallas_attn.global_formulation`` can answer is
+#: in the sweep (``packed`` since PR 32): ``pick_global_attn_impl`` exports
+#: the fastest of these whenever the knob is unset, so a formulation the
+#: default takes and the sweep lacks would be displaced by a slower one
 GLOBAL_ATTN_VARIANTS = (
     "blockwise", "flash", "blockfolded", "densefolded", "pallas",
-    "fused", "xlaflash",
+    "fused", "xlaflash", "packed",
 )
 XCORR_PRECISIONS = ("highest", "default", "bf16")
 GLOBAL_SCORES_DTYPES = ("f32", "bf16")
