@@ -249,6 +249,9 @@ def check_kda_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     from tmr_tpu.ops import kda
 
     z = TRUNK_CONFIGS[size.backbone]
+    if not any(mixer == "kda" for mixer, _ in z["layers"]):
+        say("  recurrence: the backbone has no kda layer, nothing to hold")
+        return False
     h, d, bf = z["num_heads"], z["kda_head_dim"], jnp.bfloat16
     seq = (size.image_size // 16) ** 2
     formulation = kda.kda_formulation(seq, d, d, bf, h)
@@ -506,18 +509,29 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
 
     if _is_trunk(size):
         from tmr_tpu import obs
+        from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
 
-        traced = [r["attrs"].get("trunk_kda") for r in obs.spans()
-                  if r["name"] == "compile" and "trunk_kda" in r["attrs"]]
-        want = "chunk_kernel" if verdicts.get("kda_chunk_ok") else "chunked_xla"
-        check(traced and all(t.startswith(want + " x") for t in traced),
-              f"every compiled program traced its recurrence as {want}: "
-              f"{traced}")
+        z = TRUNK_CONFIGS[size.backbone]
+        traced = [{k: v for k, v in r["attrs"].items()
+                   if k.startswith("trunk_")}
+                  for r in obs.spans() if r["name"] == "compile"]
+        traced = [t for t in traced if t]
+        say(f"  formulations the compiled programs traced: {traced}")
+        kinds = {f"trunk_{mixer}" for mixer, _ in z["layers"]} | {"trunk_moe"}
+        if z.get("hc_mult"):
+            kinds.add("trunk_hc")
+        check(traced and all(kinds <= set(t) for t in traced),
+              f"every compiled program names its {sorted(kinds)}")
+        if "trunk_kda" in kinds:
+            want = ("chunk_kernel" if verdicts.get("kda_chunk_ok")
+                    else "chunked_xla")
+            check(all(t["trunk_kda"].startswith(want + " x") for t in traced),
+                  f"every compiled program traced its recurrence as {want}")
         # a float32 copy left to route by itself breaks ties otherwise and
         # sends those tokens through other experts: that comparison is the
         # benchmark cell's, where the reference follows ties (PERF.md)
         say("  bf16 against a float32 oracle: skipped for a backbone with "
-            "routed experts; kimilinear_fscd147.eval's check makes it")
+            "routed experts; the benchmark's trunk cells' check makes it")
         report_gates("predict")
         _peak_hbm("predict")
         return

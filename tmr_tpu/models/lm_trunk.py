@@ -2,15 +2,24 @@
 decoder layers (mixer ``kda`` | ``mla``, feed-forward ``dense`` | ``moe``)
 between SAM's patch embedding and SAM's neck.
 
-The layers are the Kimi-Linear family's (gated delta-rule linear attention
-3 : 1 latent attention, sigmoid-routed experts with a shared expert), as
-published: pre-norm residual, RMSNorm eps 1e-5, no bias on a linear layer,
-SiLU, no positional encoding anywhere, causal over the patches in raster
-order. The token embedding and the output head are on no path of a detector:
-the patch embedding stands in the embedding's place and the neck in the
-head's. A layer that has experts is told which it holds (``expert_offset``,
-``experts_held``): it routes over all ``num_experts`` and computes its own
-experts' part (``ops/moe.py``).
+Two published families are built from it (``TRUNK_CONFIGS``), each as
+published, with no bias on a linear layer, SiLU, pre-norm sub-layers and
+causal over the patches in raster order (position = raster index):
+
+- Kimi-Linear's: gated delta-rule linear attention 3 : 1 latent attention
+  without rotary (no positional encoding anywhere), the plain residual add,
+  RMSNorm eps 1e-5;
+- Xing4.0's: latent attention in every layer with a low-rank query and
+  YaRN rotary on the "rope" dims (``ops/rope.py``), and in place of the
+  residual add ``hc_mult`` streams mixed by manifold-constrained
+  hyper-connections (``ops/hyper_conn.py``), RMSNorm eps 1e-6.
+
+Both feed-forwards are dense or sigmoid-routed experts with a shared
+expert. The token embedding and the output head are on no path of a
+detector: the patch embedding stands in the embedding's place and the neck
+in the head's. A layer that has experts is told which it holds
+(``expert_offset``, ``experts_held``): it routes over all ``num_experts``
+and computes its own experts' part (``ops/moe.py``).
 
 The trunk's leaves are bfloat16 (``param_dtype``), as the model is published
 and served; the router's and the gates' arithmetic is float32; the rest runs
@@ -19,7 +28,7 @@ in ``dtype``. Patch embedding and neck are ``models/vit.py``'s own.
 
 from __future__ import annotations
 
-from typing import Any, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -28,6 +37,7 @@ import jax.numpy as jnp
 from tmr_tpu.diagnostics import mosaic_off_reason
 from tmr_tpu.models.vit import apply_neck, neck_modules, patch_embed_conv
 from tmr_tpu.obs import metrics
+from tmr_tpu.ops import hyper_conn, rope as rope_ops
 from tmr_tpu.ops import moe as moe_ops
 from tmr_tpu.ops.causal_attn import causal_attention_blocked
 from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
@@ -39,16 +49,25 @@ from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
 STATS = "trunk_stats"
 
 #: what each mechanism traces with (counters ``trunk.<kind>.<formulation>``,
-#: copied onto the ``compile`` span). Latent attention has one formulation
-#: today; the recurrence and the experts' grouped products choose theirs by
+#: copied onto the ``compile`` span). Latent attention is named by the
+#: sizes its mixer was given (``mla_formulation``); the recurrence, the
+#: experts' grouped products and the hyper-connections choose theirs by
 #: device, type and sizes (``ops/kda.py:kda_formulation``,
-#: ``ops/moe.py:grouped_formulation``). ``KDA_FORMULATION`` is the
-#: recurrence's fallback, kept for the benchmark's driver alone
+#: ``ops/moe.py:grouped_formulation``, ``ops/hyper_conn.py:
+#: hc_formulation``). ``KDA_FORMULATION`` is the recurrence's fallback and
+#: ``MLA_FORMULATION`` latent attention's name without rotary, both kept as
+#: constants for the benchmark's driver alone
 #: (``benchmarks/drivers/offline_predict_lm_trunk.py:_say_gates`` prints
-#: it): it goes when a ``benchmark`` PR drops that read (ROADMAP.md
-#: Design 3a)
+#: them): the first goes when a ``benchmark`` PR drops that read
+#: (ROADMAP.md Design 3a)
 KDA_FORMULATION = "chunked_xla"
 MLA_FORMULATION = "blocked_xla"
+
+
+def mla_formulation(rope) -> str:
+    """What latent attention traces with: the row-blocked XLA attention,
+    with the rotation ahead of it where the trunk has rotary."""
+    return MLA_FORMULATION + ("_rope" if rope else "")
 
 
 def _weight(module, name, shape, init=None):
@@ -96,6 +115,7 @@ class KDAMixer(nn.Module):
     num_heads: int
     head_dim: int
     conv_size: int = 4
+    norm_eps: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.bfloat16
 
@@ -125,7 +145,7 @@ class KDAMixer(nn.Module):
         beta = jax.nn.sigmoid(lin32(h, "b_proj")(x))
         formulation = kda_formulation(s, d, d, self.dtype, h)
         metrics.counter(f"trunk.kda.{formulation}").inc()
-        o_norm = RMSNorm(param_dtype=self.param_dtype, name="o_norm")
+        o_norm = RMSNorm(self.norm_eps, self.param_dtype, name="o_norm")
         if formulation == "chunk_kernel":
             # the norms on either side of the recurrence ride in the kernel
             with jax.named_scope("scan"):
@@ -143,15 +163,23 @@ class KDAMixer(nn.Module):
 
 
 class MLAMixer(nn.Module):
-    """Latent attention without rotary: the query-key width (nope + "rope"
-    dims, unrotated) differs from the value width; one ``k_pe`` is shared by
-    all heads. A prefill has no use for the latent cache."""
+    """Latent attention: the query-key width (nope + "rope" dims) differs
+    from the value width; one ``k_pe`` is shared by all heads. ``q_rank``:
+    the query goes through a low-rank pair (``q_a`` -> RMSNorm -> ``q_b``),
+    else one ``q_proj``. ``rope`` (the config's ``rope_scaling`` group and
+    ``theta``): the "rope" dims of the query and ``k_pe`` are turned by
+    their token's position with YaRN's frequencies and the softmax scale
+    takes YaRN's ``mscale^2``; ``None``: unrotated (NoPE). A prefill has no
+    use for the latent cache."""
 
     num_heads: int
     qk_nope_dim: int
     qk_pe_dim: int
     v_dim: int
     kv_rank: int
+    q_rank: Optional[int] = None
+    rope: Any = None
+    norm_eps: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.bfloat16
 
@@ -161,18 +189,39 @@ class MLAMixer(nn.Module):
         h, dn, dp, dv = (self.num_heads, self.qk_nope_dim, self.qk_pe_dim,
                          self.v_dim)
         lin = lambda n, name: _linear(n, self.dtype, self.param_dtype, name)
-        q = lin(h * (dn + dp), "q_proj")(x).reshape(b, s, h, dn + dp)
+        norm = lambda name: RMSNorm(self.norm_eps, self.param_dtype, name=name)
+        if self.q_rank:
+            q = lin(h * (dn + dp), "q_b")(
+                norm("q_a_norm")(lin(self.q_rank, "q_a")(x)))
+        else:
+            q = lin(h * (dn + dp), "q_proj")(x)
+        q = q.reshape(b, s, h, dn + dp)
         kv = lin(self.kv_rank + dp, "kv_a")(x)
         c, k_pe = kv[..., :self.kv_rank], kv[..., self.kv_rank:]
-        c = RMSNorm(param_dtype=self.param_dtype, name="kv_a_norm")(c)
-        kv = lin(h * (dn + dv), "kv_b")(c).reshape(b, s, h, dn + dv)
+        kv = lin(h * (dn + dv), "kv_b")(norm("kv_a_norm")(c)).reshape(
+            b, s, h, dn + dv)
+        scale = (dn + dp) ** -0.5
+        if self.rope:
+            r = self.rope
+            with jax.named_scope("rope"):
+                inv_freq = rope_ops.yarn_inv_freq(
+                    dp, r["theta"], r["factor"],
+                    r["original_max_position_embeddings"], r["beta_fast"],
+                    r["beta_slow"])
+                m_all = rope_ops.yarn_mscale(r["factor"], r["mscale_all_dim"])
+                gain = rope_ops.yarn_mscale(r["factor"], r["mscale"]) / m_all
+                at = jnp.arange(s)
+                q = jnp.concatenate(
+                    [q[..., :dn],
+                     rope_ops.rotate(q[..., dn:], at, inv_freq, gain)], -1)
+                k_pe = rope_ops.rotate(k_pe, at, inv_freq, gain)
+            scale *= m_all ** 2
         k = jnp.concatenate(
             [kv[..., :dn],
              jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, dp))], -1)
-        metrics.counter(f"trunk.mla.{MLA_FORMULATION}").inc()
+        metrics.counter(f"trunk.mla.{mla_formulation(self.rope)}").inc()
         with jax.named_scope("softmax"):
-            o = causal_attention_blocked(q, k, kv[..., dn:],
-                                         (dn + dp) ** -0.5)
+            o = causal_attention_blocked(q, k, kv[..., dn:], scale)
         return lin(x.shape[-1], "o_proj")(o.reshape(b, s, h * dv))
 
 
@@ -258,9 +307,35 @@ class MoEFFN(nn.Module):
         return shared + routed.reshape(b, s, d).astype(shared.dtype)
 
 
+class HyperConn(nn.Module):
+    """One sub-layer's hyper-connection leaves (``ops/hyper_conn.py``):
+    ``phi`` (n C, 2 n + n^2) with columns [pre | post | res], the three
+    ``alpha``, and the biases. Initialised so that ``H_res`` starts near the
+    identity and the token-dependent part small, as published."""
+
+    n: int
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, width: int):
+        n = self.n
+        eye = lambda key, shape, dtype: 4.0 * jnp.eye(n, dtype=dtype)
+        return dict(
+            phi=_weight(self, "phi", (n * width, 2 * n + n * n)),
+            alpha=_weight(self, "alpha", (3,),
+                          nn.initializers.constant(0.01)),
+            b_pre=_weight(self, "b_pre", (n,), nn.initializers.zeros),
+            b_post=_weight(self, "b_post", (n,), nn.initializers.zeros),
+            b_res=_weight(self, "b_res", (n, n), eye))
+
+
 class TrunkLayer(nn.Module):
-    """``x + mixer(norm(x))``, then ``x + ffn(norm(x))``; ``sizes`` is the
-    trunk's own (``TRUNK_CONFIGS``)."""
+    """A mixer and a feed-forward, each on ``norm(x)``. With ``hc_mult`` 0
+    the residual is the plain add, ``x + f(norm(x))`` on (B, S, C); else
+    ``x`` is ``hc_mult`` streams (n, B, S, C) and each sub-layer reads their
+    ``H_pre`` mix and writes back through ``H_res`` and ``H_post``
+    (``ops/hyper_conn.py``; one stream is *not* the plain add). ``sizes``
+    is the trunk's own (``TRUNK_CONFIGS``)."""
 
     mixer: str  # "kda" | "mla"
     ffn: str  # "dense" | "moe"
@@ -274,10 +349,11 @@ class TrunkLayer(nn.Module):
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         if self.mixer == "kda":
             attn = KDAMixer(z["num_heads"], z["kda_head_dim"],
-                            z["conv_size"], name="attn", **kw)
+                            z["conv_size"], z["norm_eps"], name="attn", **kw)
         elif self.mixer == "mla":
             attn = MLAMixer(z["num_heads"], z["qk_nope_dim"],
                             z["qk_pe_dim"], z["v_dim"], z["kv_rank"],
+                            z["q_rank"], z["rope"], z["norm_eps"],
                             name="attn", **kw)
         else:
             raise KeyError(f"unknown mixer kind {self.mixer!r}")
@@ -289,20 +365,45 @@ class TrunkLayer(nn.Module):
                          z["expert_width"], name="ffn", **kw)
         else:
             raise KeyError(f"unknown feed-forward kind {self.ffn!r}")
-        norm = lambda name: RMSNorm(param_dtype=self.param_dtype, name=name)
-        x = x + attn(norm("norm1")(x))
-        return x + mlp(norm("norm2")(x))
+        norm = lambda name: RMSNorm(z["norm_eps"], self.param_dtype,
+                                    name=name)
+        if not z["hc_mult"]:
+            x = x + attn(norm("norm1")(x))
+            return x + mlp(norm("norm2")(x))
+
+        def hyper_connected(name, x, f, norm):
+            # the scopes <name>/coeff/ and <name>/mix/ hold the
+            # hyper-connection's own passes; f and norm keep their own
+            p = HyperConn(z["hc_mult"], self.param_dtype, name=name)(
+                x.shape[-1])
+            formulation = hyper_conn.hc_formulation(
+                z["hc_mult"], x.shape[-1], self.dtype)
+            metrics.counter(f"trunk.hc.{formulation}").inc()
+            with jax.named_scope(name):
+                with jax.named_scope("coeff"):
+                    h_pre, h_post, h_res = hyper_conn.coefficients(
+                        x, p["phi"], p["alpha"], p["b_pre"], p["b_post"],
+                        p["b_res"], z["hc_sinkhorn_iters"], z["hc_eps"],
+                        z["hc_clamp"], z["norm_eps"])
+                with jax.named_scope("mix"):
+                    h = hyper_conn.pre_mix(x, h_pre, self.dtype)
+            y = f(norm(h))
+            with jax.named_scope(name), jax.named_scope("mix"):
+                return hyper_conn.post_mix(x, y, h_post, h_res)
+
+        x = hyper_connected("hc_attn", x, attn, norm("norm1"))
+        return hyper_connected("hc_ffn", x, mlp, norm("norm2"))
 
 
 class LMTrunkBackbone(nn.Module):
     """(B, S, S, 3) NHWC -> (B, S/16, S/16, out_chans): patch embedding,
-    the trunk over the patches in raster order, final norm, neck."""
+    the trunk over the patches in raster order, final norm, neck. With
+    ``hc_mult`` streams the patch embedding is replicated into them ahead
+    of the first layer and they are summed ahead of the final norm."""
 
     hidden: int
     layers: Sequence[Tuple[str, str]]  # (mixer kind, ffn kind) a layer
     num_heads: int
-    kda_head_dim: int
-    conv_size: int
     qk_nope_dim: int
     qk_pe_dim: int
     v_dim: int
@@ -313,6 +414,15 @@ class LMTrunkBackbone(nn.Module):
     experts_held: int
     top_k: int
     routed_scale: float
+    kda_head_dim: Optional[int] = None  # a trunk with no "kda" layer
+    conv_size: Optional[int] = None  # needs neither
+    q_rank: Optional[int] = None  # None: one q_proj
+    rope: Any = None  # None: NoPE; else YaRN's group and "theta"
+    hc_mult: int = 0  # 0: the plain residual add
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
+    norm_eps: float = 1e-5
     expert_offset: int = 0
     patch_size: int = 16
     out_chans: int = 256
@@ -324,14 +434,15 @@ class LMTrunkBackbone(nn.Module):
                                        self.dtype)
         sizes = {f: getattr(self, f) for f in (
             "num_heads", "kda_head_dim", "conv_size", "qk_nope_dim",
-            "qk_pe_dim", "v_dim", "kv_rank", "dense_width", "expert_width",
-            "num_experts", "experts_held", "expert_offset", "top_k",
-            "routed_scale")}
+            "qk_pe_dim", "v_dim", "kv_rank", "q_rank", "rope", "dense_width",
+            "expert_width", "num_experts", "experts_held", "expert_offset",
+            "top_k", "routed_scale", "hc_mult", "hc_sinkhorn_iters",
+            "hc_eps", "hc_clamp", "norm_eps")}
         self._layers = [
             TrunkLayer(mixer, ffn, sizes, self.dtype, self.param_dtype,
                        name=f"layers_{i}")
             for i, (mixer, ffn) in enumerate(self.layers)]
-        self._final_norm = RMSNorm(param_dtype=self.param_dtype,
+        self._final_norm = RMSNorm(self.norm_eps, self.param_dtype,
                                    name="final_norm")
         self._neck = neck_modules(self.out_chans, self.dtype)
 
@@ -345,8 +456,12 @@ class LMTrunkBackbone(nn.Module):
         x = self.embed(x)
         b, h, w, d = x.shape
         x = x.reshape(b, h * w, d)
+        if self.hc_mult:
+            x = jnp.broadcast_to(x[None], (self.hc_mult,) + x.shape)
         for layer in self._layers:
             x = layer(x)
+        if self.hc_mult:
+            x = x.astype(jnp.float32).sum(0).astype(self.dtype)
         return self.neck(self._final_norm(x).reshape(b, h, w, d))
 
 
@@ -361,14 +476,27 @@ def _pattern(n_layers: int, mla_every: int = 4, first_dense: int = 1):
 
 #: name -> sizes. ``kimi_linear_a3b_share2``: the published widths of
 #: Kimi-Linear-48B-A3B, layers 1 to 5, the 128 of 256 experts that one of
-#: the two chips sharing a layer holds. (The tests and the benchmark's
-#: rehearsal add the same pattern at tiny widths under a name of their own.)
+#: the two chips sharing a layer holds. ``xing4_a4b_stage6``: the published
+#: widths of Xing4.0-29B-A4B, one pipeline stage's six layers (published 2
+#: to 7: the second leading dense layer and five expert layers), every one
+#: of a layer's 64 experts held (``ep_size`` 1). (The tests and the
+#: benchmark's rehearsals add the same patterns at tiny widths under names
+#: of their own.)
 TRUNK_CONFIGS = {
     "kimi_linear_a3b_share2": dict(
         hidden=2304, layers=_pattern(5), num_heads=32, kda_head_dim=128,
         conv_size=4, qk_nope_dim=128, qk_pe_dim=64, v_dim=128, kv_rank=512,
         dense_width=9216, expert_width=1024, num_experts=256,
         experts_held=128, top_k=8, routed_scale=2.446),
+    "xing4_a4b_stage6": dict(
+        hidden=3584, layers=(("mla", "dense"),) + (("mla", "moe"),) * 5,
+        num_heads=32, qk_nope_dim=128, qk_pe_dim=64, v_dim=128, kv_rank=512,
+        q_rank=768, dense_width=9216, expert_width=1024, num_experts=64,
+        experts_held=64, top_k=4, routed_scale=2.0, norm_eps=1e-6,
+        rope=dict(theta=10000, factor=64, beta_fast=32, beta_slow=1,
+                  mscale=1, mscale_all_dim=1,
+                  original_max_position_embeddings=4096),
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_clamp=(-30.0, 30.0)),
 }
 
 
