@@ -306,6 +306,55 @@ def check_kda_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     return True
 
 
+def check_mla_kernel(size: Size, seed: int, batch: int = 2) -> bool:
+    """Latent attention's Pallas kernel
+    (``ops/causal_attn.py:latent_attention_kernel``), which off the chip
+    runs only in the interpreter: held here to the blocked XLA form at the
+    backbone's own shape (its heads, its tokens, its rotation where it has
+    one). Returns whether a trace of the backbone takes the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
+    from tmr_tpu.ops import causal_attn, rope
+
+    z = TRUNK_CONFIGS[size.backbone]
+    if not any(mixer == "mla" for mixer, _ in z["layers"]):
+        say("  latent attention: the backbone has no mla layer, nothing to "
+            "hold")
+        return False
+    h, dn, dp, dv = (z["num_heads"], z["qk_nope_dim"], z["qk_pe_dim"],
+                     z["v_dim"])
+    seq, bf = (size.image_size // 16) ** 2, jnp.bfloat16
+    rot, scale = None, (dn + dp) ** -0.5
+    if z.get("rope"):
+        inv_freq, gain, temper = rope.yarn_rotation(dp, z["rope"])
+        rot, scale = (tuple(inv_freq.tolist()), gain), scale * temper
+    formulation = causal_attn.mla_formulation(seq, h, dn, dp, dv, bf,
+                                              rot is not None)
+    say(f"  latent attention: mla_formulation({seq}, {h}, {dn}, {dp}, {dv}, "
+        f"bfloat16, rope={rot is not None}) = {formulation}")
+    if formulation != "causal_kernel":
+        return False
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (batch, seq, h * (dn + dp))).astype(bf)
+    kv = jax.random.normal(ks[1], (batch, seq, h * (dn + dv))).astype(bf)
+    k_pe = jax.random.normal(ks[2], (batch, seq, dp)).astype(bf)
+    run = lambda fn: np.asarray(jax.jit(
+        lambda *a: fn(*a, h, scale, rot))(q, kv, k_pe), np.float32)
+    got = run(causal_attn.latent_attention_kernel)
+    want = run(causal_attn.latent_attention_blocked)
+    gap = float(np.abs(got - want).max() / np.abs(want).max())
+    say(f"  latent attention, {batch} x {seq} tokens x {h} heads of {dn} + "
+        f"{dp} / {dv}: the kernel against the blocked form, widest gap "
+        f"{gap:.5f} of the range (bfloat16 on both sides)")
+    # one bfloat16 step near the top of the range is 0.008 of it
+    check(np.isfinite(got).all() and gap < 0.02,
+          "latent attention's kernel equals the blocked form at the "
+          "backbone's shape")
+    return True
+
+
 def _vit_heads(size: Size) -> tuple:
     """(heads, head dim) of the SAM encoder ``size`` names."""
     from tmr_tpu.models.vit import VIT_CONFIGS
@@ -330,6 +379,7 @@ def decide_gates(cfg, size: Size) -> dict:
         say(f"  gate pallas_nms_compiled_ok: "
             f"{'pass' if verdicts['pallas_nms_compiled_ok'] else 'refused'}")
         verdicts["kda_chunk_ok"] = check_kda_kernel(size, seed=0)
+        verdicts["latent_kernel_ok"] = check_mla_kernel(size, seed=0)
         report_gates("decide")
         check_grouped_products(size, seed=0)
         return verdicts
@@ -527,6 +577,11 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
                     else "chunked_xla")
             check(all(t["trunk_kda"].startswith(want + " x") for t in traced),
                   f"every compiled program traced its recurrence as {want}")
+        if "trunk_mla" in kinds:
+            want = ("causal_kernel" if verdicts.get("latent_kernel_ok")
+                    else "blocked_xla") + ("_rope" if z.get("rope") else "")
+            check(all(t["trunk_mla"].startswith(want + " x") for t in traced),
+                  f"every compiled program traced latent attention as {want}")
         # a float32 copy left to route by itself breaks ties otherwise and
         # sends those tokens through other experts: that comparison is the
         # benchmark cell's, where the reference follows ties (PERF.md)

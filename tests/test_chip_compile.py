@@ -70,7 +70,8 @@ def as_tpu(monkeypatch):
     (no interpret mode, TPU defaults), and the gates the ``auto`` path asks
     answer yes — their self-checks execute, which only a chip can."""
     from tmr_tpu.diagnostics import mosaic_gate
-    from tmr_tpu.ops import flash_attn, kda, pallas_attn, pallas_nms
+    from tmr_tpu.ops import causal_attn, flash_attn, kda, pallas_attn
+    from tmr_tpu.ops import pallas_nms
 
     def admits(name):
         def gate(*a, **k):
@@ -86,6 +87,7 @@ def as_tpu(monkeypatch):
                       (pallas_attn, "packed_window_ok"),
                       (pallas_attn, "packed_global_ok"),
                       (kda, "kda_chunk_ok"),
+                      (causal_attn, "latent_kernel_ok"),
                       (pallas_nms, "pallas_nms_compiled_ok")):
         monkeypatch.setattr(mod, name, admits(name))
 
@@ -169,6 +171,25 @@ def _case_kda_chunk(sds):
         f32, f32, sds(shape, jnp.bfloat16), f32, sds(shape[:3], jnp.float32))
 
 
+def _latent_case(rope):
+    """Latent attention's kernel on what ``MLAMixer`` holds in both trunk
+    cells: 4 images of 4,096 tokens, 32 heads of 128 + 64 / 128, q, kv and
+    k_pe as the projections write them; ``rope``: the rotation inside."""
+    from tmr_tpu.ops.causal_attn import latent_attention_kernel
+    from tmr_tpu.ops.rope import yarn_inv_freq
+
+    rot = (tuple(yarn_inv_freq(64, 10000, 64, 4096, 32, 1).tolist()),
+           1.0) if rope else None
+
+    def case(sds):
+        b, s, h = _KDA["batch"], _KDA["seq"], _KDA["heads"]
+        return (lambda *a: latent_attention_kernel(*a, h, 192 ** -0.5, rot)), (
+            sds((b, s, h * 192), jnp.bfloat16),
+            sds((b, s, h * 256), jnp.bfloat16), sds((b, s, 64), jnp.bfloat16))
+
+    return case
+
+
 def _case_nms(sds):
     """The decode tail's shape: 2 images x max_detections slots, vmapped as
     postprocess.batched_nms does."""
@@ -229,6 +250,8 @@ CASES = {
     "packed_global_vith": _packed_global_case(8, 16, 80),
     "packed_global_grad": _packed_global_case(1, 12, 64, grad=True),
     "kda_chunk_kimi": _case_kda_chunk,
+    "latent_kernel_kimi": _latent_case(rope=False),
+    "latent_kernel_xing": _latent_case(rope=True),
     "nms": _case_nms,
     "int8_matmul": _case_int8_matmul,
     "predict_program": _case_predict_program,
@@ -358,6 +381,45 @@ def test_kda_layer_keeps_a_chunk_on_the_chip(one_chip, as_tpu):
     assert "custom-call" in under_scan, under_scan
     assert not {"transpose", "concatenate", "pad"} & set(under_scan), \
         sorted(set(under_scan))
+
+
+@pytest.mark.parametrize("family", ["kimi_linear_a3b_share2",
+                                    "xing4_a4b_stage6"])
+def test_mla_layer_keeps_its_scores_on_the_chip(family, one_chip, as_tpu):
+    """One latent-attention layer at a trunk cell's widths and batch,
+    compiled for the v5e: scores, softmax and values are one Mosaic kernel
+    under ``attn/softmax/``, and nothing of the layer moves an operand the
+    size of q (no ``transpose``, ``copy``, ``concatenate`` or ``pad``, in
+    the entry computation or inside a fusion): q, kv and k_pe are read where
+    the projections left them, and the kernel's output where ``o_proj``
+    reads it."""
+    import re
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, MLAMixer
+
+    z = TRUNK_CONFIGS[family]
+    mixer = MLAMixer(z["num_heads"], z["qk_nope_dim"], z["qk_pe_dim"],
+                     z["v_dim"], z["kv_rank"], z.get("q_rank"), z.get("rope"),
+                     dtype=jnp.bfloat16, name="attn")
+    b, s = _KDA["batch"], _KDA["seq"]
+    x = jax.ShapeDtypeStruct((b, s, z["hidden"]), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), x))
+    text = jax.jit(mixer.apply).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    under_softmax = [m.group(1) for m in re.finditer(
+        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*attn/softmax/", text)]
+    assert "custom-call" in under_softmax, under_softmax
+    q_elems = b * s * z["num_heads"] * z["v_dim"]  # the smallest of q, kv, o
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* ([\w\-]+)\([^\n]*"
+                         r"op_name=\"[^\"]*attn/", text):
+        elems = 1
+        for d in filter(None, m.group(1).split(",")):
+            elems *= int(d)
+        assert m.group(2) not in ("transpose", "copy", "concatenate",
+                                  "pad") or elems < q_elems, m.group(0)[:300]
 
 
 def _shallow_vit_b(cfg):

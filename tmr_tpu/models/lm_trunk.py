@@ -39,7 +39,9 @@ from tmr_tpu.models.vit import apply_neck, neck_modules, patch_embed_conv
 from tmr_tpu.obs import metrics
 from tmr_tpu.ops import hyper_conn, rope as rope_ops
 from tmr_tpu.ops import moe as moe_ops
-from tmr_tpu.ops.causal_attn import causal_attention_blocked
+from tmr_tpu.ops.causal_attn import (latent_attention_blocked,
+                                      latent_attention_kernel,
+                                      mla_formulation)
 from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
                              kda_formulation, l2norm, rms_norm)
 
@@ -49,25 +51,19 @@ from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
 STATS = "trunk_stats"
 
 #: what each mechanism traces with (counters ``trunk.<kind>.<formulation>``,
-#: copied onto the ``compile`` span). Latent attention is named by the
-#: sizes its mixer was given (``mla_formulation``); the recurrence, the
-#: experts' grouped products and the hyper-connections choose theirs by
-#: device, type and sizes (``ops/kda.py:kda_formulation``,
-#: ``ops/moe.py:grouped_formulation``, ``ops/hyper_conn.py:
-#: hc_formulation``). ``KDA_FORMULATION`` is the recurrence's fallback and
-#: ``MLA_FORMULATION`` latent attention's name without rotary, both kept as
+#: copied onto the ``compile`` span). All four choose theirs by device, type
+#: and sizes: the recurrence (``ops/kda.py:kda_formulation``), latent
+#: attention (``ops/causal_attn.py:mla_formulation``, with ``_rope`` after
+#: the name where the trunk has rotary), the experts' grouped products
+#: (``ops/moe.py:grouped_formulation``) and the hyper-connections
+#: (``ops/hyper_conn.py:hc_formulation``). ``KDA_FORMULATION`` and
+#: ``MLA_FORMULATION`` are the first two's fallbacks' names, kept as
 #: constants for the benchmark's driver alone
 #: (``benchmarks/drivers/offline_predict_lm_trunk.py:_say_gates`` prints
-#: them): the first goes when a ``benchmark`` PR drops that read
-#: (ROADMAP.md Design 3a)
+#: them, stale on the chip): they go when a ``benchmark`` PR drops that
+#: read (ROADMAP.md Design 3a)
 KDA_FORMULATION = "chunked_xla"
 MLA_FORMULATION = "blocked_xla"
-
-
-def mla_formulation(rope) -> str:
-    """What latent attention traces with: the row-blocked XLA attention,
-    with the rotation ahead of it where the trunk has rotary."""
-    return MLA_FORMULATION + ("_rope" if rope else "")
 
 
 def _weight(module, name, shape, init=None):
@@ -170,7 +166,11 @@ class MLAMixer(nn.Module):
     ``theta``): the "rope" dims of the query and ``k_pe`` are turned by
     their token's position with YaRN's frequencies and the softmax scale
     takes YaRN's ``mscale^2``; ``None``: unrotated (NoPE). A prefill has no
-    use for the latent cache."""
+    use for the latent cache. Scores, softmax and values are
+    ``ops/causal_attn.py``'s, on ``q``, ``kv`` and ``k_pe`` as the
+    projections wrote them: one Pallas kernel or the row-blocked XLA form,
+    as ``mla_formulation`` answers from the device, the type and the
+    sizes."""
 
     num_heads: int
     qk_nope_dim: int
@@ -185,7 +185,7 @@ class MLAMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        b, s, _ = x.shape
+        s = x.shape[1]
         h, dn, dp, dv = (self.num_heads, self.qk_nope_dim, self.qk_pe_dim,
                          self.v_dim)
         lin = lambda n, name: _linear(n, self.dtype, self.param_dtype, name)
@@ -195,34 +195,28 @@ class MLAMixer(nn.Module):
                 norm("q_a_norm")(lin(self.q_rank, "q_a")(x)))
         else:
             q = lin(h * (dn + dp), "q_proj")(x)
-        q = q.reshape(b, s, h, dn + dp)
         kv = lin(self.kv_rank + dp, "kv_a")(x)
         c, k_pe = kv[..., :self.kv_rank], kv[..., self.kv_rank:]
-        kv = lin(h * (dn + dv), "kv_b")(norm("kv_a_norm")(c)).reshape(
-            b, s, h, dn + dv)
+        kv = lin(h * (dn + dv), "kv_b")(norm("kv_a_norm")(c))
         scale = (dn + dp) ** -0.5
+        rot = None
         if self.rope:
-            r = self.rope
+            inv_freq, gain, temper = rope_ops.yarn_rotation(dp, self.rope)
             with jax.named_scope("rope"):
-                inv_freq = rope_ops.yarn_inv_freq(
-                    dp, r["theta"], r["factor"],
-                    r["original_max_position_embeddings"], r["beta_fast"],
-                    r["beta_slow"])
-                m_all = rope_ops.yarn_mscale(r["factor"], r["mscale_all_dim"])
-                gain = rope_ops.yarn_mscale(r["factor"], r["mscale"]) / m_all
-                at = jnp.arange(s)
-                q = jnp.concatenate(
-                    [q[..., :dn],
-                     rope_ops.rotate(q[..., dn:], at, inv_freq, gain)], -1)
-                k_pe = rope_ops.rotate(k_pe, at, inv_freq, gain)
-            scale *= m_all ** 2
-        k = jnp.concatenate(
-            [kv[..., :dn],
-             jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, dp))], -1)
-        metrics.counter(f"trunk.mla.{mla_formulation(self.rope)}").inc()
+                k_pe = rope_ops.rotate(k_pe, jnp.arange(s), inv_freq, gain)
+            rot = (tuple(inv_freq.tolist()), gain)
+            scale *= temper
+        formulation = mla_formulation(s, h, dn, dp, dv, self.dtype,
+                                      rot is not None)
+        metrics.counter(
+            f"trunk.mla.{formulation}{'_rope' if rot else ''}").inc()
+        attend = (latent_attention_kernel if formulation == "causal_kernel"
+                  else latent_attention_blocked)
+        # q, kv, k_pe as the projections wrote them: the query's rotation,
+        # the scores, the softmax and the values are all under softmax/
         with jax.named_scope("softmax"):
-            o = causal_attention_blocked(q, k, kv[..., dn:], scale)
-        return lin(x.shape[-1], "o_proj")(o.reshape(b, s, h * dv))
+            o = attend(q, kv, k_pe, h, scale, rot)
+        return lin(x.shape[-1], "o_proj")(o)
 
 
 class Experts(nn.Module):

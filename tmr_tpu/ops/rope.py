@@ -40,17 +40,34 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
+def yarn_rotation(dim: int, r) -> tuple:
+    """A config's ``rope_scaling`` group with its ``theta`` as what latent
+    attention needs of it: YaRN's inverse frequencies for ``dim`` "rope"
+    dims, the gain on the cosines and sines (``mscale / mscale_all_dim``),
+    and the factor ``mscale_all_dim^2`` on the softmax scale."""
+    m_all = yarn_mscale(r["factor"], r["mscale_all_dim"])
+    return (yarn_inv_freq(dim, r["theta"], r["factor"],
+                          r["original_max_position_embeddings"],
+                          r["beta_fast"], r["beta_slow"]),
+            yarn_mscale(r["factor"], r["mscale"]) / m_all, m_all ** 2)
+
+
+def tables(positions, inv_freq, gain: float = 1.0):
+    """``(cos, sin)``, each (S, d / 2) float32: ``gain`` times the cosine
+    and sine of ``position * inv_freq``; ``positions`` (S,). ``gain`` is
+    YaRN's ``mscale / mscale_all_dim``."""
+    angle = (positions.astype(jnp.float32)[:, None]
+             * jnp.asarray(inv_freq, jnp.float32)[None, :])
+    return gain * jnp.cos(angle), gain * jnp.sin(angle)
+
+
 def rotate(x, positions, inv_freq, gain: float = 1.0):
     """``x`` (B, S, ..., d) with each pair turned by its token's
     ``position * inv_freq``; ``positions`` (S,). ``gain`` rides on the
-    cosines and sines (YaRN's ``mscale / mscale_all_dim``). In ``x``'s
-    dtype."""
+    cosines and sines (:func:`tables`). In ``x``'s dtype."""
     half = x.shape[-1] // 2
-    angle = (positions.astype(jnp.float32)[:, None]
-             * jnp.asarray(inv_freq, jnp.float32)[None, :])
     shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
-    cos = gain * jnp.cos(angle).reshape(shape)
-    sin = gain * jnp.sin(angle).reshape(shape)
+    cos, sin = (t.reshape(shape) for t in tables(positions, inv_freq, gain))
     x32 = x.astype(jnp.float32)
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
