@@ -355,6 +355,47 @@ def check_mla_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     return True
 
 
+def check_ssd_scan(size: Size, seed: int) -> None:
+    """The state-space recurrence's chunked form (``ops/ssd.py:ssd_chunked``)
+    in bfloat16 at the backbone's own shape (its heads, its state, its chunk,
+    one image's tokens), held on the chip to the token recurrence in
+    float32, with step sizes and decays by the family's rule: a head's
+    memory from under one token to a thousand."""
+    import jax
+    import jax.numpy as jnp
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
+    from tmr_tpu.ops import ssd
+
+    z = TRUNK_CONFIGS[size.backbone]
+    if not any(mixer == "ssm" for mixer, _ in z["layers"]):
+        say("  state-space scan: the backbone has no ssm layer, nothing to "
+            "hold")
+        return
+    h, p, n, g = (z["ssm_heads"], z["ssm_head_dim"], z["ssm_state"],
+                  z["ssm_groups"])
+    seq, chunk, bf = (size.image_size // 16) ** 2, z["ssm_chunk"], jnp.bfloat16
+    say(f"  state-space scan: ssd_formulation = "
+        f"{ssd.ssd_formulation(seq, h, p, n, bf)}, chunks of {chunk}")
+    ks = jax.random.split(jax.random.key(seed), 5)
+    u = jax.random.normal(ks[0], (1, seq, h, p)).astype(bf)
+    b_in = jax.random.normal(ks[1], (1, seq, g, n)).astype(bf)
+    c_in = jax.random.normal(ks[2], (1, seq, g, n)).astype(bf)
+    delta = 1e-3 * 100.0 ** jax.random.uniform(ks[3], (1, seq, h))
+    a = jax.random.uniform(ks[4], (h,), minval=1.0, maxval=16.0)
+    d = jnp.ones((h,))
+    got = np.asarray(jax.jit(lambda *t: ssd.ssd_chunked(
+        *t, chunk=chunk, dtype=bf))(u, delta, a, b_in, c_in, d), np.float32)
+    want = np.asarray(jax.jit(ssd.ssd_recurrent)(u, delta, a, b_in, c_in, d))
+    gap = float(np.abs(got - want).max() / np.abs(want).max())
+    say(f"  state-space scan, {seq} tokens x {h} heads of {p} on a state of "
+        f"{n}: the chunked form in bfloat16 against the token recurrence, "
+        f"widest gap {gap:.5f} of the range")
+    check(np.isfinite(got).all() and gap < 0.03,
+          "the chunked state-space scan equals the token recurrence at the "
+          "backbone's shape")
+
+
 def _vit_heads(size: Size) -> tuple:
     """(heads, head dim) of the SAM encoder ``size`` names."""
     from tmr_tpu.models.vit import VIT_CONFIGS
@@ -380,6 +421,7 @@ def decide_gates(cfg, size: Size) -> dict:
             f"{'pass' if verdicts['pallas_nms_compiled_ok'] else 'refused'}")
         verdicts["kda_chunk_ok"] = check_kda_kernel(size, seed=0)
         verdicts["latent_kernel_ok"] = check_mla_kernel(size, seed=0)
+        check_ssd_scan(size, seed=0)
         report_gates("decide")
         check_grouped_products(size, seed=0)
         return verdicts
@@ -503,6 +545,13 @@ def build_predictor(size: Size, seed: int, dtype: str = "bfloat16"):
 
 
 def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
+    """``Predictor.__call__`` and ``predict_multi_exemplar`` at the full
+    width. For a trunk of typed layers every compiled program's ``compile``
+    span must name the formulation of each kind of layer the trunk has
+    (``trunk_kda``, ``trunk_mla``, ``trunk_ssm``, ``trunk_gqa`` by its
+    mixers, ``trunk_moe``, and ``trunk_hc`` where it has streams): a trunk
+    with ``ssm`` / ``gqa`` layers and no ``kda`` / ``mla`` layer names the
+    former pair and neither of the latter."""
     import inspect
 
     import jax
@@ -582,6 +631,11 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
                     else "blocked_xla") + ("_rope" if z.get("rope") else "")
             check(all(t["trunk_mla"].startswith(want + " x") for t in traced),
                   f"every compiled program traced latent attention as {want}")
+        for kind, want in (("trunk_ssm", "chunked_xla"),
+                           ("trunk_gqa", "blocked_xla")):
+            if kind in kinds:
+                check(all(t[kind].startswith(want + " x") for t in traced),
+                      f"every compiled program traced its {kind} as {want}")
         # a float32 copy left to route by itself breaks ties otherwise and
         # sends those tokens through other experts: that comparison is the
         # benchmark cell's, where the reference follows ties (PERF.md)
@@ -969,7 +1023,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--backbone", default=FULL.backbone,
                     help="a name build_backbone knows; a trunk of typed "
-                         "layers runs the predict and serve phases")
+                         "layers (kimi_linear_a3b_share2, xing4_a4b_stage6, "
+                         "granite4_h_small_share2) runs the predict and "
+                         "serve phases")
     args = ap.parse_args(argv)
 
     import jax

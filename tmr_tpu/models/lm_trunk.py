@@ -1,8 +1,8 @@
 """A language model's trunk as the detector's backbone: a list of typed
-decoder layers (mixer ``kda`` | ``mla``, feed-forward ``dense`` | ``moe``)
-between SAM's patch embedding and SAM's neck.
+decoder layers (mixer ``kda`` | ``mla`` | ``ssm`` | ``gqa``, feed-forward
+``dense`` | ``moe``) between SAM's patch embedding and SAM's neck.
 
-Two published families are built from it (``TRUNK_CONFIGS``), each as
+Three published families are built from it (``TRUNK_CONFIGS``), each as
 published, with no bias on a linear layer, SiLU, pre-norm sub-layers and
 causal over the patches in raster order (position = raster index):
 
@@ -12,10 +12,16 @@ causal over the patches in raster order (position = raster index):
 - Xing4.0's: latent attention in every layer with a low-rank query and
   YaRN rotary on the "rope" dims (``ops/rope.py``), and in place of the
   residual add ``hc_mult`` streams mixed by manifold-constrained
-  hyper-connections (``ops/hyper_conn.py``), RMSNorm eps 1e-6.
+  hyper-connections (``ops/hyper_conn.py``), RMSNorm eps 1e-6;
+- Granite 4.0-H's: Mamba-2 state-space layers (``ops/ssd.py``) 9 : 1
+  grouped-query attention without rotary, experts chosen by the largest
+  logits and weighed by a softmax over the chosen, beside a shared MLP of a
+  width of its own, both sub-layers' outputs scaled by
+  ``residual_multiplier`` ahead of the plain add and what stands in the
+  embedding's place by ``embedding_multiplier``, RMSNorm eps 1e-5.
 
-Both feed-forwards are dense or sigmoid-routed experts with a shared
-expert. The token embedding and the output head are on no path of a
+The first two's feed-forwards are dense or sigmoid-routed experts with a
+shared expert. The token embedding and the output head are on no path of a
 detector: the patch embedding stands in the embedding's place and the neck
 in the head's. A layer that has experts is told which it holds
 (``expert_offset``, ``experts_held``): it routes over all ``num_experts``
@@ -39,7 +45,10 @@ from tmr_tpu.models.vit import apply_neck, neck_modules, patch_embed_conv
 from tmr_tpu.obs import metrics
 from tmr_tpu.ops import hyper_conn, rope as rope_ops
 from tmr_tpu.ops import moe as moe_ops
-from tmr_tpu.ops.causal_attn import (latent_attention_blocked,
+from tmr_tpu.ops import ssd as ssd_ops
+from tmr_tpu.ops.causal_attn import (causal_attention_blocked,
+                                      gqa_formulation,
+                                      latent_attention_blocked,
                                       latent_attention_kernel,
                                       mla_formulation)
 from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
@@ -51,10 +60,12 @@ from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
 STATS = "trunk_stats"
 
 #: what each mechanism traces with (counters ``trunk.<kind>.<formulation>``,
-#: copied onto the ``compile`` span). All four choose theirs by device, type
+#: copied onto the ``compile`` span). All six choose theirs by device, type
 #: and sizes: the recurrence (``ops/kda.py:kda_formulation``), latent
 #: attention (``ops/causal_attn.py:mla_formulation``, with ``_rope`` after
-#: the name where the trunk has rotary), the experts' grouped products
+#: the name where the trunk has rotary), the state-space recurrence
+#: (``ops/ssd.py:ssd_formulation``), grouped-query attention
+#: (``ops/causal_attn.py:gqa_formulation``), the experts' grouped products
 #: (``ops/moe.py:grouped_formulation``) and the hyper-connections
 #: (``ops/hyper_conn.py:hc_formulation``). ``KDA_FORMULATION`` and
 #: ``MLA_FORMULATION`` are the first two's fallbacks' names, kept as
@@ -219,6 +230,109 @@ class MLAMixer(nn.Module):
         return lin(x.shape[-1], "o_proj")(o)
 
 
+class GatedRMSNorm(nn.Module):
+    """``rmsnorm(y * silu(z)) * weight``: the gate first, then the norm over
+    the whole last axis (one group), in float32."""
+
+    eps: float = 1e-5
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, y, z):
+        weight = _weight(self, "weight", (y.shape[-1],), nn.initializers.ones)
+        f32 = jnp.float32
+        return rms_norm(y.astype(f32) * jax.nn.silu(z.astype(f32)), weight,
+                        self.eps)
+
+
+class SSMMixer(nn.Module):
+    """A Mamba-2 state-space mixer (``ops/ssd.py``): one ``in_proj`` to
+    ``[z | u B C | dt]``, a depthwise causal convolution with a bias over
+    ``[u | B | C]`` together (under ``conv/``), the recurrence of ``heads``
+    heads of ``head_dim`` on a state of ``state`` a head with ``B`` and
+    ``C`` shared by the heads of each of ``groups`` groups (under ``scan/``),
+    the gated norm over the whole inner width, ``out_proj``. ``Delta``, the
+    decay and the state are float32."""
+
+    num_heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    conv_size: int = 4
+    chunk: int = 256
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, _ = x.shape
+        h, p, n, g = self.num_heads, self.head_dim, self.state, self.groups
+        inner, f32 = h * p, jnp.float32
+        conv_dim = inner + 2 * g * n
+        lin = lambda width, name: _linear(width, self.dtype,
+                                          self.param_dtype, name)
+        zxbcdt = lin(inner + conv_dim + h, "in_proj")(x)
+        z, xbc, dt = (zxbcdt[..., :inner],
+                      zxbcdt[..., inner:inner + conv_dim],
+                      zxbcdt[..., inner + conv_dim:])
+        kernel = _weight(self, "conv_kernel", (self.conv_size, conv_dim))
+        bias = _weight(self, "conv_bias", (conv_dim,), nn.initializers.zeros)
+        with jax.named_scope("conv"):
+            xbc = jax.nn.silu(causal_conv(xbc, kernel)
+                              + bias.astype(xbc.dtype))
+        u = xbc[..., :inner].reshape(b, s, h, p)
+        b_in = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
+        c_in = xbc[..., inner + g * n:].reshape(b, s, g, n)
+        # Delta about 0.01 and A 4 a head until weights are loaded (the
+        # family draws Delta log-uniform in [0.001, 0.1], A uniform in
+        # [1, 16]: the benchmark's driver does)
+        dt_bias = _weight(self, "dt_bias", (h,),
+                          nn.initializers.constant(-4.6))
+        a_log = _weight(self, "A_log", (h,), nn.initializers.constant(1.386))
+        skip = _weight(self, "D", (h,), nn.initializers.ones)
+        delta = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        formulation = ssd_ops.ssd_formulation(s, h, p, n, self.dtype)
+        metrics.counter(f"trunk.ssm.{formulation}").inc()
+        with jax.named_scope("scan"):
+            y = ssd_ops.ssd_chunked(u, delta, jnp.exp(a_log.astype(f32)),
+                                    b_in, c_in, skip, self.chunk, self.dtype)
+        y = GatedRMSNorm(self.norm_eps, self.param_dtype, name="norm")(
+            y.reshape(b, s, inner), z)
+        return lin(x.shape[-1], "out_proj")(y.astype(self.dtype))
+
+
+class GQAMixer(nn.Module):
+    """Grouped-query causal attention without rotary: ``num_heads`` query
+    heads on ``kv_heads`` key-value heads of ``head_dim``, the scores times
+    ``scale`` (the family's ``attention_multiplier``, not ``head_dim^-1/2``),
+    softmax in float32; scores, softmax and values under ``softmax/``
+    (``ops/causal_attn.py:causal_attention_blocked``: the key-value heads are
+    not written out once a query head)."""
+
+    num_heads: int
+    kv_heads: int
+    head_dim: int
+    scale: float
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, _ = x.shape
+        h, hkv, d = self.num_heads, self.kv_heads, self.head_dim
+        lin = lambda width, name: _linear(width, self.dtype,
+                                          self.param_dtype, name)
+        q = lin(h * d, "q_proj")(x).reshape(b, s, h, d)
+        k = lin(hkv * d, "k_proj")(x).reshape(b, s, hkv, d)
+        v = lin(hkv * d, "v_proj")(x).reshape(b, s, hkv, d)
+        formulation = gqa_formulation(s, h, hkv, d, self.dtype)
+        metrics.counter(f"trunk.gqa.{formulation}").inc()
+        with jax.named_scope("softmax"):
+            o = causal_attention_blocked(q, k, v, self.scale)
+        return lin(x.shape[-1], "o_proj")(o.reshape(b, s, h * d))
+
+
 class Experts(nn.Module):
     """The held experts' stacked weights and their grouped products."""
 
@@ -243,21 +357,32 @@ class Experts(nn.Module):
 
 
 class Router(nn.Module):
+    """``kind`` ``sigmoid_bias``: sigmoid scores, chosen with a selection
+    bias, renormalised and scaled (``ops/moe.py:route``); ``softmax_topk``:
+    the largest logits, weighed by a softmax over the chosen, with neither
+    bias nor scale (``route_softmax_topk``)."""
+
     num_experts: int
     top_k: int
     scale: float
     param_dtype: Any = jnp.bfloat16
+    kind: str = "sigmoid_bias"
 
     @nn.compact
     def __call__(self, x):
         kernel = _weight(self, "kernel", (x.shape[-1], self.num_experts))
+        if self.kind == "softmax_topk":
+            return moe_ops.route_softmax_topk(x, kernel, self.top_k)
+        if self.kind != "sigmoid_bias":
+            raise KeyError(f"unknown router kind {self.kind!r}")
         bias = _weight(self, "bias", (self.num_experts,),
                        nn.initializers.zeros)
         return moe_ops.route(x, kernel, bias, self.top_k, self.scale)
 
 
 class MoEFFN(nn.Module):
-    """``shared(x) + sum over the chosen experts held here``."""
+    """``shared(x) + sum over the chosen experts held here``; the shared
+    MLP is ``shared_width`` wide, or as wide as an expert."""
 
     num_experts: int
     experts_held: int
@@ -265,6 +390,8 @@ class MoEFFN(nn.Module):
     top_k: int
     scale: float
     width: int
+    router: str = "sigmoid_bias"
+    shared_width: Optional[int] = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.bfloat16
 
@@ -284,7 +411,8 @@ class MoEFFN(nn.Module):
         b, s, d = x.shape
         tokens = x.reshape(b * s, d)
         idx, weights = Router(self.num_experts, self.top_k, self.scale,
-                              self.param_dtype, name="router")(tokens)
+                              self.param_dtype, self.router,
+                              name="router")(tokens)
         with jax.named_scope("dispatch"):
             xs, group_sizes, here, slot = moe_ops.dispatch(
                 tokens, idx, self.experts_held, self.expert_offset)
@@ -296,8 +424,8 @@ class MoEFFN(nn.Module):
                      self.param_dtype, name="experts")(xs, group_sizes)
         with jax.named_scope("dispatch"):
             routed = moe_ops.combine(ys, weights, here, slot)
-        shared = GatedMLP(self.width, self.dtype, self.param_dtype,
-                          name="shared")(x)
+        shared = GatedMLP(self.shared_width or self.width, self.dtype,
+                          self.param_dtype, name="shared")(x)
         return shared + routed.reshape(b, s, d).astype(shared.dtype)
 
 
@@ -325,13 +453,14 @@ class HyperConn(nn.Module):
 
 class TrunkLayer(nn.Module):
     """A mixer and a feed-forward, each on ``norm(x)``. With ``hc_mult`` 0
-    the residual is the plain add, ``x + f(norm(x))`` on (B, S, C); else
+    the residual is the plain add, ``x + f(norm(x))`` on (B, S, C), or with
+    a ``residual_multiplier`` ``r``, ``x + r f(norm(x))``; else
     ``x`` is ``hc_mult`` streams (n, B, S, C) and each sub-layer reads their
     ``H_pre`` mix and writes back through ``H_res`` and ``H_post``
     (``ops/hyper_conn.py``; one stream is *not* the plain add). ``sizes``
     is the trunk's own (``TRUNK_CONFIGS``)."""
 
-    mixer: str  # "kda" | "mla"
+    mixer: str  # "kda" | "mla" | "ssm" | "gqa"
     ffn: str  # "dense" | "moe"
     sizes: Any
     dtype: Any = jnp.float32
@@ -349,6 +478,13 @@ class TrunkLayer(nn.Module):
                             z["qk_pe_dim"], z["v_dim"], z["kv_rank"],
                             z["q_rank"], z["rope"], z["norm_eps"],
                             name="attn", **kw)
+        elif self.mixer == "ssm":
+            attn = SSMMixer(z["ssm_heads"], z["ssm_head_dim"],
+                            z["ssm_state"], z["ssm_groups"], z["conv_size"],
+                            z["ssm_chunk"], z["norm_eps"], name="attn", **kw)
+        elif self.mixer == "gqa":
+            attn = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
+                            z["attn_scale"], name="attn", **kw)
         else:
             raise KeyError(f"unknown mixer kind {self.mixer!r}")
         if self.ffn == "dense":
@@ -356,14 +492,17 @@ class TrunkLayer(nn.Module):
         elif self.ffn == "moe":
             mlp = MoEFFN(z["num_experts"], z["experts_held"],
                          z["expert_offset"], z["top_k"], z["routed_scale"],
-                         z["expert_width"], name="ffn", **kw)
+                         z["expert_width"], z["router"], z["shared_width"],
+                         name="ffn", **kw)
         else:
             raise KeyError(f"unknown feed-forward kind {self.ffn!r}")
         norm = lambda name: RMSNorm(z["norm_eps"], self.param_dtype,
                                     name=name)
         if not z["hc_mult"]:
-            x = x + attn(norm("norm1")(x))
-            return x + mlp(norm("norm2")(x))
+            r = z.get("residual_multiplier")  # a size newer than some callers
+            scaled = (lambda y: y) if r is None else (lambda y: r * y)
+            x = x + scaled(attn(norm("norm1")(x)))
+            return x + scaled(mlp(norm("norm2")(x)))
 
         def hyper_connected(name, x, f, norm):
             # the scopes <name>/coeff/ and <name>/mix/ hold the
@@ -393,23 +532,36 @@ class LMTrunkBackbone(nn.Module):
     """(B, S, S, 3) NHWC -> (B, S/16, S/16, out_chans): patch embedding,
     the trunk over the patches in raster order, final norm, neck. With
     ``hc_mult`` streams the patch embedding is replicated into them ahead
-    of the first layer and they are summed ahead of the final norm."""
+    of the first layer and they are summed ahead of the final norm. A trunk
+    states the sizes of the kinds of layer it has and no others."""
 
     hidden: int
     layers: Sequence[Tuple[str, str]]  # (mixer kind, ffn kind) a layer
     num_heads: int
-    qk_nope_dim: int
-    qk_pe_dim: int
-    v_dim: int
-    kv_rank: int
-    dense_width: int
     expert_width: int
     num_experts: int
     experts_held: int
     top_k: int
-    routed_scale: float
-    kda_head_dim: Optional[int] = None  # a trunk with no "kda" layer
-    conv_size: Optional[int] = None  # needs neither
+    routed_scale: float = 1.0
+    qk_nope_dim: Optional[int] = None  # "mla" layers
+    qk_pe_dim: Optional[int] = None
+    v_dim: Optional[int] = None
+    kv_rank: Optional[int] = None
+    dense_width: Optional[int] = None  # "dense" feed-forwards
+    kda_head_dim: Optional[int] = None  # "kda" layers
+    conv_size: Optional[int] = None  # "kda" and "ssm" layers
+    ssm_heads: Optional[int] = None  # "ssm" layers
+    ssm_head_dim: Optional[int] = None
+    ssm_state: Optional[int] = None
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    kv_heads: Optional[int] = None  # "gqa" layers
+    head_dim: Optional[int] = None
+    attn_scale: Optional[float] = None
+    router: str = "sigmoid_bias"  # or "softmax_topk"
+    shared_width: Optional[int] = None  # None: an expert's own width
+    residual_multiplier: Optional[float] = None  # None: x + f(norm(x))
+    embedding_multiplier: Optional[float] = None
     q_rank: Optional[int] = None  # None: one q_proj
     rope: Any = None  # None: NoPE; else YaRN's group and "theta"
     hc_mult: int = 0  # 0: the plain residual add
@@ -431,7 +583,9 @@ class LMTrunkBackbone(nn.Module):
             "qk_pe_dim", "v_dim", "kv_rank", "q_rank", "rope", "dense_width",
             "expert_width", "num_experts", "experts_held", "expert_offset",
             "top_k", "routed_scale", "hc_mult", "hc_sinkhorn_iters",
-            "hc_eps", "hc_clamp", "norm_eps")}
+            "hc_eps", "hc_clamp", "norm_eps", "ssm_heads", "ssm_head_dim",
+            "ssm_state", "ssm_groups", "ssm_chunk", "kv_heads", "head_dim",
+            "attn_scale", "router", "shared_width", "residual_multiplier")}
         self._layers = [
             TrunkLayer(mixer, ffn, sizes, self.dtype, self.param_dtype,
                        name=f"layers_{i}")
@@ -448,6 +602,8 @@ class LMTrunkBackbone(nn.Module):
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         x = self.embed(x)
+        if self.embedding_multiplier is not None:
+            x = x * self.embedding_multiplier
         b, h, w, d = x.shape
         x = x.reshape(b, h * w, d)
         if self.hc_mult:
@@ -473,9 +629,13 @@ def _pattern(n_layers: int, mla_every: int = 4, first_dense: int = 1):
 #: the two chips sharing a layer holds. ``xing4_a4b_stage6``: the published
 #: widths of Xing4.0-29B-A4B, one pipeline stage's six layers (published 2
 #: to 7: the second leading dense layer and five expert layers), every one
-#: of a layer's 64 experts held (``ep_size`` 1). (The tests and the
-#: benchmark's rehearsals add the same patterns at tiny widths under names
-#: of their own.)
+#: of a layer's 64 experts held (``ep_size`` 1).
+#: ``granite4_h_small_share2``: the published widths of Granite 4.0-H Small
+#: (32B-A9B), one period of its pattern (published layers 7 to 16: nine
+#: state-space layers and one attention layer, a pipeline stage of ten), the
+#: 36 of 72 experts that one of the two chips sharing a layer holds. (The
+#: tests and the benchmark's rehearsals add the same patterns at tiny widths
+#: under names of their own.)
 TRUNK_CONFIGS = {
     "kimi_linear_a3b_share2": dict(
         hidden=2304, layers=_pattern(5), num_heads=32, kda_head_dim=128,
@@ -491,6 +651,13 @@ TRUNK_CONFIGS = {
                   mscale=1, mscale_all_dim=1,
                   original_max_position_embeddings=4096),
         hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_clamp=(-30.0, 30.0)),
+    "granite4_h_small_share2": dict(
+        hidden=4096, layers=(("ssm", "moe"),) * 9 + (("gqa", "moe"),),
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        conv_size=4, ssm_chunk=256, num_heads=32, kv_heads=8, head_dim=128,
+        attn_scale=0.0078125, expert_width=768, shared_width=1536,
+        num_experts=72, experts_held=36, top_k=10, router="softmax_topk",
+        residual_multiplier=0.22, embedding_multiplier=12.0),
 }
 
 

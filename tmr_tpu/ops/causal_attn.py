@@ -38,19 +38,35 @@ from tmr_tpu.ops import rope as rope_ops
 
 
 def causal_attention_blocked(q, k, v, scale: float, block: int = 256):
-    """``q``, ``k`` (B, S, H, dqk), ``v`` (B, S, H, dv) -> (B, S, H, dv) in
-    ``v``'s dtype."""
-    s = q.shape[1]
+    """``q`` (B, S, H, dqk), ``k`` (B, S, Hkv, dqk), ``v`` (B, S, Hkv, dv)
+    -> (B, S, H, dv) in ``v``'s dtype. With fewer key-value heads than query
+    heads (grouped-query attention) query head ``j`` reads key-value head
+    ``j // (H / Hkv)``; the key-value heads are never written out again."""
+    b, s, h, _ = q.shape
+    hkv = k.shape[2]
+    if hkv == h:
+        to_scores, to_out = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
+    else:
+        q = q.reshape(b, s, hkv, h // hkv, -1)
+        to_scores, to_out = "bqgrd,bkgd->bgrqk", "bgrqk,bkgd->bqgrd"
     outs = []
     for lo in range(0, s, block):
         hi = min(lo + block, s)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q[:, lo:hi], k[:, :hi],
+        scores = jnp.einsum(to_scores, q[:, lo:hi], k[:, :hi],
                             preferred_element_type=jnp.float32) * scale
         after = jnp.arange(hi)[None, :] > jnp.arange(lo, hi)[:, None]
         probs = jax.nn.softmax(jnp.where(after, -jnp.inf, scores), axis=-1)
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype),
-                               v[:, :hi]))
-    return jnp.concatenate(outs, axis=1)
+        outs.append(jnp.einsum(to_out, probs.astype(v.dtype), v[:, :hi]))
+    return jnp.concatenate(outs, axis=1).reshape(b, s, h, -1)
+
+
+def gqa_formulation(seq: int, heads: int, kv_heads: int, head_dim: int,
+                    dtype) -> str:
+    """What grouped-query attention traces with (counter
+    ``trunk.gqa.<formulation>``). One answer today:
+    :func:`causal_attention_blocked`, plain XLA; a kernel gets its name and
+    its gate here."""
+    return "blocked_xla"
 
 
 def latent_attention_blocked(q, kv, k_pe, heads: int, scale: float,

@@ -1,5 +1,7 @@
-"""Sparse expert layer: a sigmoid router over all the experts of the model,
-and the part of the layer's result that the experts *held here* give.
+"""Sparse expert layer: a router over all the experts of the model (sigmoid
+scores with a selection bias, :func:`route`, or a softmax over the chosen
+experts' logits, :func:`route_softmax_topk`), and the part of the layer's
+result that the experts *held here* give.
 
 A layer is told which experts it holds (``expert_offset`` and the leading
 size of its expert weights): under expert parallelism every chip routes over
@@ -34,6 +36,17 @@ def route(x, kernel, bias, top_k: int, scale: float):
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
     return idx.astype(jnp.int32), weights
+
+
+def route_softmax_topk(x, kernel, top_k: int):
+    """The other router, in float32: ``logits = x W``; the ``top_k`` experts
+    with the largest logits are chosen and weighed by a softmax over the
+    chosen logits alone. No selection bias, no scale. Returns as
+    :func:`route`."""
+    f32 = jnp.float32
+    logits = jnp.matmul(x.astype(f32), kernel.astype(f32), precision=HI)
+    chosen, idx = lax.top_k(logits, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
 
 
 def dispatch(x, idx, experts_held: int, expert_offset: int = 0):
