@@ -286,7 +286,8 @@ ENV_KNOBS = {
     "TMR_TRACE": "span tracing on/off (default off)",
     "TMR_TRACE_RING": "per-thread span ring-buffer capacity",
     "TMR_TRACE_ANNOTATE": "mirror spans as jax.profiler annotations",
-    "TMR_GATE_DEBUG": "print gate refusals to stderr as they happen",
+    "TMR_GATE_DEBUG": "print gate refusals to stderr as they happen, and "
+    "which self-checks ran or were answered from disk",
     "TMR_FLIGHT": "performance flight recorder on/off (default off): "
         "per-program device-time/MFU attribution + request/shard ring",
     "TMR_FLIGHT_RING": "flight-recorder ring capacity (records)",

@@ -28,8 +28,10 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import os
 import threading
-from typing import Dict, List, Optional
+import warnings
+from typing import Dict, List, Optional, Tuple
 
 #: schema tag stamped on every refusal record and on the gate_probe.py
 #: --json document — bump when the record shape changes incompatibly
@@ -2111,14 +2113,9 @@ def gate_refused(
     definition of the refuse-and-say-why move every oracle gate makes
     (fused_heads / quant / postprocess use it; the older attention and
     xcorr gates predate it)."""
-    import os
-
     record_gate_refusal(gate, cause, message=reason, exception=exception,
                         config=config)
-    if os.environ.get("TMR_GATE_DEBUG"):
-        import sys
-
-        print(f"[gate] {gate}: refused — {reason}", file=sys.stderr)
+    _debug(f"{gate}: refused — {reason}")
     return False
 
 
@@ -2151,8 +2148,37 @@ def mosaic_gate(fn):
     """``functools.lru_cache`` for a gate that admits a Pallas TPU kernel,
     honouring :func:`mosaic_kernels_off` ahead of the cache (a verdict
     reached for a one-device program must not admit the kernel into a
-    partitioned one, nor the reverse)."""
-    cached = functools.lru_cache(maxsize=None)(fn)
+    partitioned one, nor the reverse).
+
+    While ``fn`` runs, this thread knows which gate is being asked and with
+    what arguments: the self-check ``fn`` reaches through
+    :func:`run_outside_trace` is then answered from the result an earlier
+    process kept beside the compile cache (see there). Everything ``fn``
+    decides ahead of that call is decided anew in every process.
+    ``cache_clear()`` also turns the kept results away for the rest of the
+    process: the next call of each argument set runs its self-check and
+    replaces the file."""
+    import inspect
+
+    _check_context()  # made while modules are imported, ahead of any trace
+    signature = inspect.signature(fn)
+    refresh = False
+
+    @functools.wraps(fn)
+    def asked(*args, **kw):
+        bound = signature.bind(*args, **kw)
+        bound.apply_defaults()
+        prev = getattr(_ASKED, "gate", None)
+        _ASKED.gate = {
+            "name": fn.__name__, "refresh": refresh,
+            "args": {k: repr(v) for k, v in bound.arguments.items()},
+        }
+        try:
+            return fn(*args, **kw)
+        finally:
+            _ASKED.gate = prev
+
+    cached = functools.lru_cache(maxsize=None)(asked)
 
     @functools.wraps(fn)
     def gate(*args, **kw):
@@ -2162,9 +2188,192 @@ def mosaic_gate(fn):
                                 config={"args": list(args), **kw})
         return cached(*args, **kw)
 
-    gate.cache_clear = cached.cache_clear
+    def cache_clear():
+        nonlocal refresh
+        refresh = True
+        cached.cache_clear()
+
+    gate.cache_clear = cache_clear
     gate.cache_info = cached.cache_info
     return gate
+
+
+# --------------------------------------------------------------------------
+# A self-check's result, kept beside the compile cache.
+#
+# A gate's compiled self-check costs a process seconds of tracing and
+# lowering that no compile cache can skip (its key is the lowered text),
+# and its answer is a function of nothing that changes between two starts
+# on one machine. So what the check returned is kept as one small file in
+# the directory of JAX's persistent cache, under a key that holds all the
+# answer depends on: the gate and its arguments, the backend, device kind,
+# jax, jaxlib and libtpu, the contents (not the paths) of the source the
+# checks run, and the trace-time knobs that source reads. Only what passed
+# is kept (``True``, or the number the gate then holds to its own tolerance
+# in every process): a refusal's cause would be lost and an exception may
+# be the machine's. Kept and taken on a TPU only, where the checks compile;
+# with no cache directory, or the cache switched off, nothing is read or
+# written. Deleting the ``tmr-gate-*.json`` files, or ``cache_clear()``,
+# asks again.
+# --------------------------------------------------------------------------
+
+GATE_RESULT_SCHEMA = "tmr_gate_result/v1"
+
+#: the :func:`mosaic_gate` call this thread is inside, if any
+_ASKED = threading.local()
+
+#: the package's directory; the digested files are named relative to it
+_PACKAGE_DIR = os.path.dirname(os.path.abspath(__file__))
+
+#: the knobs among the digested source's that change no program
+_KEY_BLIND_KNOBS = ("TMR_GATE_DEBUG",)
+
+
+@functools.lru_cache(maxsize=None)
+def _source_digest(root: str) -> Tuple[str, Tuple[str, ...]]:
+    """(sha256 over the relative names and contents of every ``.py`` under
+    ``ops/`` and ``models/`` plus this file, the ``TMR_*`` names they
+    hold), once a process: an edit to any kernel or oracle asks every gate
+    again, as it sends the programs past the compile cache."""
+    import hashlib
+    import re
+
+    names = ["diagnostics.py"]
+    for sub in ("ops", "models"):
+        for at, _, files in os.walk(os.path.join(root, sub)):
+            names += [os.path.relpath(os.path.join(at, f), root)
+                      for f in files if f.endswith(".py")]
+    sha, knobs = hashlib.sha256(), set()
+    for name in sorted(n.replace(os.sep, "/") for n in names):
+        with open(os.path.join(root, name), "rb") as f:
+            text = f.read()
+        sha.update(name.encode() + b"\0" + text + b"\0")
+        knobs.update(re.findall(rb"TMR_[A-Z0-9_]+", text))
+    return sha.hexdigest(), tuple(sorted(k.decode() for k in knobs))
+
+
+def _backend_identity() -> Optional[Dict[str, str]]:
+    """What a compiled check's answer depends on outside this repo, or None
+    off a TPU (there every Mosaic gate refuses ahead of its check, and the
+    interpreter-mode checks of tests must leave nothing behind)."""
+    import jax
+    import jaxlib
+
+    # the device's own word: tests lift a gate's backend test by patching
+    # ``jax.default_backend``, and must not be taken for a chip here
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    return {
+        "backend": "tpu", "device_kind": device.device_kind,
+        "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+        "platform_version": device.client.platform_version,
+    }
+
+
+def _gate_result_file(asked: dict) -> Tuple[Optional[str], Optional[dict]]:
+    """(file, key) of the result of the self-check that the gate ``asked``
+    (what :func:`mosaic_gate` left on this thread) is running, or (None,
+    None) where nothing is kept: a gate's second self-check (one key cannot
+    name two results), no TPU, no cache directory. Never raises."""
+    import hashlib
+    import json
+
+    if asked.get("used"):
+        return None, None
+    asked["used"] = True
+    gate = asked["name"]
+    try:
+        from tmr_tpu.utils.cache import persistent_cache_dir
+
+        where = persistent_cache_dir()
+        identity = _backend_identity() if where else None
+        if identity is None:
+            return None, None
+        digest, knobs = _source_digest(_PACKAGE_DIR)
+        key = {
+            "schema": GATE_RESULT_SCHEMA, "gate": gate,
+            "args": asked["args"], **identity, "source": digest,
+            "env": {k: os.environ[k] for k in knobs
+                    if k in os.environ and k not in _KEY_BLIND_KNOBS},
+        }
+        name = hashlib.sha256(
+            json.dumps(key, sort_keys=True).encode()).hexdigest()[:32]
+        return os.path.join(where, f"tmr-gate-{gate}-{name}.json"), key
+    except Exception as e:  # a cache is a nicety; never a crash
+        warnings.warn(f"gate results not kept: {type(e).__name__}: {e}")
+        return None, None
+
+
+def _storable(result) -> bool:
+    """A pass: ``True``, or a finite number for the gate's tolerance."""
+    import math
+
+    return result is True or (
+        type(result) in (int, float) and math.isfinite(result))
+
+
+def _read_gate_result(path: str, key: dict):
+    """The result kept at ``path`` under exactly ``key``, or None: a file
+    that is missing, torn, another key's or no pass is a miss."""
+    import json
+
+    try:
+        with open(path) as f:
+            doc = json.load(f)
+    except (OSError, ValueError):
+        return None
+    if not isinstance(doc, dict) or doc.get("key") != key:
+        return None
+    return doc["result"] if _storable(doc.get("result")) else None
+
+
+def _keep_gate_result(path: str, key: dict, result) -> None:
+    """Leave ``path`` holding ``result`` if it is a pass, else absent.
+    Written under a temporary name and renamed, so that two processes
+    cannot tear it; a directory that cannot be written is a warning."""
+    import json
+    import tempfile
+
+    try:
+        if not _storable(result):
+            if os.path.exists(path):
+                os.remove(path)
+            return
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        fd, tmp = tempfile.mkstemp(
+            dir=os.path.dirname(path), prefix=".tmr-gate-", suffix=".tmp")
+        try:
+            with os.fdopen(fd, "w") as f:
+                json.dump({"key": key, "result": result}, f, sort_keys=True)
+            os.replace(tmp, path)
+        except BaseException:
+            os.remove(tmp)
+            raise
+    except OSError as e:
+        warnings.warn(f"gate result not kept at {path}: {e}")
+
+
+_CHECK_CONTEXT = None
+_CHECK_CONTEXT_LOCK = threading.Lock()
+
+
+def _check_context():
+    """The trace context of the self-checks (``jax.make_user_context``, part
+    of every tracing cache's key), made once a process. A check runs under a
+    value of its own, so that nothing it traces is shared with a later trace:
+    JAX keeps the jaxpr of every jitted helper (``jnp.where`` and the like)
+    by shape, with the call stack of whoever traced it first, and a Mosaic
+    kernel's serialized body carries those stacks. A program traced after a
+    check would hold other bytes, and another compile-cache key, than the
+    same program in a process whose gates were answered from disk."""
+    global _CHECK_CONTEXT
+    with _CHECK_CONTEXT_LOCK:
+        if _CHECK_CONTEXT is None:
+            import jax
+
+            _CHECK_CONTEXT = jax.make_user_context()
+    return _CHECK_CONTEXT
 
 
 def run_outside_trace(fn, gate: str = ""):
@@ -2175,19 +2384,66 @@ def run_outside_trace(fn, gate: str = ""):
     on concrete values. JAX's trace state is thread-local, so a fresh
     thread starts outside every ambient trace: jitted calls there compile
     and run — Pallas kernels included, which
-    ``jax.ensure_compile_time_eval`` cannot evaluate. Exceptions
-    propagate to the caller.
+    ``jax.ensure_compile_time_eval`` cannot evaluate. It runs under a trace
+    context of its own (:func:`_check_context`), so what it traces leaves
+    no mark on a program traced after it. Exceptions propagate to the
+    caller.
+
+    Called by the :func:`mosaic_gate` gate named ``gate``, the result is
+    taken from the file an earlier process kept (above) and no thread is
+    started; else ``fn`` runs and a pass is kept. ``gate.result.disk`` /
+    ``gate.result.check`` count the two.
 
     The wait is a ``gate.selfcheck`` set-up span named for ``gate``,
     recorded on the calling thread: asked inside a program's first call,
-    it is that ``compile`` span's child (obs/tracing.py)."""
+    it is that ``compile`` span's child (obs/tracing.py). Under a
+    ``mosaic_gate`` call its ``source`` says which it was: ``"disk"``
+    (the span covers computing the key and reading the file) or
+    ``"check"``."""
     from concurrent.futures import ThreadPoolExecutor
 
+    from tmr_tpu.obs import metrics
     from tmr_tpu.obs.tracing import span
 
-    with span("gate.selfcheck", scope="setup", gate=gate), \
-            ThreadPoolExecutor(max_workers=1) as pool:
-        return pool.submit(fn).result()
+    asked = getattr(_ASKED, "gate", None)
+    if asked is not None and asked["name"] != gate:
+        asked = None  # a check of another name is not that gate's own
+    with span("gate.selfcheck", scope="setup", gate=gate) as record:
+        path, key = _gate_result_file(asked) if asked else (None, None)
+        if path is not None and not asked["refresh"]:
+            kept = _read_gate_result(path, key)
+            if kept is not None:
+                record.set_attr(source="disk")
+                metrics.counter("gate.result.disk").inc()
+                _debug(f"{gate}: answered from disk, {path}")
+                return kept
+        if asked:
+            record.set_attr(source="check")
+        metrics.counter("gate.result.check").inc()
+        context = _check_context()
+
+        def isolated():
+            with context("gate.selfcheck"):
+                return fn()
+
+        result = None  # what an exception leaves: no pass
+        try:
+            with ThreadPoolExecutor(max_workers=1) as pool:
+                result = pool.submit(isolated).result()
+        finally:
+            if path is not None:
+                _keep_gate_result(path, key, result)
+        if path is not None:
+            _debug(f"{gate}: self-check ran, result "
+                   f"{'kept at ' + path if _storable(result) else 'not kept'}")
+        return result
+
+
+def _debug(line: str) -> None:
+    if os.environ.get("TMR_GATE_DEBUG"):
+        import sys
+
+        print(f"[gate] {line}", file=sys.stderr)
 
 
 def gate_refusals() -> List[dict]:

@@ -87,3 +87,17 @@ def enable_compilation_cache() -> str | None:
         )
         return None
     return path
+
+
+def persistent_cache_dir() -> str | None:
+    """The directory JAX's persistent compilation cache uses in this
+    process, or None: none configured (``enable_compilation_cache`` not
+    called and ``JAX_COMPILATION_CACHE_DIR`` unset), or the cache switched
+    off (``JAX_ENABLE_COMPILATION_CACHE=false``). What the repo keeps
+    beside the compiled programs (``diagnostics.run_outside_trace``: the
+    results of the gates' self-checks) goes there and nowhere else."""
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    return jax.config.jax_compilation_cache_dir or None
