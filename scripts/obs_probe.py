@@ -21,6 +21,15 @@ What it runs and what it asserts:
 - **export** — the Chrome trace JSON (Perfetto-loadable) must round-trip
   ``json.loads`` with every span present.
 
+What it does not run, because the program reports it for itself in every
+process: on the offline path (``Predictor.__call__`` ->
+``detections_to_numpy``) a batch whose answer or whose copy home took 1.5
+times the smallest its program has shown is one ``[WARNING] predict: batch
+<id> <program>(<bucket>) stalled ...`` line on standard error, one count of
+``predict.batches_stalled`` in the registry this probe's reports attach, and
+``stalled`` / ``excess_s`` on that batch's ``predict.fetch`` span
+(QUICKSTART_RUN.md "Observability").
+
 Usage:  python scripts/obs_probe.py [--tiny] [--out FILE] [--trace-out FILE]
 
 ``--tiny`` (or TMR_BENCH_TINY=1) runs the CPU smoke geometry tier-1 uses

@@ -15,8 +15,11 @@ host-selected static buckets, each compiled once and cached.
 
 from __future__ import annotations
 
+import collections
 import functools
 import re
+import threading
+import time
 
 from typing import Dict, Optional, Tuple
 
@@ -30,13 +33,21 @@ from flax.traverse_util import flatten_dict
 from tmr_tpu.models import build_model
 from tmr_tpu.models.matching_net import select_capacity_bucket
 from tmr_tpu.models.lm_trunk import STATS as TRUNK_STATS
-from tmr_tpu.obs import counter, span, track_compile, track_devtime
+from tmr_tpu.obs import (
+    counter,
+    span,
+    stage_batch,
+    take_answer,
+    track_compile,
+    track_devtime,
+)
 from tmr_tpu.ops.postprocess import (
     batched_nms,
     compact_detections,
     decode_detections,
     device_tail_ok,
 )
+from tmr_tpu.utils.profiling import log_warning
 
 #: the detections' keys under which a program with expert layers returns
 #: its routing: the run-time counts [tokens x expert layers, token-expert
@@ -513,8 +524,8 @@ class Predictor:
         Returns dict boxes/scores/refs/valid as fixed-shape device arrays."""
         if self.params is None:
             raise RuntimeError("call init_params() or load params first")
-        with span("predict.stage", scope="batch", program="run_single",
-                  rows=int(image.shape[0])) as sp:
+        with span("predict.stage", scope="batch", batch=stage_batch(),
+                  program="run_single", rows=int(image.shape[0])) as sp:
             cap = self.pick_capacity(exemplars, int(image.shape[1]))
             sp.set_attr(capacity=cap)
             fn = self._get_fn(cap)
@@ -662,8 +673,8 @@ class Predictor:
             raise ValueError(
                 f"k_real={k} out of range for {len(exemplars)} exemplar rows"
             )
-        with span("predict.stage", scope="batch", program="run_multi",
-                  rows=int(image.shape[0])) as sp:
+        with span("predict.stage", scope="batch", batch=stage_batch(),
+                  program="run_multi", rows=int(image.shape[0])) as sp:
             exemplars = exemplars[:k]
             k_bucket = int(next((b for b in self.K_BUCKETS if b >= k), k))
             pad = np.tile(exemplars[-1:], (k_bucket - k, 1))  # masked below
@@ -740,7 +751,7 @@ class Predictor:
         counts. Returns fixed-slot dets with leading dim B."""
         if self.params is None:
             raise RuntimeError("call init_params() or load params first")
-        with span("predict.stage", scope="batch",
+        with span("predict.stage", scope="batch", batch=stage_batch(),
                   program="run_multi_batched",
                   rows=int(images.shape[0])) as sp:
             exemplars = jnp.asarray(exemplars)
@@ -1069,8 +1080,8 @@ class Predictor:
                     "features-arm predict_gallery needs image_size"
                 )
             size = int(image_size)
-        with span("predict.stage", scope="batch", rows=1,
-                  program="run_gallery" if features is None
+        with span("predict.stage", scope="batch", batch=stage_batch(),
+                  rows=1, program="run_gallery" if features is None
                   else "run_gallery_heads") as sp:
             rows = np.concatenate(
                 [exemplars[i, :int(k_real[i])] for i in range(n)], axis=0
@@ -1253,6 +1264,111 @@ class Predictor:
         return run
 
 
+#: a batch counts as stalled when its service time passes this many times
+#: what its program is held to at that batch size (``least_s``), or its
+#: copy home passes its own smallest by as many seconds, once that program
+#: has shown this many batches
+_STALL_RATIO = 1.5
+_STALL_AFTER = 4
+
+
+class _BatchClock:
+    """When each fetched batch's answer became ready, and from it the
+    batch's service time: ``ready_ts`` less the later of the stamp at which
+    its dispatch returned and the ``ready_ts`` of the batch fetched before
+    it. In a closed loop with one batch in flight that is the device's time
+    for the batch plus whatever the device waited. A batch the host came
+    late to (its answer was ready before the fetch asked, or the one before
+    it was while this one was in flight) has an upper bound for a service
+    time and takes no part in the stall test.
+
+    The stamps are the host's: where it *noticed* late that batch n was
+    ready, by d, batch n reads d over and batch n + 1, whose time starts at
+    that stamp, d under (on the chip: 20-120 ms, about once in two
+    minutes, on a host that shares its cores). So the smallest a program
+    is held to (``least_s``) is the smallest middle value of five
+    consecutive batches, not the smallest batch, and a sum of ``service_s``
+    less the program's ``least_s`` over consecutive batches keeps what the
+    device waited and loses the host's d."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        #: ready_ts of the batch fetched last, and whether the host came
+        #: late to it (the stamp then says when the host asked)
+        self._last = (float("-inf"), False)
+        #: (the tracked program's id, rows) -> [batches, least_s,
+        #: smallest copy seconds, the last five service_s]
+        self._best: Dict[tuple, list] = {}
+        #: (batch, its program, bucket and rows) of the two fetched last
+        self._before = collections.deque(maxlen=2)
+
+    @staticmethod
+    def _label(batch: int, sizes: dict) -> str:
+        return "batch {} {}({})".format(
+            batch, sizes.get("program"), ", ".join(
+                f"{k}={v}" for k, v in sorted(sizes.items())
+                if k != "program"))
+
+    def fetched(self, known: dict, ready: float, late: bool
+                ) -> Tuple[dict, Optional[str]]:
+        """The ``predict.fetch`` span's attributes for the batch whose
+        dispatch noted ``known`` (``obs.take_answer``), and the line to
+        report if it stalled. Called where the span's body ends."""
+        copy = time.perf_counter() - ready
+        batch, returned = known["batch"], known["returned"]
+        sizes = {k: v for k, v in known.items()
+                 if k not in ("batch", "returned", "compiled")}
+        bucket = (known["compiled"], sizes.get("rows"))
+        line = None
+        with self._lock:
+            last_ready, last_late = self._last
+            self._last = (ready, late)
+            late = late or (last_late and returned < last_ready)
+            service = ready - (returned if last_late
+                               else max(returned, last_ready))
+            attrs = dict(sizes, batch=batch, ready_ts=ready,
+                         service_s=service, late=late)
+            if not late:
+                best = self._best.setdefault(bucket, [
+                    0, service, copy, collections.deque(maxlen=5)])
+                seen, least, least_copy, recent = best
+                excess = max(service - least, copy - least_copy)
+                if (seen >= _STALL_AFTER
+                        and excess > (_STALL_RATIO - 1.0) * least):
+                    attrs.update(stalled=True, excess_s=excess)
+                    where = ("waiting for the answer"
+                             if service - least >= copy - least_copy
+                             else "copying it home")
+                    before = ", ".join(self._label(*b)
+                                       for b in self._before) or "none"
+                    line = (
+                        f"predict: {self._label(batch, sizes)} stalled "
+                        f"{excess:.3f}s {where}: service {service:.3f}s "
+                        f"against {least:.3f}s smallest, copy "
+                        f"{1e3 * copy:.2f}ms against "
+                        f"{1e3 * least_copy:.2f}ms; before it: {before}")
+                recent.append(service)
+                middle = sorted(recent)[(len(recent) - 1) // 2]
+                best[:3] = (seen + 1, min(least, middle),
+                            min(least_copy, copy))
+                attrs["least_s"] = best[1]
+            self._before.append((batch, sizes))
+        if line is not None:
+            counter("predict.batches_stalled").inc()
+        return attrs, line
+
+
+_CLOCK = _BatchClock()
+
+
+def _wait_ready(arrays: list) -> bool:
+    """Wait for an answer's arrays; True where all were ready already (the
+    host came late, and the stamp after this call is not when they were)."""
+    late = all(a.is_ready() for a in arrays if isinstance(a, jax.Array))
+    jax.block_until_ready(arrays)
+    return late
+
+
 def detections_to_numpy(dets: dict) -> list:
     """Fixed-slot device detections -> per-image ragged numpy dicts
     (the reference's pred_logits/pred_boxes/ref_points lists).
@@ -1261,13 +1377,22 @@ def detections_to_numpy(dets: dict) -> list:
     leading ``count`` slots) take the prefix-slice fast path; the host
     form scans the validity mask. Both yield identical lists."""
     # predict.fetch waits for the device and copies back; predict.unpack
-    # is the host's ragged split (obs/tracing.py: always-on batch spans)
+    # is the host's ragged split (obs/tracing.py: always-on batch spans).
+    # For an answer a tracked program returned, the fetch stamps when the
+    # answer became ready (``ready_ts``: an attribute, not a child span,
+    # which a reader of the span's self time would subtract) and carries
+    # the batch's id, program, bucket and service time (_BatchClock)
+    compacted = "count" in dets
+    fetch = ["boxes", "scores", "refs", "count" if compacted else "valid"]
+    if ROUTING_KEY in dets:
+        fetch.append(ROUTING_KEY)
+    stalled = None
     with span("predict.fetch", scope="batch") as sp:
-        boxes = np.asarray(dets["boxes"])
-        scores = np.asarray(dets["scores"])
-        refs = np.asarray(dets["refs"])
-        compacted = "count" in dets
-        keep = np.asarray(dets["count" if compacted else "valid"])
+        known = take_answer(dets)
+        if known is not None:
+            late = _wait_ready([dets[k] for k in fetch])
+            ready = time.perf_counter()
+        boxes, scores, refs, keep = (np.asarray(dets[k]) for k in fetch[:4])
         rows = int(boxes.shape[0])
         sp.set_attr(rows=rows)
         if ROUTING_KEY in dets:
@@ -1276,8 +1401,14 @@ def detections_to_numpy(dets: dict) -> list:
             for name, n in routed.items():
                 counter(name).inc(n)
             sp.set_attr(**routed)  # this batch's own, beside the totals
+        if known is not None:
+            attrs, stalled = _CLOCK.fetched(known, ready, late)
+            sp.set_attr(**attrs)
+    if stalled:
+        log_warning(stalled)
     out = []
-    with span("predict.unpack", scope="batch", rows=rows):
+    ids = {} if known is None else {"batch": known["batch"]}
+    with span("predict.unpack", scope="batch", rows=rows, **ids):
         for b in range(rows):
             if compacted:
                 # .copy(): a prefix-slice VIEW would pin the whole padded

@@ -26,6 +26,7 @@ from tmr_tpu.obs.compile import (
     compile_events_since,
     drain_compile_events,
     record_compile_event,
+    take_answer,
     track_compile,
 )
 from tmr_tpu.obs.devtime import (
@@ -73,6 +74,7 @@ from tmr_tpu.obs.tracing import (
     span,
     spans,
     spans_ns,
+    stage_batch,
     tracing_enabled,
 )
 
@@ -116,7 +118,9 @@ __all__ = [
     "span",
     "spans",
     "spans_ns",
+    "stage_batch",
     "stitch_chrome_traces",
+    "take_answer",
     "tracing_enabled",
     "track_compile",
     "track_devtime",

@@ -34,6 +34,11 @@ the same of the global blocks (``global_attn``, ``global_attn_blocks``, from
 program is called through, so it records each call as a
 ``predict.dispatch`` span (``scope="batch"``): ``Predictor.__call__``, the
 trainer's eval step and ``ServeEngine._run_batch`` all get it from here.
+The span carries the batch's id (the one ``predict.stage`` left on this
+thread, else its own), and the wrapper notes beside the answer it returns
+what it knew of it (id, program, bucket, rows, the stamp at which the
+dispatch returned) for ``detections_to_numpy`` to take
+(:func:`take_answer`), so that the fetch of a batch names the batch.
 
 The wall time is measured on the wrapped program's FIRST call, not at
 cache-insert: jit wrappers are lazy, and the first call is where trace +
@@ -43,9 +48,12 @@ built but never called records nothing.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
-from typing import Any, List, Optional
+import weakref
+from collections import OrderedDict
+from typing import Any, Iterator, List, Optional
 
 from tmr_tpu.obs import metrics as _metrics
 from tmr_tpu.obs import tracing as _tracing
@@ -78,6 +86,19 @@ _SEQ = 0
 #: "cold" (expected instance warmup), only a genuinely NEW key of a
 #: known kind is "key-change" (the storm signature)
 _SEEN_KEYS: dict = {}
+
+#: answers dispatched and not yet fetched, oldest first: ``id`` of an
+#: answer's ``boxes`` array -> (weak reference to it, what its dispatch
+#: knew). Keyed by the array and not by a key of the dict, so callers find
+#: in an answer what they found before; weak and bounded, so an answer
+#: nobody fetches (the serve engine reads its own) pins nothing and rolls
+#: off
+_MAX_ANSWERS = 64
+_ANSWERS: "OrderedDict[int, tuple]" = OrderedDict()
+#: one id a tracked program: two Predictors' programs of one name and
+#: bucket are two programs to whoever compares a batch with its program's
+#: others (inference.py:_BatchClock)
+_PROGRAM_IDS = itertools.count(1)
 
 
 def record_compile_event(kind: str, key: Any, t0: float, t1: float,
@@ -130,6 +151,42 @@ def _trunk_attrs(before: dict, after: dict) -> dict:
     return attrs
 
 
+def _detections(out) -> Iterator[dict]:
+    """The detections in a program's answer: the answer itself, or inside
+    the tuples a program with a loss or a feedback scalar returns."""
+    if isinstance(out, dict):
+        if "boxes" in out:
+            yield out
+    elif isinstance(out, tuple):
+        for part in out:
+            yield from _detections(part)
+
+
+def _note_answer(out, known: dict) -> None:
+    for dets in _detections(out):
+        boxes = dets["boxes"]
+        with _LOCK:
+            _ANSWERS.pop(id(boxes), None)  # a dead array's id, reused
+            _ANSWERS[id(boxes)] = (weakref.ref(boxes), known)
+            while len(_ANSWERS) > _MAX_ANSWERS:
+                _ANSWERS.popitem(last=False)
+
+
+def take_answer(dets) -> Optional[dict]:
+    """What the dispatch that returned ``dets`` noted of it, once: its
+    ``batch``, ``program``, bucket, ``rows``, ``compiled`` (the tracked
+    program's own id) and ``returned`` (the ``time.perf_counter`` stamp at
+    which the dispatch returned). None for
+    an answer no tracked program returned as it stands (numpy arrays, a
+    dict put together by hand) and for one that rolled off."""
+    boxes = dets.get("boxes") if isinstance(dets, dict) else None
+    with _LOCK:
+        entry = _ANSWERS.pop(id(boxes), None)
+    if entry is None or entry[0]() is not boxes:
+        return None
+    return entry[1]
+
+
 def compile_events() -> List[dict]:
     """Snapshot of recorded events (oldest first), not cleared."""
     with _LOCK:
@@ -168,50 +225,56 @@ def track_compile(fn, kind: str, key: Any,
                   batch_arg: Optional[int] = None):
     """Wrap a freshly built jitted program so its first call records a
     compile event, and every call a ``predict.dispatch`` span with the
-    program's name, its ``bucket`` and, where ``batch_arg`` names the
-    positional argument that carries the batch, its ``rows``. The
+    batch's id, the program's name, its ``bucket`` and, where ``batch_arg``
+    names the positional argument that carries the batch, its ``rows``;
+    the same is noted for the answer's fetch (:func:`take_answer`). The
     wrapped callable is what goes into the ``_compiled`` cache, so every
     consumer sees the same accounting exactly once per cache entry."""
     done: List[bool] = []
     lock = threading.Lock()
     static = dict(bucket or {}, program=getattr(fn, "__name__", kind))
+    compiled = next(_PROGRAM_IDS)
 
     def wrapped(*args, **kw):
-        attrs = dict(static)
+        attrs = dict(static, batch=_tracing.take_batch())
         if batch_arg is not None:
             attrs["rows"] = int(args[batch_arg].shape[0])
         with _tracing.span("predict.dispatch", scope="batch", **attrs):
-            if done:
-                return fn(*args, **kw)
-            with _tracing.span("compile", scope="setup", kind=kind,
-                               key=repr(key)) as sp:
-                # models/vit.py counts each windowed block it traces by
-                # the formulation taken: the difference over the first
-                # call is this program's own
-                counts = _metrics.get_registry().counters
-                before = {"win_attn": counts(_WIN_ATTN),
-                          "global_attn": counts(_GLOBAL_ATTN)}
-                before_trunk = counts(_TRUNK)
-                t0 = time.perf_counter()
-                out = fn(*args, **kw)
-                t1 = time.perf_counter()
-                for attr, prefix in (("win_attn", _WIN_ATTN),
-                                     ("global_attn", _GLOBAL_ATTN)):
-                    traced = {n: v - before[attr].get(n, 0)
-                              for n, v in counts(prefix).items()
-                              if v > before[attr].get(n, 0)}
-                    if traced:
-                        sp.set_attr(**{
-                            attr: "+".join(sorted(traced)),
-                            f"{attr}_blocks": sum(traced.values())})
-                sp.set_attr(**_trunk_attrs(before_trunk, counts(_TRUNK)))
-                with lock:
-                    if not done:
-                        done.append(True)
-                        rec = record_compile_event(kind, key, t0, t1,
-                                                   bucket=bucket)
-                        sp.set_attr(cause=rec["cause"])
-            return out
+            out = fn(*args, **kw) if done else first_call(args, kw)
+            _note_answer(out, dict(attrs, compiled=compiled,
+                                   returned=time.perf_counter()))
+        return out
+
+    def first_call(args, kw):
+        with _tracing.span("compile", scope="setup", kind=kind,
+                           key=repr(key)) as sp:
+            # models/vit.py counts each windowed block it traces by
+            # the formulation taken: the difference over the first
+            # call is this program's own
+            counts = _metrics.get_registry().counters
+            before = {"win_attn": counts(_WIN_ATTN),
+                      "global_attn": counts(_GLOBAL_ATTN)}
+            before_trunk = counts(_TRUNK)
+            t0 = time.perf_counter()
+            out = fn(*args, **kw)
+            t1 = time.perf_counter()
+            for attr, prefix in (("win_attn", _WIN_ATTN),
+                                 ("global_attn", _GLOBAL_ATTN)):
+                traced = {n: v - before[attr].get(n, 0)
+                          for n, v in counts(prefix).items()
+                          if v > before[attr].get(n, 0)}
+                if traced:
+                    sp.set_attr(**{
+                        attr: "+".join(sorted(traced)),
+                        f"{attr}_blocks": sum(traced.values())})
+            sp.set_attr(**_trunk_attrs(before_trunk, counts(_TRUNK)))
+            with lock:
+                if not done:
+                    done.append(True)
+                    rec = record_compile_event(kind, key, t0, t1,
+                                               bucket=bucket)
+                    sp.set_attr(cause=rec["cause"])
+        return out
 
     wrapped.__wrapped__ = fn
     return wrapped

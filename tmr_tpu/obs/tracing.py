@@ -16,6 +16,11 @@ Every span has a ``scope``, and the scope decides whether it is recorded:
   untraced run too, its end-to-end comparison prices them.
 - ``"request"`` (the default) is recorded only under ``TMR_TRACE=1``.
 
+The Predictor's four spans of one batch share an id, ``batch``
+(:func:`stage_batch` at ``predict.stage``, :func:`take_batch` at
+``predict.dispatch``; obs/compile.py carries it to the fetch of that
+batch's answer, which may come after the next batch's dispatch).
+
 Exports:
 
 - :func:`chrome_trace` — Chrome trace-event JSON (open in Perfetto /
@@ -111,6 +116,9 @@ def _resolve_env() -> None:
 _REG_LOCK = threading.Lock()
 _ALL_BUFS: List["_Buf"] = []
 _SPAN_IDS = itertools.count(1)  # .__next__ is atomic under the GIL
+#: a batch's identity: one process-wide increasing integer shared by the
+#: four ``predict.*`` spans of a batch (obs/compile.py joins them)
+_BATCH_IDS = itertools.count(1)
 
 #: resolved jax.profiler.TraceAnnotation class, None = not yet resolved,
 #: False = unavailable/disabled
@@ -178,6 +186,8 @@ class _Buf:
 
 class _Local(threading.local):
     buf: Optional[_Buf] = None
+    #: the batch id ``predict.stage`` left for this thread's next dispatch
+    batch: Optional[int] = None
 
 
 _TLS = _Local()
@@ -210,6 +220,21 @@ def next_span_id() -> int:
     open; obs/fleetobs.py). Ids are process-local: cross-process
     consumers must key by (process, span)."""
     return next(_SPAN_IDS)
+
+
+def stage_batch() -> int:
+    """Mint a batch's id at its ``predict.stage`` and leave it for the next
+    ``predict.dispatch`` on this thread (:func:`take_batch`)."""
+    _TLS.batch = batch = next(_BATCH_IDS)
+    return batch
+
+
+def take_batch() -> int:
+    """The id the stage on this thread left, once; a dispatch that no stage
+    came before (the trainer's eval step, ``ServeEngine._run_batch``) mints
+    its own."""
+    batch, _TLS.batch = _TLS.batch, None
+    return next(_BATCH_IDS) if batch is None else batch
 
 
 def configure(enabled: Optional[bool] = None,
@@ -359,10 +384,20 @@ def spans_ns(names=None) -> List[list]:
     ``time.perf_counter_ns``'s clock, oldest first; ``names`` keeps only
     those names. The shape of ``benchmarks/trace.py:Spans.records``, so a
     driver can hand the program's spans to ``trace.gaps_add`` as they
-    are."""
-    return [[r["name"], round(r["ts"] * 1e9),
-             round((r["ts"] + r["dur"]) * 1e9)]
-            for r in spans() if names is None or r["name"] in names]
+    are. A span that stamped when its answer became ready (``ready_ts``:
+    ``predict.fetch``) is followed by its two halves, ``<name>.wait`` up
+    to the stamp and ``<name>.copy`` from it, so that a gap can be given
+    to the half it fell in."""
+    rows = []
+    for r in spans():
+        t0, t1 = round(r["ts"] * 1e9), round((r["ts"] + r["dur"]) * 1e9)
+        rows.append([r["name"], t0, t1])
+        ready = r["attrs"].get("ready_ts")
+        if ready is not None:
+            ready = round(ready * 1e9)
+            rows.append([r["name"] + ".wait", t0, ready])
+            rows.append([r["name"] + ".copy", ready, t1])
+    return [row for row in rows if names is None or row[0] in names]
 
 
 def dropped_spans() -> int:
