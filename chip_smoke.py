@@ -236,6 +236,55 @@ def check_grouped_products(size: Size, seed: int, batch: int = 2) -> None:
                       "the backbone's sizes")
 
 
+def check_pairs_kernels(size: Size, seed: int, batch: int = 2) -> bool:
+    """The row kernels that bring the experts' results to their tokens on
+    the chip (``ops/moe.py:sum_rows``), which off the chip run only in the
+    interpreter: held here to ``combine`` as XLA runs it, at the backbone's
+    own tokens, choices a token and width, with the share of the experts it
+    holds, and garbage in the rows past the groups' total. Returns whether
+    a trace of the backbone takes the kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
+    from tmr_tpu.ops import moe
+
+    z = TRUNK_CONFIGS[size.backbone]
+    d, k, held = z["hidden"], z["top_k"], z["experts_held"]
+    tokens, bf = batch * (size.image_size // 16) ** 2, jnp.bfloat16
+    formulation = moe.pairs_formulation(tokens * k, k, d, bf)
+    say(f"  pairs: pairs_formulation({tokens * k}, {k}, {d}, bfloat16) = "
+        f"{formulation}")
+    if formulation != "row_dma":
+        return False
+    keys = jax.random.split(jax.random.key(seed), 4)
+    x = jax.random.normal(keys[0], (tokens, d)).astype(bf)
+    _, idx = jax.lax.top_k(
+        jax.random.normal(keys[1], (tokens, z["num_experts"])), k)
+    weights = jax.random.uniform(keys[2], (tokens, k), minval=0.05)
+    ys = jax.random.normal(keys[3], (tokens * k, d)).astype(bf)
+
+    @jax.jit
+    def gap(x, idx, weights, ys):
+        _, sizes, here, slot = moe.dispatch(x, idx, held)
+        total = sizes.sum()
+        ys = jnp.where((jnp.arange(tokens * k) < total)[:, None], ys, jnp.nan)
+        got = moe.combine(ys, weights, here, slot, "row_dma")
+        want = moe.combine(ys, weights, here, slot)
+        return (total, jnp.isfinite(got).all(),
+                jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    total, finite, widest = (float(v) for v in gap(x, idx, weights, ys))
+    say(f"  pairs, {tokens} tokens x {k} of {d}, {int(total)} of "
+        f"{tokens * k} rows held here, NaN past them: the kernels' sums "
+        f"against combine's, widest gap {widest:.2g} of the range (float32 "
+        f"sums of the same bfloat16 rows)")
+    check(bool(finite) and widest < 1e-5,
+          "the row kernels' weighted sums equal combine's at the backbone's "
+          "shape and no row past the total reaches them")
+    return True
+
+
 def check_kda_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     """The recurrence's Pallas kernel (``ops/kda.py:kda_chunk_kernel``),
     which off the chip runs only in the interpreter: held here to
@@ -424,6 +473,7 @@ def decide_gates(cfg, size: Size) -> dict:
         check_ssd_scan(size, seed=0)
         report_gates("decide")
         check_grouped_products(size, seed=0)
+        verdicts["pairs_kernels_ok"] = check_pairs_kernels(size, seed=0)
         return verdicts
     num_heads, head_dim = _vit_heads(size)
     grid = size.image_size // 16
@@ -549,9 +599,11 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
     width. For a trunk of typed layers every compiled program's ``compile``
     span must name the formulation of each kind of layer the trunk has
     (``trunk_kda``, ``trunk_mla``, ``trunk_ssm``, ``trunk_gqa`` by its
-    mixers, ``trunk_moe``, and ``trunk_hc`` where it has streams): a trunk
-    with ``ssm`` / ``gqa`` layers and no ``kda`` / ``mla`` layer names the
-    former pair and neither of the latter."""
+    mixers, ``trunk_moe``, ``trunk_pairs``, and ``trunk_hc`` where it has
+    streams): a trunk with ``ssm`` / ``gqa`` layers and no ``kda`` / ``mla``
+    layer names the former pair and neither of the latter; its experts'
+    pairs travel by ``row_dma`` where ``check_pairs_kernels`` found the
+    gate's yes."""
     import inspect
 
     import jax
@@ -616,7 +668,8 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
                   for r in obs.spans() if r["name"] == "compile"]
         traced = [t for t in traced if t]
         say(f"  formulations the compiled programs traced: {traced}")
-        kinds = {f"trunk_{mixer}" for mixer, _ in z["layers"]} | {"trunk_moe"}
+        kinds = {f"trunk_{mixer}" for mixer, _ in z["layers"]} | {
+            "trunk_moe", "trunk_pairs"}
         if z.get("hc_mult"):
             kinds.add("trunk_hc")
         check(traced and all(kinds <= set(t) for t in traced),
@@ -631,6 +684,11 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
                     else "blocked_xla") + ("_rope" if z.get("rope") else "")
             check(all(t["trunk_mla"].startswith(want + " x") for t in traced),
                   f"every compiled program traced latent attention as {want}")
+        want = "row_dma" if verdicts.get("pairs_kernels_ok") else "xla_gather"
+        say("  trunk_pairs of every compiled program: "
+            f"{[t.get('trunk_pairs') for t in traced]}")
+        check(all(t["trunk_pairs"].startswith(want + " x") for t in traced),
+              f"every compiled program moves its experts' pairs by {want}")
         for kind, want in (("trunk_ssm", "chunked_xla"),
                            ("trunk_gqa", "blocked_xla")):
             if kind in kinds:

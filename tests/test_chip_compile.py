@@ -70,7 +70,7 @@ def as_tpu(monkeypatch):
     (no interpret mode, TPU defaults), and the gates the ``auto`` path asks
     answer yes — their self-checks execute, which only a chip can."""
     from tmr_tpu.diagnostics import mosaic_gate
-    from tmr_tpu.ops import causal_attn, flash_attn, kda, pallas_attn
+    from tmr_tpu.ops import causal_attn, flash_attn, kda, moe, pallas_attn
     from tmr_tpu.ops import pallas_nms
 
     def admits(name):
@@ -88,6 +88,7 @@ def as_tpu(monkeypatch):
                       (pallas_attn, "packed_global_ok"),
                       (kda, "kda_chunk_ok"),
                       (causal_attn, "latent_kernel_ok"),
+                      (moe, "pairs_kernels_ok"),
                       (pallas_nms, "pallas_nms_compiled_ok")):
         monkeypatch.setattr(mod, name, admits(name))
 
@@ -171,6 +172,21 @@ def _case_kda_chunk(sds):
         f32, f32, sds(shape, jnp.bfloat16), f32, sds(shape[:3], jnp.float32))
 
 
+def _pairs_case(tokens, k, d):
+    """The row kernels that bring the experts' results to their tokens
+    (``ops/moe.py:sum_rows``) on what ``MoEFFN`` holds: the products'
+    (tokens x k, d) bfloat16 result, the weights, who is held here and each
+    pair's row."""
+    from tmr_tpu.ops import moe
+
+    def case(sds):
+        return moe.sum_rows, (
+            sds((tokens * k, d), jnp.bfloat16), sds((tokens, k), jnp.float32),
+            sds((tokens, k), jnp.bool_), sds((tokens, k), jnp.int32))
+
+    return case
+
+
 def _latent_case(rope):
     """Latent attention's kernel on what ``MLAMixer`` holds in both trunk
     cells: 4 images of 4,096 tokens, 32 heads of 128 + 64 / 128, q, kv and
@@ -252,6 +268,12 @@ CASES = {
     "kda_chunk_kimi": _case_kda_chunk,
     "latent_kernel_kimi": _latent_case(rope=False),
     "latent_kernel_xing": _latent_case(rope=True),
+    # a cell's tokens a batch, choices a token and hidden width; and one
+    # image of the 1536 bucket, which no cell runs
+    "pairs_kimi": _pairs_case(4 * 4096, 8, 2304),
+    "pairs_xing": _pairs_case(4 * 4096, 4, 3584),
+    "pairs_granite": _pairs_case(2 * 4096, 10, 4096),
+    "pairs_granite_1536": _pairs_case(9216, 10, 4096),
     "nms": _case_nms,
     "int8_matmul": _case_int8_matmul,
     "predict_program": _case_predict_program,
@@ -381,6 +403,45 @@ def test_kda_layer_keeps_a_chunk_on_the_chip(one_chip, as_tpu):
     assert "custom-call" in under_scan, under_scan
     assert not {"transpose", "concatenate", "pad"} & set(under_scan), \
         sorted(set(under_scan))
+
+
+def test_expert_layer_brings_its_results_home_by_two_kernels(one_chip,
+                                                              as_tpu):
+    """One expert layer at ``granite4h_fscd147.eval``'s widths and batch,
+    compiled for the v5e: under ``dispatch/`` the program holds the two row
+    kernels and XLA's one gather of the rows in, and nothing there is as
+    large as the pairs in float32 (the (tokens, k, D) relayout that the XLA
+    form of ``combine`` costs on a TPU, 2 GB a layer and batch)."""
+    import re
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, MoEFFN
+
+    z = TRUNK_CONFIGS["granite4_h_small_share2"]
+    tokens, d, k = 2 * 4096, z["hidden"], z["top_k"]
+    layer = MoEFFN(z["num_experts"], z["experts_held"], 0, k,
+                   z.get("routed_scale", 1.0), z["expert_width"],
+                   z.get("router", "sigmoid_bias"), z.get("shared_width"),
+                   jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 4096, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.key(0), x))
+    text = jax.jit(lambda p, x: layer.apply(p, x, mutable=["trunk_stats"])[
+        0]).lower(params, x).compile().as_text()
+    under = re.findall(
+        r"= (\w+)\[([\d,]*)\]\S* ([\w\-]+)\([^\n]*op_name=\"[^\"]*"
+        r"MoEFFN/dispatch/", text)
+    kernels = [shape for _, shape, op in under if op == "custom-call"
+               and shape in (f"{tokens * k + 512},1,{d // 2}",
+                             f"{tokens},{d}")]
+    assert len(kernels) == 2, kernels
+    pairs_f32 = tokens * k * d * 4
+    import math
+
+    sizes = {(dtype, shape): math.prod(int(n) for n in shape.split(","))
+             * {"f32": 4, "u32": 4, "s32": 4, "bf16": 2}.get(dtype, 1)
+             for dtype, shape, _ in under if shape}
+    assert max(sizes.values()) < pairs_f32, max(sizes, key=sizes.get)
 
 
 @pytest.mark.parametrize("family", ["kimi_linear_a3b_share2",

@@ -378,6 +378,7 @@ def test_through_predictor_call_with_counters_and_compile_span(tiny_f32):
     assert attrs["trunk_ssm"] == "chunked_xla x2"
     assert attrs["trunk_gqa"] == "blocked_xla x1"
     assert attrs["trunk_moe"] == "ragged_dot x3"  # gmm on a TPU in bfloat16
+    assert attrs["trunk_pairs"] == "xla_gather x3"  # row_dma there
     assert attrs["experts_held"] == 4
     assert "trunk_kda" not in attrs and "trunk_mla" not in attrs
 

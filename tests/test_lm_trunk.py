@@ -407,6 +407,7 @@ def test_through_predictor_call_with_counters_and_compile_span(tiny_f32):
     assert attrs["trunk_kda"] == "chunked_xla x4"
     assert attrs["trunk_mla"] == "blocked_xla x1"
     assert attrs["trunk_moe"] == "ragged_dot x4"  # gmm on a TPU in bfloat16
+    assert attrs["trunk_pairs"] == "xla_gather x4"  # row_dma there
     assert attrs["experts_held"] == 4
 
 
@@ -422,6 +423,29 @@ def test_scopes_name_the_layers_the_metrics_match(tiny_f32):
                   "backbone/layers_1/ffn/experts/",
                   "backbone/layers_1/ffn/shared/"):
         assert scope in text, scope
+
+
+def test_scopes_hold_every_operation_of_the_row_kernels(monkeypatch):
+    """What ``trunk.moe_dispatch.ms`` owns on the chip: with the results
+    brought to their tokens by row DMAs (here in the interpreter, at the
+    narrowest trunk the kernels take) every operation of the two kernels and
+    of what feeds them carries ``ffn/dispatch/`` in the compiled program's
+    ``op_name``."""
+    import re
+
+    wide = dict(TRUNK_CONFIGS[TINY], hidden=256,
+                layers=(("kda", "dense"), ("kda", "moe")))
+    monkeypatch.setitem(TRUNK_CONFIGS, "kimi_linear_tiny_wide", wide)
+    monkeypatch.setattr(moe, "pairs_formulation", lambda *a: "row_dma")
+    trunk = build_lm_trunk("kimi_linear_tiny_wide", dtype=jnp.bfloat16)
+    x = jnp.zeros((1, 256, 256, 3), jnp.bfloat16)  # 256 tokens, 2 a token
+    params = jax.eval_shape(trunk.init, jax.random.key(0), x)
+    text = jax.jit(trunk.apply).lower(params, x).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    kernels = [n for n in names if "_rows_impl" in n or "_pack_impl" in n]
+    assert sum("_pack_impl" in n for n in kernels) > 5
+    assert sum("_sum_rows_impl" in n for n in kernels) > 10
+    assert all("layers_1/ffn/dispatch/" in n for n in kernels), kernels
 
 
 def test_sam_vit_keeps_its_leaf_names_beside_the_shared_stem():

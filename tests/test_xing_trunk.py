@@ -357,6 +357,7 @@ def test_through_predictor_call_with_counters_and_compile_span(tiny_f32):
     assert attrs["trunk_hc"] == "xla x12"
     assert attrs["trunk_mla"] == "blocked_xla_rope x6"
     assert attrs["trunk_moe"] == "ragged_dot x5"  # gmm on a TPU in bfloat16
+    assert attrs["trunk_pairs"] == "xla_gather x5"  # row_dma there
     assert attrs["experts_held"] == 8
     assert "trunk_kda" not in attrs
 

@@ -413,6 +413,9 @@ class MoEFFN(nn.Module):
         idx, weights = Router(self.num_experts, self.top_k, self.scale,
                               self.param_dtype, self.router,
                               name="router")(tokens)
+        pairs = moe_ops.pairs_formulation(b * s * self.top_k, self.top_k, d,
+                                          self.dtype)
+        metrics.counter(f"trunk.pairs.{pairs}").inc()
         with jax.named_scope("dispatch"):
             xs, group_sizes, here, slot = moe_ops.dispatch(
                 tokens, idx, self.experts_held, self.expert_offset)
@@ -423,7 +426,7 @@ class MoEFFN(nn.Module):
         ys = Experts(self.experts_held, self.width, self.dtype,
                      self.param_dtype, name="experts")(xs, group_sizes)
         with jax.named_scope("dispatch"):
-            routed = moe_ops.combine(ys, weights, here, slot)
+            routed = moe_ops.combine(ys, weights, here, slot, pairs)
         shared = GatedMLP(self.shared_width or self.width, self.dtype,
                           self.param_dtype, name="shared")(x)
         return shared + routed.reshape(b, s, d).astype(shared.dtype)

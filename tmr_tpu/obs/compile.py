@@ -134,15 +134,15 @@ def record_compile_event(kind: str, key: Any, t0: float, t1: float,
 
 def _trunk_attrs(before: dict, after: dict) -> dict:
     """The ``compile`` span's ``trunk_kda`` / ``trunk_mla`` / ``trunk_ssm`` /
-    ``trunk_gqa`` / ``trunk_moe`` (formulation x layers traced),
-    ``trunk_hc`` (x sub-layers) and ``experts_held``, from what the
+    ``trunk_gqa`` / ``trunk_moe`` / ``trunk_pairs`` (formulation x layers
+    traced), ``trunk_hc`` (x sub-layers) and ``experts_held``, from what the
     trace-time counters ``trunk.*`` gained over a program's first call."""
     traced = {n: v - before.get(n, 0)  # names come without the prefix
               for n, v in after.items() if v > before.get(n, 0)}
     attrs = {}
     for name, count in sorted(traced.items()):
         kind, _, formulation = name.partition(".")
-        if (kind in ("kda", "mla", "ssm", "gqa", "moe", "hc")
+        if (kind in ("kda", "mla", "ssm", "gqa", "moe", "pairs", "hc")
                 and formulation not in _RUN_TIME):
             attrs[f"trunk_{kind}"] = f"{formulation} x{count}"
     layers = sum(c for n, c in traced.items() if n.startswith("moe."))
