@@ -404,6 +404,49 @@ def check_mla_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     return True
 
 
+def check_gqa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
+    """Grouped-query attention's Pallas kernel
+    (``ops/causal_attn.py:gqa_attention_kernel``), which off the chip runs
+    only in the interpreter: held here to the blocked XLA form at the
+    backbone's own shape (its query and key-value heads, their width, its
+    tokens, its scale). Returns whether a trace of the backbone takes the
+    kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
+    from tmr_tpu.ops import causal_attn
+
+    z = TRUNK_CONFIGS[size.backbone]
+    if not any(mixer == "gqa" for mixer, _ in z["layers"]):
+        say("  grouped-query attention: the backbone has no gqa layer, "
+            "nothing to hold")
+        return False
+    h, hkv, d = z["num_heads"], z["kv_heads"], z["head_dim"]
+    seq, bf = (size.image_size // 16) ** 2, jnp.bfloat16
+    formulation = causal_attn.gqa_formulation(seq, h, hkv, d, bf)
+    say(f"  grouped-query attention: gqa_formulation({seq}, {h}, {hkv}, {d}, "
+        f"bfloat16) = {formulation}")
+    if formulation != "causal_kernel":
+        return False
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (batch, seq, h * d)).astype(bf)
+    k = jax.random.normal(ks[1], (batch, seq, hkv * d)).astype(bf)
+    v = jax.random.normal(ks[2], (batch, seq, hkv * d)).astype(bf)
+    run = lambda fn: np.asarray(jax.jit(
+        lambda *a: fn(*a, h, hkv, z["attn_scale"]))(q, k, v), np.float32)
+    got = run(causal_attn.gqa_attention_kernel)
+    want = run(causal_attn.gqa_attention_blocked)
+    gap = float(np.abs(got - want).max() / np.abs(want).max())
+    say(f"  grouped-query attention, {batch} x {seq} tokens x {h} heads on "
+        f"{hkv} of {d}: the kernel against the blocked form, widest gap "
+        f"{gap:.5f} of the range (bfloat16 on both sides)")
+    check(np.isfinite(got).all() and gap < 0.02,
+          "grouped-query attention's kernel equals the blocked form at the "
+          "backbone's shape")
+    return True
+
+
 def check_ssd_scan(size: Size, seed: int) -> None:
     """The state-space recurrence's chunked form (``ops/ssd.py:ssd_chunked``)
     in bfloat16 at the backbone's own shape (its heads, its state, its chunk,
@@ -470,6 +513,7 @@ def decide_gates(cfg, size: Size) -> dict:
             f"{'pass' if verdicts['pallas_nms_compiled_ok'] else 'refused'}")
         verdicts["kda_chunk_ok"] = check_kda_kernel(size, seed=0)
         verdicts["latent_kernel_ok"] = check_mla_kernel(size, seed=0)
+        verdicts["gqa_kernel_ok"] = check_gqa_kernel(size, seed=0)
         check_ssd_scan(size, seed=0)
         report_gates("decide")
         check_grouped_products(size, seed=0)
@@ -604,8 +648,9 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
     and no ``kda`` / ``mla`` layer names the former pair and neither of the
     latter; the state-space, grouped-query (``_rope`` where the trunk has
     rotary there), short-convolution and pairs' attributes each name their
-    layers' count; the experts' pairs travel by ``row_dma`` where
-    ``check_pairs_kernels`` found the gate's yes."""
+    layers' count; grouped-query attention is ``causal_kernel`` where
+    ``check_gqa_kernel`` found the gate's yes and the experts' pairs travel
+    by ``row_dma`` where ``check_pairs_kernels`` did."""
     import inspect
 
     import jax
@@ -691,11 +736,11 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
         say("  trunk_pairs of every compiled program: "
             f"{[t.get('trunk_pairs') for t in traced]}")
         layers = lambda mixer: sum(m == mixer for m, _ in z["layers"])
+        gqa = ("causal_kernel" if verdicts.get("gqa_kernel_ok")
+               else "blocked_xla") + ("_rope" if z.get("rope_theta") else "")
         for kind, want in (
                 ("trunk_ssm", f"chunked_xla x{layers('ssm')}"),
-                ("trunk_gqa", "blocked_xla" + ("_rope" if z.get("rope_theta")
-                                               else "")
-                 + f" x{layers('gqa')}"),
+                ("trunk_gqa", f"{gqa} x{layers('gqa')}"),
                 ("trunk_short_conv", f"xla x{layers('short_conv')}")):
             if kind in kinds:
                 check(all(t[kind] == want for t in traced),

@@ -88,6 +88,7 @@ def as_tpu(monkeypatch):
                       (pallas_attn, "packed_global_ok"),
                       (kda, "kda_chunk_ok"),
                       (causal_attn, "latent_kernel_ok"),
+                      (causal_attn, "gqa_kernel_ok"),
                       (moe, "pairs_kernels_ok"),
                       (pallas_nms, "pallas_nms_compiled_ok")):
         monkeypatch.setattr(mod, name, admits(name))
@@ -206,6 +207,23 @@ def _latent_case(rope):
     return case
 
 
+def _gqa_case(batch, seq, head_dim):
+    """Grouped-query attention's kernel on what ``GQAMixer`` holds: 32
+    query heads on 8 key-value heads of ``head_dim``, q, k and v as the
+    projections (and the mixer's norms and rotation) write them."""
+    from tmr_tpu.ops.causal_attn import gqa_attention_kernel
+
+    def case(sds):
+        h, hkv = 32, 8
+        return (lambda *a: gqa_attention_kernel(*a, h, hkv,
+                                                head_dim ** -0.5)), (
+            sds((batch, seq, h * head_dim), jnp.bfloat16),
+            sds((batch, seq, hkv * head_dim), jnp.bfloat16),
+            sds((batch, seq, hkv * head_dim), jnp.bfloat16))
+
+    return case
+
+
 def _case_nms(sds):
     """The decode tail's shape: 2 images x max_detections slots, vmapped as
     postprocess.batched_nms does."""
@@ -268,6 +286,12 @@ CASES = {
     "kda_chunk_kimi": _case_kda_chunk,
     "latent_kernel_kimi": _latent_case(rope=False),
     "latent_kernel_xing": _latent_case(rope=True),
+    # lfm2moe_fscd147.eval: 4 images, heads of 64; granite4h_fscd147.eval:
+    # 2 images, heads of 128; one image of the 1536 bucket, which no cell
+    # runs (its VMEM ask, 43 MiB)
+    "gqa_kernel_lfm2": _gqa_case(4, 4096, 64),
+    "gqa_kernel_granite": _gqa_case(2, 4096, 128),
+    "gqa_kernel_1536": _gqa_case(1, 9216, 64),
     # a cell's tokens a batch, choices a token and hidden width; and one
     # image of the 1536 bucket, which no cell runs
     "pairs_kimi": _pairs_case(4 * 4096, 8, 2304),
@@ -482,6 +506,53 @@ def test_mla_layer_keeps_its_scores_on_the_chip(family, one_chip, as_tpu):
             elems *= int(d)
         assert m.group(2) not in ("transpose", "copy", "concatenate",
                                   "pad") or elems < q_elems, m.group(0)[:300]
+
+
+@pytest.mark.parametrize("family,batch", [("granite4_h_small_share2", 2),
+                                          ("lfm2_24b_a2b_share8", 4)])
+def test_gqa_layer_keeps_its_scores_on_the_chip(family, batch, one_chip,
+                                                as_tpu):
+    """One grouped-query attention layer at a trunk cell's widths and
+    batch, compiled for the v5e: scores, softmax and values are one Mosaic
+    kernel and nothing else under ``attn/softmax/``, and no operand the size
+    of k or larger is copied, transposed, concatenated or padded by the
+    entry computation under ``attn/`` but by the rotation: heads of 64
+    turned as (B, S, H, 64) are copied once to the (B, S, H x 64) the kernel
+    reads (q and k: a relayout the tiling makes; no rotation, no copy)."""
+    import re
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, GQAMixer
+
+    z = TRUNK_CONFIGS[family]
+    mixer = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
+                     z["attn_scale"], dtype=jnp.bfloat16,
+                     qk_norm=z.get("qk_norm", False),
+                     rope_theta=z.get("rope_theta"), name="attn")
+    s = _KDA["seq"]
+    x = jax.ShapeDtypeStruct((batch, s, z["hidden"]), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), x))
+    text = jax.jit(mixer.apply).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    under_softmax = {m.group(1) for m in re.finditer(
+        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*attn/softmax/", text)}
+    assert under_softmax == {"custom-call"}, under_softmax
+    k_elems = batch * s * z["kv_heads"] * z["head_dim"]
+    moved = []  # by the entry computation: what the device runs
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* ([\w\-]+)\([^\n]*"
+                         r"op_name=\"[^\"]*attn/(\w+)",
+                         text[text.index("\nENTRY"):]):
+        elems = 1
+        for d in filter(None, m.group(1).split(",")):
+            elems *= int(d)
+        if m.group(2) in ("transpose", "copy", "concatenate", "pad") \
+                and elems >= k_elems:
+            moved.append((m.group(2), m.group(3), elems))
+    want = [("copy", "rope", k_elems * 4), ("copy", "rope", k_elems)] \
+        if z.get("rope_theta") else []
+    assert sorted(moved, reverse=True) == want, moved
 
 
 def _shallow_vit_b(cfg):

@@ -383,7 +383,8 @@ def test_every_mosaic_gate_of_the_package_is_wrapped():
         pallas_nms.pallas_nms_compiled_ok, pallas_attn.packed_window_ok,
         pallas_attn.packed_global_ok, pallas_attn.pallas_global_ok,
         pallas_attn.pallas_fused_ok, kda.kda_chunk_ok,
-        causal_attn.latent_kernel_ok, pallas_int8.pallas_int8_ok,
+        causal_attn.latent_kernel_ok, causal_attn.gqa_kernel_ok,
+        pallas_int8.pallas_int8_ok,
         moe.pairs_kernels_ok]
     for gate in gates:
         assert gate.cache_clear.__qualname__.startswith("mosaic_gate.")

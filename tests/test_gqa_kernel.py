@@ -1,0 +1,223 @@
+"""Grouped-query attention's Pallas kernel (``ops/causal_attn.py``) in the
+Pallas interpreter, at both head widths the trunks have (64: a pair of heads
+a slab; 128: one) and tiny everything else: against the blocked XLA form and
+a plain masked softmax with the key-value heads repeated, its gradient, what
+``gqa_formulation`` answers from what it observes, and that a trunk traced
+with the kernel names it where the ``compile`` span and ``trunk.gqa.ms``
+read it."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tmr_tpu import diagnostics, obs
+from tmr_tpu.models.lm_trunk import build_lm_trunk
+from tmr_tpu.obs.compile import _trunk_attrs
+from tmr_tpu.ops import causal_attn, rope
+from tmr_tpu.ops.causal_attn import (gqa_attention_blocked,
+                                     gqa_attention_kernel, gqa_formulation)
+
+BLOCK = 128  # a query and key block of the tests: the cells' are 512
+
+
+def _heads(head_dim, group, slabs=1):
+    """(query heads, key-value heads): the fewest that fill ``slabs`` slabs
+    of k."""
+    kv_heads = slabs * max(128 // head_dim, 2 if group == 1 else 1)
+    return group * kv_heads, kv_heads
+
+
+def _operands(seq, head_dim, group, batch=1, rotated=False, seed=0,
+              slabs=1):
+    """q, k, v flat as the projections write them; ``rotated``: q and k
+    turned by their token's position as the mixer turns them."""
+    heads, kv_heads = _heads(head_dim, group, slabs)
+    ks = jax.random.split(jax.random.key(seed), 3)
+    draw = lambda key, h: jax.random.normal(
+        key, (batch, seq, h, head_dim)).astype(jnp.bfloat16)
+    q, k, v = draw(ks[0], heads), draw(ks[1], kv_heads), draw(ks[2], kv_heads)
+    if rotated:
+        inv_freq = rope.default_inv_freq(head_dim, 10000.0)
+        q, k = (rope.rotate(t, jnp.arange(seq), inv_freq) for t in (q, k))
+    flat = lambda t: t.reshape(batch, seq, -1)
+    return flat(q), flat(k), flat(v), heads, kv_heads
+
+
+def _masked_softmax(q, k, v, heads, kv_heads):
+    """Float32, all S x S scores a head at once, the key-value heads
+    repeated over their groups."""
+    b, s, _ = q.shape
+    f32 = lambda t, h: np.asarray(t, np.float32).reshape(b, s, h, -1)
+    q, k, v = f32(q, heads), f32(k, kv_heads), f32(v, kv_heads)
+    k, v = (np.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
+    scores = np.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    scores = np.where(np.tril(np.ones((s, s), bool)), scores, -np.inf)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, s, -1)
+
+
+@pytest.mark.parametrize("rotated", [False, True], ids=["nope", "rope"])
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+# two slabs of k and v: query heads reach a key-value slab past the first,
+# as both cells' 8 key-value heads do
+@pytest.mark.parametrize("group,slabs", [(4, 1), (1, 1), (4, 2)],
+                         ids=["gqa4", "mha", "gqa4_two_slabs"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_kernel_equals_the_blocked_form_and_a_masked_softmax(
+        head_dim, group, slabs, blocks, batch, rotated):
+    """bfloat16 operands on both sides, rounded in other places: 0.01 of
+    outputs of order 1 against each other, and the kernel no further from
+    the float32 softmax than the blocked form is."""
+    q, k, v, heads, kv_heads = _operands(blocks * BLOCK, head_dim, group,
+                                         batch, rotated, seed=blocks,
+                                         slabs=slabs)
+    scale = head_dim ** -0.5
+    got = np.asarray(gqa_attention_kernel(q, k, v, heads, kv_heads, scale,
+                                          (BLOCK, BLOCK)), np.float32)
+    blocked = np.asarray(gqa_attention_blocked(q, k, v, heads, kv_heads,
+                                               scale), np.float32)
+    want = _masked_softmax(q, k, v, heads, kv_heads)
+    assert np.isfinite(got).all()
+    span = np.abs(want).max()
+    assert np.abs(got - blocked).max() < 1e-2 * span
+    assert np.abs(got - want).max() < 5e-3 * span
+    assert np.abs(got - want).max() < 1.5 * np.abs(blocked - want).max() \
+        + 1e-3 * span
+    # the first row's only key is itself: each head's output is its key-value
+    # head's value there
+    v0 = np.asarray(v, np.float32).reshape(batch, -1, kv_heads, head_dim)[:, 0]
+    np.testing.assert_array_equal(
+        got[:, 0], np.repeat(v0, group, axis=1).reshape(batch, -1))
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_a_query_block_sees_no_key_after_it(head_dim):
+    """Keys and values after a query block changed: its rows do not."""
+    q, k, v, heads, kv_heads = _operands(2 * BLOCK, head_dim, 4)
+    run = lambda k, v: np.asarray(gqa_attention_kernel(
+        q, k, v, heads, kv_heads, head_dim ** -0.5, (BLOCK, BLOCK)),
+        np.float32)
+    got = run(k, v)
+    again = run(k.at[:, BLOCK + 5:].set(7.0), v.at[:, BLOCK + 5:].set(-3.0))
+    np.testing.assert_array_equal(got[:, :BLOCK + 5], again[:, :BLOCK + 5])
+    assert np.abs(got[:, BLOCK + 5:] - again[:, BLOCK + 5:]).max() > 0.1
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_kernel_differentiates_through_the_blocked_form(head_dim):
+    q, k, v, heads, kv_heads = _operands(2 * BLOCK, head_dim, 4, batch=2)
+    w = jax.random.normal(jax.random.key(9), q.shape)
+    scale = head_dim ** -0.5
+
+    def loss(fn, *extra):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, heads, kv_heads, scale, *extra).astype(jnp.float32)
+            * w)
+
+    got = jax.grad(loss(gqa_attention_kernel, (BLOCK, BLOCK)),
+                   (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(gqa_attention_blocked), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("case,want", [
+    ("lfm2", "causal_kernel"), ("granite", "causal_kernel"),
+    ("the_1536_bucket", "causal_kernel"), ("mha_of_64", "causal_kernel"),
+    ("float32", "blocked_xla"), ("head_dim_80", "blocked_xla"),
+    ("odd_length", "blocked_xla"), ("too_long", "blocked_xla"),
+    ("group_splits_a_slab", "blocked_xla"), ("cpu_backend", "blocked_xla"),
+    ("partitioned", "blocked_xla")])
+def test_gqa_formulation_by_what_it_observes(case, want, monkeypatch):
+    """The kernel where the type, the head geometry and the length allow it
+    and its gate says yes; the blocked form everywhere else, and off a TPU
+    and in a trace XLA partitions the gate says why."""
+    if case not in ("cpu_backend", "partitioned"):  # there the gate answers
+        monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda d: True)
+    seq, heads, kv_heads, head_dim, dtype = {
+        "granite": (4096, 32, 8, 128, jnp.bfloat16),
+        "the_1536_bucket": (9216, 32, 8, 64, jnp.bfloat16),
+        "mha_of_64": (4096, 16, 16, 64, jnp.bfloat16),
+        "float32": (4096, 32, 8, 64, jnp.float32),
+        "head_dim_80": (4096, 32, 8, 80, jnp.bfloat16),
+        "odd_length": (4000, 32, 8, 64, jnp.bfloat16),
+        "too_long": (32768, 32, 8, 64, jnp.bfloat16),
+        # three heads a group: heads 2 and 3 share a slab of q and not a
+        # key-value head
+        "group_splits_a_slab": (4096, 24, 8, 64, jnp.bfloat16),
+    }.get(case, (4096, 32, 8, 64, jnp.bfloat16))
+    if case == "cpu_backend":  # a verdict another test left answers quietly
+        causal_attn.gqa_kernel_ok.cache_clear()
+    diagnostics.drain_gate_refusals()
+    if case == "partitioned":
+        with diagnostics.mosaic_kernels_off("a two-chip mesh"):
+            got = gqa_formulation(seq, heads, kv_heads, head_dim, dtype)
+    else:
+        got = gqa_formulation(seq, heads, kv_heads, head_dim, dtype)
+    causes = {(r["gate"], r["cause"])
+              for r in diagnostics.drain_gate_refusals()}
+    assert causes == {"cpu_backend": {("gqa_kernel_ok", "backend")},
+                      "partitioned": {("gqa_kernel_ok", "partitioned")}
+                      }.get(case, set())
+    assert got == want
+
+
+_TRUNKS = {
+    # a state-space layer and a NoPE attention layer, heads of 128
+    "granite4_h_small_share2": dict(
+        hidden=64, layers=(("ssm", "moe"), ("gqa", "moe")), ssm_heads=2,
+        ssm_head_dim=16, ssm_state=16, num_heads=4, kv_heads=1,
+        expert_width=32, shared_width=32, num_experts=4, experts_held=4,
+        top_k=2),
+    # a short convolution and a rotary attention layer with q/k norms,
+    # heads of 64
+    "lfm2_24b_a2b_share8": dict(
+        hidden=64, layers=(("short_conv", "dense"), ("gqa", "moe")),
+        num_heads=8, kv_heads=2, dense_width=64, expert_width=32,
+        num_experts=4, experts_held=4, top_k=2),
+}
+
+
+@pytest.mark.parametrize("name,formulation", [
+    ("granite4_h_small_share2", "causal_kernel"),
+    ("lfm2_24b_a2b_share8", "causal_kernel_rope")])
+def test_a_trunk_traced_with_the_kernel_names_it_where_the_metrics_read(
+        name, formulation, monkeypatch):
+    """With the gate's yes, every attention layer counts
+    ``trunk.gqa.<kernel's name>`` (the ``compile`` span's ``trunk_gqa``)
+    and the lowered text carries the kernel's call under
+    ``backbone/layers_N/attn/softmax/``, the scope ``trunk.gqa.ms`` owns
+    time by; without it, the blocked form's. The trunk at its heads' widths
+    in bfloat16, tiny everything else, 1,024 patches: two query blocks."""
+    over = _TRUNKS[name]
+    model = build_lm_trunk(name, dtype=jnp.bfloat16, **over)
+    image = jax.ShapeDtypeStruct((1, 512, 512, 3), jnp.bfloat16)
+    params = jax.eval_shape(model.init, jax.random.key(0), image)
+    gqa_layers = [i for i, (mixer, _) in enumerate(over["layers"])
+                  if mixer == "gqa"]
+    counts = lambda: obs.get_registry().counters("trunk.")
+
+    def lowered():
+        before = counts()
+        text = jax.jit(model.apply).lower(params, image).as_text(
+            debug_info=True)
+        return _trunk_attrs(before, counts())["trunk_gqa"], text
+
+    call = r"layers_(\d+)/attn/softmax/[^\"]*_gqa_kernel_fwd_impl"
+    attr, text = lowered()
+    blocked = formulation.replace("causal_kernel", "blocked_xla")
+    assert attr == f"{blocked} x{len(gqa_layers)}"
+    assert not re.search(call, text)
+    monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda d: True)
+    attr, text = lowered()
+    assert attr == f"{formulation} x{len(gqa_layers)}"
+    assert sorted({int(n) for n in re.findall(call, text)}) == gqa_layers
+    for layer in gqa_layers:  # and nothing of the blocked form is left
+        assert f"layers_{layer}/attn/softmax/bqgrd" not in text

@@ -54,7 +54,7 @@ from tmr_tpu.ops import moe as moe_ops
 from tmr_tpu.ops import short_conv as short_conv_ops
 from tmr_tpu.ops import ssd as ssd_ops
 from tmr_tpu.ops.causal_attn import (causal_attention_blocked,
-                                      gqa_formulation,
+                                      gqa_attention_kernel, gqa_formulation,
                                       latent_attention_blocked,
                                       latent_attention_kernel,
                                       mla_formulation)
@@ -340,9 +340,12 @@ class GQAMixer(nn.Module):
     """Grouped-query causal attention: ``num_heads`` query heads on
     ``kv_heads`` key-value heads of ``head_dim``, the scores times ``scale``
     (Granite's ``attention_multiplier``, LFM2's ``head_dim^-1/2``), softmax
-    in float32; scores, softmax and values under ``softmax/``
-    (``ops/causal_attn.py:causal_attention_blocked``: the key-value heads are
-    not written out once a query head). ``qk_norm``: an RMSNorm over a
+    in float32; scores, softmax and values under ``softmax/``: one Pallas
+    kernel on q, k and v as the projections wrote them
+    (``ops/causal_attn.py:gqa_attention_kernel``) or the row-blocked XLA
+    form (``causal_attention_blocked``), as ``gqa_formulation`` answers from
+    the device, the type and the sizes; neither writes the key-value heads
+    out once a query head. ``qk_norm``: an RMSNorm over a
     head's dims on q and on k (``q_norm``, ``k_norm``), then with
     ``rope_theta`` the default rotary on both (under ``rope/``); else
     neither (NoPE)."""
@@ -380,7 +383,12 @@ class GQAMixer(nn.Module):
         formulation = gqa_formulation(s, h, hkv, d, self.dtype)
         metrics.counter(f"trunk.gqa.{formulation}{rope}").inc()
         with jax.named_scope("softmax"):
-            o = causal_attention_blocked(q, k, v, self.scale)
+            if formulation == "causal_kernel":
+                o = gqa_attention_kernel(
+                    q.reshape(b, s, h * d), k.reshape(b, s, hkv * d),
+                    v.reshape(b, s, hkv * d), h, hkv, self.scale)
+            else:
+                o = causal_attention_blocked(q, k, v, self.scale)
         return lin(x.shape[-1], "o_proj")(o.reshape(b, s, h * d))
 
 

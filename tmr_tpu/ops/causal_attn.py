@@ -1,6 +1,7 @@
-"""Causal softmax attention whose query-key width differs from its value
-width (latent attention's 128 + 64 against 128), with no bias and no S x S
-score tensor anywhere. Softmax in float32, over the true row maximum.
+"""Causal softmax attention with no bias and no S x S score tensor
+anywhere: latent attention's (a query-key width of 128 + 64 against a value
+width of 128) and grouped-query attention's (query heads sharing key-value
+heads, of 64 or 128). Softmax in float32, over the true row maximum.
 
 :func:`causal_attention_blocked` is plain XLA: one block of query rows at a
 time against the keys at or before it, so the scores of a step are
@@ -17,9 +18,12 @@ from ``q_b`` / ``q_proj``, ``kv`` (B, S, H x 256) from ``kv_b`` (a head's
 ``k_pe`` (B, S, 64) all heads share, so that the broadcast of ``k_pe`` over
 the heads, both concatenates and every (B, S, H, d) relayout go. A query
 block's whole row of scores stays in VMEM and a head's keys and values stay
-there across its query blocks. :func:`mla_formulation` says which of the two
-a trace takes; the blocked form is the kernel's fallback, its gate's oracle
-and its backward pass.
+there across its query blocks. :func:`gqa_attention_kernel` is the same
+kernel's two passes (:func:`_causal_softmax`) on grouped-query attention's
+``q`` (B, S, H x d), ``k`` and ``v`` (B, S, Hkv x d), a key-value slab read
+once for all the query heads that share it. :func:`mla_formulation` and
+:func:`gqa_formulation` say which form a trace takes; the blocked form is
+each kernel's fallback, its gate's oracle and its backward pass.
 """
 
 from __future__ import annotations
@@ -60,13 +64,17 @@ def causal_attention_blocked(q, k, v, scale: float, block: int = 256):
     return jnp.concatenate(outs, axis=1).reshape(b, s, h, -1)
 
 
-def gqa_formulation(seq: int, heads: int, kv_heads: int, head_dim: int,
-                    dtype) -> str:
-    """What grouped-query attention traces with (counter
-    ``trunk.gqa.<formulation>``). One answer today:
-    :func:`causal_attention_blocked`, plain XLA; a kernel gets its name and
-    its gate here."""
-    return "blocked_xla"
+def gqa_attention_blocked(q, k, v, heads: int, kv_heads: int,
+                          scale: float):
+    """:func:`causal_attention_blocked` on the projections' own outputs (the
+    kernel's signature, :func:`gqa_attention_kernel`): ``q`` (B, S, H x d),
+    ``k`` and ``v`` (B, S, Hkv x d) -> (B, S, H x d)."""
+    b, s, _ = q.shape
+    d = q.shape[-1] // heads
+    o = causal_attention_blocked(q.reshape(b, s, heads, d),
+                                 k.reshape(b, s, kv_heads, d),
+                                 v.reshape(b, s, kv_heads, d), scale)
+    return o.reshape(b, s, -1)
 
 
 def latent_attention_blocked(q, kv, k_pe, heads: int, scale: float,
@@ -142,38 +150,18 @@ _NEG = -1e30
 _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 
 
-def _latent_kernel_body(q_ref, kv_ref, kpe_ref, *rest, scale: float,
-                        bq: int, bk: int, rope: bool):
-    """Refs: q (1, bq, 384) the pair of heads' columns, kv (1, S, 256) the
-    head's ``[k_nope | v]``, kpe (1, S, 128) ``[k_pe | k_pe]``, with
-    ``rope`` cos and sin (bq, 128) float32, out (1, bq, 128); scratch: the
-    block's scores (bq, S) and m, l, acc (bq, 128), float32. ``bk``
-    divides ``bq``."""
-    if rope:
-        cos_ref, sin_ref, *rest = rest
-    o_ref, s_ref, m_ref, l_ref, acc_ref = rest
-    f32, dtype = jnp.float32, q_ref.dtype
-    iq, per = pl.program_id(2), bq // bk
-    lane = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
-    low = lane < _DP
-    odd = (jnp.zeros((1, _LANES), jnp.int32) + pl.program_id(1)) % 2 == 1
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
-    slab = lambda i: q_ref[0, :, i * _LANES:(i + 1) * _LANES].astype(f32)
-    s0, s1, s2 = slab(0), slab(1), slab(2)
-    q_nope = jnp.where(
-        odd, pltpu.roll(jnp.where(low, s2, s1), _DP, 1), s0).astype(dtype)
-    # the head's "rope" dims: the low half of slab 1 (even), the high half
-    # of slab 2 (odd); the other half is zeroed after the rotation
-    q_pe = jnp.where(odd, s2, s1)
-    if rope:
-        first = lane % _DP < _DP // 2
-        swapped = jnp.where(first, pltpu.roll(q_pe, _LANES - _DP // 2, 1),
-                            pltpu.roll(q_pe, _DP // 2, 1))
-        q_pe = q_pe * cos_ref[...] + swapped * sin_ref[...]
-    q_pe = jnp.where(low != odd, q_pe, 0.0).astype(dtype)
 
-    def dot(a, b, dims=(((1,), (0,)), ((), ()))):
-        return lax.dot_general(a, b, dims, preferred_element_type=f32)
+def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
+                    *, scale: float, bq: int, bk: int, dtype):
+    """The two passes every causal kernel of this file makes for query
+    block ``iq``, whatever its operands: ``scores_of(cols)`` is the block's
+    (bq, bk) float32 scores against the keys ``cols`` before ``scale``,
+    ``values_of(cols)`` those keys' (bk, 128) values. Returns the block's
+    (bq, 128) float32 output, normalised by the float32 sum."""
+    per = bq // bk
 
     def folded(op, t):  # (bq, bk) -> (bq, 128), lane on lane
         return functools.reduce(op, [
@@ -181,8 +169,7 @@ def _latent_kernel_body(q_ref, kv_ref, kpe_ref, *rest, scale: float,
 
     def scores(ik, masked):
         cols = pl.ds(pl.multiple_of(ik * bk, bk), bk)
-        s = (dot(q_nope, kv_ref[0, cols, :_DN], _NT)
-             + dot(q_pe, kpe_ref[0, cols, :], _NT)) * scale
+        s = scores_of(cols) * scale
         if masked:
             row = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             col = ik * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -207,23 +194,59 @@ def _latent_kernel_body(q_ref, kv_ref, kpe_ref, *rest, scale: float,
         cols = pl.ds(pl.multiple_of(ik * bk, bk), bk)
         p = jnp.exp(s_ref[:, cols] - jnp.tile(m_ref[...], (1, bk // _LANES)))
         l_ref[...] += folded(jnp.add, p)
-        acc_ref[...] += dot(p.astype(dtype), kv_ref[0, cols, _DN:])
+        acc_ref[...] += _dot(p.astype(dtype), values_of(cols))
         return c
 
     lax.fori_loop(0, (iq + 1) * per, weighted, 0)
-    o_ref[0] = (acc_ref[...] / jnp.sum(l_ref[...], axis=1, keepdims=True)
-                ).astype(o_ref.dtype)
+    return acc_ref[...] / jnp.sum(l_ref[...], axis=1, keepdims=True)
 
 
-def _vmem_bytes(seq: int, bq: int, itemsize: int) -> int:
-    """What the kernel asks of VMEM: a query block's row of float32 scores,
-    the head's ``[k_nope | v]`` and the doubled ``k_pe`` twice over (Pallas
-    double-buffers them), and 16 MiB for the blocks of q, the tables and the
-    output, the three float32 rows and Mosaic's own temporaries: 30 MiB at
-    4,096 tokens. No more than it needs: what a kernel reserves, XLA cannot
-    keep its neighbours' operands in."""
-    held = 2 * seq * (_DN + _DV + _LANES) * itemsize
-    return bq * seq * 4 + held + 16 * 1024 * 1024
+def _latent_kernel_body(q_ref, kv_ref, kpe_ref, *rest, scale: float,
+                        bq: int, bk: int, rope: bool):
+    """Refs: q (1, bq, 384) the pair of heads' columns, kv (1, S, 256) the
+    head's ``[k_nope | v]``, kpe (1, S, 128) ``[k_pe | k_pe]``, with
+    ``rope`` cos and sin (bq, 128) float32, out (1, bq, 128); scratch: the
+    block's scores (bq, S) and m, l, acc (bq, 128), float32. ``bk``
+    divides ``bq``."""
+    if rope:
+        cos_ref, sin_ref, *rest = rest
+    o_ref, *scratch = rest
+    f32, dtype = jnp.float32, q_ref.dtype
+    iq = pl.program_id(2)
+    lane = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    low = lane < _DP
+    odd = (jnp.zeros((1, _LANES), jnp.int32) + pl.program_id(1)) % 2 == 1
+
+    slab = lambda i: q_ref[0, :, i * _LANES:(i + 1) * _LANES].astype(f32)
+    s0, s1, s2 = slab(0), slab(1), slab(2)
+    q_nope = jnp.where(
+        odd, pltpu.roll(jnp.where(low, s2, s1), _DP, 1), s0).astype(dtype)
+    # the head's "rope" dims: the low half of slab 1 (even), the high half
+    # of slab 2 (odd); the other half is zeroed after the rotation
+    q_pe = jnp.where(odd, s2, s1)
+    if rope:
+        first = lane % _DP < _DP // 2
+        swapped = jnp.where(first, pltpu.roll(q_pe, _LANES - _DP // 2, 1),
+                            pltpu.roll(q_pe, _DP // 2, 1))
+        q_pe = q_pe * cos_ref[...] + swapped * sin_ref[...]
+    q_pe = jnp.where(low != odd, q_pe, 0.0).astype(dtype)
+    o_ref[0] = _causal_softmax(
+        iq, lambda cols: (_dot(q_nope, kv_ref[0, cols, :_DN], _NT)
+                          + _dot(q_pe, kpe_ref[0, cols, :], _NT)),
+        lambda cols: kv_ref[0, cols, _DN:], *scratch, scale=scale, bq=bq,
+        bk=bk, dtype=dtype).astype(o_ref.dtype)
+
+
+def _vmem_bytes(seq: int, bq: int, lanes: int, itemsize: int) -> int:
+    """What a kernel asks of VMEM: a query block's row of float32 scores,
+    the ``lanes`` columns a token it holds of keys and values twice over
+    (Pallas double-buffers them: the latent kernel's ``[k_nope | v]`` and
+    doubled ``k_pe``, 384; grouped-query attention's k and v slabs, 256),
+    and 16 MiB for the blocks of q, the tables and the output, the three
+    float32 rows and Mosaic's own temporaries: 30 / 28 MiB at 4,096 tokens.
+    No more than it needs: what a kernel reserves, XLA cannot keep its
+    neighbours' operands in."""
+    return bq * seq * 4 + 2 * seq * lanes * itemsize + 16 * 1024 * 1024
 
 
 def latent_kernel_supported(seq: int, heads: int, dn: int, dp: int, dv: int,
@@ -270,7 +293,8 @@ def _latent_kernel_fwd_impl(q, kv, k_pe, heads, scale, rot, block):
         scratch_shapes=[pltpu.VMEM((bq, s), jnp.float32), row, row, row],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_bytes(s, bq, q.dtype.itemsize)),
+            vmem_limit_bytes=_vmem_bytes(s, bq, _DN + _DV + _LANES,
+                                         q.dtype.itemsize)),
         interpret=jax.default_backend() != "tpu",
     )(*operands)
 
@@ -377,5 +401,205 @@ def mla_formulation(seq: int, heads: int, dn: int, dp: int, dv: int, dtype,
     if (dtype == jnp.bfloat16 and latent_kernel_supported(seq, heads, dn, dp,
                                                           dv)
             and latent_kernel_ok(bool(rope))):
+        return "causal_kernel"
+    return "blocked_xla"
+
+
+# --------------------------------------------------------------------------
+# Grouped-query attention as the same kernel (formulation ``causal_kernel``).
+#
+# q (B, S, H x d), k and v (B, S, Hkv x d) are read where the projections
+# (and the mixer's norms and rotation) wrote them: no (B, S, H, d) relayout
+# and no key-value head written out once a query head. One grid step is one
+# query block of one query head; the grid is (image, slab of q, query block,
+# head within the slab), the query blocks and the heads of a slab
+# innermost. A head reads the 128-lane slab of k and of v that holds its
+# key-value head, which depends on neither, so Pallas fetches it once for
+# all the query heads that share it.
+#
+# - heads of 128: a slab is one head and one key-value head.
+# - heads of 64: a slab of q holds a pair of heads, and a slab of k a pair
+#   of key-value heads. The step keeps its head's half of the q slab,
+#   zeroes the other and rolls it by half a slab where its key-value head
+#   sits in the other half of its slab, so that one 128-deep product is the
+#   head's scores; the values product's (bq, 128) carries the key-value
+#   head's 64 columns in that same half, rolled back to the head's half of
+#   the output block, which the pair's two steps share.
+# - the scores, the causal softmax and the values are
+#   :func:`_causal_softmax`, the latent kernel's two passes.
+# --------------------------------------------------------------------------
+def _gqa_kernel_body(q_ref, k_ref, v_ref, o_ref, *scratch, scale: float,
+                     bq: int, bk: int, head_dim: int, group: int):
+    """Refs: q (1, bq, 128) a slab of query heads, k and v (1, S, 128) the
+    slab that holds the step's key-value head, out (1, bq, 128); scratch as
+    :func:`_latent_kernel_body`'s. ``group`` query heads share a key-value
+    head."""
+    f32, dtype = jnp.float32, q_ref.dtype
+    iq = pl.program_id(2)
+    if head_dim == _LANES:
+        q = q_ref[0]
+    else:
+        vector = lambda n: jnp.zeros((1, _LANES), jnp.int32) + n
+        mine = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) // head_dim \
+            == vector(pl.program_id(3))
+        # the step's head and its key-value head lie in different halves
+        head = pl.program_id(1) * (_LANES // head_dim) + pl.program_id(3)
+        apart = vector(pl.program_id(3)) != vector(head // group) % 2
+        q = jnp.where(mine, q_ref[0].astype(f32), 0.0)
+        q = jnp.where(apart, pltpu.roll(q, head_dim, 1), q).astype(dtype)
+    o = _causal_softmax(
+        iq, lambda cols: _dot(q, k_ref[0, cols, :], _NT),
+        lambda cols: v_ref[0, cols, :], *scratch, scale=scale, bq=bq, bk=bk,
+        dtype=dtype)
+    if head_dim == _LANES:
+        o_ref[0] = o.astype(o_ref.dtype)
+    else:
+        o = jnp.where(apart, pltpu.roll(o, head_dim, 1), o)
+        o_ref[0] = jnp.where(mine, o.astype(o_ref.dtype), o_ref[0])
+
+
+def gqa_kernel_supported(seq: int, heads: int, kv_heads: int, head_dim: int,
+                         block=_BLOCK) -> bool:
+    """Heads of 128, or of 64 where the two heads of a slab of q read one
+    slab of k and v (a group of one or of an even count); whole slabs of k
+    and v; a sequence that is whole query blocks (of whole key blocks) and
+    that VMEM holds."""
+    bq, bk = block
+    if head_dim not in (64, _LANES) or heads % kv_heads:
+        return False
+    group = heads // kv_heads
+    return ((kv_heads * head_dim) % _LANES == 0
+            and (head_dim == _LANES or group == 1 or group % 2 == 0)
+            and bq % bk == 0 and seq % bq == 0 and seq <= _MAX_SEQ)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block):
+    # a jit of its own: a trunk's attention layers are one shape, and share
+    # one traced and lowered function in the enclosing program
+    b, s, _ = q.shape
+    d = q.shape[-1] // heads
+    if not (gqa_kernel_supported(s, heads, kv_heads, d, block)
+            and q.shape[-1] == heads * d and k.shape[-1] == kv_heads * d
+            and v.shape == k.shape):
+        raise ValueError(
+            f"q {q.shape}, k {k.shape}, v {v.shape} at {heads} / {kv_heads} "
+            "heads have no kernel; gate callers on gqa_formulation()")
+    bq, bk = block
+    per, group = _LANES // d, heads // kv_heads
+    slab = pl.BlockSpec((1, bq, _LANES), lambda i, j, n, r: (i, n, j))
+    kv = pl.BlockSpec((1, s, _LANES),
+                      lambda i, j, n, r: (i, 0, (j * per + r) // group // per))
+    row = pltpu.VMEM((bq, _LANES), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_gqa_kernel_body, scale=scale, bq=bq, bk=bk,
+                          head_dim=d, group=group),
+        grid=(b, heads // per, s // bq, per),
+        in_specs=[slab, kv, kv],
+        out_specs=slab,
+        out_shape=jax.ShapeDtypeStruct(q.shape, v.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, s), jnp.float32), row, row, row],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_vmem_bytes(s, bq, 2 * _LANES,
+                                         q.dtype.itemsize)),
+        interpret=jax.default_backend() != "tpu",
+    )(q, k, v)
+
+
+def gqa_attention_kernel(q, k, v, heads: int, kv_heads: int, scale: float,
+                         block=_BLOCK):
+    """Causal grouped-query attention as one Pallas TPU kernel, on the
+    projections' own outputs: ``q`` (B, S, H x d), ``k`` and ``v``
+    (B, S, Hkv x d), query head ``j`` on key-value head ``j // (H / Hkv)``,
+    heads of 128 or of 64 (:func:`gqa_kernel_supported`). Returns
+    (B, S, H x d) in ``v``'s dtype, what ``o_proj`` reads. Same mathematics
+    as :func:`gqa_attention_blocked`, but for where the probabilities are
+    normalised: there ahead of their rounding to the operand dtype, here
+    after the values product, by their float32 sum. Differentiable by
+    recomputing through the blocked form."""
+    return _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block):
+    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block)
+
+
+def _gqa_vjp_fwd(q, k, v, heads, kv_heads, scale, block):
+    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block), (
+        q, k, v)
+
+
+def _gqa_vjp_bwd(heads, kv_heads, scale, block, res, ct):
+    return jax.vjp(
+        lambda *a: gqa_attention_blocked(*a, heads, kv_heads, scale), *res
+    )[1](ct)
+
+
+_gqa_kernel_vjp.defvjp(_gqa_vjp_fwd, _gqa_vjp_bwd)
+
+
+@mosaic_gate
+def gqa_kernel_ok(head_dim: int) -> bool:
+    """Compiled self-check of :func:`gqa_attention_kernel` as the mixer
+    calls it, once a machine: two query blocks (a full key block and the
+    diagonal), a batch of two and two slabs of k and v (groups of four heads
+    of 128 on their key-value head; of heads of 64, whose pairs roll and
+    whose pairs do not) against :func:`gqa_attention_blocked`. One
+    program, its inputs drawn on the host (``chip_smoke.py`` holds the
+    kernel to the blocked form at the backbones' shapes)."""
+    from tmr_tpu.diagnostics import gate_refused, run_outside_trace
+
+    config = {"head_dim": head_dim}
+    if jax.default_backend() != "tpu":
+        return gate_refused(
+            "gqa_kernel_ok", f"backend {jax.default_backend()!r} != 'tpu'",
+            "backend", config)
+
+    b, s = 2, 2 * _BLOCK[0]
+    kv_heads = 2 * _LANES // head_dim
+    heads, scale = 4 * kv_heads, head_dim ** -0.5
+
+    @jax.jit
+    def gap(q, k, v):
+        got = gqa_attention_kernel(q, k, v, heads, kv_heads, scale)
+        want = gqa_attention_blocked(q, k, v, heads, kv_heads, scale)
+        gap = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+        return gap.max() / jnp.abs(want.astype(jnp.float32)).max()
+
+    def check():
+        rng = np.random.default_rng(0)
+        draw = lambda width: rng.standard_normal(
+            (b, s, width), np.float32).astype(jnp.bfloat16)
+        return float(gap(draw(heads * head_dim), draw(kv_heads * head_dim),
+                         draw(kv_heads * head_dim)))
+
+    try:
+        widest = run_outside_trace(check, "gqa_kernel_ok")
+    except Exception as e:  # Mosaic's refusals included
+        return gate_refused("gqa_kernel_ok", str(e)[:500], "exception",
+                            config, exception=type(e).__name__)
+    if not widest < 2e-2:
+        return gate_refused(
+            "gqa_kernel_ok", f"widest gap to the blocked form "
+            f"{widest:.3g} of its range", "forward-mismatch", config)
+    return True
+
+
+def gqa_formulation(seq: int, heads: int, kv_heads: int, head_dim: int,
+                    dtype) -> str:
+    """What grouped-query attention traces with (counter
+    ``trunk.gqa.<formulation>``), by what can be observed: in bfloat16, at
+    heads of 128, or of 64 whose pairs share a slab of k and v, and a
+    sequence of whole 512-token blocks that VMEM holds (4,096 tokens ask 28
+    MiB, the 1536 bucket's 9,216 43 MiB; over 16,384 not), where the
+    kernel's self-check says yes (it says no off a TPU and inside a trace
+    XLA partitions), the Pallas kernel (``causal_kernel``); else
+    ``blocked_xla``, :func:`causal_attention_blocked`."""
+    if (dtype == jnp.bfloat16
+            and gqa_kernel_supported(seq, heads, kv_heads, head_dim)
+            and gqa_kernel_ok(head_dim)):
         return "causal_kernel"
     return "blocked_xla"
