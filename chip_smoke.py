@@ -409,8 +409,8 @@ def check_gqa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     (``ops/causal_attn.py:gqa_attention_kernel``), which off the chip runs
     only in the interpreter: held here to the blocked XLA form at the
     backbone's own shape (its query and key-value heads, their width, its
-    tokens, its scale). Returns whether a trace of the backbone takes the
-    kernel."""
+    tokens, its scale, its mask: causal or block-causal by its diffusion
+    block). Returns whether a trace of the backbone takes the kernel."""
     import jax
     import jax.numpy as jnp
 
@@ -423,10 +423,11 @@ def check_gqa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
             "nothing to hold")
         return False
     h, hkv, d = z["num_heads"], z["kv_heads"], z["head_dim"]
+    n = z.get("diffusion_block", 1)
     seq, bf = (size.image_size // 16) ** 2, jnp.bfloat16
-    formulation = causal_attn.gqa_formulation(seq, h, hkv, d, bf)
+    formulation = causal_attn.gqa_formulation(seq, h, hkv, d, bf, n)
     say(f"  grouped-query attention: gqa_formulation({seq}, {h}, {hkv}, {d}, "
-        f"bfloat16) = {formulation}")
+        f"bfloat16, {n}) = {formulation}")
     if formulation != "causal_kernel":
         return False
     ks = jax.random.split(jax.random.key(seed), 3)
@@ -434,13 +435,14 @@ def check_gqa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     k = jax.random.normal(ks[1], (batch, seq, hkv * d)).astype(bf)
     v = jax.random.normal(ks[2], (batch, seq, hkv * d)).astype(bf)
     run = lambda fn: np.asarray(jax.jit(
-        lambda *a: fn(*a, h, hkv, z["attn_scale"]))(q, k, v), np.float32)
+        lambda *a: fn(*a, h, hkv, z["attn_scale"], diffusion_block=n))(
+            q, k, v), np.float32)
     got = run(causal_attn.gqa_attention_kernel)
     want = run(causal_attn.gqa_attention_blocked)
     gap = float(np.abs(got - want).max() / np.abs(want).max())
     say(f"  grouped-query attention, {batch} x {seq} tokens x {h} heads on "
-        f"{hkv} of {d}: the kernel against the blocked form, widest gap "
-        f"{gap:.5f} of the range (bfloat16 on both sides)")
+        f"{hkv} of {d}, diffusion block {n}: the kernel against the blocked "
+        f"form, widest gap {gap:.5f} of the range (bfloat16 on both sides)")
     check(np.isfinite(got).all() and gap < 0.02,
           "grouped-query attention's kernel equals the blocked form at the "
           "backbone's shape")
@@ -647,10 +649,11 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
     ``trunk_hc`` where it has streams): a trunk with ``ssm`` / ``gqa`` layers
     and no ``kda`` / ``mla`` layer names the former pair and neither of the
     latter; the state-space, grouped-query (``_rope`` where the trunk has
-    rotary there), short-convolution and pairs' attributes each name their
-    layers' count; grouped-query attention is ``causal_kernel`` where
-    ``check_gqa_kernel`` found the gate's yes and the experts' pairs travel
-    by ``row_dma`` where ``check_pairs_kernels`` did."""
+    rotary there, then ``_block<L>`` where its mask is block-causal),
+    short-convolution and pairs' attributes each name their layers' count;
+    grouped-query attention is ``causal_kernel`` where ``check_gqa_kernel``
+    found the gate's yes and the experts' pairs travel by ``row_dma`` where
+    ``check_pairs_kernels`` did."""
     import inspect
 
     import jax
@@ -738,6 +741,8 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
         layers = lambda mixer: sum(m == mixer for m, _ in z["layers"])
         gqa = ("causal_kernel" if verdicts.get("gqa_kernel_ok")
                else "blocked_xla") + ("_rope" if z.get("rope_theta") else "")
+        if z.get("diffusion_block", 1) > 1:
+            gqa += f"_block{z['diffusion_block']}"
         for kind, want in (
                 ("trunk_ssm", f"chunked_xla x{layers('ssm')}"),
                 ("trunk_gqa", f"{gqa} x{layers('gqa')}"),
@@ -1138,8 +1143,9 @@ def main(argv=None) -> int:
     ap.add_argument("--backbone", default=FULL.backbone,
                     help="a name build_backbone knows; a trunk of typed "
                          "layers (kimi_linear_a3b_share2, xing4_a4b_stage6, "
-                         "granite4_h_small_share2, lfm2_24b_a2b_share8) "
-                         "runs the predict and serve phases")
+                         "granite4_h_small_share2, lfm2_24b_a2b_share8, "
+                         "sdar_30b_a3b_share8) runs the predict and serve "
+                         "phases")
     args = ap.parse_args(argv)
 
     import jax

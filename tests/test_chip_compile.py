@@ -207,16 +207,18 @@ def _latent_case(rope):
     return case
 
 
-def _gqa_case(batch, seq, head_dim):
+def _gqa_case(batch, seq, head_dim, hkv=8, diffusion_block=1):
     """Grouped-query attention's kernel on what ``GQAMixer`` holds: 32
-    query heads on 8 key-value heads of ``head_dim``, q, k and v as the
-    projections (and the mixer's norms and rotation) write them."""
+    query heads on ``hkv`` key-value heads of ``head_dim``, q, k and v as
+    the projections (and the mixer's norms and rotation) write them, under
+    the mask of ``diffusion_block``."""
     from tmr_tpu.ops.causal_attn import gqa_attention_kernel
 
     def case(sds):
-        h, hkv = 32, 8
-        return (lambda *a: gqa_attention_kernel(*a, h, hkv,
-                                                head_dim ** -0.5)), (
+        h = 32
+        return (lambda *a: gqa_attention_kernel(
+            *a, h, hkv, head_dim ** -0.5,
+            diffusion_block=diffusion_block)), (
             sds((batch, seq, h * head_dim), jnp.bfloat16),
             sds((batch, seq, hkv * head_dim), jnp.bfloat16),
             sds((batch, seq, hkv * head_dim), jnp.bfloat16))
@@ -292,6 +294,9 @@ CASES = {
     "gqa_kernel_lfm2": _gqa_case(4, 4096, 64),
     "gqa_kernel_granite": _gqa_case(2, 4096, 128),
     "gqa_kernel_1536": _gqa_case(1, 9216, 64),
+    # sdar30b_fscd147.eval: 4 images, groups of 8 heads of 128, diffusion
+    # blocks of 4
+    "gqa_kernel_sdar": _gqa_case(4, 4096, 128, hkv=4, diffusion_block=4),
     # a cell's tokens a batch, choices a token and hidden width; and one
     # image of the 1536 bucket, which no cell runs
     "pairs_kimi": _pairs_case(4 * 4096, 8, 2304),
@@ -299,6 +304,7 @@ CASES = {
     "pairs_granite": _pairs_case(2 * 4096, 10, 4096),
     "pairs_granite_1536": _pairs_case(9216, 10, 4096),
     "pairs_lfm2": _pairs_case(4 * 4096, 4, 2048),
+    "pairs_sdar": _pairs_case(4 * 4096, 8, 2048),  # 131,072 rows, the most
     "nms": _case_nms,
     "int8_matmul": _case_int8_matmul,
     "predict_program": _case_predict_program,
@@ -509,16 +515,17 @@ def test_mla_layer_keeps_its_scores_on_the_chip(family, one_chip, as_tpu):
 
 
 @pytest.mark.parametrize("family,batch", [("granite4_h_small_share2", 2),
-                                          ("lfm2_24b_a2b_share8", 4)])
+                                          ("lfm2_24b_a2b_share8", 4),
+                                          ("sdar_30b_a3b_share8", 4)])
 def test_gqa_layer_keeps_its_scores_on_the_chip(family, batch, one_chip,
                                                 as_tpu):
     """One grouped-query attention layer at a trunk cell's widths and
     batch, compiled for the v5e: scores, softmax and values are one Mosaic
     kernel and nothing else under ``attn/softmax/``, and no operand the size
     of k or larger is copied, transposed, concatenated or padded by the
-    entry computation under ``attn/`` but by the rotation: heads of 64
-    turned as (B, S, H, 64) are copied once to the (B, S, H x 64) the kernel
-    reads (q and k: a relayout the tiling makes; no rotation, no copy)."""
+    entry computation under ``attn/`` but by the rotation: heads turned as
+    (B, S, H, d) are copied once to the (B, S, H x d) the kernel reads (q
+    and k: a relayout the tiling makes; no rotation, no copy)."""
     import re
 
     from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, GQAMixer
@@ -527,7 +534,8 @@ def test_gqa_layer_keeps_its_scores_on_the_chip(family, batch, one_chip,
     mixer = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
                      z["attn_scale"], dtype=jnp.bfloat16,
                      qk_norm=z.get("qk_norm", False),
-                     rope_theta=z.get("rope_theta"), name="attn")
+                     rope_theta=z.get("rope_theta"),
+                     diffusion_block=z.get("diffusion_block", 1), name="attn")
     s = _KDA["seq"]
     x = jax.ShapeDtypeStruct((batch, s, z["hidden"]), jnp.bfloat16,
                              sharding=one_chip)
@@ -550,7 +558,8 @@ def test_gqa_layer_keeps_its_scores_on_the_chip(family, batch, one_chip,
         if m.group(2) in ("transpose", "copy", "concatenate", "pad") \
                 and elems >= k_elems:
             moved.append((m.group(2), m.group(3), elems))
-    want = [("copy", "rope", k_elems * 4), ("copy", "rope", k_elems)] \
+    q_elems = k_elems * z["num_heads"] // z["kv_heads"]
+    want = [("copy", "rope", q_elems), ("copy", "rope", k_elems)] \
         if z.get("rope_theta") else []
     assert sorted(moved, reverse=True) == want, moved
 

@@ -4,7 +4,8 @@ a slab; 128: one) and tiny everything else: against the blocked XLA form and
 a plain masked softmax with the key-value heads repeated, its gradient, what
 ``gqa_formulation`` answers from what it observes, and that a trunk traced
 with the kernel names it where the ``compile`` span and ``trunk.gqa.ms``
-read it."""
+read it; and block diffusion's block-causal mask (``diffusion_block``) in
+the kernel and the blocked form alike."""
 
 import re
 
@@ -46,15 +47,18 @@ def _operands(seq, head_dim, group, batch=1, rotated=False, seed=0,
     return flat(q), flat(k), flat(v), heads, kv_heads
 
 
-def _masked_softmax(q, k, v, heads, kv_heads):
+def _masked_softmax(q, k, v, heads, kv_heads, diffusion_block=1):
     """Float32, all S x S scores a head at once, the key-value heads
-    repeated over their groups."""
+    repeated over their groups; query ``i`` sees key ``j`` where
+    ``j // L <= i // L`` (``L`` 1: causal)."""
     b, s, _ = q.shape
     f32 = lambda t, h: np.asarray(t, np.float32).reshape(b, s, h, -1)
     q, k, v = f32(q, heads), f32(k, kv_heads), f32(v, kv_heads)
     k, v = (np.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
     scores = np.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
-    scores = np.where(np.tril(np.ones((s, s), bool)), scores, -np.inf)
+    block = np.arange(s) // diffusion_block
+    seen = block[None, :] <= block[:, None]
+    scores = np.where(seen, scores, -np.inf)
     p = np.exp(scores - scores.max(-1, keepdims=True))
     p /= p.sum(-1, keepdims=True)
     return np.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, s, -1)
@@ -93,6 +97,89 @@ def test_kernel_equals_the_blocked_form_and_a_masked_softmax(
     v0 = np.asarray(v, np.float32).reshape(batch, -1, kv_heads, head_dim)[:, 0]
     np.testing.assert_array_equal(
         got[:, 0], np.repeat(v0, group, axis=1).reshape(batch, -1))
+
+
+@pytest.mark.parametrize("diffusion_block", [1, 4, 16])
+@pytest.mark.parametrize("group", [4, 8])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_block_causal_kernel_equals_the_blocked_form_and_a_masked_softmax(
+        head_dim, group, diffusion_block):
+    """Two query blocks, rotated operands, bfloat16 on both sides: the
+    kernel against the blocked form under the same mask (0.01 of outputs of
+    order 1) and the float32 masked softmax, which the causal mask does not
+    give."""
+    q, k, v, heads, kv_heads = _operands(2 * BLOCK, head_dim, group,
+                                         rotated=True, seed=diffusion_block)
+    scale = head_dim ** -0.5
+    got = np.asarray(gqa_attention_kernel(
+        q, k, v, heads, kv_heads, scale, (BLOCK, BLOCK),
+        diffusion_block=diffusion_block), np.float32)
+    blocked = np.asarray(gqa_attention_blocked(
+        q, k, v, heads, kv_heads, scale, diffusion_block), np.float32)
+    want = _masked_softmax(q, k, v, heads, kv_heads, diffusion_block)
+    assert np.isfinite(got).all()
+    span = np.abs(want).max()
+    assert np.abs(got - blocked).max() < 1e-2 * span
+    assert np.abs(got - want).max() < 5e-3 * span
+    if diffusion_block > 1:
+        assert np.abs(want - _masked_softmax(q, k, v, heads, kv_heads)
+                      ).max() > 0.05 * span
+
+
+@pytest.mark.parametrize("form", ["kernel", "blocked"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_a_query_sees_its_own_diffusion_block_and_none_after_it(form,
+                                                                 head_dim):
+    """Diffusion blocks of 4: a later key of a query's own block moves its
+    row, a key of the next block moves nothing at or before that block."""
+    n = 4
+    q, k, v, heads, kv_heads = _operands(2 * BLOCK, head_dim, 8)
+    scale = head_dim ** -0.5
+
+    def run(k, v):
+        if form == "kernel":
+            o = gqa_attention_kernel(q, k, v, heads, kv_heads, scale,
+                                     (BLOCK, BLOCK), diffusion_block=n)
+        else:
+            o = gqa_attention_blocked(q, k, v, heads, kv_heads, scale, n)
+        return np.asarray(o, np.float32)
+
+    got = run(k, v)
+    row = BLOCK + 5  # the second query block, second of its diffusion block
+    key = row + 2  # a later token of the same block of 4
+    inside = run(k.at[:, key].set(7.0), v.at[:, key].set(-3.0))
+    assert np.abs(inside[:, row] - got[:, row]).max() > 0.1
+    np.testing.assert_array_equal(inside[:, :row - 1], got[:, :row - 1])
+    after = key + 1  # the first token of the next diffusion block
+    moved = run(k.at[:, after].set(7.0), v.at[:, after].set(-3.0))
+    np.testing.assert_array_equal(moved[:, :after], got[:, :after])
+    assert np.abs(moved[:, after] - got[:, after]).max() > 0.1
+
+
+@pytest.mark.parametrize("form", ["kernel", "blocked"])
+def test_a_diffusion_block_of_one_lowers_as_the_causal_call(form):
+    """``diffusion_block`` 1 is the causal mask as it was traced before the
+    block mask existed: the same program text as a call that does not name
+    it, and no integer division or bit mask in it; 4 lowers to another."""
+    q, k, v, heads, kv_heads = _operands(2 * BLOCK, 128, 8)
+    scale = 128 ** -0.5
+    if form == "kernel":
+        call = lambda **kw: jax.make_jaxpr(
+            lambda q, k, v: gqa_attention_kernel(
+                q, k, v, heads, kv_heads, scale, (BLOCK, BLOCK), **kw))(
+                    q, k, v)
+    else:
+        call = lambda **kw: jax.jit(lambda q, k, v: gqa_attention_blocked(
+            q, k, v, heads, kv_heads, scale, **kw)).lower(q, k, v)
+    text = lambda lowered: (str(lowered) if form == "kernel"
+                            else lowered.as_text(debug_info=False))
+    # an integer divide, remainder or bit mask, as each form prints one
+    integer_op = (r"i32\[[\d,]*\] = (and|div|rem) " if form == "kernel"
+                  else r"stablehlo\.(and|divide|remainder) [^\n]*i32>")
+    causal = text(call())
+    assert text(call(diffusion_block=1)) == causal
+    assert not re.search(integer_op, causal)
+    assert re.search(integer_op, text(call(diffusion_block=4)))
 
 
 @pytest.mark.parametrize("head_dim", [64, 128])
@@ -134,13 +221,16 @@ def test_kernel_differentiates_through_the_blocked_form(head_dim):
     ("float32", "blocked_xla"), ("head_dim_80", "blocked_xla"),
     ("odd_length", "blocked_xla"), ("too_long", "blocked_xla"),
     ("group_splits_a_slab", "blocked_xla"), ("cpu_backend", "blocked_xla"),
-    ("partitioned", "blocked_xla")])
+    ("partitioned", "blocked_xla"), ("sdar_block4", "causal_kernel"),
+    ("diffusion_block_3", "blocked_xla"),
+    ("diffusion_block_1024", "blocked_xla")])
 def test_gqa_formulation_by_what_it_observes(case, want, monkeypatch):
     """The kernel where the type, the head geometry and the length allow it
     and its gate says yes; the blocked form everywhere else, and off a TPU
     and in a trace XLA partitions the gate says why."""
     if case not in ("cpu_backend", "partitioned"):  # there the gate answers
-        monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda d: True)
+        monkeypatch.setattr(causal_attn, "gqa_kernel_ok",
+                            lambda d, n=1: True)
     seq, heads, kv_heads, head_dim, dtype = {
         "granite": (4096, 32, 8, 128, jnp.bfloat16),
         "the_1536_bucket": (9216, 32, 8, 64, jnp.bfloat16),
@@ -152,7 +242,12 @@ def test_gqa_formulation_by_what_it_observes(case, want, monkeypatch):
         # three heads a group: heads 2 and 3 share a slab of q and not a
         # key-value head
         "group_splits_a_slab": (4096, 24, 8, 64, jnp.bfloat16),
+        # groups of 8 heads of 128, diffusion blocks of 4
+        "sdar_block4": (4096, 32, 4, 128, jnp.bfloat16),
     }.get(case, (4096, 32, 8, 64, jnp.bfloat16))
+    # a block that is no power of two, or longer than a key block
+    diffusion_block = {"sdar_block4": 4, "diffusion_block_3": 3,
+                       "diffusion_block_1024": 1024}.get(case, 1)
     if case == "cpu_backend":  # a verdict another test left answers quietly
         causal_attn.gqa_kernel_ok.cache_clear()
     diagnostics.drain_gate_refusals()
@@ -160,7 +255,8 @@ def test_gqa_formulation_by_what_it_observes(case, want, monkeypatch):
         with diagnostics.mosaic_kernels_off("a two-chip mesh"):
             got = gqa_formulation(seq, heads, kv_heads, head_dim, dtype)
     else:
-        got = gqa_formulation(seq, heads, kv_heads, head_dim, dtype)
+        got = gqa_formulation(seq, heads, kv_heads, head_dim, dtype,
+                              diffusion_block)
     causes = {(r["gate"], r["cause"])
               for r in diagnostics.drain_gate_refusals()}
     assert causes == {"cpu_backend": {("gqa_kernel_ok", "backend")},
@@ -182,12 +278,18 @@ _TRUNKS = {
         hidden=64, layers=(("short_conv", "dense"), ("gqa", "moe")),
         num_heads=8, kv_heads=2, dense_width=64, expert_width=32,
         num_experts=4, experts_held=4, top_k=2),
+    # rotary attention with q/k norms under diffusion blocks of 4, groups of
+    # 8 heads of 128
+    "sdar_30b_a3b_share8": dict(
+        hidden=64, layers=(("gqa", "moe"),) * 2, num_heads=16, kv_heads=2,
+        expert_width=32, num_experts=4, experts_held=4, top_k=2),
 }
 
 
 @pytest.mark.parametrize("name,formulation", [
     ("granite4_h_small_share2", "causal_kernel"),
-    ("lfm2_24b_a2b_share8", "causal_kernel_rope")])
+    ("lfm2_24b_a2b_share8", "causal_kernel_rope"),
+    ("sdar_30b_a3b_share8", "causal_kernel_rope_block4")])
 def test_a_trunk_traced_with_the_kernel_names_it_where_the_metrics_read(
         name, formulation, monkeypatch):
     """With the gate's yes, every attention layer counts
@@ -215,7 +317,7 @@ def test_a_trunk_traced_with_the_kernel_names_it_where_the_metrics_read(
     blocked = formulation.replace("causal_kernel", "blocked_xla")
     assert attr == f"{blocked} x{len(gqa_layers)}"
     assert not re.search(call, text)
-    monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda d: True)
+    monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda d, n=1: True)
     attr, text = lowered()
     assert attr == f"{formulation} x{len(gqa_layers)}"
     assert sorted({int(n) for n in re.findall(call, text)}) == gqa_layers

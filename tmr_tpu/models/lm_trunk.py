@@ -3,7 +3,7 @@ decoder layers (mixer ``kda`` | ``mla`` | ``ssm`` | ``gqa`` | ``short_conv``,
 feed-forward ``dense`` | ``moe``) between SAM's patch embedding and SAM's
 neck.
 
-Four published families are built from it (``TRUNK_CONFIGS``), each as
+Five published families are built from it (``TRUNK_CONFIGS``), each as
 published, with no bias on a linear layer, SiLU, pre-norm sub-layers and
 causal over the patches in raster order (position = raster index):
 
@@ -24,7 +24,15 @@ causal over the patches in raster order (position = raster index):
   grouped-query attention with per-head RMSNorm on q and k and default
   rotary, the plain residual add, RMSNorm eps 1e-5; its expert layers have
   no shared expert (``shared_width`` 0) and renormalise the chosen scores
-  with an eps of their own (``router_eps``).
+  with an eps of their own (``router_eps``);
+- SDAR-MoE's (Qwen3-MoE's layer served by block diffusion): grouped-query
+  attention in every layer, with per-head RMSNorm on q and k and default
+  rotary, under block diffusion's block-causal mask (``diffusion_block``
+  L: bidirectional within a block of L patches, causal across blocks;
+  ``ops/causal_attn.py``), experts chosen by the largest logits and
+  weighed by a softmax over the chosen (the published softmax over all,
+  renormalised over the chosen, is the same weights), no shared expert,
+  the plain residual add, RMSNorm eps 1e-6.
 
 The first two's feed-forwards are dense or sigmoid-routed experts with a
 shared expert. The token embedding and the output head are on no path of a
@@ -348,7 +356,9 @@ class GQAMixer(nn.Module):
     out once a query head. ``qk_norm``: an RMSNorm over a
     head's dims on q and on k (``q_norm``, ``k_norm``), then with
     ``rope_theta`` the default rotary on both (under ``rope/``); else
-    neither (NoPE)."""
+    neither (NoPE). ``diffusion_block`` L over 1: block diffusion's
+    block-causal mask in place of the causal one (the counter's name ends
+    in ``_block<L>``)."""
 
     num_heads: int
     kv_heads: int
@@ -359,6 +369,7 @@ class GQAMixer(nn.Module):
     qk_norm: bool = False
     rope_theta: Optional[float] = None
     norm_eps: float = 1e-5
+    diffusion_block: int = 1
 
     @nn.compact
     def __call__(self, x):
@@ -380,15 +391,21 @@ class GQAMixer(nn.Module):
                 q = rope_ops.rotate(q, jnp.arange(s), inv_freq)
                 k = rope_ops.rotate(k, jnp.arange(s), inv_freq)
             rope = "_rope"
-        formulation = gqa_formulation(s, h, hkv, d, self.dtype)
-        metrics.counter(f"trunk.gqa.{formulation}{rope}").inc()
+        n = self.diffusion_block
+        formulation = gqa_formulation(s, h, hkv, d, self.dtype, n)
+        block = f"_block{n}" if n > 1 else ""
+        metrics.counter(f"trunk.gqa.{formulation}{rope}{block}").inc()
         with jax.named_scope("softmax"):
             if formulation == "causal_kernel":
                 o = gqa_attention_kernel(
                     q.reshape(b, s, h * d), k.reshape(b, s, hkv * d),
-                    v.reshape(b, s, hkv * d), h, hkv, self.scale)
+                    v.reshape(b, s, hkv * d), h, hkv, self.scale,
+                    diffusion_block=n)
             else:
-                o = causal_attention_blocked(q, k, v, self.scale)
+                # at L = 1 the four-argument call, which the benchmark's
+                # granite fault replaces by a function of four arguments
+                mask = {"diffusion_block": n} if n > 1 else {}
+                o = causal_attention_blocked(q, k, v, self.scale, **mask)
         return lin(x.shape[-1], "o_proj")(o.reshape(b, s, h * d))
 
 
@@ -556,7 +573,9 @@ class TrunkLayer(nn.Module):
             attn = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
                             z["attn_scale"], qk_norm=z["qk_norm"],
                             rope_theta=z["rope_theta"],
-                            norm_eps=z["norm_eps"], name="attn", **kw)
+                            norm_eps=z["norm_eps"],
+                            diffusion_block=z["diffusion_block"],
+                            name="attn", **kw)
         elif self.mixer == "short_conv":
             attn = ShortConvMixer(z["conv_size"], name="attn", **kw)
         else:
@@ -634,6 +653,7 @@ class LMTrunkBackbone(nn.Module):
     attn_scale: Optional[float] = None
     qk_norm: bool = False  # "gqa" layers: q and k normed a head
     rope_theta: Optional[float] = None  # "gqa" layers: None, NoPE
+    diffusion_block: int = 1  # "gqa" layers: 1, causal; else block-causal
     router: str = "sigmoid_bias"  # or "softmax_topk"
     router_eps: Optional[float] = None  # None: ops/moe.py:route's own
     shared_width: Optional[int] = None  # None: an expert's own; 0: none
@@ -662,8 +682,8 @@ class LMTrunkBackbone(nn.Module):
             "top_k", "routed_scale", "hc_mult", "hc_sinkhorn_iters",
             "hc_eps", "hc_clamp", "norm_eps", "ssm_heads", "ssm_head_dim",
             "ssm_state", "ssm_groups", "ssm_chunk", "kv_heads", "head_dim",
-            "attn_scale", "qk_norm", "rope_theta", "router", "router_eps",
-            "shared_width", "residual_multiplier")}
+            "attn_scale", "qk_norm", "rope_theta", "diffusion_block",
+            "router", "router_eps", "shared_width", "residual_multiplier")}
         self._layers = [
             TrunkLayer(mixer, ffn, sizes, self.dtype, self.param_dtype,
                        name=f"layers_{i}")
@@ -729,9 +749,13 @@ _LFM2_24B_LAYER_TYPES = tuple(
 #: ``lfm2_24b_a2b_share8``: the published widths and all 40 layers of
 #: LFM2-24B-A2B (30 gated short convolutions, 10 grouped-query attention
 #: layers; 2 dense, 38 with experts), the 8 of 64 experts that one of the
-#: eight chips sharing every layer holds. (The
-#: tests and the benchmark's rehearsals add the same patterns at tiny widths
-#: under names of their own.)
+#: eight chips sharing every layer holds. ``sdar_30b_a3b_share8``: the
+#: published widths and all 48 layers of SDAR-30B-A3B (grouped-query
+#: attention under a block-causal mask of 4 patches, 128 experts, 8 a
+#: token, in every layer), the 16 of 128 experts that one of the eight
+#: chips sharing every layer holds. (The tests and the benchmark's
+#: rehearsals add the same patterns at tiny widths under names of their
+#: own.)
 TRUNK_CONFIGS = {
     "kimi_linear_a3b_share2": dict(
         hidden=2304, layers=_pattern(5), num_heads=32, kda_head_dim=128,
@@ -760,6 +784,12 @@ TRUNK_CONFIGS = {
         qk_norm=True, rope_theta=1000000.0, dense_width=11776,
         expert_width=1536, shared_width=0, num_experts=64, experts_held=8,
         expert_offset=0, top_k=4, routed_scale=1.0, router_eps=1e-6),
+    "sdar_30b_a3b_share8": dict(
+        hidden=2048, layers=(("gqa", "moe"),) * 48, num_heads=32,
+        kv_heads=4, head_dim=128, attn_scale=128 ** -0.5, qk_norm=True,
+        rope_theta=1000000.0, diffusion_block=4, expert_width=768,
+        shared_width=0, num_experts=128, experts_held=16, expert_offset=0,
+        top_k=8, router="softmax_topk", norm_eps=1e-6),
 }
 
 

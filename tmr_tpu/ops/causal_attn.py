@@ -2,6 +2,10 @@
 anywhere: latent attention's (a query-key width of 128 + 64 against a value
 width of 128) and grouped-query attention's (query heads sharing key-value
 heads, of 64 or 128). Softmax in float32, over the true row maximum.
+Grouped-query attention also takes block diffusion's block-causal mask
+(``diffusion_block`` L: query ``i`` sees key ``j`` where
+``j // L <= i // L``); at L = 1 every form is the causal one, traced as it
+was before the mask existed.
 
 :func:`causal_attention_blocked` is plain XLA: one block of query rows at a
 time against the keys at or before it, so the scores of a step are
@@ -41,11 +45,15 @@ from tmr_tpu.diagnostics import mosaic_gate
 from tmr_tpu.ops import rope as rope_ops
 
 
-def causal_attention_blocked(q, k, v, scale: float, block: int = 256):
+def causal_attention_blocked(q, k, v, scale: float, block: int = 256,
+                             diffusion_block: int = 1):
     """``q`` (B, S, H, dqk), ``k`` (B, S, Hkv, dqk), ``v`` (B, S, Hkv, dv)
     -> (B, S, H, dv) in ``v``'s dtype. With fewer key-value heads than query
     heads (grouped-query attention) query head ``j`` reads key-value head
-    ``j // (H / Hkv)``; the key-value heads are never written out again."""
+    ``j // (H / Hkv)``; the key-value heads are never written out again.
+    ``diffusion_block`` ``L``: the block-causal mask of block diffusion,
+    query ``i`` sees key ``j`` where ``j // L <= i // L`` (bidirectional
+    within a block of ``L`` tokens, causal across blocks); 1 is causal."""
     b, s, h, _ = q.shape
     hkv = k.shape[2]
     if hkv == h:
@@ -56,16 +64,29 @@ def causal_attention_blocked(q, k, v, scale: float, block: int = 256):
     outs = []
     for lo in range(0, s, block):
         hi = min(lo + block, s)
-        scores = jnp.einsum(to_scores, q[:, lo:hi], k[:, :hi],
+        # the keys up to the end of the diffusion block that holds the last
+        # query of the row block
+        end = hi if diffusion_block == 1 else min(
+            s, -(-hi // diffusion_block) * diffusion_block)
+        scores = jnp.einsum(to_scores, q[:, lo:hi], k[:, :end],
                             preferred_element_type=jnp.float32) * scale
-        after = jnp.arange(hi)[None, :] > jnp.arange(lo, hi)[:, None]
+        after = _after(lo, hi, end, diffusion_block)
         probs = jax.nn.softmax(jnp.where(after, -jnp.inf, scores), axis=-1)
-        outs.append(jnp.einsum(to_out, probs.astype(v.dtype), v[:, :hi]))
+        outs.append(jnp.einsum(to_out, probs.astype(v.dtype), v[:, :end]))
     return jnp.concatenate(outs, axis=1).reshape(b, s, h, -1)
 
 
+def _after(lo: int, hi: int, end: int, diffusion_block: int):
+    """(hi - lo, end): where key ``j`` lies after query ``lo + i``'s view;
+    at ``diffusion_block`` 1 the causal mask as it always was, no divide."""
+    if diffusion_block == 1:
+        return jnp.arange(end)[None, :] > jnp.arange(lo, hi)[:, None]
+    return (jnp.arange(end)[None, :] // diffusion_block
+            > jnp.arange(lo, hi)[:, None] // diffusion_block)
+
+
 def gqa_attention_blocked(q, k, v, heads: int, kv_heads: int,
-                          scale: float):
+                          scale: float, diffusion_block: int = 1):
     """:func:`causal_attention_blocked` on the projections' own outputs (the
     kernel's signature, :func:`gqa_attention_kernel`): ``q`` (B, S, H x d),
     ``k`` and ``v`` (B, S, Hkv x d) -> (B, S, H x d)."""
@@ -73,7 +94,8 @@ def gqa_attention_blocked(q, k, v, heads: int, kv_heads: int,
     d = q.shape[-1] // heads
     o = causal_attention_blocked(q.reshape(b, s, heads, d),
                                  k.reshape(b, s, kv_heads, d),
-                                 v.reshape(b, s, kv_heads, d), scale)
+                                 v.reshape(b, s, kv_heads, d), scale,
+                                 diffusion_block=diffusion_block)
     return o.reshape(b, s, -1)
 
 
@@ -155,12 +177,17 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
 
 
 def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
-                    *, scale: float, bq: int, bk: int, dtype):
+                    *, scale: float, bq: int, bk: int, dtype,
+                    diffusion_block: int = 1):
     """The two passes every causal kernel of this file makes for query
     block ``iq``, whatever its operands: ``scores_of(cols)`` is the block's
     (bq, bk) float32 scores against the keys ``cols`` before ``scale``,
     ``values_of(cols)`` those keys' (bk, 128) values. Returns the block's
-    (bq, 128) float32 output, normalised by the float32 sum."""
+    (bq, 128) float32 output, normalised by the float32 sum.
+    ``diffusion_block`` ``L`` (a power of two that divides ``bk``): the
+    block-causal mask, ``col // L > row // L`` masked. A query block then
+    sees the same key blocks as under the causal mask, so only the diagonal
+    blocks' mask changes."""
     per = bq // bk
 
     def folded(op, t):  # (bq, bk) -> (bq, 128), lane on lane
@@ -173,6 +200,10 @@ def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
         if masked:
             row = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             col = ik * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            if diffusion_block > 1:
+                # the first key of col's diffusion block, (col // L) L,
+                # passes row exactly where col // L > row // L
+                col = col & -diffusion_block
             s = jnp.where(col > row, _NEG, s)
         s_ref[:, cols] = s
         m_ref[...] = jnp.maximum(m_ref[...], folded(jnp.maximum, s))
@@ -426,10 +457,14 @@ def mla_formulation(seq: int, heads: int, dn: int, dp: int, dv: int, dtype,
 #   head's 64 columns in that same half, rolled back to the head's half of
 #   the output block, which the pair's two steps share.
 # - the scores, the causal softmax and the values are
-#   :func:`_causal_softmax`, the latent kernel's two passes.
+#   :func:`_causal_softmax`, the latent kernel's two passes; with a
+#   diffusion block of L tokens (block diffusion's mask: bidirectional
+#   within a block, causal across blocks) only the diagonal blocks' mask
+#   differs, since L divides a key block.
 # --------------------------------------------------------------------------
 def _gqa_kernel_body(q_ref, k_ref, v_ref, o_ref, *scratch, scale: float,
-                     bq: int, bk: int, head_dim: int, group: int):
+                     bq: int, bk: int, head_dim: int, group: int,
+                     diffusion_block: int):
     """Refs: q (1, bq, 128) a slab of query heads, k and v (1, S, 128) the
     slab that holds the step's key-value head, out (1, bq, 128); scratch as
     :func:`_latent_kernel_body`'s. ``group`` query heads share a key-value
@@ -450,7 +485,7 @@ def _gqa_kernel_body(q_ref, k_ref, v_ref, o_ref, *scratch, scale: float,
     o = _causal_softmax(
         iq, lambda cols: _dot(q, k_ref[0, cols, :], _NT),
         lambda cols: v_ref[0, cols, :], *scratch, scale=scale, bq=bq, bk=bk,
-        dtype=dtype)
+        dtype=dtype, diffusion_block=diffusion_block)
     if head_dim == _LANES:
         o_ref[0] = o.astype(o_ref.dtype)
     else:
@@ -459,32 +494,37 @@ def _gqa_kernel_body(q_ref, k_ref, v_ref, o_ref, *scratch, scale: float,
 
 
 def gqa_kernel_supported(seq: int, heads: int, kv_heads: int, head_dim: int,
-                         block=_BLOCK) -> bool:
+                         block=_BLOCK, diffusion_block: int = 1) -> bool:
     """Heads of 128, or of 64 where the two heads of a slab of q read one
     slab of k and v (a group of one or of an even count); whole slabs of k
     and v; a sequence that is whole query blocks (of whole key blocks) and
-    that VMEM holds."""
+    that VMEM holds; a diffusion block that is a power of two and divides
+    the key block."""
     bq, bk = block
     if head_dim not in (64, _LANES) or heads % kv_heads:
         return False
-    group = heads // kv_heads
+    group, n = heads // kv_heads, diffusion_block
     return ((kv_heads * head_dim) % _LANES == 0
             and (head_dim == _LANES or group == 1 or group % 2 == 0)
-            and bq % bk == 0 and seq % bq == 0 and seq <= _MAX_SEQ)
+            and bq % bk == 0 and seq % bq == 0 and seq <= _MAX_SEQ
+            and n >= 1 and n & (n - 1) == 0 and bk % n == 0)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block,
+                         diffusion_block):
     # a jit of its own: a trunk's attention layers are one shape, and share
     # one traced and lowered function in the enclosing program
     b, s, _ = q.shape
     d = q.shape[-1] // heads
-    if not (gqa_kernel_supported(s, heads, kv_heads, d, block)
+    if not (gqa_kernel_supported(s, heads, kv_heads, d, block,
+                                 diffusion_block)
             and q.shape[-1] == heads * d and k.shape[-1] == kv_heads * d
             and v.shape == k.shape):
         raise ValueError(
             f"q {q.shape}, k {k.shape}, v {v.shape} at {heads} / {kv_heads} "
-            "heads have no kernel; gate callers on gqa_formulation()")
+            f"heads, diffusion block {diffusion_block}, have no kernel; gate "
+            "callers on gqa_formulation()")
     bq, bk = block
     per, group = _LANES // d, heads // kv_heads
     slab = pl.BlockSpec((1, bq, _LANES), lambda i, j, n, r: (i, n, j))
@@ -493,7 +533,8 @@ def _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block):
     row = pltpu.VMEM((bq, _LANES), jnp.float32)
     return pl.pallas_call(
         functools.partial(_gqa_kernel_body, scale=scale, bq=bq, bk=bk,
-                          head_dim=d, group=group),
+                          head_dim=d, group=group,
+                          diffusion_block=diffusion_block),
         grid=(b, heads // per, s // bq, per),
         in_specs=[slab, kv, kv],
         out_specs=slab,
@@ -509,32 +550,37 @@ def _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block):
 
 
 def gqa_attention_kernel(q, k, v, heads: int, kv_heads: int, scale: float,
-                         block=_BLOCK):
+                         block=_BLOCK, diffusion_block: int = 1):
     """Causal grouped-query attention as one Pallas TPU kernel, on the
     projections' own outputs: ``q`` (B, S, H x d), ``k`` and ``v``
     (B, S, Hkv x d), query head ``j`` on key-value head ``j // (H / Hkv)``,
-    heads of 128 or of 64 (:func:`gqa_kernel_supported`). Returns
-    (B, S, H x d) in ``v``'s dtype, what ``o_proj`` reads. Same mathematics
-    as :func:`gqa_attention_blocked`, but for where the probabilities are
+    heads of 128 or of 64 (:func:`gqa_kernel_supported`); with a
+    ``diffusion_block`` over 1, block-causal as
+    :func:`causal_attention_blocked`. Returns (B, S, H x d) in ``v``'s
+    dtype, what ``o_proj`` reads. Same mathematics as
+    :func:`gqa_attention_blocked`, but for where the probabilities are
     normalised: there ahead of their rounding to the operand dtype, here
     after the values product, by their float32 sum. Differentiable by
     recomputing through the blocked form."""
-    return _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block)
+    return _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block,
+                           diffusion_block)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block):
-    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block, diffusion_block):
+    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block,
+                                diffusion_block)
 
 
-def _gqa_vjp_fwd(q, k, v, heads, kv_heads, scale, block):
-    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block), (
-        q, k, v)
+def _gqa_vjp_fwd(q, k, v, heads, kv_heads, scale, block, diffusion_block):
+    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block,
+                                diffusion_block), (q, k, v)
 
 
-def _gqa_vjp_bwd(heads, kv_heads, scale, block, res, ct):
+def _gqa_vjp_bwd(heads, kv_heads, scale, block, diffusion_block, res, ct):
     return jax.vjp(
-        lambda *a: gqa_attention_blocked(*a, heads, kv_heads, scale), *res
+        lambda *a: gqa_attention_blocked(*a, heads, kv_heads, scale,
+                                         diffusion_block), *res
     )[1](ct)
 
 
@@ -542,30 +588,33 @@ _gqa_kernel_vjp.defvjp(_gqa_vjp_fwd, _gqa_vjp_bwd)
 
 
 @mosaic_gate
-def gqa_kernel_ok(head_dim: int) -> bool:
+def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1) -> bool:
     """Compiled self-check of :func:`gqa_attention_kernel` as the mixer
     calls it, once a machine: two query blocks (a full key block and the
-    diagonal), a batch of two and two slabs of k and v (groups of four heads
-    of 128 on their key-value head; of heads of 64, whose pairs roll and
-    whose pairs do not) against :func:`gqa_attention_blocked`. One
-    program, its inputs drawn on the host (``chip_smoke.py`` holds the
-    kernel to the blocked form at the backbones' shapes)."""
+    diagonal), a batch of two and two slabs of k and v (sixteen query heads:
+    groups of eight heads of 128 on their key-value head; of heads of 64,
+    groups of four, whose pairs roll and whose pairs do not), masked with
+    the ``diffusion_block`` it is asked for, against
+    :func:`gqa_attention_blocked` under the same mask. One program, its
+    inputs drawn on the host (``chip_smoke.py`` holds the kernel to the
+    blocked form at the backbones' shapes)."""
     from tmr_tpu.diagnostics import gate_refused, run_outside_trace
 
-    config = {"head_dim": head_dim}
+    config = {"head_dim": head_dim, "diffusion_block": diffusion_block}
     if jax.default_backend() != "tpu":
         return gate_refused(
             "gqa_kernel_ok", f"backend {jax.default_backend()!r} != 'tpu'",
             "backend", config)
 
-    b, s = 2, 2 * _BLOCK[0]
-    kv_heads = 2 * _LANES // head_dim
-    heads, scale = 4 * kv_heads, head_dim ** -0.5
+    b, s, heads = 2, 2 * _BLOCK[0], 16
+    kv_heads, scale = 2 * _LANES // head_dim, head_dim ** -0.5
 
     @jax.jit
     def gap(q, k, v):
-        got = gqa_attention_kernel(q, k, v, heads, kv_heads, scale)
-        want = gqa_attention_blocked(q, k, v, heads, kv_heads, scale)
+        got = gqa_attention_kernel(q, k, v, heads, kv_heads, scale,
+                                   diffusion_block=diffusion_block)
+        want = gqa_attention_blocked(q, k, v, heads, kv_heads, scale,
+                                     diffusion_block)
         gap = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
         return gap.max() / jnp.abs(want.astype(jnp.float32)).max()
 
@@ -589,17 +638,19 @@ def gqa_kernel_ok(head_dim: int) -> bool:
 
 
 def gqa_formulation(seq: int, heads: int, kv_heads: int, head_dim: int,
-                    dtype) -> str:
+                    dtype, diffusion_block: int = 1) -> str:
     """What grouped-query attention traces with (counter
     ``trunk.gqa.<formulation>``), by what can be observed: in bfloat16, at
-    heads of 128, or of 64 whose pairs share a slab of k and v, and a
-    sequence of whole 512-token blocks that VMEM holds (4,096 tokens ask 28
-    MiB, the 1536 bucket's 9,216 43 MiB; over 16,384 not), where the
-    kernel's self-check says yes (it says no off a TPU and inside a trace
-    XLA partitions), the Pallas kernel (``causal_kernel``); else
+    heads of 128, or of 64 whose pairs share a slab of k and v, a sequence
+    of whole 512-token blocks that VMEM holds (4,096 tokens ask 28 MiB, the
+    1536 bucket's 9,216 43 MiB; over 16,384 not) and a diffusion block that
+    divides a key block, where the kernel's self-check at that diffusion
+    block says yes (it says no off a TPU and inside a trace XLA
+    partitions), the Pallas kernel (``causal_kernel``); else
     ``blocked_xla``, :func:`causal_attention_blocked`."""
     if (dtype == jnp.bfloat16
-            and gqa_kernel_supported(seq, heads, kv_heads, head_dim)
-            and gqa_kernel_ok(head_dim)):
+            and gqa_kernel_supported(seq, heads, kv_heads, head_dim,
+                                     diffusion_block=diffusion_block)
+            and gqa_kernel_ok(head_dim, diffusion_block)):
         return "causal_kernel"
     return "blocked_xla"
