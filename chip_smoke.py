@@ -449,29 +449,34 @@ def check_gqa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     return True
 
 
-def check_ssd_scan(size: Size, seed: int) -> None:
-    """The state-space recurrence's chunked form (``ops/ssd.py:ssd_chunked``)
-    in bfloat16 at the backbone's own shape (its heads, its state, its chunk,
-    one image's tokens), held on the chip to the token recurrence in
-    float32, with step sizes and decays by the family's rule: a head's
-    memory from under one token to a thousand."""
+def check_ssd_scan(size: Size, seed: int) -> bool:
+    """The state-space recurrence at the backbone's own shape (its heads,
+    its state, its chunk, one image's tokens), held on the chip to the token
+    recurrence in float32, with step sizes and decays by the family's rule:
+    a head's memory from under one token to a thousand. The chunked form
+    (``ops/ssd.py:ssd_chunked``) in bfloat16; and where ``ssd_kernel_ok``
+    passes, the kernel with its gated norm (``ssd_chunk_kernel``) against
+    the recurrence and the same norm. Returns the gate's verdict (False for
+    a backbone with no state-space layer)."""
     import jax
     import jax.numpy as jnp
 
     from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
     from tmr_tpu.ops import ssd
+    from tmr_tpu.ops.kda import rms_norm
 
     z = TRUNK_CONFIGS[size.backbone]
     if not any(mixer == "ssm" for mixer, _ in z["layers"]):
         say("  state-space scan: the backbone has no ssm layer, nothing to "
             "hold")
-        return
+        return False
     h, p, n, g = (z["ssm_heads"], z["ssm_head_dim"], z["ssm_state"],
                   z["ssm_groups"])
     seq, chunk, bf = (size.image_size // 16) ** 2, z["ssm_chunk"], jnp.bfloat16
-    say(f"  state-space scan: ssd_formulation = "
-        f"{ssd.ssd_formulation(seq, h, p, n, bf)}, chunks of {chunk}")
-    ks = jax.random.split(jax.random.key(seed), 5)
+    formulation = ssd.ssd_formulation(h, p, n, g, chunk, bf)
+    say(f"  state-space scan: ssd_formulation = {formulation}, chunks of "
+        f"{chunk}")
+    ks = jax.random.split(jax.random.key(seed), 7)
     u = jax.random.normal(ks[0], (1, seq, h, p)).astype(bf)
     b_in = jax.random.normal(ks[1], (1, seq, g, n)).astype(bf)
     c_in = jax.random.normal(ks[2], (1, seq, g, n)).astype(bf)
@@ -480,7 +485,8 @@ def check_ssd_scan(size: Size, seed: int) -> None:
     d = jnp.ones((h,))
     got = np.asarray(jax.jit(lambda *t: ssd.ssd_chunked(
         *t, chunk=chunk, dtype=bf))(u, delta, a, b_in, c_in, d), np.float32)
-    want = np.asarray(jax.jit(ssd.ssd_recurrent)(u, delta, a, b_in, c_in, d))
+    y = jax.jit(ssd.ssd_recurrent)(u, delta, a, b_in, c_in, d)
+    want = np.asarray(y)
     gap = float(np.abs(got - want).max() / np.abs(want).max())
     say(f"  state-space scan, {seq} tokens x {h} heads of {p} on a state of "
         f"{n}: the chunked form in bfloat16 against the token recurrence, "
@@ -488,6 +494,25 @@ def check_ssd_scan(size: Size, seed: int) -> None:
     check(np.isfinite(got).all() and gap < 0.03,
           "the chunked state-space scan equals the token recurrence at the "
           "backbone's shape")
+    if formulation != "chunk_kernel":
+        return False
+    zz = jax.random.normal(ks[5], (1, seq, h * p)).astype(bf)
+    weight = 1.0 + 0.1 * jax.random.normal(ks[6], (h * p,))
+    xbc = jnp.concatenate([u.reshape(1, seq, h * p), b_in[:, :, 0],
+                           c_in[:, :, 0]], axis=-1)
+    got = np.asarray(jax.jit(lambda *t: ssd.ssd_chunk_kernel(
+        *t, 1e-5, h, p, chunk))(xbc, zz, delta, a, d, weight), np.float32)
+    want = np.asarray(jax.jit(lambda y, z, w: rms_norm(
+        y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32)), w, 1e-5))(
+        y, zz, weight))
+    gap = float(np.abs(got - want).max() / np.abs(want).max())
+    say(f"  state-space kernel with its gated norm, the same shape, against "
+        f"the token recurrence and the norm in float32: widest gap "
+        f"{gap:.5f} of the range")
+    check(np.isfinite(got).all() and gap < 0.03,
+          "the state-space kernel with its gated norm equals the token "
+          "recurrence and the norm at the backbone's shape")
+    return True
 
 
 def _vit_heads(size: Size) -> tuple:
@@ -516,7 +541,7 @@ def decide_gates(cfg, size: Size) -> dict:
         verdicts["kda_chunk_ok"] = check_kda_kernel(size, seed=0)
         verdicts["latent_kernel_ok"] = check_mla_kernel(size, seed=0)
         verdicts["gqa_kernel_ok"] = check_gqa_kernel(size, seed=0)
-        check_ssd_scan(size, seed=0)
+        verdicts["ssd_kernel_ok"] = check_ssd_scan(size, seed=0)
         report_gates("decide")
         check_grouped_products(size, seed=0)
         verdicts["pairs_kernels_ok"] = check_pairs_kernels(size, seed=0)
@@ -743,8 +768,10 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
                else "blocked_xla") + ("_rope" if z.get("rope_theta") else "")
         if z.get("diffusion_block", 1) > 1:
             gqa += f"_block{z['diffusion_block']}"
+        ssm = ("chunk_kernel" if verdicts.get("ssd_kernel_ok")
+               else "chunked_xla")
         for kind, want in (
-                ("trunk_ssm", f"chunked_xla x{layers('ssm')}"),
+                ("trunk_ssm", f"{ssm} x{layers('ssm')}"),
                 ("trunk_gqa", f"{gqa} x{layers('gqa')}"),
                 ("trunk_short_conv", f"xla x{layers('short_conv')}")):
             if kind in kinds:

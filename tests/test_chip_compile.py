@@ -71,7 +71,7 @@ def as_tpu(monkeypatch):
     answer yes — their self-checks execute, which only a chip can."""
     from tmr_tpu.diagnostics import mosaic_gate
     from tmr_tpu.ops import causal_attn, flash_attn, kda, moe, pallas_attn
-    from tmr_tpu.ops import pallas_nms
+    from tmr_tpu.ops import pallas_nms, ssd
 
     def admits(name):
         def gate(*a, **k):
@@ -89,6 +89,7 @@ def as_tpu(monkeypatch):
                       (kda, "kda_chunk_ok"),
                       (causal_attn, "latent_kernel_ok"),
                       (causal_attn, "gqa_kernel_ok"),
+                      (ssd, "ssd_kernel_ok"),
                       (moe, "pairs_kernels_ok"),
                       (pallas_nms, "pallas_nms_compiled_ok")):
         monkeypatch.setattr(mod, name, admits(name))
@@ -562,6 +563,53 @@ def test_gqa_layer_keeps_its_scores_on_the_chip(family, batch, one_chip,
     want = [("copy", "rope", q_elems), ("copy", "rope", k_elems)] \
         if z.get("rope_theta") else []
     assert sorted(moved, reverse=True) == want, moved
+
+
+def test_ssm_layer_reads_the_scans_operands_where_they_were_written(
+        one_chip, as_tpu):
+    """One state-space layer at ``granite4h_fscd147.eval``'s widths and
+    batch, compiled for the v5e: the scan and its gated norm are one Mosaic
+    kernel under ``attn/scan/``; nothing in the program has the shape of u
+    or y with a head of 64 on an axis of its own; and the kernel reads the
+    convolution's and ``in_proj``'s outputs themselves, no slice or copy of
+    their columns between. The one large slice left is XLA's, of
+    ``in_proj``'s output ahead of the convolution, as in the XLA form."""
+    import re
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, SSMMixer
+
+    z = TRUNK_CONFIGS["granite4_h_small_share2"]
+    b, s = 2, _KDA["seq"]
+    mixer = SSMMixer(z["ssm_heads"], z["ssm_head_dim"], z["ssm_state"],
+                     z["ssm_groups"], z["conv_size"], z["ssm_chunk"],
+                     dtype=jnp.bfloat16, name="attn")
+    x = jax.ShapeDtypeStruct((b, s, z["hidden"]), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), x))
+    text = jax.jit(mixer.apply).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    kernel = re.search(r"= \S+ custom-call\(([^)]*)\)[^\n]*op_name=\"[^\"]*"
+                       r"attn/scan/", text)
+    assert kernel, "no kernel under attn/scan/"
+    entry = text[text.index("\nENTRY"):]
+    made = {m.group(1): (m.group(3), m.group(2)) for m in re.finditer(
+        r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", entry)}
+    operands = [made[name.strip().split("%")[-1]]
+                for name in kernel.group(1).split(",")]
+    assert not [o for o in operands if o[0] in ("slice", "copy")], operands
+    inner = z["ssm_heads"] * z["ssm_head_dim"]
+    conv = inner + 2 * z["ssm_state"]  # [u | B | C]
+    # the convolution's output and in_proj's [z | u B C | dt]
+    assert ("fusion", f"{b},{s},{conv}") in operands, operands
+    assert ("fusion", f"{b},{s},{inner + conv + z['ssm_heads']}") \
+        in operands, operands
+    assert not re.search(r"\[[\d,]+,\d+,128,64\]", text)
+    moved = sorted((op, shape) for op, shape in made.values()
+                   if op in ("slice", "copy", "transpose")
+                   and shape.startswith(f"{b},{s},"))
+    assert moved == [("slice", f"{b},{s},{conv}")], moved
 
 
 def _shallow_vit_b(cfg):

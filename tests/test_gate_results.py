@@ -376,7 +376,7 @@ def test_a_gate_asked_inside_a_gate_keeps_its_own(machine):
 def test_every_mosaic_gate_of_the_package_is_wrapped():
     from tmr_tpu.ops import (
         causal_attn, flash_attn, kda, moe, pallas_attn, pallas_int8,
-        pallas_nms)
+        pallas_nms, ssd)
 
     gates = [
         flash_attn.flash_window_ok, flash_attn.flash_attention_ok,
@@ -385,7 +385,7 @@ def test_every_mosaic_gate_of_the_package_is_wrapped():
         pallas_attn.pallas_fused_ok, kda.kda_chunk_ok,
         causal_attn.latent_kernel_ok, causal_attn.gqa_kernel_ok,
         pallas_int8.pallas_int8_ok,
-        moe.pairs_kernels_ok]
+        moe.pairs_kernels_ok, ssd.ssd_kernel_ok]
     for gate in gates:
         assert gate.cache_clear.__qualname__.startswith("mosaic_gate.")
         assert gate.cache_info().maxsize is None

@@ -7,6 +7,9 @@ widths on the CPU: hidden 64, 4 state-space heads of 16 on a state of 16,
 token, two state-space layers and one attention layer, 64 px images.
 """
 
+import functools
+from unittest import mock
+
 import flax
 import jax
 import jax.numpy as jnp
@@ -97,6 +100,148 @@ def test_ssd_chunked_in_bfloat16_keeps_decays_and_state_in_float32():
     assert got.dtype == jnp.bfloat16
     gap = float(jnp.abs(got.astype(jnp.float32) - want).max())
     assert gap < 0.02 * float(jnp.abs(want).max())
+
+
+# ------------------------------------------- the recurrence as one kernel
+def _kernel_inputs(batch, seq, heads, decay, dtype, seed=0):
+    """What ``SSMMixer`` hands the kernel, at heads of 64 on a state of
+    128: the convolution's ``[u | B | C]``, ``in_proj``'s ``[z | ...]``
+    (wider than z), Delta as ``_scan_inputs`` draws it, A 1, D and the
+    norm's weight drawn."""
+    k = jax.random.split(jax.random.key(seed), 6)
+    inner, n = heads * 64, 128
+    lo, hi = (1e-3, 1e-2) if decay == "slow" else (0.5, 1.6)
+    xbc = jax.random.normal(k[0], (batch, seq, inner + 2 * n)).astype(dtype)
+    zx = jax.random.normal(k[1], (batch, seq, 2 * inner + 2 * n + heads))
+    delta = jax.random.uniform(k[2], (batch, seq, heads), minval=lo,
+                               maxval=hi)
+    d = jax.random.normal(k[3], (heads,))
+    weight = 1.0 + 0.1 * jax.random.normal(k[4], (inner,))
+    return xbc, zx.astype(dtype), delta, jnp.ones((heads,)), d, weight
+
+
+@functools.partial(jax.jit, static_argnums=6)
+def _recurrence_gated(xbc, zx, delta, a, d, weight, heads):
+    """The token recurrence, then the gated norm, all in float32."""
+    u, b, c, z = ssd._operands(xbc, zx, heads, 64)
+    y = ssd.ssd_recurrent(u, delta, a, b, c, d).reshape(z.shape)
+    return _rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), weight, 1e-5)
+
+
+#: heads, chunk, length (no multiple of the chunk but in the second), batch
+_KERNEL_CASES = [(4, 16, 40, 2, "slow"), (8, 64, 100, 1, "fast"),
+                 (6, 256, 300, 1, "slow"), (4, 32, 64, 2, "fast")]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads,chunk,seq,batch,decay", _KERNEL_CASES)
+def test_chunk_kernel_with_its_gated_norm_equals_the_recurrence(
+        heads, chunk, seq, batch, decay, dtype):
+    """The kernel in the Pallas interpreter. float32: against the token
+    recurrence and the gated norm, 2e-5 of the range (read 3e-6).
+    bfloat16: against ``ssd_chunked`` and the norm as XLA runs them, and
+    against the float32 recurrence, 0.02 of the range each (read 0.005)."""
+    args = _kernel_inputs(batch, seq, heads, decay, jnp.dtype(dtype))
+    got = ssd.ssd_chunk_kernel(*args, 1e-5, heads, 64, chunk)
+    assert got.shape == (batch, seq, heads * 64) and got.dtype == dtype
+    got = got.astype(jnp.float32)
+    want = _recurrence_gated(*args, heads)
+    scale = float(jnp.abs(want).max())
+    if dtype == "float32":
+        assert float(jnp.abs(got - want).max()) < 2e-5 * scale
+        return
+    xla = jax.jit(ssd.ssd_gated_chunked, static_argnums=range(6, 10))(
+        *args, 1e-5, heads, 64, chunk)
+    assert float(jnp.abs(got - xla.astype(jnp.float32)).max()) < 0.02 * scale
+    assert float(jnp.abs(got - want).max()) < 0.02 * scale
+
+
+def test_chunk_kernels_gradient_is_the_xla_forms():
+    """The backward recomputes through ``ssd_gated_chunked``: every
+    argument's gradient equals the XLA form's."""
+    args = _kernel_inputs(2, 40, 4, "fast", jnp.float32, seed=3)
+    probe = jax.random.normal(jax.random.key(9), (2, 40, 256))
+    grad = lambda fn: jax.jit(jax.grad(
+        lambda *t: jnp.sum(fn(*t, 1e-5, 4, 64, 16) * probe),
+        argnums=range(6)))(*args)
+    got, want = grad(ssd.ssd_chunk_kernel), grad(ssd.ssd_gated_chunked)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 0
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-6 * float(jnp.abs(w).max()))
+
+
+def test_the_planted_fault_reaches_the_kernel():
+    """The benchmark's fault "the state not handed over" patches
+    ``ssd.hand_over``: the kernel, traced after the patch, moves as
+    ``ssd_chunked`` does, and is itself again after it."""
+    args = _kernel_inputs(2, 40, 4, "slow", jnp.float32, seed=4)
+    # a jit of its own a call: what is traced under the patch stays there
+    run = lambda fn: jax.jit(lambda *t: fn(*t, 1e-5, 4, 64, 16))(*args)
+    sound = run(ssd.ssd_chunk_kernel)
+    with mock.patch.object(ssd, "hand_over",
+                           lambda state, decay_end, local: local):
+        cut, cut_xla = run(ssd.ssd_chunk_kernel), run(ssd.ssd_gated_chunked)
+    scale = float(jnp.abs(sound).max())
+    assert float(jnp.abs(cut - sound).max()) > 0.1 * scale
+    np.testing.assert_allclose(cut, cut_xla, atol=2e-5 * scale)
+    np.testing.assert_allclose(cut[:, :16], sound[:, :16], atol=2e-5 * scale)
+    np.testing.assert_allclose(run(ssd.ssd_chunk_kernel), sound, atol=0)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_mixer_on_the_kernel_is_the_mixer_on_xla(dtype):
+    """``SSMMixer`` where ``ssd_formulation`` answers ``chunk_kernel``
+    (heads of 64 on a state of 128, chunks of 16 over 40 tokens) against
+    the same mixer on ``ssd_chunked``: 2e-6 of the range in float32, 0.02
+    in bfloat16; the counter names the kernel."""
+    mixer = SSMMixer(4, 64, 128, 1, 4, 16, dtype=jnp.dtype(dtype),
+                     param_dtype=jnp.float32)
+    x = jax.random.normal(jax.random.key(2), (2, 40, 64))
+    params = mixer.init(jax.random.key(3), x)["params"]
+    params = jax.tree.map(lambda l: l + 0.1 * jax.random.normal(
+        jax.random.key(7), l.shape), params)
+    xla = mixer.apply({"params": params}, x).astype(jnp.float32)
+    before = obs.get_registry().counters("trunk.").get("ssm.chunk_kernel", 0)
+    with mock.patch.object(ssd, "ssd_formulation",
+                           lambda *a: "chunk_kernel"):
+        got = mixer.apply({"params": params}, x).astype(jnp.float32)
+    assert obs.get_registry().counters("trunk.")["ssm.chunk_kernel"] \
+        == before + 1
+    scale = float(jnp.abs(xla).max())
+    tol = 2e-6 if dtype == "float32" else 0.02
+    assert float(jnp.abs(got - xla).max()) < tol * scale
+
+
+def test_the_gated_norm_hands_its_own_weight_to_a_kernel():
+    norm = GatedRMSNorm(1e-5, jnp.float32)
+    y = jnp.ones((2, 32))
+    params = {"params": {"weight": jnp.arange(32.0)}}
+    weight, eps = norm.apply(params, y, weight_only=True)
+    np.testing.assert_array_equal(weight, jnp.arange(32.0))
+    assert eps == 1e-5
+    assert norm.init(jax.random.key(0), y, weight_only=True)["params"][
+        "weight"].shape == (32,)
+
+
+@pytest.mark.parametrize("sizes,dtype,want", [
+    ((128, 64, 128, 1, 256), "bfloat16", "chunk_kernel"),
+    ((4, 64, 128, 1, 16), "bfloat16", "chunk_kernel"),
+    ((128, 64, 128, 1, 256), "float32", "chunked_xla"),
+    ((64, 128, 128, 1, 256), "bfloat16", "chunked_xla"),
+    ((5, 64, 128, 1, 256), "bfloat16", "chunked_xla"),
+    ((128, 64, 64, 1, 256), "bfloat16", "chunked_xla"),
+    ((128, 64, 128, 2, 256), "bfloat16", "chunked_xla"),
+    ((128, 64, 128, 1, 512), "bfloat16", "chunked_xla")])
+def test_ssd_formulation_decides_from_what_it_observes(monkeypatch, sizes,
+                                                       dtype, want):
+    """On a TPU, with the gate passing: bfloat16, heads of 64 in pairs, a
+    state of 128, one group, chunks up to 256; off a TPU always the XLA
+    form, whose gate refuses by the backend."""
+    assert ssd.ssd_formulation(*sizes, jnp.dtype(dtype)) == "chunked_xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ssd, "ssd_kernel_ok", lambda heads, chunk: True)
+    assert ssd.ssd_formulation(*sizes, jnp.dtype(dtype)) == want
 
 
 # ------------------------------------------------- the mixer's other parts
