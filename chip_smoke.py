@@ -410,7 +410,11 @@ def check_gqa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
     only in the interpreter: held here to the blocked XLA form at the
     backbone's own shape (its query and key-value heads, their width, its
     tokens, its scale, its mask: causal or block-causal by its diffusion
-    block). Returns whether a trace of the backbone takes the kernel."""
+    block) and with its own prologue: where the backbone has q/k norms
+    (weights drawn about the benchmark's 1.75) and rotary, the kernel norms
+    and turns q and k itself and the blocked form is handed them normed and
+    turned, as the mixer's XLA path does. Returns whether a trace of the
+    backbone takes the kernel."""
     import jax
     import jax.numpy as jnp
 
@@ -423,26 +427,34 @@ def check_gqa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
             "nothing to hold")
         return False
     h, hkv, d = z["num_heads"], z["kv_heads"], z["head_dim"]
-    n = z.get("diffusion_block", 1)
+    n, theta = z.get("diffusion_block", 1), z.get("rope_theta")
+    qk_norm = bool(z.get("qk_norm"))
     seq, bf = (size.image_size // 16) ** 2, jnp.bfloat16
-    formulation = causal_attn.gqa_formulation(seq, h, hkv, d, bf, n)
+    formulation = causal_attn.gqa_formulation(seq, h, hkv, d, bf, n,
+                                              qk_norm, theta is not None)
     say(f"  grouped-query attention: gqa_formulation({seq}, {h}, {hkv}, {d}, "
-        f"bfloat16, {n}) = {formulation}")
+        f"bfloat16, {n}, qk_norm={qk_norm}, rope={theta is not None}) = "
+        f"{formulation}")
     if formulation != "causal_kernel":
         return False
-    ks = jax.random.split(jax.random.key(seed), 3)
+    ks = jax.random.split(jax.random.key(seed), 5)
     q = jax.random.normal(ks[0], (batch, seq, h * d)).astype(bf)
     k = jax.random.normal(ks[1], (batch, seq, hkv * d)).astype(bf)
     v = jax.random.normal(ks[2], (batch, seq, hkv * d)).astype(bf)
+    weight = lambda key: jax.random.uniform(key, (d,), minval=1.25,
+                                            maxval=2.25).astype(bf)
+    norms = ((weight(ks[3]), weight(ks[4]), float(z.get("norm_eps", 1e-5)))
+             if qk_norm else None)
     run = lambda fn: np.asarray(jax.jit(
-        lambda *a: fn(*a, h, hkv, z["attn_scale"], diffusion_block=n))(
-            q, k, v), np.float32)
+        lambda *a: fn(*a, h, hkv, z["attn_scale"], norms, theta,
+                      diffusion_block=n))(q, k, v), np.float32)
     got = run(causal_attn.gqa_attention_kernel)
     want = run(causal_attn.gqa_attention_blocked)
     gap = float(np.abs(got - want).max() / np.abs(want).max())
     say(f"  grouped-query attention, {batch} x {seq} tokens x {h} heads on "
-        f"{hkv} of {d}, diffusion block {n}: the kernel against the blocked "
-        f"form, widest gap {gap:.5f} of the range (bfloat16 on both sides)")
+        f"{hkv} of {d}, diffusion block {n}, q/k norms {qk_norm}, rotary "
+        f"theta {theta}: the kernel against the blocked form, widest gap "
+        f"{gap:.5f} of the range (bfloat16 on both sides)")
     check(np.isfinite(got).all() and gap < 0.02,
           "grouped-query attention's kernel equals the blocked form at the "
           "backbone's shape")
@@ -764,8 +776,9 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
         say("  trunk_pairs of every compiled program: "
             f"{[t.get('trunk_pairs') for t in traced]}")
         layers = lambda mixer: sum(m == mixer for m, _ in z["layers"])
-        gqa = ("causal_kernel" if verdicts.get("gqa_kernel_ok")
-               else "blocked_xla") + ("_rope" if z.get("rope_theta") else "")
+        gqa = ("causal_kernel" + ("_qknorm" if z.get("qk_norm") else "")
+               if verdicts.get("gqa_kernel_ok") else "blocked_xla") + (
+                   "_rope" if z.get("rope_theta") else "")
         if z.get("diffusion_block", 1) > 1:
             gqa += f"_block{z['diffusion_block']}"
         ssm = ("chunk_kernel" if verdicts.get("ssd_kernel_ok")

@@ -522,11 +522,14 @@ def test_gqa_layer_keeps_its_scores_on_the_chip(family, batch, one_chip,
                                                 as_tpu):
     """One grouped-query attention layer at a trunk cell's widths and
     batch, compiled for the v5e: scores, softmax and values are one Mosaic
-    kernel and nothing else under ``attn/softmax/``, and no operand the size
-    of k or larger is copied, transposed, concatenated or padded by the
-    entry computation under ``attn/`` but by the rotation: heads turned as
-    (B, S, H, d) are copied once to the (B, S, H x d) the kernel reads (q
-    and k: a relayout the tiling makes; no rotation, no copy)."""
+    kernel under ``attn/softmax/``, beside which stand only the operands of
+    its prologue where the trunk has q/k norms or rotary (the rotary's table
+    as a constant, the norms' two weights over a slab: 256 numbers); no op
+    is left under ``rope/``, ``q_norm/`` or ``k_norm/``, and no operand the
+    size of k or larger is copied, transposed, concatenated or padded by the
+    entry computation under ``attn/``: the kernel reads q and k where the
+    projections wrote them (the (B, S, H x d) copies of q and k the rotation
+    left before the norms and the rotation went into the kernel are gone)."""
     import re
 
     from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, GQAMixer
@@ -545,24 +548,84 @@ def test_gqa_layer_keeps_its_scores_on_the_chip(family, batch, one_chip,
         jax.eval_shape(mixer.init, jax.random.key(0), x))
     text = jax.jit(mixer.apply).lower(params, x).compile().as_text()
     assert text.count("tpu_custom_call") == 1
-    under_softmax = {m.group(1) for m in re.finditer(
-        r"= \S+ ([\w\-]+)\([^\n]*op_name=\"[^\"]*attn/softmax/", text)}
-    assert under_softmax == {"custom-call"}, under_softmax
+
+    def elems(dims):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        return n
+
+    under_softmax = {}  # op -> its outputs' types
+    for m in re.finditer(r"= (\w+)\[([\d,]*)\]\S* ([\w\-]+)\([^\n]*"
+                         r"op_name=\"[^\"]*attn/softmax/", text):
+        under_softmax.setdefault(m.group(3), set()).add(
+            (m.group(1), elems(m.group(2))))
+    q_elems = batch * s * z["num_heads"] * z["head_dim"]
+    assert under_softmax.pop("custom-call") == {("bf16", q_elems)}
+    if z.get("rope_theta"):
+        assert under_softmax.pop("constant") == {("f32", s * 128)}
+    assert all(n <= 2 * 128 for outs in under_softmax.values()
+               for _, n in outs), under_softmax
+    if not (z.get("qk_norm") or z.get("rope_theta")):
+        assert under_softmax == {}
+    assert not re.search(r"op_name=\"[^\"]*attn/(rope|q_norm|k_norm)/", text)
     k_elems = batch * s * z["kv_heads"] * z["head_dim"]
     moved = []  # by the entry computation: what the device runs
     for m in re.finditer(r"= \w+\[([\d,]*)\]\S* ([\w\-]+)\([^\n]*"
                          r"op_name=\"[^\"]*attn/(\w+)",
                          text[text.index("\nENTRY"):]):
-        elems = 1
-        for d in filter(None, m.group(1).split(",")):
-            elems *= int(d)
         if m.group(2) in ("transpose", "copy", "concatenate", "pad") \
-                and elems >= k_elems:
-            moved.append((m.group(2), m.group(3), elems))
-    q_elems = k_elems * z["num_heads"] // z["kv_heads"]
-    want = [("copy", "rope", q_elems), ("copy", "rope", k_elems)] \
-        if z.get("rope_theta") else []
-    assert sorted(moved, reverse=True) == want, moved
+                and elems(m.group(1)) >= k_elems:
+            moved.append((m.group(2), m.group(3), elems(m.group(1))))
+    assert moved == [], moved
+
+
+def _without_locations(text: str) -> str:
+    """A compiled program's text without what says where its ops were
+    written: the tables of files, functions and stack frames, each op's
+    ``metadata``, and the locations inside each Mosaic kernel's serialized
+    body (which carries the file and line of every op)."""
+    import base64
+    import json
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    def body(m):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            return '"body":' + json.dumps(
+                module.operation.get_asm(enable_debug_info=False))
+
+    text = text[text.index("\n%"):]  # the computations
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return re.sub(r'"body":"([A-Za-z0-9+/=]+)"', body, text)
+
+
+def test_a_gqa_layer_without_norms_or_rotary_compiles_as_before(one_chip,
+                                                               as_tpu):
+    """``granite4h_fscd147.eval``'s attention layer (heads of 128, neither
+    q/k norms nor rotary) compiled for the v5e with the kernel admitted:
+    its program (instructions, layouts, schedule and the kernel's body),
+    with every location stripped, is the one it had before the kernel took
+    a prologue (sha256 pinned from that tree)."""
+    import hashlib
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, GQAMixer
+
+    z = TRUNK_CONFIGS["granite4_h_small_share2"]
+    mixer = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
+                     z["attn_scale"], dtype=jnp.bfloat16, name="attn")
+    x = jax.ShapeDtypeStruct((2, _KDA["seq"], z["hidden"]), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), x))
+    text = jax.jit(mixer.apply).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert hashlib.sha256(_without_locations(text).encode()).hexdigest() \
+        == "27a50ef09e7b43e2e0842ada0c268037589c9b4644a0be3bcb1bcf8716ed1e04"
 
 
 def test_ssm_layer_reads_the_scans_operands_where_they_were_written(

@@ -18,6 +18,7 @@ from tmr_tpu import diagnostics, obs
 from tmr_tpu.models.lm_trunk import build_lm_trunk
 from tmr_tpu.obs.compile import _trunk_attrs
 from tmr_tpu.ops import causal_attn, rope
+from tmr_tpu.ops.kda import rms_norm
 from tmr_tpu.ops.causal_attn import (gqa_attention_blocked,
                                      gqa_attention_kernel, gqa_formulation)
 
@@ -64,7 +65,33 @@ def _masked_softmax(q, k, v, heads, kv_heads, diffusion_block=1):
     return np.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, s, -1)
 
 
-@pytest.mark.parametrize("rotated", [False, True], ids=["nope", "rope"])
+def _norms(head_dim, seed):
+    """q's and k's norm weights about the benchmark's 1.75, and eps."""
+    ks = jax.random.split(jax.random.key(100 + seed))
+    draw = lambda key: jax.random.uniform(key, (head_dim,), minval=1.25,
+                                          maxval=2.25).astype(jnp.bfloat16)
+    return draw(ks[0]), draw(ks[1]), 1e-6
+
+
+def _xla_path(q, k, heads, kv_heads, norms, theta):
+    """q and k as ``GQAMixer``'s XLA path hands them to the blocked form on
+    the chip: each head's RMSNorm, then the default rotary, in float32 and
+    rounded once."""
+    b, s, _ = q.shape
+    wq, wk, eps = norms
+    out = []
+    for t, h, w in ((q, heads, wq), (k, kv_heads, wk)):
+        t = rms_norm(t.reshape(b, s, h, -1), w, eps)
+        t = rope.rotate(t, jnp.arange(s),
+                        rope.default_inv_freq(t.shape[-1], theta))
+        out.append(t.astype(q.dtype).reshape(b, s, -1))
+    return out
+
+
+# "qknorm_rope": q and k as the projections wrote them, normed and turned by
+# the kernel's prologue; "_block4" under diffusion blocks of 4
+@pytest.mark.parametrize("rotated", ["nope", "rope", "qknorm_rope",
+                                     "qknorm_rope_block4"])
 @pytest.mark.parametrize("batch", [1, 2])
 @pytest.mark.parametrize("blocks", [1, 2, 4])
 # two slabs of k and v: query heads reach a key-value slab past the first,
@@ -76,27 +103,38 @@ def test_kernel_equals_the_blocked_form_and_a_masked_softmax(
         head_dim, group, slabs, blocks, batch, rotated):
     """bfloat16 operands on both sides, rounded in other places: 0.01 of
     outputs of order 1 against each other, and the kernel no further from
-    the float32 softmax than the blocked form is."""
+    the float32 softmax than the blocked form is. With the prologue, the
+    blocked form is the mixer's XLA path (norms, rotary, blocked form) and
+    the softmax is taken over that path's q and k."""
+    prologue = rotated.startswith("qknorm")
+    n = 4 if rotated.endswith("_block4") else 1
     q, k, v, heads, kv_heads = _operands(blocks * BLOCK, head_dim, group,
-                                         batch, rotated, seed=blocks,
-                                         slabs=slabs)
+                                         batch, rotated == "rope",
+                                         seed=blocks, slabs=slabs)
     scale = head_dim ** -0.5
-    got = np.asarray(gqa_attention_kernel(q, k, v, heads, kv_heads, scale,
-                                          (BLOCK, BLOCK)), np.float32)
-    blocked = np.asarray(gqa_attention_blocked(q, k, v, heads, kv_heads,
-                                               scale), np.float32)
-    want = _masked_softmax(q, k, v, heads, kv_heads)
+    norms, theta, seen = None, None, (q, k)
+    if prologue:
+        norms, theta = _norms(head_dim, blocks), 1e6
+        seen = _xla_path(q, k, heads, kv_heads, norms, theta)
+    got = np.asarray(gqa_attention_kernel(
+        q, k, v, heads, kv_heads, scale, norms, theta, block=(BLOCK, BLOCK),
+        diffusion_block=n), np.float32)
+    blocked = np.asarray(gqa_attention_blocked(
+        q, k, v, heads, kv_heads, scale, norms, theta, n), np.float32)
+    want = _masked_softmax(*seen, v, heads, kv_heads, n)
     assert np.isfinite(got).all()
     span = np.abs(want).max()
     assert np.abs(got - blocked).max() < 1e-2 * span
     assert np.abs(got - want).max() < 5e-3 * span
     assert np.abs(got - want).max() < 1.5 * np.abs(blocked - want).max() \
         + 1e-3 * span
-    # the first row's only key is itself: each head's output is its key-value
-    # head's value there
-    v0 = np.asarray(v, np.float32).reshape(batch, -1, kv_heads, head_dim)[:, 0]
-    np.testing.assert_array_equal(
-        got[:, 0], np.repeat(v0, group, axis=1).reshape(batch, -1))
+    if n == 1:
+        # the first row's only key is itself: each head's output is its
+        # key-value head's value there
+        v0 = np.asarray(v, np.float32).reshape(
+            batch, -1, kv_heads, head_dim)[:, 0]
+        np.testing.assert_array_equal(
+            got[:, 0], np.repeat(v0, group, axis=1).reshape(batch, -1))
 
 
 @pytest.mark.parametrize("diffusion_block", [1, 4, 16])
@@ -112,10 +150,11 @@ def test_block_causal_kernel_equals_the_blocked_form_and_a_masked_softmax(
                                          rotated=True, seed=diffusion_block)
     scale = head_dim ** -0.5
     got = np.asarray(gqa_attention_kernel(
-        q, k, v, heads, kv_heads, scale, (BLOCK, BLOCK),
+        q, k, v, heads, kv_heads, scale, block=(BLOCK, BLOCK),
         diffusion_block=diffusion_block), np.float32)
     blocked = np.asarray(gqa_attention_blocked(
-        q, k, v, heads, kv_heads, scale, diffusion_block), np.float32)
+        q, k, v, heads, kv_heads, scale, diffusion_block=diffusion_block),
+        np.float32)
     want = _masked_softmax(q, k, v, heads, kv_heads, diffusion_block)
     assert np.isfinite(got).all()
     span = np.abs(want).max()
@@ -139,9 +178,10 @@ def test_a_query_sees_its_own_diffusion_block_and_none_after_it(form,
     def run(k, v):
         if form == "kernel":
             o = gqa_attention_kernel(q, k, v, heads, kv_heads, scale,
-                                     (BLOCK, BLOCK), diffusion_block=n)
+                                     block=(BLOCK, BLOCK), diffusion_block=n)
         else:
-            o = gqa_attention_blocked(q, k, v, heads, kv_heads, scale, n)
+            o = gqa_attention_blocked(q, k, v, heads, kv_heads, scale,
+                                      diffusion_block=n)
         return np.asarray(o, np.float32)
 
     got = run(k, v)
@@ -166,7 +206,8 @@ def test_a_diffusion_block_of_one_lowers_as_the_causal_call(form):
     if form == "kernel":
         call = lambda **kw: jax.make_jaxpr(
             lambda q, k, v: gqa_attention_kernel(
-                q, k, v, heads, kv_heads, scale, (BLOCK, BLOCK), **kw))(
+                q, k, v, heads, kv_heads, scale, block=(BLOCK, BLOCK),
+                **kw))(
                     q, k, v)
     else:
         call = lambda **kw: jax.jit(lambda q, k, v: gqa_attention_blocked(
@@ -187,7 +228,7 @@ def test_a_query_block_sees_no_key_after_it(head_dim):
     """Keys and values after a query block changed: its rows do not."""
     q, k, v, heads, kv_heads = _operands(2 * BLOCK, head_dim, 4)
     run = lambda k, v: np.asarray(gqa_attention_kernel(
-        q, k, v, heads, kv_heads, head_dim ** -0.5, (BLOCK, BLOCK)),
+        q, k, v, heads, kv_heads, head_dim ** -0.5, block=(BLOCK, BLOCK)),
         np.float32)
     got = run(k, v)
     again = run(k.at[:, BLOCK + 5:].set(7.0), v.at[:, BLOCK + 5:].set(-3.0))
@@ -201,18 +242,103 @@ def test_kernel_differentiates_through_the_blocked_form(head_dim):
     w = jax.random.normal(jax.random.key(9), q.shape)
     scale = head_dim ** -0.5
 
-    def loss(fn, *extra):
+    def loss(fn, **extra):
         return lambda q, k, v: jnp.sum(
-            fn(q, k, v, heads, kv_heads, scale, *extra).astype(jnp.float32)
+            fn(q, k, v, heads, kv_heads, scale, **extra).astype(jnp.float32)
             * w)
 
-    got = jax.grad(loss(gqa_attention_kernel, (BLOCK, BLOCK)),
+    got = jax.grad(loss(gqa_attention_kernel, block=(BLOCK, BLOCK)),
                    (0, 1, 2))(q, k, v)
     want = jax.grad(loss(gqa_attention_blocked), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         assert a.dtype == b.dtype and a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_kernel_with_its_prologue_differentiates_through_the_blocked_form(
+        head_dim):
+    """With the q/k norms and the rotary, the gradients of q, k, v and both
+    norm weights are the blocked form's (which norms and turns first)."""
+    q, k, v, heads, kv_heads = _operands(2 * BLOCK, head_dim, 4, batch=2)
+    wq, wk, eps = _norms(head_dim, 0)
+    w = jax.random.normal(jax.random.key(9), q.shape)
+    scale = head_dim ** -0.5
+
+    def loss(fn, **extra):
+        return lambda q, k, v, wq, wk: jnp.sum(
+            fn(q, k, v, heads, kv_heads, scale, (wq, wk, eps), 1e6,
+               **extra).astype(jnp.float32) * w)
+
+    got = jax.grad(loss(gqa_attention_kernel, block=(BLOCK, BLOCK)),
+                   tuple(range(5)))(q, k, v, wq, wk)
+    want = jax.grad(loss(gqa_attention_blocked), tuple(range(5)))(
+        q, k, v, wq, wk)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    assert np.abs(np.asarray(got[3], np.float32)).max() > 0
+
+
+@pytest.mark.parametrize("family", ["lfm2_24b_a2b_share8",
+                                    "sdar_30b_a3b_share8"])
+def test_the_mixer_norms_and_turns_in_the_kernel_as_its_xla_path_does(
+        family, monkeypatch):
+    """``GQAMixer`` at the trunk's heads (of 64 in pairs, groups of 4; of
+    128, groups of 8 under diffusion blocks of 4), its norms, eps and
+    rotary, 1,024 tokens, norm weights about 1.75: with the gate's yes the
+    kernel is handed q and k as the projections wrote them and norms and
+    turns them itself; the layer's output is its XLA path's (norms,
+    rotary, blocked form) from the same leaves, within bfloat16's
+    rounding."""
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, GQAMixer
+
+    z = TRUNK_CONFIGS[family]
+    heads, kv_heads = (8, 2) if z["head_dim"] == 64 else (16, 2)
+    mixer = GQAMixer(heads, kv_heads, z["head_dim"], z["attn_scale"],
+                     dtype=jnp.bfloat16, qk_norm=z["qk_norm"],
+                     rope_theta=z["rope_theta"],
+                     norm_eps=z.get("norm_eps", 1e-5),
+                     diffusion_block=z.get("diffusion_block", 1))
+    x = jax.random.normal(jax.random.key(3), (1, 1024, 64), jnp.bfloat16)
+    params = mixer.init(jax.random.key(4), x)
+    params = jax.tree.map(lambda p: p * 1.75 if p.ndim == 1 else p, params)
+    counts = lambda: obs.get_registry().counters("trunk.gqa.")
+    before = counts()
+    xla = np.asarray(mixer.apply(params, x), np.float32)
+    monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda *a: True)
+    fused = np.asarray(mixer.apply(params, x), np.float32)
+    block = "_block4" if family.startswith("sdar") else ""
+    assert {n for n, c in counts().items() if c > before.get(n, 0)} == {
+        f"blocked_xla_rope{block}", f"causal_kernel_qknorm_rope{block}"}
+    span = np.abs(xla).max()
+    assert np.isfinite(fused).all()
+    assert np.abs(fused - xla).max() < 1e-2 * span
+
+
+def test_the_gate_is_asked_with_the_mixers_prologue(monkeypatch):
+    """Each trunk's attention asks the gate at its head width, diffusion
+    block, q/k norms and rotary: the kernel it would run is the one
+    checked."""
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, GQAMixer
+
+    asked = []
+    monkeypatch.setattr(causal_attn, "gqa_kernel_ok",
+                        lambda *a: asked.append(a) or False)
+    for family, want in (("granite4_h_small_share2", (128, 1, False, False)),
+                         ("lfm2_24b_a2b_share8", (64, 1, True, True)),
+                         ("sdar_30b_a3b_share8", (128, 4, True, True))):
+        z = TRUNK_CONFIGS[family]
+        mixer = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
+                         z["attn_scale"], dtype=jnp.bfloat16,
+                         qk_norm=z.get("qk_norm", False),
+                         rope_theta=z.get("rope_theta"),
+                         diffusion_block=z.get("diffusion_block", 1))
+        x = jax.ShapeDtypeStruct((1, 4096, z["hidden"]), jnp.bfloat16)
+        jax.eval_shape(mixer.init, jax.random.key(0), x)
+        assert asked.pop() == want
 
 
 @pytest.mark.parametrize("case,want", [
@@ -230,7 +356,7 @@ def test_gqa_formulation_by_what_it_observes(case, want, monkeypatch):
     and in a trace XLA partitions the gate says why."""
     if case not in ("cpu_backend", "partitioned"):  # there the gate answers
         monkeypatch.setattr(causal_attn, "gqa_kernel_ok",
-                            lambda d, n=1: True)
+                            lambda *a: True)
     seq, heads, kv_heads, head_dim, dtype = {
         "granite": (4096, 32, 8, 128, jnp.bfloat16),
         "the_1536_bucket": (9216, 32, 8, 64, jnp.bfloat16),
@@ -288,16 +414,21 @@ _TRUNKS = {
 
 @pytest.mark.parametrize("name,formulation", [
     ("granite4_h_small_share2", "causal_kernel"),
-    ("lfm2_24b_a2b_share8", "causal_kernel_rope"),
-    ("sdar_30b_a3b_share8", "causal_kernel_rope_block4")])
+    ("lfm2_24b_a2b_share8", "causal_kernel_qknorm_rope"),
+    ("sdar_30b_a3b_share8", "causal_kernel_qknorm_rope_block4")],
+    ids=["granite4_h_small_share2-causal_kernel",
+         "lfm2_24b_a2b_share8-causal_kernel_rope",
+         "sdar_30b_a3b_share8-causal_kernel_rope_block4"])
 def test_a_trunk_traced_with_the_kernel_names_it_where_the_metrics_read(
         name, formulation, monkeypatch):
     """With the gate's yes, every attention layer counts
-    ``trunk.gqa.<kernel's name>`` (the ``compile`` span's ``trunk_gqa``)
-    and the lowered text carries the kernel's call under
-    ``backbone/layers_N/attn/softmax/``, the scope ``trunk.gqa.ms`` owns
-    time by; without it, the blocked form's. The trunk at its heads' widths
-    in bfloat16, tiny everything else, 1,024 patches: two query blocks."""
+    ``trunk.gqa.<kernel's name>`` (the ``compile`` span's ``trunk_gqa``;
+    ``_qknorm`` where the norms ride in the kernel) and the lowered text
+    carries the kernel's call under ``backbone/layers_N/attn/softmax/``, the
+    scope ``trunk.gqa.ms`` owns time by, and nothing under the layer's
+    ``rope/``, ``q_norm/`` or ``k_norm/``; without it, the blocked form's.
+    The trunk at its heads' widths in bfloat16, tiny everything else, 1,024
+    patches: two query blocks."""
     over = _TRUNKS[name]
     model = build_lm_trunk(name, dtype=jnp.bfloat16, **over)
     image = jax.ShapeDtypeStruct((1, 512, 512, 3), jnp.bfloat16)
@@ -314,12 +445,17 @@ def test_a_trunk_traced_with_the_kernel_names_it_where_the_metrics_read(
 
     call = r"layers_(\d+)/attn/softmax/[^\"]*_gqa_kernel_fwd_impl"
     attr, text = lowered()
-    blocked = formulation.replace("causal_kernel", "blocked_xla")
+    blocked = formulation.replace("causal_kernel", "blocked_xla").replace(
+        "_qknorm", "")
     assert attr == f"{blocked} x{len(gqa_layers)}"
     assert not re.search(call, text)
-    monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda d, n=1: True)
+    prologue = r"layers_\d+/attn/(rope|q_norm|k_norm)/"
+    nope = name == "granite4_h_small_share2"
+    assert bool(re.search(prologue, text)) != nope
+    monkeypatch.setattr(causal_attn, "gqa_kernel_ok", lambda *a: True)
     attr, text = lowered()
     assert attr == f"{formulation} x{len(gqa_layers)}"
     assert sorted({int(n) for n in re.findall(call, text)}) == gqa_layers
     for layer in gqa_layers:  # and nothing of the blocked form is left
         assert f"layers_{layer}/attn/softmax/bqgrd" not in text
+    assert not re.search(prologue, text)

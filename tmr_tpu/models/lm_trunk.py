@@ -80,7 +80,8 @@ STATS = "trunk_stats"
 #: attention (``ops/causal_attn.py:mla_formulation``, with ``_rope`` after
 #: the name where the trunk has rotary), the state-space recurrence
 #: (``ops/ssd.py:ssd_formulation``), grouped-query attention
-#: (``ops/causal_attn.py:gqa_formulation``, ``_rope`` likewise), the gated
+#: (``ops/causal_attn.py:gqa_formulation``, ``_rope`` likewise, and
+#: ``_qknorm`` ahead of it where the kernel norms q and k), the gated
 #: short convolution (``ops/short_conv.py:short_conv_formulation``), the
 #: experts' grouped products
 #: (``ops/moe.py:grouped_formulation``) and the hyper-connections
@@ -373,9 +374,12 @@ class GQAMixer(nn.Module):
     out once a query head. ``qk_norm``: an RMSNorm over a
     head's dims on q and on k (``q_norm``, ``k_norm``), then with
     ``rope_theta`` the default rotary on both (under ``rope/``); else
-    neither (NoPE). ``diffusion_block`` L over 1: block diffusion's
-    block-causal mask in place of the causal one (the counter's name ends
-    in ``_block<L>``)."""
+    neither (NoPE). Where the kernel runs, it applies both itself to q and
+    k as the projections wrote them (the norms' weights handed over by
+    ``RMSNorm(weight_only=True)``), and the counter's name gains
+    ``_qknorm`` where the norms went in. ``diffusion_block`` L over 1:
+    block diffusion's block-causal mask in place of the causal one (the
+    counter's name ends in ``_block<L>``)."""
 
     num_heads: int
     kv_heads: int
@@ -394,34 +398,45 @@ class GQAMixer(nn.Module):
         h, hkv, d = self.num_heads, self.kv_heads, self.head_dim
         lin = lambda width, name: _linear(width, self.dtype,
                                           self.param_dtype, name)
-        q = lin(h * d, "q_proj")(x).reshape(b, s, h, d)
-        k = lin(hkv * d, "k_proj")(x).reshape(b, s, hkv, d)
-        v = lin(hkv * d, "v_proj")(x).reshape(b, s, hkv, d)
-        if self.qk_norm:
-            norm = lambda name: RMSNorm(self.norm_eps, self.param_dtype,
-                                        name=name)
-            q, k = norm("q_norm")(q), norm("k_norm")(k)
-        rope = ""
-        if self.rope_theta is not None:
-            inv_freq = rope_ops.default_inv_freq(d, self.rope_theta)
-            with jax.named_scope("rope"):
-                q = rope_ops.rotate(q, jnp.arange(s), inv_freq)
-                k = rope_ops.rotate(k, jnp.arange(s), inv_freq)
-            rope = "_rope"
-        n = self.diffusion_block
-        formulation = gqa_formulation(s, h, hkv, d, self.dtype, n)
-        block = f"_block{n}" if n > 1 else ""
-        metrics.counter(f"trunk.gqa.{formulation}{rope}{block}").inc()
-        with jax.named_scope("softmax"):
-            if formulation == "causal_kernel":
-                o = gqa_attention_kernel(
-                    q.reshape(b, s, h * d), k.reshape(b, s, hkv * d),
-                    v.reshape(b, s, hkv * d), h, hkv, self.scale,
-                    diffusion_block=n)
-            else:
-                # at L = 1 the four-argument call, which the benchmark's
-                # granite fault replaces by a function of four arguments
-                mask = {"diffusion_block": n} if n > 1 else {}
+        norm = lambda name: RMSNorm(self.norm_eps, self.param_dtype,
+                                    name=name)
+        n, rope = self.diffusion_block, self.rope_theta is not None
+        formulation = gqa_formulation(s, h, hkv, d, self.dtype, n,
+                                      self.qk_norm, rope)
+        # the kernel norms and turns q and k itself, where they were written
+        kernel = formulation == "causal_kernel"
+        metrics.counter(
+            f"trunk.gqa.{formulation}"
+            + ("_qknorm" if kernel and self.qk_norm else "")
+            + ("_rope" if rope else "") + (f"_block{n}" if n > 1 else "")
+        ).inc()
+        shape = (b, s, -1) if kernel else (b, s, -1, d)
+        q = lin(h * d, "q_proj")(x).reshape(shape)
+        k = lin(hkv * d, "k_proj")(x).reshape(shape)
+        v = lin(hkv * d, "v_proj")(x).reshape(shape)
+        if kernel:
+            qk_norm = None
+            if self.qk_norm:
+                head = jax.ShapeDtypeStruct((d,), self.dtype)  # its width
+                (wq, eps), (wk, _) = (norm(name)(head, weight_only=True)
+                                      for name in ("q_norm", "k_norm"))
+                qk_norm = (wq, wk, eps)
+            with jax.named_scope("softmax"):
+                o = gqa_attention_kernel(q, k, v, h, hkv, self.scale,
+                                         qk_norm, self.rope_theta,
+                                         diffusion_block=n)
+        else:
+            if self.qk_norm:
+                q, k = norm("q_norm")(q), norm("k_norm")(k)
+            if rope:
+                inv_freq = rope_ops.default_inv_freq(d, self.rope_theta)
+                with jax.named_scope("rope"):
+                    q = rope_ops.rotate(q, jnp.arange(s), inv_freq)
+                    k = rope_ops.rotate(k, jnp.arange(s), inv_freq)
+            # at L = 1 the four-argument call, which the benchmark's granite
+            # fault replaces by a function of four arguments
+            mask = {"diffusion_block": n} if n > 1 else {}
+            with jax.named_scope("softmax"):
                 o = causal_attention_blocked(q, k, v, self.scale, **mask)
         return lin(x.shape[-1], "o_proj")(o.reshape(b, s, h * d))
 

@@ -25,7 +25,8 @@ block's whole row of scores stays in VMEM and a head's keys and values stay
 there across its query blocks. :func:`gqa_attention_kernel` is the same
 kernel's two passes (:func:`_causal_softmax`) on grouped-query attention's
 ``q`` (B, S, H x d), ``k`` and ``v`` (B, S, Hkv x d), a key-value slab read
-once for all the query heads that share it. :func:`mla_formulation` and
+once for all the query heads that share it, with the mixer's q/k norms and
+rotary applied inside it where the trunk has them. :func:`mla_formulation` and
 :func:`gqa_formulation` say which form a trace takes; the blocked form is
 each kernel's fallback, its gate's oracle and its backward pass.
 """
@@ -43,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tmr_tpu.diagnostics import mosaic_gate
 from tmr_tpu.ops import rope as rope_ops
+from tmr_tpu.ops.kda import rms_norm
 
 
 def causal_attention_blocked(q, k, v, scale: float, block: int = 256,
@@ -86,15 +88,29 @@ def _after(lo: int, hi: int, end: int, diffusion_block: int):
 
 
 def gqa_attention_blocked(q, k, v, heads: int, kv_heads: int,
-                          scale: float, diffusion_block: int = 1):
+                          scale: float, qk_norm=None, theta=None,
+                          diffusion_block: int = 1):
     """:func:`causal_attention_blocked` on the projections' own outputs (the
     kernel's signature, :func:`gqa_attention_kernel`): ``q`` (B, S, H x d),
-    ``k`` and ``v`` (B, S, Hkv x d) -> (B, S, H x d)."""
+    ``k`` and ``v`` (B, S, Hkv x d) -> (B, S, H x d). ``qk_norm`` (q's
+    weight, k's weight, eps): an RMSNorm over each head's dims on q and on k
+    first, and ``theta``: the default rotary on both after it, in float32
+    and rounded to the operands' dtype once, as the chip's XLA computes
+    ``GQAMixer``'s path (its fusion keeps the norm's output in float32)."""
     b, s, _ = q.shape
     d = q.shape[-1] // heads
-    o = causal_attention_blocked(q.reshape(b, s, heads, d),
-                                 k.reshape(b, s, kv_heads, d),
-                                 v.reshape(b, s, kv_heads, d), scale,
+    q, k = q.reshape(b, s, heads, d), k.reshape(b, s, kv_heads, d)
+    if qk_norm is not None or theta is not None:
+        dtype, (q, k) = q.dtype, (t.astype(jnp.float32) for t in (q, k))
+        if qk_norm is not None:
+            wq, wk, eps = qk_norm
+            q, k = rms_norm(q, wq, eps), rms_norm(k, wk, eps)
+        if theta is not None:
+            inv_freq = rope_ops.default_inv_freq(d, theta)
+            q, k = (rope_ops.rotate(t, jnp.arange(s), inv_freq)
+                    for t in (q, k))
+        q, k = q.astype(dtype), k.astype(dtype)
+    o = causal_attention_blocked(q, k, v.reshape(b, s, kv_heads, d), scale,
                                  diffusion_block=diffusion_block)
     return o.reshape(b, s, -1)
 
@@ -440,13 +456,12 @@ def mla_formulation(seq: int, heads: int, dn: int, dp: int, dv: int, dtype,
 # Grouped-query attention as the same kernel (formulation ``causal_kernel``).
 #
 # q (B, S, H x d), k and v (B, S, Hkv x d) are read where the projections
-# (and the mixer's norms and rotation) wrote them: no (B, S, H, d) relayout
-# and no key-value head written out once a query head. One grid step is one
-# query block of one query head; the grid is (image, slab of q, query block,
-# head within the slab), the query blocks and the heads of a slab
-# innermost. A head reads the 128-lane slab of k and of v that holds its
-# key-value head, which depends on neither, so Pallas fetches it once for
-# all the query heads that share it.
+# wrote them: no (B, S, H, d) relayout and no key-value head written out
+# once a query head. One grid step is one query block of one query head;
+# the grid is (image, slab of q, query block, head within the slab), the
+# query blocks and the heads of a slab innermost. A head reads the 128-lane
+# slab of k and of v that holds its key-value head, which depends on
+# neither, so Pallas fetches it once for all the query heads that share it.
 #
 # - heads of 128: a slab is one head and one key-value head.
 # - heads of 64: a slab of q holds a pair of heads, and a slab of k a pair
@@ -456,23 +471,134 @@ def mla_formulation(seq: int, heads: int, dn: int, dp: int, dv: int, dtype,
 #   head's scores; the values product's (bq, 128) carries the key-value
 #   head's 64 columns in that same half, rolled back to the head's half of
 #   the output block, which the pair's two steps share.
+# - with the mixer's q/k norms or its rotary (the prologue), q and k are
+#   read as the projections wrote them and turned into what the XLA path
+#   would have handed over (:func:`_norm_turn`): each head's RMSNorm over
+#   its own lanes (two masked sums over lanes where a slab holds two
+#   heads), then the rotation as a multiply-add with each head's halves
+#   swapped by two lane rolls, against (S, 128) tables of cosines and
+#   signed sines that stay in VMEM for the whole call; float32, rounded to
+#   the operand dtype once, at the end, as XLA's fusion of the two rounds
+#   on the chip. k's whole slab is turned once, at the first step that
+#   reads it, and q's block once a slab (by the first head of a pair at
+#   heads of 64), into scratches the later steps read, so the slab axis
+#   runs in order. Without either, a Python branch keeps the kernel as it
+#   was.
 # - the scores, the causal softmax and the values are
 #   :func:`_causal_softmax`, the latent kernel's two passes; with a
 #   diffusion block of L tokens (block diffusion's mask: bidirectional
 #   within a block, causal across blocks) only the diagonal blocks' mask
 #   differs, since L divides a key block.
 # --------------------------------------------------------------------------
-def _gqa_kernel_body(q_ref, k_ref, v_ref, o_ref, *scratch, scale: float,
-                     bq: int, bk: int, head_dim: int, group: int,
-                     diffusion_block: int):
+def _norm_turn(x, w, cos, sin, *, eps, head_dim: int, dtype):
+    """A (rows, 128) slab of heads as ``GQAMixer``'s XLA path leaves it on
+    the chip, in float32 holding ``dtype``'s values: with ``eps``, each
+    head's RMSNorm weighted by ``w`` (1, 128); with ``cos`` and ``sin``
+    (rows, 128), each head's rotation (:func:`_turn_tables`); rounded to
+    ``dtype`` once, at the end, as the chip's fusion of the two rounds
+    (XLA keeps the norm's output in float32 inside it)."""
+    f32 = jnp.float32
+    x = x.astype(f32)
+    lane = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    if eps is not None:
+        sq = x * x
+        if head_dim == _LANES:
+            ss = jnp.sum(sq, axis=1, keepdims=True)
+        else:  # the two heads' sums, each over its own half
+            low = lane < head_dim
+            ss = jnp.where(
+                low, jnp.sum(jnp.where(low, sq, 0.0), axis=1, keepdims=True),
+                jnp.sum(jnp.where(low, 0.0, sq), axis=1, keepdims=True))
+        x = x * lax.rsqrt(ss * (1.0 / head_dim) + eps) * w
+    if cos is not None:
+        half = head_dim // 2
+        # lane i's partner: i + half in a head's first half, i - half after
+        partner = jnp.where(lane % head_dim < half,
+                            pltpu.roll(x, _LANES - half, 1),
+                            pltpu.roll(x, half, 1))
+        x = x * cos + partner * sin
+    return x.astype(dtype).astype(f32)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _turn_tables(seq: int, head_dim: int, theta: float):
+    """(cos, sin), each (S, 128) float32, by which the default rotary of
+    ``theta`` turns a slab of heads as ``x cos + partner sin``: over each
+    head, the cosines of its pairs' angles at each token twice, and their
+    sines negated and then not. The unit pairs (1, 0) and (0, 1) turned by
+    ``ops/rope.py:rotate``, the very rotation the XLA path applies, give
+    both. One program of its own, which the kernel's caller runs as it
+    traces (the tables are constants of what it traces)."""
+    half = head_dim // 2
+    first = jnp.arange(head_dim) < half
+    units = jnp.stack([first, ~first]).astype(jnp.float32)
+    # (cos, sin) and (-sin, cos) over each head
+    turned = rope_ops.rotate(
+        jnp.broadcast_to(units, (1, seq, 2, head_dim)), jnp.arange(seq),
+        rope_ops.default_inv_freq(head_dim, theta))[0]
+    cos = jnp.where(first, turned[:, 0], turned[:, 1])
+    sin = jnp.where(first, turned[:, 1], turned[:, 0])
+    return tuple(jnp.tile(t, (1, _LANES // head_dim)) for t in (cos, sin))
+
+
+def _gqa_kernel_body(q_ref, k_ref, v_ref, *rest, scale: float, bq: int,
+                     bk: int, head_dim: int, group: int,
+                     diffusion_block: int, eps=None, rope: bool = False):
     """Refs: q (1, bq, 128) a slab of query heads, k and v (1, S, 128) the
-    slab that holds the step's key-value head, out (1, bq, 128); scratch as
-    :func:`_latent_kernel_body`'s. ``group`` query heads share a key-value
-    head."""
+    slab that holds the step's key-value head, with ``eps`` w (2, 128) q's
+    and k's norm weights over a slab, float32, with ``rope`` the (S, 128)
+    cos and sin of :func:`_turn_tables`, out (1, bq, 128); scratch as
+    :func:`_latent_kernel_body`'s and, with either, k's slab turned
+    (S, 128) and at heads of 64 the q slab turned (bq, 128). ``group``
+    query heads share a key-value head."""
+    prologue = eps is not None or rope
+    w_ref = cos_ref = sin_ref = None
+    if eps is not None:
+        w_ref, *rest = rest
+    if rope:
+        cos_ref, sin_ref, *rest = rest
+    o_ref, *scratch = rest
     f32, dtype = jnp.float32, q_ref.dtype
     iq = pl.program_id(2)
+    if prologue:
+        if head_dim == _LANES:
+            *scratch, kt_ref = scratch
+        else:
+            *scratch, kt_ref, qt_ref = scratch
+
+        def turn(x, row, rows):  # row 0: q's weight, 1: k's
+            return _norm_turn(
+                x, None if w_ref is None else w_ref[row:row + 1, :],
+                *((None, None) if cos_ref is None
+                  else (cos_ref[rows, :], sin_ref[rows, :])),
+                eps=eps, head_dim=head_dim, dtype=dtype)
+
+        # the slab's first step: its query heads' first, its first block
+        @pl.when((pl.program_id(1) % group == 0) & (iq == 0)
+                 & (pl.program_id(3) == 0))
+        def _():
+            def block(i, c):
+                rows = pl.ds(pl.multiple_of(i * bk, bk), bk)
+                kt_ref[rows, :] = turn(k_ref[0, rows, :], 1, rows).astype(
+                    dtype)
+                return c
+
+            lax.fori_loop(0, k_ref.shape[1] // bk, block, 0)
+
+        rows = pl.ds(pl.multiple_of(iq * bq, bq), bq)
+        if head_dim == _LANES:
+            q = turn(q_ref[0], 0, rows)
+        else:  # the pair's first step turns the slab both read
+            @pl.when(pl.program_id(3) == 0)
+            def _():
+                qt_ref[...] = turn(q_ref[0], 0, rows).astype(dtype)
+
+            q = qt_ref[...].astype(f32)
+        keys = lambda cols: kt_ref[cols, :]
+    else:
+        keys = lambda cols: k_ref[0, cols, :]
     if head_dim == _LANES:
-        q = q_ref[0]
+        q = q.astype(dtype) if prologue else q_ref[0]
     else:
         vector = lambda n: jnp.zeros((1, _LANES), jnp.int32) + n
         mine = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) // head_dim \
@@ -480,10 +606,10 @@ def _gqa_kernel_body(q_ref, k_ref, v_ref, o_ref, *scratch, scale: float,
         # the step's head and its key-value head lie in different halves
         head = pl.program_id(1) * (_LANES // head_dim) + pl.program_id(3)
         apart = vector(pl.program_id(3)) != vector(head // group) % 2
-        q = jnp.where(mine, q_ref[0].astype(f32), 0.0)
+        q = jnp.where(mine, q if prologue else q_ref[0].astype(f32), 0.0)
         q = jnp.where(apart, pltpu.roll(q, head_dim, 1), q).astype(dtype)
     o = _causal_softmax(
-        iq, lambda cols: _dot(q, k_ref[0, cols, :], _NT),
+        iq, lambda cols: _dot(q, keys(cols), _NT),
         lambda cols: v_ref[0, cols, :], *scratch, scale=scale, bq=bq, bk=bk,
         dtype=dtype, diffusion_block=diffusion_block)
     if head_dim == _LANES:
@@ -510,9 +636,9 @@ def gqa_kernel_supported(seq: int, heads: int, kv_heads: int, head_dim: int,
             and n >= 1 and n & (n - 1) == 0 and bk % n == 0)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block,
-                         diffusion_block):
+@functools.partial(jax.jit, static_argnums=tuple(range(4, 11)))
+def _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale, eps,
+                         theta, block, diffusion_block):
     # a jit of its own: a trunk's attention layers are one shape, and share
     # one traced and lowered function in the enclosing program
     b, s, _ = q.shape
@@ -530,77 +656,114 @@ def _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block,
     slab = pl.BlockSpec((1, bq, _LANES), lambda i, j, n, r: (i, n, j))
     kv = pl.BlockSpec((1, s, _LANES),
                       lambda i, j, n, r: (i, 0, (j * per + r) // group // per))
+    # what the whole call reads, fetched once and held once
+    whole = lambda rows: pl.BlockSpec((rows, _LANES),
+                                      lambda i, j, n, r: (0, 0),
+                                      pipeline_mode=pl.Buffered(1))
     row = pltpu.VMEM((bq, _LANES), jnp.float32)
+    operands, in_specs = [q, k, v], [slab, kv, kv]
+    scratch = [pltpu.VMEM((bq, s), jnp.float32), row, row, row]
+    axes, prologue_bytes = ("parallel", "parallel"), 0
+    if weights is not None:
+        operands.append(jnp.tile(jnp.stack(weights).astype(jnp.float32),
+                                 (1, per)))
+        in_specs.append(whole(2))
+    if theta is not None:
+        with jax.ensure_compile_time_eval():
+            operands += _turn_tables(s, d, theta)
+        in_specs += [whole(s)] * 2
+        prologue_bytes += 2 * s * _LANES * 4
+    if weights is not None or theta is not None:
+        # k's slab turned, and at heads of 64 the pair's q slab
+        scratch.append(pltpu.VMEM((s, _LANES), k.dtype))
+        prologue_bytes += s * _LANES * k.dtype.itemsize
+        if per > 1:
+            scratch.append(pltpu.VMEM((bq, _LANES), q.dtype))
+        axes = ("parallel", "arbitrary")  # the turned slabs carry over
     return pl.pallas_call(
         functools.partial(_gqa_kernel_body, scale=scale, bq=bq, bk=bk,
                           head_dim=d, group=group,
-                          diffusion_block=diffusion_block),
+                          diffusion_block=diffusion_block, eps=eps,
+                          rope=theta is not None),
         grid=(b, heads // per, s // bq, per),
-        in_specs=[slab, kv, kv],
+        in_specs=in_specs,
         out_specs=slab,
         out_shape=jax.ShapeDtypeStruct(q.shape, v.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, s), jnp.float32), row, row, row],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"),
+            dimension_semantics=axes + ("arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_bytes(s, bq, 2 * _LANES,
-                                         q.dtype.itemsize)),
+                                         q.dtype.itemsize) + prologue_bytes),
         interpret=jax.default_backend() != "tpu",
-    )(q, k, v)
+    )(*operands)
 
 
 def gqa_attention_kernel(q, k, v, heads: int, kv_heads: int, scale: float,
-                         block=_BLOCK, diffusion_block: int = 1):
+                         qk_norm=None, theta=None, block=_BLOCK,
+                         diffusion_block: int = 1):
     """Causal grouped-query attention as one Pallas TPU kernel, on the
     projections' own outputs: ``q`` (B, S, H x d), ``k`` and ``v``
     (B, S, Hkv x d), query head ``j`` on key-value head ``j // (H / Hkv)``,
     heads of 128 or of 64 (:func:`gqa_kernel_supported`); with a
     ``diffusion_block`` over 1, block-causal as
-    :func:`causal_attention_blocked`. Returns (B, S, H x d) in ``v``'s
-    dtype, what ``o_proj`` reads. Same mathematics as
-    :func:`gqa_attention_blocked`, but for where the probabilities are
+    :func:`causal_attention_blocked`. ``qk_norm`` (q's weight, k's weight,
+    each of a head's width, and eps) and ``theta``: the mixer's norms and
+    default rotary on q and k, which the kernel applies itself. Returns
+    (B, S, H x d) in ``v``'s dtype, what ``o_proj`` reads. Same mathematics
+    as :func:`gqa_attention_blocked`, but for where the probabilities are
     normalised: there ahead of their rounding to the operand dtype, here
     after the values product, by their float32 sum. Differentiable by
     recomputing through the blocked form."""
-    return _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block,
-                           diffusion_block)
+    weights, eps = (None, None) if qk_norm is None else (qk_norm[:2],
+                                                         qk_norm[2])
+    return _gqa_kernel_vjp(q, k, v, weights, heads, kv_heads, scale, eps,
+                           theta, block, diffusion_block)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _gqa_kernel_vjp(q, k, v, heads, kv_heads, scale, block, diffusion_block):
-    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block,
-                                diffusion_block)
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 11)))
+def _gqa_kernel_vjp(q, k, v, weights, heads, kv_heads, scale, eps, theta,
+                    block, diffusion_block):
+    return _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale,
+                                eps, theta, block, diffusion_block)
 
 
-def _gqa_vjp_fwd(q, k, v, heads, kv_heads, scale, block, diffusion_block):
-    return _gqa_kernel_fwd_impl(q, k, v, heads, kv_heads, scale, block,
-                                diffusion_block), (q, k, v)
+def _gqa_vjp_fwd(q, k, v, weights, heads, kv_heads, scale, eps, theta, block,
+                 diffusion_block):
+    return _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale,
+                                eps, theta, block, diffusion_block), (
+        q, k, v, weights)
 
 
-def _gqa_vjp_bwd(heads, kv_heads, scale, block, diffusion_block, res, ct):
-    return jax.vjp(
-        lambda *a: gqa_attention_blocked(*a, heads, kv_heads, scale,
-                                         diffusion_block), *res
-    )[1](ct)
+def _gqa_vjp_bwd(heads, kv_heads, scale, eps, theta, block, diffusion_block,
+                 res, ct):
+    def blocked(q, k, v, weights):
+        qk_norm = None if weights is None else (*weights, eps)
+        return gqa_attention_blocked(q, k, v, heads, kv_heads, scale,
+                                     qk_norm, theta, diffusion_block)
+
+    return jax.vjp(blocked, *res)[1](ct)
 
 
 _gqa_kernel_vjp.defvjp(_gqa_vjp_fwd, _gqa_vjp_bwd)
 
 
 @mosaic_gate
-def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1) -> bool:
+def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1,
+                  qk_norm: bool = False, rope: bool = False) -> bool:
     """Compiled self-check of :func:`gqa_attention_kernel` as the mixer
     calls it, once a machine: two query blocks (a full key block and the
     diagonal), a batch of two and two slabs of k and v (sixteen query heads:
     groups of eight heads of 128 on their key-value head; of heads of 64,
     groups of four, whose pairs roll and whose pairs do not), masked with
-    the ``diffusion_block`` it is asked for, against
-    :func:`gqa_attention_blocked` under the same mask. One program, its
-    inputs drawn on the host (``chip_smoke.py`` holds the kernel to the
-    blocked form at the backbones' shapes)."""
+    the ``diffusion_block`` it is asked for, with the q/k norms (weights
+    drawn about the benchmark's 1.75) and the rotary (theta 1e6) where
+    asked, against :func:`gqa_attention_blocked` under the same mask and
+    prologue. One program, its inputs drawn on the host (``chip_smoke.py``
+    holds the kernel to the blocked form at the backbones' shapes)."""
     from tmr_tpu.diagnostics import gate_refused, run_outside_trace
 
-    config = {"head_dim": head_dim, "diffusion_block": diffusion_block}
+    config = {"head_dim": head_dim, "diffusion_block": diffusion_block,
+              "qk_norm": qk_norm, "rope": rope}
     if jax.default_backend() != "tpu":
         return gate_refused(
             "gqa_kernel_ok", f"backend {jax.default_backend()!r} != 'tpu'",
@@ -608,13 +771,15 @@ def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1) -> bool:
 
     b, s, heads = 2, 2 * _BLOCK[0], 16
     kv_heads, scale = 2 * _LANES // head_dim, head_dim ** -0.5
+    theta = 1e6 if rope else None
 
     @jax.jit
-    def gap(q, k, v):
-        got = gqa_attention_kernel(q, k, v, heads, kv_heads, scale,
-                                   diffusion_block=diffusion_block)
-        want = gqa_attention_blocked(q, k, v, heads, kv_heads, scale,
-                                     diffusion_block)
+    def gap(q, k, v, wq, wk):
+        norms = (wq, wk, 1e-6) if qk_norm else None
+        got = gqa_attention_kernel(q, k, v, heads, kv_heads, scale, norms,
+                                   theta, diffusion_block=diffusion_block)
+        want = gqa_attention_blocked(q, k, v, heads, kv_heads, scale, norms,
+                                     theta, diffusion_block)
         gap = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
         return gap.max() / jnp.abs(want.astype(jnp.float32)).max()
 
@@ -622,8 +787,10 @@ def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1) -> bool:
         rng = np.random.default_rng(0)
         draw = lambda width: rng.standard_normal(
             (b, s, width), np.float32).astype(jnp.bfloat16)
+        weight = lambda: rng.uniform(1.25, 2.25, head_dim).astype(
+            jnp.bfloat16)
         return float(gap(draw(heads * head_dim), draw(kv_heads * head_dim),
-                         draw(kv_heads * head_dim)))
+                         draw(kv_heads * head_dim), weight(), weight()))
 
     try:
         widest = run_outside_trace(check, "gqa_kernel_ok")
@@ -638,19 +805,23 @@ def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1) -> bool:
 
 
 def gqa_formulation(seq: int, heads: int, kv_heads: int, head_dim: int,
-                    dtype, diffusion_block: int = 1) -> str:
+                    dtype, diffusion_block: int = 1, qk_norm: bool = False,
+                    rope: bool = False) -> str:
     """What grouped-query attention traces with (counter
     ``trunk.gqa.<formulation>``), by what can be observed: in bfloat16, at
     heads of 128, or of 64 whose pairs share a slab of k and v, a sequence
-    of whole 512-token blocks that VMEM holds (4,096 tokens ask 28 MiB, the
-    1536 bucket's 9,216 43 MiB; over 16,384 not) and a diffusion block that
-    divides a key block, where the kernel's self-check at that diffusion
-    block says yes (it says no off a TPU and inside a trace XLA
-    partitions), the Pallas kernel (``causal_kernel``); else
-    ``blocked_xla``, :func:`causal_attention_blocked`."""
+    of whole 512-token blocks that VMEM holds (4,096 tokens ask 28 MiB, 33
+    with the prologue's tables and turned k, the 1536 bucket's 9,216 43 MiB
+    and 54; over 16,384 not) and a diffusion block that divides a key block,
+    where the kernel's self-check at that diffusion block and with the
+    mixer's q/k norms and rotary (``qk_norm``, ``rope``) says yes (it says
+    no off a TPU and inside a trace XLA partitions), the Pallas kernel
+    (``causal_kernel``); else ``blocked_xla``,
+    :func:`causal_attention_blocked`."""
     if (dtype == jnp.bfloat16
             and gqa_kernel_supported(seq, heads, kv_heads, head_dim,
                                      diffusion_block=diffusion_block)
-            and gqa_kernel_ok(head_dim, diffusion_block)):
+            and gqa_kernel_ok(head_dim, diffusion_block, bool(qk_norm),
+                              bool(rope))):
         return "causal_kernel"
     return "blocked_xla"
