@@ -363,9 +363,8 @@ def _run(cancel_watchdog) -> None:
         )
         from tmr_tpu.utils.autotune import autotune
 
-        snap_keys = ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL",
-                     "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
-                     "TMR_GLOBAL_SCORES_DTYPE", "TMR_DECODER_IMPL",
+        snap_keys = ("TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL",
+                     "TMR_XCORR_PRECISION", "TMR_DECODER_IMPL",
                      "TMR_QUANT", "TMR_QUANT_STORAGE", "TMR_QUANT_KERNEL")
         before = {k: os.environ.get(k) for k in snap_keys}
         tune = {**tune, **autotune(cfg, IMAGE_SIZE, BATCH, log=_progress)}
@@ -638,13 +637,9 @@ def _build_and_measure(cfg, tune) -> dict:
         # picks, so env-pinned A/B runs need this to be readable
         "knobs": {
             k: os.environ[k]
-            for k in ("TMR_GLOBAL_ATTN",
-                      "TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL",
-                      "TMR_XCORR_PRECISION", "TMR_PALLAS_ATTN_BQ",
-                      "TMR_PALLAS_ATTN_BK", "TMR_GLOBAL_BANDS_UNROLL",
-                      "TMR_GLOBAL_SCORES_DTYPE",
-                      "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK",
-                      "TMR_DECODER_IMPL", "TMR_QUANT", "TMR_DECODE_TAIL")
+            for k in ("TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL",
+                      "TMR_XCORR_PRECISION", "TMR_DECODER_IMPL",
+                      "TMR_QUANT", "TMR_DECODE_TAIL")
             if k in os.environ
         },
     }

@@ -58,7 +58,6 @@ TP_REL_TOL = 0.05
 
 #: the plain-XLA oracle formulations of the float32 reference run
 ORACLE_ENV = {
-    "TMR_GLOBAL_ATTN": "blockwise",
     "TMR_XCORR_IMPL": "conv",
     "TMR_DECODER_IMPL": "xla",
 }
@@ -575,8 +574,8 @@ def decide_gates(cfg, size: Size) -> dict:
 def report_formulations(cfg, size: Size, verdicts: dict) -> None:
     """The formulation each layer runs under the current environment: the
     knob when set, else what ``auto`` resolves to given the gates; for the
-    ViT's blocks, ``global_formulation``'s answer (with ``TMR_GLOBAL_ATTN``
-    unset or ``auto``) and ``window_formulation``'s (no knob)."""
+    ViT's blocks, ``global_formulation``'s and ``window_formulation``'s
+    answers (no knob)."""
     import jax.numpy as jnp
 
     from tmr_tpu.inference import decode_tail_mode
@@ -585,17 +584,13 @@ def report_formulations(cfg, size: Size, verdicts: dict) -> None:
 
     env = os.environ.get
     win = glob = "none"
-    glob_by = "global_formulation"
     if not _is_trunk(size):
         dtype = jnp.dtype(cfg.compute_dtype)
+        grid = size.image_size // 16
         win = window_formulation((14, 14), *_vit_heads(size), dtype)
-        if env("TMR_GLOBAL_ATTN", "auto") != "auto":
-            glob, glob_by = env("TMR_GLOBAL_ATTN"), "TMR_GLOBAL_ATTN"
-        else:
-            grid = size.image_size // 16
-            glob = global_formulation((grid, grid), *_vit_heads(size), dtype)
+        glob = global_formulation((grid, grid), *_vit_heads(size), dtype)
     say("  formulations: " + json.dumps({
-        f"global_attention({glob_by})": glob,
+        "global_attention(global_formulation)": glob,
         "windowed_attention(window_formulation)": win,
         "xcorr_small(TMR_XCORR_IMPL[_SMALL])": env(
             "TMR_XCORR_IMPL", env("TMR_XCORR_IMPL_SMALL",

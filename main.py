@@ -124,7 +124,7 @@ def config_parser(argv=None):
                         "memory ~1/depth for one extra forward)")
     p.add_argument("--autotune", action="store_true",
                    help="microbenchmark kernel formulations (x-corr "
-                        "lowering, windowed attention) on this device at "
+                        "lowering, decoder tail) on this device at "
                         "the run's shapes and use the winners (TPU only)")
 
     args = p.parse_args(argv)
@@ -216,8 +216,8 @@ def main(argv=None):
             tune_batch = max(cfg.batch_size // max(dp, 1), 1)
         # precision relaxation is justified for inference score ranking
         # only — training must not inherit bf16-rounded matcher gradients.
-        # train=True times the block sweeps fwd+bwd (recompute-backward
-        # kernels rank differently) and caches under a separate key.
+        # train=True times the sweeps fwd+bwd (backward cost ranks
+        # formulations differently) and caches under a separate key.
         autotune(cfg, cfg.image_size, tune_batch, log=log_info,
                  tune_precision=bool(cfg.eval), train=not cfg.eval)
 

@@ -1,14 +1,13 @@
 """Diagnose WHY each gated custom kernel is refused on the live backend.
 
 Round-5 session-7 finding: on the real TPU every require_tpu formulation
-(flash global/windowed, pallas global/windowed, pallas xcorr) fell back,
-while the one pure-XLA alternative (blockfolded) won the headline — but
-the gates swallow their refusal reason, so "Mosaic can't lower through
-this backend" vs "kernel miscompiles numerically" vs "backend-name
-mismatch" were indistinguishable. This script runs each gate at the
-production geometry and, for the pallas paths, also calls the kernel
-DIRECTLY (no gate) so a lowering exception surfaces with its full
-traceback.
+(flash global/windowed, pallas global/windowed, pallas xcorr) fell back —
+but the gates swallow their refusal reason, so "Mosaic can't lower
+through this backend" vs "kernel miscompiles numerically" vs
+"backend-name mismatch" were indistinguishable. This script runs each
+gate at the production geometry and, for the packed global kernel, also
+calls the kernel DIRECTLY (no gate) so a lowering exception surfaces with
+its full traceback.
 
 Since the structured-diagnostics layer (tmr_tpu/diagnostics.py) landed,
 every gate refusal records a machine-readable cause (category, exception
@@ -89,13 +88,11 @@ def main(argv=None) -> int:
         emit(probe="pallas_trivial", ok=False,
              error=f"{type(e).__name__}: {e}")
 
-    # 2. the global-attention pallas kernels DIRECT (no gate), bench
-    # geometry: grid 64x64, head_dim 64, B1 H2 (the gate's own shape)
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops.pallas_attn import (
-        pallas_decomposed_attention,
-        pallas_fused_attention,
-    )
+    # 2. the packed global-attention kernel DIRECT (no gate), bench
+    # geometry: grid 64x64, head_dim 64, B1 H2, rel-pos tables as
+    # get_rel_pos makes them (the kernel reads them as Toeplitz)
+    from tmr_tpu.models.vit import blockwise_decomposed_attention, get_rel_pos
+    from tmr_tpu.ops.pallas_attn import _packed_global_on_heads
 
     rng = np.random.default_rng(0)
     gh = gw = 64
@@ -104,73 +101,41 @@ def main(argv=None) -> int:
     q = jnp.asarray(rng.standard_normal((1, 2, S, D)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((1, 2, S, D)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((1, 2, S, D)), jnp.bfloat16)
-    rh = jnp.asarray(rng.standard_normal((gh, gh, D)) * 0.2, jnp.float32)
-    rw = jnp.asarray(rng.standard_normal((gw, gw, D)) * 0.2, jnp.float32)
-    # the blockwise oracle is the same for every probed kernel: run once
-    want = None
-    for probe_name, attn_fn in (
-        ("pallas_global_direct", pallas_decomposed_attention),
-        ("pallas_fused_direct", pallas_fused_attention),
-    ):
-        try:
-            got = jax.jit(
-                lambda *a, _f=attn_fn: _f(*a, (gh, gw), D**-0.5)
-            )(q, k, v, rh, rw)
-            got.block_until_ready()
-
-            if want is None:
-                want = np.asarray(jax.jit(
-                    lambda *a: blockwise_decomposed_attention(
-                        *a, (gh, gw), D**-0.5)
-                )(q, k, v, rh, rw), np.float32)
-            err = float(np.abs(np.asarray(got, np.float32) - want).max())
-            ref = float(np.abs(want).max())
-            emit(probe=probe_name, ok=bool(err / (ref + 1e-6) < 0.05),
-                 rel_err=err / (ref + 1e-6))
-        except Exception as e:
-            traceback.print_exc()
-            emit(probe=probe_name, ok=False,
-                 error=f"{type(e).__name__}: {e}")
+    rh, rw = (get_rel_pos(g, g, jnp.asarray(
+        rng.standard_normal((2 * g - 1, D)) * 0.2, jnp.float32))
+        for g in (gh, gw))
+    try:
+        got = jax.jit(
+            lambda *a: _packed_global_on_heads(*a, (gh, gw), D**-0.5)
+        )(q, k, v, rh, rw)
+        want = np.asarray(jax.jit(
+            lambda *a: blockwise_decomposed_attention(*a, (gh, gw), D**-0.5)
+        )(q, k, v, rh, rw), np.float32)
+        err = float(np.abs(np.asarray(got, np.float32) - want).max())
+        ref = float(np.abs(want).max())
+        emit(probe="packed_global_direct",
+             ok=bool(err / (ref + 1e-6) < 0.05), rel_err=err / (ref + 1e-6))
+    except Exception as e:
+        traceback.print_exc()
+        emit(probe="packed_global_direct", ok=False,
+             error=f"{type(e).__name__}: {e}")
 
     # 3. every production gate, cache-cleared so refusal causes record
-    from tmr_tpu.ops.flash_attn import (
-        blockfolded_ok,
-        densefolded_ok,
-        flash_attention_ok,
-        flash_window_ok,
-        xlaflash_ok,
-    )
-    from tmr_tpu.ops.pallas_attn import (
-        effective_fused_tiles,
-        effective_global_tiles,
-        pallas_fused_ok,
-        pallas_global_ok,
-    )
+    from tmr_tpu.ops.flash_attn import flash_attention_ok, flash_window_ok
+    from tmr_tpu.ops.pallas_attn import packed_global_ok
     from tmr_tpu.ops.pallas_xcorr import pallas_xcorr_ok
-    from tmr_tpu.models.vit import _scores_dtype
 
-    for gate_fn in (blockfolded_ok, densefolded_ok, flash_attention_ok,
-                    flash_window_ok, xlaflash_ok, pallas_fused_ok,
-                    pallas_global_ok, pallas_xcorr_ok):
+    for gate_fn in (flash_attention_ok, flash_window_ok, packed_global_ok,
+                    pallas_xcorr_ok):
         clear = getattr(gate_fn, "cache_clear", None)  # not all are cached
         if clear is not None:
             clear()
 
-    bq, bk = effective_global_tiles(64 * 64)
-    fbq, fbk = effective_fused_tiles(64 * 64, 64)
-    live_scores = _scores_dtype()
     gates = {
         "flash_global_64x64_d64": lambda: flash_attention_ok(64, 64, 64),
-        f"blockfolded_64x64_d64_scores_{live_scores}":
-            lambda: blockfolded_ok(64, 64, 64, live_scores),
-        f"densefolded_64x64_d64_scores_{live_scores}":
-            lambda: densefolded_ok(64, 64, 64, live_scores),
-        "xlaflash_64x64_d64": lambda: xlaflash_ok(64, 64, 64),
         "flash_window_14x14_d64": lambda: flash_window_ok(14, 14, 64),
-        "pallas_global_64x64_d64":
-            lambda: pallas_global_ok(64, 64, 64, bq, bk),
-        f"pallas_fused_64x64_d64_bq{fbq}_bk{fbk}":
-            lambda: pallas_fused_ok(64, 64, 64, fbq, fbk),
+        "packed_global_64x64_d64_h12":
+            lambda: packed_global_ok(64, 64, 64, 12),
         "pallas_xcorr_c256_64_t17": lambda: pallas_xcorr_ok(256, 64, 64, 17),
     }
     drain_gate_refusals()  # discard causes from the direct probes above
@@ -182,28 +147,6 @@ def main(argv=None) -> int:
             traceback.print_exc()
             emit(probe=name, ok=False, error=f"{type(e).__name__}: {e}",
                  refusals=drain_gate_refusals())
-
-    # the bf16-score-tile gates (the env the check traces under must match
-    # the cache key being probed — set it for the duration)
-    if live_scores != "bf16":
-        os.environ["TMR_GLOBAL_SCORES_DTYPE"] = "bf16"
-        try:
-            for name, fn in {
-                "blockfolded_64x64_d64_scores_bf16":
-                    lambda: blockfolded_ok(64, 64, 64, "bf16"),
-                "densefolded_64x64_d64_scores_bf16":
-                    lambda: densefolded_ok(64, 64, 64, "bf16"),
-            }.items():
-                try:
-                    emit(probe=name, ok=bool(fn()),
-                         refusals=drain_gate_refusals())
-                except Exception as e:
-                    traceback.print_exc()
-                    emit(probe=name, ok=False,
-                         error=f"{type(e).__name__}: {e}",
-                         refusals=drain_gate_refusals())
-        finally:
-            os.environ.pop("TMR_GLOBAL_SCORES_DTYPE", None)
 
     # 4. the decoder-tail gates (PR-6 surface: fused decoder heads, int8
     # quant tiers, device decode tail) at the production geometry — the

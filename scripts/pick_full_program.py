@@ -1,13 +1,13 @@
 #!/usr/bin/env python
 """Pick formulation winners from FULL-PROGRAM bench A/Bs (VERDICT r4 #4).
 
-The autotune sweep times one isolated block per formulation; round 4 showed
+The autotune sweep times one isolated stage per formulation; round 4 showed
 that granularity can disagree with the production program (the sweep
-crowned a formulation that the one-block profile measured slower than the
+crowned a formulation that a one-stage profile measured slower than the
 one it displaced). Per the verdict, the resolution is to record BOTH
-granularities and let the FULL-PROGRAM number decide: the watch2 battery
-benches the complete fused eval program under env-pinned formulation
-combos (bench_pallas) plus the autotuned headline; this script reads those
+granularities and let the FULL-PROGRAM number decide: a battery benches
+the complete fused eval program under env-pinned formulation combos plus
+the autotuned headline (bench.py's JSON records); this script reads those
 records and, when an env-pinned combo beats the autotuned headline
 decisively (>3% img/s), pins its knobs into AUTOTUNE_SEED.json so every
 later process (including the driver's round-end bench) defaults to the
@@ -18,7 +18,7 @@ Prints one JSON summary line; exit 0 = seed updated, 3 = no update needed
 (headline already optimal or no valid records), 1 = error.
 
 Usage: python scripts/pick_full_program.py [bench1.json bench2.json ...]
-(defaults to the watch2 battery's output files in the repo root).
+(defaults to the autotuned headline's record in the repo root).
 """
 
 from __future__ import annotations
@@ -30,15 +30,10 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_FILES = (
     "bench_live.json",      # autotuned headline (sweep-ranked winners)
-    "bench_pallas.json",    # TMR_GLOBAL_ATTN=pallas
 )
-#: knobs a full-program winner may pin (the global formulation + its tile
-#: sub-knobs; batch is handled by bench_extra's own sweep)
-PINNABLE = (
-    "TMR_GLOBAL_ATTN", "TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-    "TMR_GLOBAL_BANDS_UNROLL", "TMR_GLOBAL_SCORES_DTYPE",
-    "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK",
-)
+#: knobs a full-program winner may pin (the matcher's small-bucket
+#: formulation; batch is handled by bench_extra's own sweep)
+PINNABLE = ("TMR_XCORR_IMPL_SMALL",)
 #: decisive-win margin: below this the sweep ranking stands (same
 #: philosophy as the precision stage's >10% bar, scaled to whole-program
 #: variance)
@@ -179,22 +174,14 @@ def main(argv=None) -> int:
                 # every versioned knob needs a fresh stamp or the loader
                 # drops the pin as stale on the very next run
                 entry[f"_variants_{k}"] = _variants_sig(k)
-        # full-program A/Bs supersede the one-block sweep for the
+        # full-program A/Bs supersede the one-stage sweep for a
         # formulation knob: left at its autotuned value by the winner, it
         # is also full-program-endorsed (it was part of the winning run)
         auto = best.get("autotuned", {})
-        if "TMR_GLOBAL_ATTN" not in pinned and "TMR_GLOBAL_ATTN" in auto:
-            entry["TMR_GLOBAL_ATTN"] = auto["TMR_GLOBAL_ATTN"]
-            entry["_variants_TMR_GLOBAL_ATTN"] = _variants_sig(
-                "TMR_GLOBAL_ATTN")
-        if "TMR_GLOBAL_SCORES_DTYPE" in entry:
-            # the scores-dtype evidence is paired to the global formulation
-            # of the winning run — record it or the loader's pairing check
-            # drops (or worse, mis-vouches) the pin
-            entry["_scores_global_impl"] = entry.get(
-                "TMR_GLOBAL_ATTN",
-                best.get("autotuned", {}).get("TMR_GLOBAL_ATTN", "auto"),
-            )
+        for k in PINNABLE:
+            if k not in pinned and k in auto:
+                entry[k] = auto[k]
+                entry[f"_variants_{k}"] = _variants_sig(k)
         entry["_full_program_ab"] = json.dumps(
             {n: r["value"] for n, r in records.items()}, sort_keys=True
         )

@@ -131,108 +131,34 @@ def main():
     tokens = jnp.asarray(
         rng.standard_normal((BATCH, grid, grid, 768)), jnp.bfloat16
     )
-    cases = (
-        # (label, window, {knob: value}): global blocks read TMR_GLOBAL_ATTN
-        # at trace time; the pallas rows also sweep the kernel's tile sizes
-        # (TMR_PALLAS_ATTN_BQ/BK). The windowed block has no knob: it runs
-        # what ops/pallas_attn.window_formulation answers here
-        ("one_global_block_blockwise", 0, {"TMR_GLOBAL_ATTN": "blockwise"}),
-        ("one_global_block_flash", 0, {"TMR_GLOBAL_ATTN": "flash"}),
-        ("one_global_block_blockfolded", 0,
-         {"TMR_GLOBAL_ATTN": "blockfolded"}),
-        ("one_global_block_blockfolded_unroll2", 0,
-         {"TMR_GLOBAL_ATTN": "blockfolded",
-          "TMR_GLOBAL_BANDS_UNROLL": "2"}),
-        ("one_global_block_blockfolded_unroll4", 0,
-         {"TMR_GLOBAL_ATTN": "blockfolded",
-          "TMR_GLOBAL_BANDS_UNROLL": "4"}),
-        ("one_global_block_densefolded", 0,
-         {"TMR_GLOBAL_ATTN": "densefolded"}),
-        ("one_global_block_blockfolded_scores16", 0,
-         {"TMR_GLOBAL_ATTN": "blockfolded",
-          "TMR_GLOBAL_SCORES_DTYPE": "bf16"}),
-        ("one_global_block_densefolded_scores16", 0,
-         {"TMR_GLOBAL_ATTN": "densefolded",
-          "TMR_GLOBAL_SCORES_DTYPE": "bf16"}),
-        ("one_global_block_pallas", 0, {"TMR_GLOBAL_ATTN": "pallas"}),
-        ("one_global_block_pallas_bq256", 0,
-         {"TMR_GLOBAL_ATTN": "pallas", "TMR_PALLAS_ATTN_BQ": "256"}),
-        ("one_global_block_pallas_bk1024", 0,
-         {"TMR_GLOBAL_ATTN": "pallas", "TMR_PALLAS_ATTN_BK": "1024"}),
-        # the fused-bias rewrite (broadcast bias tiles, no selector
-        # matmuls) and its tile sweep — the verdict's "highest-information
-        # measurement" rows — plus the Mosaic-independent XLA flash form
-        ("one_global_block_fused", 0, {"TMR_GLOBAL_ATTN": "fused"}),
-        ("one_global_block_fused_bq256", 0,
-         {"TMR_GLOBAL_ATTN": "fused", "TMR_PALLAS_ATTN_BQ": "256"}),
-        ("one_global_block_fused_bk1024", 0,
-         {"TMR_GLOBAL_ATTN": "fused", "TMR_PALLAS_ATTN_BK": "1024"}),
-        ("one_global_block_xlaflash", 0, {"TMR_GLOBAL_ATTN": "xlaflash"}),
-        ("one_global_block_xlaflash_bk1024", 0,
-         {"TMR_GLOBAL_ATTN": "xlaflash", "TMR_XLA_FLASH_BK": "1024"}),
-        # the kernel on qkv where the product wrote it (what ``auto``
-        # answers on a TPU in bfloat16 since PR 32)
-        ("one_global_block_packed", 0, {"TMR_GLOBAL_ATTN": "packed"}),
-        ("one_windowed_block", 14, {}),
-    )
-    # restore the user's knobs afterwards (autotune's _restore): the
-    # full-program timing in section 1 honoured them, and later sections /
-    # the rest of the process must keep seeing them
-    from tmr_tpu.utils.autotune import _restore
+    # each block runs what ops/pallas_attn.global_formulation /
+    # window_formulation answer here (no knob); the global row is labelled
+    # with the answer
+    from tmr_tpu.ops.pallas_attn import global_formulation
 
-    prev = {
-        k: os.environ.get(k)
-        for k in ("TMR_GLOBAL_ATTN", "TMR_PALLAS_ATTN_BQ",
-                  "TMR_PALLAS_ATTN_BK", "TMR_GLOBAL_BANDS_UNROLL",
-                  "TMR_GLOBAL_SCORES_DTYPE", "TMR_XLA_FLASH_BQ",
-                  "TMR_XLA_FLASH_BK")
-    }
-    try:
-        for label, win, knobs in cases:
-            if "TMR_PALLAS_ATTN_BQ" in knobs or "TMR_PALLAS_ATTN_BK" in knobs:
-                # skip tile rows whose preference clamps back to the default
-                # tile at this S — they would re-measure the plain pallas
-                # row under a label claiming a different tile size
-                from tmr_tpu.ops.flash_attn import _block_for
+    glob = global_formulation((grid, grid), 12, 64, jnp.bfloat16)
+    for label, win in ((f"one_global_block_{glob}", 0),
+                       ("one_windowed_block", 14)):
+        _progress(f"stage 3: {label}")
+        blk = Block(num_heads=12, window_size=win,
+                    rel_pos_size=(grid, grid), dtype=jnp.bfloat16)
+        bp = jax.jit(blk.init)(jax.random.key(1), tokens)["params"]
 
-                s_glob = grid * grid
-                eff = (
-                    _block_for(s_glob,
-                               int(knobs.get("TMR_PALLAS_ATTN_BQ", 512))),
-                    _block_for(s_glob,
-                               int(knobs.get("TMR_PALLAS_ATTN_BK", 512))),
-                )
-                if eff == (_block_for(s_glob, 512), _block_for(s_glob, 512)):
-                    _progress(f"stage 3: {label} skipped (tiles clamp to "
-                              f"the default {eff} at S={s_glob})")
-                    continue
-            _progress(f"stage 3: {label}")
-            for k in ("TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-                      "TMR_GLOBAL_BANDS_UNROLL", "TMR_GLOBAL_SCORES_DTYPE",
-                      "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK"):
-                os.environ.pop(k, None)  # tile overrides are per-case
-            os.environ.update(knobs)
-            blk = Block(num_heads=12, window_size=win,
-                        rel_pos_size=(grid, grid), dtype=jnp.bfloat16)
-            bp = jax.jit(blk.init)(jax.random.key(1), tokens)["params"]
+        @jax.jit
+        def blk_step(p, x, fb):
+            y = blk.apply({"params": p}, x + fb.astype(x.dtype))
+            return y, jnp.sum(y).astype(jnp.float32) * 0.0
 
-            @jax.jit
-            def blk_step(p, x, fb):
-                y = blk.apply({"params": p}, x + fb.astype(x.dtype))
-                return y, jnp.sum(y).astype(jnp.float32) * 0.0
-
-            report[label] = chained(
-                lambda x, fb: blk_step(bp, x, fb), tokens, rtt=rtt
-            )
-            _progress(f"{label}: {report[label]*1000:.2f} ms")
-    finally:
-        for k, v in prev.items():
-            _restore(v, k)
+        report[label] = chained(
+            lambda x, fb: blk_step(bp, x, fb), tokens, rtt=rtt
+        )
+        _progress(f"{label}: {report[label]*1000:.2f} ms")
 
     # 4. matcher x-corr on the upsampled grid: every formulation at the
     # production capacity (TMR_XCORR_IMPL, read at trace time — ops/xcorr.py)
     # plus the default big-template path at 127
     from tmr_tpu.ops.xcorr import match_templates
+    from tmr_tpu.utils.autotune import _restore
 
     up_hw = pred.feature_hw(SIZE)
     proj = jnp.asarray(

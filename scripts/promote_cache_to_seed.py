@@ -47,13 +47,13 @@ def main(argv=None) -> int:
     seed = seed_load()
 
     #: knobs a full-program A/B (scripts/pick_full_program.py) may have
-    #: pinned — its whole-program evidence outranks the one-block sweep,
+    #: pinned — its whole-program evidence outranks the one-stage sweep,
     #: so promotion must not overwrite them in an entry carrying the
     #: _full_program_ab marker WHILE the pin's own stamp is still current.
     #: Once a _SWEEP_REV bump stales the pin, runtime drops it and
     #: re-sweeps anyway, so the fresh sweep winner must promote or every
     #: fresh container re-sweeps forever.
-    FULL_PROGRAM_KNOBS = ("TMR_GLOBAL_ATTN",)
+    FULL_PROGRAM_KNOBS = ("TMR_XCORR_IMPL_SMALL",)
 
     promoted = {}
     for key, entry in cache.items():
@@ -89,13 +89,6 @@ def main(argv=None) -> int:
             if out.get("_precision_impl") != entry["_precision_impl"]:
                 out["_precision_impl"] = entry["_precision_impl"]
                 changed["_precision_impl"] = entry["_precision_impl"]
-        # same ownership rule for the scores-dtype <-> global formulation
-        # pairing: it moves only with its owner knob
-        if ("TMR_GLOBAL_SCORES_DTYPE" in changed
-                and "_scores_global_impl" in entry):
-            if out.get("_scores_global_impl") != entry["_scores_global_impl"]:
-                out["_scores_global_impl"] = entry["_scores_global_impl"]
-                changed["_scores_global_impl"] = entry["_scores_global_impl"]
         # the measured throughput-optimal batch is an independent
         # measurement: rides alone
         if (

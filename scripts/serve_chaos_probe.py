@@ -258,6 +258,11 @@ def _run(cancel_watchdog, argv=None) -> int:
         return all(fleet.holder_for(s) is not None
                    for s in range(fleet.n_shards))
 
+    def all_live(fleet, n):
+        live = fleet._svc.live_workers()
+        return (len(live) >= n
+                and all(fleet._addr_of(w) is not None for w in live))
+
     def search_clean(client) -> bool:
         return _clean_and_exact(client.search(_frame(99)),
                                 reference.search(_frame(99)))
@@ -274,8 +279,11 @@ def _run(cancel_watchdog, argv=None) -> int:
         _progress(f"spawning {WORKERS} stub gallery workers")
         for i in range(WORKERS):
             spawn(fleet, f"w{i}")
-        if not _poll(lambda: all_held(fleet), 30.0):
-            raise RuntimeError("gallery workers never leased all shards")
+        # The first worker up leases every shard: wait for all of them
+        # (each with its data plane) so the replicas have somewhere to go.
+        if not _poll(lambda: all_held(fleet) and all_live(fleet, WORKERS),
+                     30.0):
+            raise RuntimeError("gallery workers never all came up")
         names = _pattern_names(BASE_PATTERNS, SHARDS)
         acks = [register(fleet, name) for name in names]
         replicated = all(

@@ -11,8 +11,7 @@ from tmr_tpu.config import preset
 from tmr_tpu.utils import autotune as at
 
 KNOBS = ("TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL",
-         "TMR_XCORR_PRECISION", "TMR_GLOBAL_ATTN",
-         "TMR_GLOBAL_SCORES_DTYPE", "TMR_DECODER_IMPL", "TMR_QUANT")
+         "TMR_XCORR_PRECISION", "TMR_DECODER_IMPL", "TMR_QUANT")
 
 
 @pytest.fixture
@@ -26,7 +25,7 @@ def clean_knobs(monkeypatch, tmp_path):
     stage short-circuits to "off" without a sweep): a REAL
     pick_decoder_impl at the production 128^2 x 1024 geometry is minutes
     of CPU matmul, and the pre-existing autotune tests exercise the
-    attention/xcorr stages. Tail-election tests re-patch with their own
+    xcorr stages. Tail-election tests re-patch with their own
     stubs (or call the picks directly at tiny geometry)."""
     for k in KNOBS:
         monkeypatch.delenv(k, raising=False)
@@ -64,30 +63,25 @@ def test_autotune_picks_min_and_exports_env(clean_knobs, monkeypatch):
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.03, "vmap": 0.05, "fft": 0.01},
     )
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: {"blockwise": 0.03, "flash": 0.02},
-    )
     report = at.autotune(_cfg(), 1024, 4)
     # the xcorr winner exports through the SMALL-scoped knob only: the
     # 127/191 buckets must keep their FFT auto path
     assert report["TMR_XCORR_IMPL_SMALL"]["picked"] == "fft"
-    assert report["TMR_GLOBAL_ATTN"]["picked"] == "flash"
     assert os.environ["TMR_XCORR_IMPL_SMALL"] == "fft"
     assert "TMR_XCORR_IMPL" not in os.environ
-    assert os.environ["TMR_GLOBAL_ATTN"] == "flash"
 
 
 def test_autotune_exports_nothing_the_windowed_blocks_read(
     clean_knobs, monkeypatch
 ):
-    """An ``autotune()`` run on a TPU backend must leave the windowed
-    blocks' formulation where ``ops/pallas_attn.window_formulation`` put
-    it: it sets no environment variable that models/vit.py or
-    ops/pallas_attn.py reads on a windowed block's path (the parent swept
-    the windows' knob among four variants without ``packed`` and exported
-    the fastest, displacing PR 28's path), and a bfloat16 trace on that
-    backend still takes ``packed`` afterwards."""
+    """An ``autotune()`` run on a TPU backend must leave every ViT
+    attention block's formulation where ``ops/pallas_attn`` put it: it
+    sets no environment variable that models/vit.py or
+    ops/pallas_attn.py reads (the windows' knob once displaced their
+    ``packed`` path, the global blocks' knob exported a formulation no cell
+    measured), the choosers' own code reads no environment at all, and a
+    bfloat16 trace on that backend still takes ``packed`` in both kinds
+    of block afterwards."""
     import inspect
 
     from tmr_tpu.models import vit
@@ -97,22 +91,25 @@ def test_autotune_exports_nothing_the_windowed_blocks_read(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
         at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.03, "fft": 0.01})
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: {"blockwise": 0.03, "flash": 0.02})
     before = dict(os.environ)
     report = at.autotune(_cfg(), 1024, 4)
     exported = {k for k, v in os.environ.items() if before.get(k) != v}
     assert exported and exported <= set(report), (exported, set(report))
-    assert not any("WIN" in k for k in exported | set(report))
-    # and the choice's own code reads no environment at all
+    read = inspect.getsource(vit) + inspect.getsource(pallas_attn)
+    assert not any(k in read for k in exported | set(report))
     for fn in (vit.Attention._window_formulation,
-               pallas_attn.window_formulation, pallas_attn.packed_supported):
+               vit.Attention._global_formulation,
+               pallas_attn.window_formulation, pallas_attn.packed_supported,
+               pallas_attn.global_formulation,
+               pallas_attn.packed_global_supported):
         src = inspect.getsource(fn)
         assert "environ" not in src and "TMR_" not in src, fn.__name__
     monkeypatch.setattr(pallas_attn, "packed_window_ok", lambda *a: True)
+    monkeypatch.setattr(pallas_attn, "packed_global_ok", lambda *a: True)
     assert pallas_attn.window_formulation(
         (14, 14), 12, 64, jnp.bfloat16) == "packed"
+    assert pallas_attn.global_formulation(
+        (64, 64), 12, 64, jnp.bfloat16) == "packed"
 
 
 def test_fallback_annotated_entries_never_win(clean_knobs, monkeypatch):
@@ -126,17 +123,10 @@ def test_fallback_annotated_entries_never_win(clean_knobs, monkeypatch):
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.01, "pallas" + at.FALLBACK_SUFFIX: 1e-5},
     )
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: {"blockwise": 0.03, "flash (fallback)": 0.001},
-    )
     report = at.autotune(_cfg(), 1024, 4, tune_precision=False)
     assert report["TMR_XCORR_IMPL_SMALL"]["picked"] == "conv"
     assert os.environ["TMR_XCORR_IMPL_SMALL"] == "conv"
-    assert report["TMR_GLOBAL_ATTN"]["picked"] == "blockwise"
-    assert os.environ["TMR_GLOBAL_ATTN"] == "blockwise"
     # the annotated evidence is preserved in the report
-    assert "flash (fallback)" in report["TMR_GLOBAL_ATTN"]["times"]
     assert "pallas" + at.FALLBACK_SUFFIX in (
         report["TMR_XCORR_IMPL_SMALL"]["times"]
     )
@@ -155,7 +145,6 @@ def test_autotune_sweep_false_exports_cached_and_reports_pending(
         AssertionError(f"{tag} swept under sweep=False")
     )
     monkeypatch.setattr(at, "pick_xcorr_impl", boom("x"))
-    monkeypatch.setattr(at, "pick_global_attn_impl", boom("g"))
     monkeypatch.setattr(at, "pick_xcorr_precision", boom("p"))
     monkeypatch.setattr(at, "measure_rtt_floor", boom("rtt"))
 
@@ -166,32 +155,26 @@ def test_autotune_sweep_false_exports_cached_and_reports_pending(
     seed = tmp_path / "seed.json"
     seed.write_text(json.dumps({
         "cpu|1024|128|4|512|vit_b": {
-            "TMR_GLOBAL_ATTN": "blockwise",
-            "_variants_TMR_GLOBAL_ATTN": at._variants_sig(
-                "TMR_GLOBAL_ATTN"
+            "TMR_XCORR_IMPL_SMALL": "conv",
+            "_variants_TMR_XCORR_IMPL_SMALL": at._variants_sig(
+                "TMR_XCORR_IMPL_SMALL"
             ),
         }
     }))
     monkeypatch.setenv("TMR_AUTOTUNE_SEED", str(seed))
     report = at.autotune(_cfg(), 1024, 4, sweep=False)
-    assert report["TMR_GLOBAL_ATTN"] == {"picked": "blockwise",
-                                         "cached": True}
-    assert os.environ["TMR_GLOBAL_ATTN"] == "blockwise"
-    # the un-cached knobs are reported, not measured; the scores knob
-    # resolved to its measurement-free no-op (seeded global formulation is
-    # not folded) so it is recorded, not pending
-    assert report["TMR_GLOBAL_SCORES_DTYPE"] == {"picked": "f32",
-                                                 "times": {}}
+    assert report["TMR_XCORR_IMPL_SMALL"] == {"picked": "conv",
+                                              "cached": True}
+    assert os.environ["TMR_XCORR_IMPL_SMALL"] == "conv"
+    # the un-cached knobs are reported, not measured
     assert set(report["_pending"]) == {
-        "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
-        "TMR_DECODER_IMPL", "TMR_QUANT",
+        "TMR_XCORR_PRECISION", "TMR_DECODER_IMPL", "TMR_QUANT",
     }
 
 
 def test_autotune_respects_explicit_knobs(clean_knobs, monkeypatch):
     monkeypatch.setenv("TMR_XCORR_IMPL", "conv")
     monkeypatch.setenv("TMR_XCORR_PRECISION", "highest")
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
     monkeypatch.setenv("TMR_DECODER_IMPL", "xla")
     monkeypatch.setenv("TMR_QUANT", "off")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -204,20 +187,13 @@ def test_autotune_respects_explicit_knobs(clean_knobs, monkeypatch):
         at, "pick_xcorr_precision", lambda *a, **k: called.append("p") or {}
     )
     monkeypatch.setattr(
-        at, "pick_global_attn_impl", lambda *a, **k: called.append("g") or {}
-    )
-    monkeypatch.setattr(
         at, "pick_decoder_impl", lambda *a, **k: called.append("d") or {}
     )
     monkeypatch.setattr(
         at, "pick_quant", lambda *a, **k: called.append("q") or {}
     )
-    # the one unpinned knob (scores dtype) completes its cache entry as
-    # the f32 no-op — no measurement runs (the pinned global formulation
-    # is not folded, so there is nothing to sweep)
-    assert at.autotune(_cfg(), 1024, 4) == {
-        "TMR_GLOBAL_SCORES_DTYPE": {"picked": "f32", "times": {}}
-    }
+    # every knob pinned: nothing measured, nothing reported
+    assert at.autotune(_cfg(), 1024, 4) == {}
     assert called == []
     assert os.environ["TMR_XCORR_IMPL"] == "conv"
 
@@ -256,14 +232,7 @@ def test_microbenchmarks_run_and_time_all_variants(clean_knobs):
     )
     assert "pallas" + at.FALLBACK_SUFFIX in tx and "pallas" not in tx
     assert all(v > 0 for v in tx.values())
-    # global block (1024 tokens, the smallest grid that dispatches on the
-    # knob): the kernels fall back off-TPU but must not crash the sweep;
-    # the XLA formulations always time
-    tg = at.pick_global_attn_impl(1, 32, 16, 2, rtt=0.0)
-    assert {"blockwise", "blockfolded"} <= set(tg)
-    assert all(v > 0 for v in tg.values())
     assert "TMR_XCORR_IMPL" not in os.environ  # knobs restored
-    assert "TMR_GLOBAL_ATTN" not in os.environ
 
 
 def test_autotune_precision_stage_flips_only_on_decisive_win(
@@ -279,7 +248,6 @@ def test_autotune_precision_stage_flips_only_on_decisive_win(
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.01, "vmap": 0.05, "fft": 0.03},
     )
-    monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     swept = []
     monkeypatch.setattr(
         at, "pick_xcorr_precision",
@@ -326,7 +294,6 @@ def test_autotune_tune_precision_false_skips_sweep(clean_knobs, monkeypatch):
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.01, "vmap": 0.05, "fft": 0.03},
     )
-    monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     boom = lambda *a, **k: (_ for _ in ()).throw(AssertionError("swept"))
     monkeypatch.setattr(at, "pick_xcorr_precision", boom)
     r = at.autotune(_cfg(), 1024, 4, tune_precision=False)
@@ -344,7 +311,6 @@ def test_autotune_cached_precision_is_impl_specific(clean_knobs, monkeypatch):
         at, "pick_xcorr_impl",
         lambda *a, **k: {"conv": 0.01, "vmap": 0.05, "fft": 0.03},
     )
-    monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(
         at, "pick_xcorr_precision",
         lambda *a, **k: {"highest": 0.010, "default": 0.004, "bf16": 0.006},
@@ -370,10 +336,8 @@ def test_autotune_cached_precision_is_impl_specific(clean_knobs, monkeypatch):
     assert os.environ["TMR_XCORR_PRECISION"] == "highest"
 
     # with the SAME impl as measured, the cached winner exports directly
-    # (attention pinned: its sweep returned {} above so it was never cached)
     for k in KNOBS:
         os.environ.pop(k, None)
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
     boom = lambda *a, **k: (_ for _ in ()).throw(AssertionError("swept"))
     monkeypatch.setattr(at, "pick_xcorr_precision", boom)
     monkeypatch.setattr(
@@ -396,121 +360,70 @@ def test_autotune_cache_persists_winners_across_processes(
         at, "pick_xcorr_impl",
         lambda *a, **k: calls.append("x") or {"conv": 0.03, "fft": 0.01},
     )
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: calls.append("g") or {"blockwise": 0.02,
-                                              "flash": 0.01},
-    )
     r1 = at.autotune(_cfg(), 1024, 4)
-    assert calls == ["x", "g"]
-    assert r1["TMR_GLOBAL_ATTN"]["picked"] == "flash"
+    assert calls == ["x"]
+    assert r1["TMR_XCORR_IMPL_SMALL"]["picked"] == "fft"
 
     # fresh process simulation: knobs cleared, cache file remains
     for k in KNOBS:
         os.environ.pop(k, None)
     r2 = at.autotune(_cfg(), 1024, 4)
-    assert calls == ["x", "g"], "cached hit must not re-measure"
+    assert calls == ["x"], "cached hit must not re-measure"
     assert r2["TMR_XCORR_IMPL_SMALL"] == {"picked": "fft", "cached": True}
-    assert r2["TMR_GLOBAL_ATTN"] == {"picked": "flash", "cached": True}
     assert os.environ["TMR_XCORR_IMPL_SMALL"] == "fft"
-    assert os.environ["TMR_GLOBAL_ATTN"] == "flash"
 
     # a different shape key measures fresh
     for k in KNOBS:
         os.environ.pop(k, None)
     at.autotune(_cfg(), 1536, 1)
-    assert calls == ["x", "g", "x", "g"]
+    assert calls == ["x", "x"]
 
     # force bypasses the cache
     for k in KNOBS:
         os.environ.pop(k, None)
     monkeypatch.setenv("TMR_AUTOTUNE_FORCE", "1")
     at.autotune(_cfg(), 1024, 4)
-    assert calls == ["x", "g", "x", "g", "x", "g"]
+    assert calls == ["x", "x", "x"]
 
 
 def test_train_autotune_uses_separate_key_and_grad_sweep(
     clean_knobs, monkeypatch
 ):
-    """train=True must (a) time the block sweeps with a gradient pass —
-    the Pallas kernels' recompute backward inverts the fwd-only ranking —
-    and (b) cache under a distinct key so eval winners never leak into
-    training and vice versa."""
+    """train=True must (a) time the sweeps with a gradient pass — backward
+    cost ranks formulations differently — and (b) cache under a distinct
+    key so eval winners never leak into training and vice versa."""
     seen_train = []
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def fake_xcorr(*a, train=False, **k):
         seen_train.append(("x", train))
-        return {"conv": 0.03, "fft": 0.01}
+        return ({"conv": 0.02, "fft": 0.01} if train
+                else {"conv": 0.01, "fft": 0.02})
 
     monkeypatch.setattr(at, "pick_xcorr_impl", fake_xcorr)
 
-    def fake_sweep(*a, train=False, **k):
-        seen_train.append(("a", train))
-        return ({"blockwise": 0.02, "flash": 0.01} if train
-                else {"blockwise": 0.01, "flash": 0.02})
-
-    monkeypatch.setattr(at, "pick_global_attn_impl", fake_sweep)
-
     r_eval = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert r_eval["TMR_GLOBAL_ATTN"]["picked"] == "blockwise"
-    assert seen_train == [("x", False), ("a", False)]
+    assert r_eval["TMR_XCORR_IMPL_SMALL"]["picked"] == "conv"
+    assert seen_train == [("x", False)]
 
     for k in KNOBS:
         os.environ.pop(k, None)
     r_train = at.autotune(_cfg(), 1024, 4, tune_precision=False, train=True)
-    # the eval cache entry must NOT satisfy the train run, and every sweep
-    # (xcorr included) must time with gradients
-    assert seen_train[2:] == [("x", True), ("a", True)]
-    assert r_train["TMR_GLOBAL_ATTN"]["picked"] == "flash"
+    # the eval cache entry must NOT satisfy the train run, and the sweep
+    # must time with gradients
+    assert seen_train[1:] == [("x", True)]
+    assert r_train["TMR_XCORR_IMPL_SMALL"]["picked"] == "fft"
 
     # both keys now cached independently
     for k in KNOBS:
         os.environ.pop(k, None)
     r2 = at.autotune(_cfg(), 1024, 4, tune_precision=False, train=True)
-    assert r2["TMR_GLOBAL_ATTN"] == {"picked": "flash", "cached": True}
+    assert r2["TMR_XCORR_IMPL_SMALL"] == {"picked": "fft", "cached": True}
     for k in KNOBS:
         os.environ.pop(k, None)
     r3 = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert r3["TMR_GLOBAL_ATTN"] == {"picked": "blockwise", "cached": True}
-
-
-@pytest.mark.slow
-def test_block_sweep_train_mode_times_grad(clean_knobs, monkeypatch):
-    """The real harness under train=True must build a differentiable step
-    (value_and_grad through the block) and produce a time for every
-    variant that can differentiate — on CPU every variant falls back to a
-    differentiable path, so all seven global variants report. Off-TPU the
-    flash/pallas gates refuse, so those entries come back ANNOTATED
-    ("<impl> (fallback)"): the harness must label what it measured, never
-    record a fallback timing under the requested name (ADVICE r4)."""
-    monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
-    times = at.pick_global_attn_impl(1, 32, 16, 2, rtt=0.0, train=True)
-    base = {k.replace(at.FALLBACK_SUFFIX, "") for k in times}
-    assert base == set(at.GLOBAL_ATTN_VARIANTS)
-    # CPU: the kernel gates refuse -> their rows must carry the annotation
-    for impl in ("flash", "pallas"):
-        assert impl + at.FALLBACK_SUFFIX in times and impl not in times
-    assert all(t > 0 for t in times.values())
-
-
-def test_the_sweep_holds_every_formulation_auto_can_answer():
-    """``pick_global_attn_impl`` exports the fastest of
-    ``GLOBAL_ATTN_VARIANTS`` whenever ``TMR_GLOBAL_ATTN`` is unset, which
-    is when ``ops/pallas_attn.global_formulation`` decides: a formulation
-    it can answer and the sweep lacks would be displaced by a slower one
-    the first time ``--autotune`` runs (PERF.md section 6, PR 31, finding
-    2). Each is also a legal explicit value of the knob."""
-    from tmr_tpu.ops.pallas_attn import GLOBAL_FORMULATIONS
-
-    assert "packed" in GLOBAL_FORMULATIONS
-    assert set(GLOBAL_FORMULATIONS) <= set(at.GLOBAL_ATTN_VARIANTS)
-    legal = at._validate_cache_obj({"k": {
-        "TMR_GLOBAL_ATTN": "packed",
-        "_variants_TMR_GLOBAL_ATTN": at._variants_sig("TMR_GLOBAL_ATTN"),
-    }})
-    assert legal["k"]["TMR_GLOBAL_ATTN"] == "packed"
+    assert r3["TMR_XCORR_IMPL_SMALL"] == {"picked": "conv", "cached": True}
 
 
 def test_cached_winner_stale_when_variant_set_grows(clean_knobs, monkeypatch):
@@ -526,31 +439,29 @@ def test_cached_winner_stale_when_variant_set_grows(clean_knobs, monkeypatch):
         lambda *a, **k: calls.append("x") or {"conv": 0.03, "fft": 0.01},
     )
     monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: calls.append("g") or {"blockwise": 0.02,
-                                              "flash": 0.01},
+        at, "pick_decoder_impl",
+        lambda *a, **k: calls.append("d") or {"xla": 0.01, "fused": 0.02},
     )
-    r1 = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "g"]
+    at.autotune(_cfg(), 1024, 4, tune_precision=False)
+    assert calls == ["x", "d"]
 
     # cached entries were stamped: a rerun re-measures nothing
     for k in KNOBS:
         os.environ.pop(k, None)
     at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "g"]
+    assert calls == ["x", "d"]
 
-    # the global-attn variant set grows (new kernel lands): ONLY that knob
-    # re-sweeps; the stamped siblings stay cached
+    # the xcorr variant set grows (new kernel lands): ONLY that knob
+    # re-sweeps; the stamped sibling stays cached
     for k in KNOBS:
         os.environ.pop(k, None)
     monkeypatch.setattr(
-        at, "GLOBAL_ATTN_VARIANTS",
-        at.GLOBAL_ATTN_VARIANTS + ("newkernel",),
+        at, "XCORR_VARIANTS", at.XCORR_VARIANTS + ("newkernel",),
     )
     r3 = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "g", "g"]
-    assert r3["TMR_XCORR_IMPL_SMALL"].get("cached") is True
-    assert "cached" not in r3["TMR_GLOBAL_ATTN"]
+    assert calls == ["x", "d", "x"]
+    assert r3["TMR_DECODER_IMPL"].get("cached") is True
+    assert "cached" not in r3["TMR_XCORR_IMPL_SMALL"]
 
     # legacy stamp-less entries (pre-versioning caches/seeds) also re-sweep
     import json
@@ -564,7 +475,7 @@ def test_cached_winner_stale_when_variant_set_grows(clean_knobs, monkeypatch):
     for k in KNOBS:
         os.environ.pop(k, None)
     at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert calls == ["x", "g", "g", "x", "g"]
+    assert calls == ["x", "d", "x", "x", "d"]
 
 
 def test_autotune_cached_hit_respects_explicit_knobs(
@@ -575,19 +486,15 @@ def test_autotune_cached_hit_respects_explicit_knobs(
     monkeypatch.setattr(
         at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.03, "fft": 0.01}
     )
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: {"blockwise": 0.02, "flash": 0.01},
-    )
     at.autotune(_cfg(), 1024, 4)
     for k in KNOBS:
         os.environ.pop(k, None)
-    # user pins the attention knob: the cached hit must not override it
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
+    # user pins the correlation knob: the cached hit must not override it
+    monkeypatch.setenv("TMR_XCORR_IMPL_SMALL", "conv")
     r = at.autotune(_cfg(), 1024, 4)
-    assert "TMR_GLOBAL_ATTN" not in r
-    assert os.environ["TMR_GLOBAL_ATTN"] == "blockwise"
-    assert r["TMR_XCORR_IMPL_SMALL"]["cached"] is True
+    assert "TMR_XCORR_IMPL_SMALL" not in r
+    assert os.environ["TMR_XCORR_IMPL_SMALL"] == "conv"
+    assert r["TMR_DECODER_IMPL"]["cached"] is True
 
 
 def test_measured_tpu_defaults(monkeypatch):
@@ -649,32 +556,6 @@ def test_cache_accepts_measured_batch_winner(clean_knobs):
     assert "other" not in loaded
 
 
-@pytest.mark.slow
-def test_global_attn_knob_validates_and_matches(monkeypatch):
-    """TMR_GLOBAL_ATTN forces the global-attention formulation at trace
-    time: invalid values raise, and 'blockwise' matches the auto dispatch
-    off-TPU (where the flash gate falls back to blockwise anyway)."""
-    from tmr_tpu.models.vit import Block
-
-    tokens = jnp.asarray(
-        np.random.default_rng(0).standard_normal((1, 32, 32, 32)),
-        jnp.bfloat16,
-    )
-    blk = Block(num_heads=2, window_size=0, rel_pos_size=(32, 32),
-                dtype=jnp.bfloat16)
-    monkeypatch.delenv("TMR_GLOBAL_ATTN", raising=False)
-    params = jax.jit(blk.init)(jax.random.key(0), tokens)["params"]
-    auto = blk.apply({"params": params}, tokens)
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
-    forced = blk.apply({"params": params}, tokens)
-    np.testing.assert_array_equal(np.asarray(auto), np.asarray(forced))
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "spiral")
-    with pytest.raises(ValueError, match="TMR_GLOBAL_ATTN"):
-        blk.apply({"params": params}, tokens)
-
-
 def test_autotune_seed_file_partial_sweep(clean_knobs, monkeypatch, tmp_path):
     """A committed seed file (AUTOTUNE_SEED.json) pre-covers knobs for a
     fresh machine: covered knobs export without measuring, ONLY the
@@ -688,20 +569,20 @@ def test_autotune_seed_file_partial_sweep(clean_knobs, monkeypatch, tmp_path):
     key = "|".join(str(p) for p in (
         jax.devices()[0].device_kind, 1024, 128, 4, 512, "vit_b"))
     seed.write_text(json.dumps({key: {
-        "TMR_XCORR_IMPL_SMALL": "vmap", "TMR_GLOBAL_ATTN": "flash",
+        "TMR_XCORR_IMPL_SMALL": "vmap", "TMR_DECODER_IMPL": "xla",
         # seeds carry the variant sets their winners beat (an unstamped
         # entry is treated as stale — covered by
         # test_cached_winner_stale_when_variant_set_grows)
         "_variants_TMR_XCORR_IMPL_SMALL": at._variants_sig(
             "TMR_XCORR_IMPL_SMALL"),
-        "_variants_TMR_GLOBAL_ATTN": at._variants_sig("TMR_GLOBAL_ATTN"),
+        "_variants_TMR_DECODER_IMPL": at._variants_sig("TMR_DECODER_IMPL"),
     }}))
     monkeypatch.setenv("TMR_AUTOTUNE_SEED", str(seed))
 
     calls = []
     boom = lambda tag: lambda *a, **k: calls.append(tag) or {}
     monkeypatch.setattr(at, "pick_xcorr_impl", boom("x"))
-    monkeypatch.setattr(at, "pick_global_attn_impl", boom("g"))
+    monkeypatch.setattr(at, "pick_decoder_impl", boom("d"))
     monkeypatch.setattr(
         at, "pick_xcorr_precision",
         lambda *a, **k: calls.append("p") or {
@@ -709,11 +590,11 @@ def test_autotune_seed_file_partial_sweep(clean_knobs, monkeypatch, tmp_path):
     )
     r = at.autotune(_cfg(), 1024, 4)
     # seeded knobs exported without their sweeps; unseeded ones measured
-    assert "x" not in calls and "g" not in calls
+    assert "x" not in calls and "d" not in calls
     assert "p" in calls
     assert r["TMR_XCORR_IMPL_SMALL"] == {"picked": "vmap", "cached": True}
-    assert r["TMR_GLOBAL_ATTN"] == {"picked": "flash", "cached": True}
-    assert os.environ["TMR_GLOBAL_ATTN"] == "flash"
+    assert r["TMR_DECODER_IMPL"] == {"picked": "xla", "cached": True}
+    assert os.environ["TMR_DECODER_IMPL"] == "xla"
     # precision measured on the seeded vmap winner, decisive win -> default
     assert r["TMR_XCORR_PRECISION"]["picked"] == "default"
 
@@ -725,7 +606,7 @@ def test_autotune_seed_file_partial_sweep(clean_knobs, monkeypatch, tmp_path):
     at._cache_store(key, {"TMR_XCORR_IMPL_SMALL": {"picked": "conv"}})
     cached = at._cache_load()[key]
     assert cached["TMR_XCORR_IMPL_SMALL"] == "conv"
-    assert cached["TMR_GLOBAL_ATTN"] == "flash"
+    assert cached["TMR_DECODER_IMPL"] == "xla"
 
     # and with the user cache absent, the seed alone still serves
     os.environ["TMR_AUTOTUNE_CACHE"] = str(tmp_path / "fresh_cache.json")
@@ -741,7 +622,6 @@ def test_cached_precision_dropped_when_impl_sweep_pending(
     fresh sweep picks, so relaxed numerics never outlive their pairing."""
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(at, "pick_global_attn_impl", lambda *a, **k: {})
     monkeypatch.setattr(
         at, "pick_xcorr_precision",
         lambda *a, **k: {"highest": 0.010, "default": 0.004, "bf16": 0.003},
@@ -772,81 +652,6 @@ def test_cached_precision_dropped_when_impl_sweep_pending(
     assert os.environ["TMR_XCORR_PRECISION"] == "highest"
 
 
-def test_scores_dtype_sweep_decisive_win_policy(clean_knobs, monkeypatch):
-    """The TMR_GLOBAL_SCORES_DTYPE stage mirrors the xcorr precision
-    policy: swept only when a folded formulation won, bf16 exported only
-    on a decisive (>10%) win over the exact f32 baseline, f32 kept when
-    the margin is thin or the baseline is missing, and the evidence paired
-    to the formulation it was measured under."""
-    monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(
-        at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.01})
-    monkeypatch.setattr(
-        at, "pick_xcorr_precision", lambda *a, **k: {"highest": 0.01})
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl",
-        lambda *a, **k: {"blockwise": 0.03, "blockfolded": 0.01},
-    )
-
-    # decisive win: bf16 exported, evidence paired to blockfolded
-    monkeypatch.setattr(
-        at, "pick_global_scores_dtype",
-        lambda *a, **k: {"f32": 0.010, "bf16": 0.005},
-    )
-    report = at.autotune(_cfg(), 1024, 4)
-    assert report["TMR_GLOBAL_SCORES_DTYPE"]["picked"] == "bf16"
-    assert os.environ["TMR_GLOBAL_SCORES_DTYPE"] == "bf16"
-
-    # thin margin: f32 kept
-    for k in KNOBS:
-        os.environ.pop(k, None)
-    monkeypatch.setattr(
-        at, "pick_global_scores_dtype",
-        lambda *a, **k: {"f32": 0.010, "bf16": 0.0095},
-    )
-    monkeypatch.setenv(
-        "TMR_AUTOTUNE_CACHE",
-        os.environ["TMR_AUTOTUNE_CACHE"] + ".2",
-    )
-    report = at.autotune(_cfg(), 1024, 4)
-    assert report["TMR_GLOBAL_SCORES_DTYPE"]["picked"] == "f32"
-    assert os.environ["TMR_GLOBAL_SCORES_DTYPE"] == "f32"
-
-    # fallback-annotated bf16 row (TMR_GLOBAL_ATTN gate refused mid-sweep)
-    # must not be electable -> f32
-    for k in KNOBS:
-        os.environ.pop(k, None)
-    monkeypatch.setattr(
-        at, "pick_global_scores_dtype",
-        lambda *a, **k: {"f32": 0.010,
-                         "bf16" + at.FALLBACK_SUFFIX: 0.001},
-    )
-    monkeypatch.setenv(
-        "TMR_AUTOTUNE_CACHE",
-        os.environ["TMR_AUTOTUNE_CACHE"] + ".3",
-    )
-    report = at.autotune(_cfg(), 1024, 4)
-    assert report["TMR_GLOBAL_SCORES_DTYPE"]["picked"] == "f32"
-
-    # non-folded winner: stage records the no-op without sweeping
-    for k in KNOBS:
-        os.environ.pop(k, None)
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl", lambda *a, **k: {"blockwise": 0.01})
-    monkeypatch.setattr(
-        at, "pick_global_scores_dtype",
-        lambda *a, **k: (_ for _ in ()).throw(AssertionError("swept!")),
-    )
-    monkeypatch.setenv(
-        "TMR_AUTOTUNE_CACHE",
-        os.environ["TMR_AUTOTUNE_CACHE"] + ".4",
-    )
-    report = at.autotune(_cfg(), 1024, 4)
-    assert report["TMR_GLOBAL_SCORES_DTYPE"]["picked"] == "f32"
-    assert report["TMR_GLOBAL_SCORES_DTYPE"]["times"] == {}
-
-
 def test_stale_winners_returns_only_stale_stamped_entries(
     clean_knobs, monkeypatch, tmp_path
 ):
@@ -863,8 +668,8 @@ def test_stale_winners_returns_only_stale_stamped_entries(
     cache = tmp_path / "cache.json"
     cache.write_text(json.dumps({
         "cpu|1024|128|4|512|vit_b": {
-            "TMR_GLOBAL_ATTN": "blockfolded",
-            "_variants_TMR_GLOBAL_ATTN": "old,set|old-rev",  # stale
+            "TMR_DECODER_IMPL": "fused",
+            "_variants_TMR_DECODER_IMPL": "old,set|old-rev",  # stale
             "TMR_XCORR_IMPL_SMALL": "vmap",
             "_variants_TMR_XCORR_IMPL_SMALL": at._variants_sig(
                 "TMR_XCORR_IMPL_SMALL"),
@@ -874,44 +679,13 @@ def test_stale_winners_returns_only_stale_stamped_entries(
     }))
     monkeypatch.setenv("TMR_AUTOTUNE_CACHE", str(cache))
     out = at.stale_winners(_cfg(), 1024, 4)
-    assert out == {"TMR_GLOBAL_ATTN": "blockfolded",
+    assert out == {"TMR_DECODER_IMPL": "fused",
                    "TMR_XCORR_PRECISION": "bf16"}
 
     # an env pin wins over the stale entry
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
+    monkeypatch.setenv("TMR_DECODER_IMPL", "xla")
     out = at.stale_winners(_cfg(), 1024, 4)
     assert out == {"TMR_XCORR_PRECISION": "bf16"}
-
-
-def test_new_fused_variants_registered_and_rev_bumped():
-    """The fused kernel and the XLA flash path must be electable sweep
-    variants, and the _SWEEP_REV bump must make every pre-existing
-    TMR_GLOBAL_ATTN winner stamp stale so it re-records at the next
-    hardware window (the acceptance contract for registering a variant)."""
-    assert "fused" in at.GLOBAL_ATTN_VARIANTS
-    assert "xlaflash" in at.GLOBAL_ATTN_VARIANTS
-    sig = at._variants_sig("TMR_GLOBAL_ATTN")
-    assert "fused" in sig and "xlaflash" in sig
-    assert sig.endswith("|" + at._SWEEP_REV)
-    # the committed seed's stamps predate this revision by construction:
-    # whatever they say, they must not equal the live signature
-    for entry in at.seed_load().values():
-        stamp = entry.get("_variants_TMR_GLOBAL_ATTN")
-        if stamp is not None:
-            assert stamp != sig, (
-                "committed seed already stamped with the new revision — "
-                "bump _SWEEP_REV when the variant set or harness changes"
-            )
-    # validation accepts the new variants as cached winners, and the
-    # scores-dtype pairing stamp survives an 'auto' resolution (reload
-    # churn fix: autotune.py _validate_cache_obj)
-    kept = at._validate_cache_obj({
-        "k": {"TMR_GLOBAL_ATTN": "fused", "_scores_global_impl": "auto"},
-        "k2": {"TMR_GLOBAL_ATTN": "xlaflash"},
-    })
-    assert kept["k"]["TMR_GLOBAL_ATTN"] == "fused"
-    assert kept["k"]["_scores_global_impl"] == "auto"
-    assert kept["k2"]["TMR_GLOBAL_ATTN"] == "xlaflash"
 
 
 def test_stale_winners_uses_vit_kind_helper():
@@ -926,63 +700,33 @@ def test_stale_winners_uses_vit_kind_helper():
     assert '"sam_vit_b"' not in src  # the old inlined dict is gone
 
 
-@pytest.mark.slow
-def test_block_sweep_fallback_rows_carry_structured_refusals(
-    clean_knobs, monkeypatch
-):
-    """The real global-attention sweep harness off-TPU: gate-refused
-    kernel variants come back fallback-annotated AND their rows carry the
-    structured refusal causes (gate name, cause category, config) in
-    LAST_SWEEP_REFUSALS — the sweep-side half of the gate_probe.json
-    diagnostics (verdict r5 #1)."""
-    monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
-    times = at._sweep_block_env(
-        "TMR_GLOBAL_ATTN", ("blockwise", "pallas", "fused"),
-        1, 32, 16, 2, 0.0, lambda s: None,
-    )
-    assert "blockwise" in times
-    for impl, gate in (("pallas", "pallas_global_ok"),
-                       ("fused", "pallas_fused_ok")):
-        row = impl + at.FALLBACK_SUFFIX
-        assert row in times and impl not in times
-        causes = at.LAST_SWEEP_REFUSALS["TMR_GLOBAL_ATTN"][row]
-        assert causes, f"{row} carries no structured causes"
-        assert any(c["gate"] == gate for c in causes)
-        for c in causes:
-            assert c["schema"] == "gate_probe/v1"
-            assert c["cause"]
-            assert "config" in c and "device_kind" in c
-
-
 def test_autotune_report_attaches_sweep_refusals(clean_knobs, monkeypatch):
     """autotune() must copy the harness's structured refusal causes into
     the report entry of any knob whose sweep produced fallback rows — the
     path bench.py's autotune_refusals JSON field reads."""
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cause = {"schema": "gate_probe/v1", "gate": "pallas_global_ok",
+    cause = {"schema": "gate_probe/v1", "gate": "pallas_xcorr_ok",
              "cause": "backend", "message": "", "exception": None,
              "config": {}, "backend": "cpu", "device_kind": "cpu"}
 
-    def fake_global_sweep(*a, **k):
-        at.LAST_SWEEP_REFUSALS["TMR_GLOBAL_ATTN"] = {
+    def fake_xcorr_sweep(*a, **k):
+        at.LAST_SWEEP_REFUSALS["TMR_XCORR_IMPL"] = {
             "pallas" + at.FALLBACK_SUFFIX: [cause],
         }
-        return {"blockwise": 0.03,
-                "pallas" + at.FALLBACK_SUFFIX: 0.001}
+        return {"conv": 0.03, "pallas" + at.FALLBACK_SUFFIX: 0.001}
 
-    monkeypatch.setattr(at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.01})
-    monkeypatch.setattr(at, "pick_global_attn_impl", fake_global_sweep)
+    monkeypatch.setattr(at, "pick_xcorr_impl", fake_xcorr_sweep)
     report = at.autotune(_cfg(), 1024, 4, tune_precision=False)
-    assert report["TMR_GLOBAL_ATTN"]["picked"] == "blockwise"
-    ref = report["TMR_GLOBAL_ATTN"]["refusals"]
+    assert report["TMR_XCORR_IMPL_SMALL"]["picked"] == "conv"
+    ref = report["TMR_XCORR_IMPL_SMALL"]["refusals"]
     assert ref == {"pallas" + at.FALLBACK_SUFFIX: [cause]}
 
 
 # ----------------------------------------------- decoder-tail elections
 def _stub_non_tail_picks(monkeypatch):
     """The tail-election tests exercise the TMR_DECODER_IMPL/TMR_QUANT
-    stages only: every other sweep is stubbed (the real attention/xcorr
+    stages only: every other sweep is stubbed (the real xcorr
     microbenchmarks at the 1024 geometry are minutes of CPU work)."""
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -991,12 +735,6 @@ def _stub_non_tail_picks(monkeypatch):
     )
     monkeypatch.setattr(
         at, "pick_xcorr_precision", lambda *a, **k: {"highest": 0.01}
-    )
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl", lambda *a, **k: {"blockwise": 0.01}
-    )
-    monkeypatch.setattr(
-        at, "pick_global_scores_dtype", lambda *a, **k: {"f32": 0.01}
     )
 
 
@@ -1021,7 +759,7 @@ def test_decoder_tail_knobs_registered_and_rev_bumped():
 
 
 def test_autotune_elects_decoder_impl_then_quant(clean_knobs, monkeypatch):
-    """The tail stages run AFTER the attention/xcorr stages: the impl
+    """The tail stages run AFTER the xcorr stages: the impl
     sweep elects plain-min (both formulations are oracle-pinned identical
     numerics), then the quant sweep applies the decisive-win policy
     against the exact baseline and stamps which impl its evidence was

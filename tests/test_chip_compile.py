@@ -30,7 +30,6 @@ from tmr_tpu.ops.flash_attn import (
 from tmr_tpu.ops.pallas_attn import (
     packed_global_attention,
     packed_windowed_attention,
-    pallas_decomposed_attention,
 )
 
 V5E_HBM_BYTES = 16 * 1024**3
@@ -271,7 +270,6 @@ def _case_predict_program(sds):
 
 
 CASES = {
-    "pallas_global": _attn_case(pallas_decomposed_attention, _G, 1),
     "flash_global": _attn_case(flash_decomposed_attention, _G, 1),
     "flash_global_grad": _attn_case(flash_decomposed_attention, _G, 1,
                                     grad=True),
@@ -808,21 +806,19 @@ def test_partitioned_program_compiles_for_two_v5e_chips(
 
 
 @pytest.mark.parametrize("gate,args", [
-    ("pallas_fused_ok", (64, 64, 64, 512, 512)),
     ("pallas_xcorr_ok", (512, 128, 128, 15)),
 ])
 def test_retired_kernels_answer_no_with_the_compilers_words(
     gate, args, as_tpu
 ):
-    """The two kernels the chip's compiler refuses are not selectable on a
-    TPU: their gates answer no with a structured cause that carries the
+    """The kernel the chip's compiler refuses is not selectable on a TPU:
+    its gate answers no with a structured cause that carries the
     compiler's message, never ``exception``."""
     from tmr_tpu.diagnostics import drain_gate_refusals
-    from tmr_tpu.ops import pallas_attn, pallas_xcorr
+    from tmr_tpu.ops import pallas_xcorr
 
     drain_gate_refusals()
-    fn = {"pallas_fused_ok": pallas_attn.pallas_fused_ok,
-          "pallas_xcorr_ok": pallas_xcorr.pallas_xcorr_ok}[gate]
+    fn = {"pallas_xcorr_ok": pallas_xcorr.pallas_xcorr_ok}[gate]
     getattr(fn, "cache_clear", lambda: None)()
     assert fn(*args) is False
     getattr(fn, "cache_clear", lambda: None)()

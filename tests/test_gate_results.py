@@ -27,7 +27,7 @@ IDENTITY = {
 def write_sources(root) -> str:
     for name, text in (
             ("diagnostics.py", "# the gates' own file\n"),
-            ("ops/kernel.py", "BLOCK = 128  # reads TMR_GLOBAL_BANDS_UNROLL "
+            ("ops/kernel.py", "BLOCK = 128  # reads TMR_XCORR_PRECISION "
              "and TMR_GATE_DEBUG\n"),
             ("models/oracle.py", "def oracle(x):\n    return x\n"),
             ("serve/engine.py", "# not a gate's business\n")):
@@ -48,7 +48,7 @@ def machine(tmp_path, monkeypatch):
                         lambda: dict(IDENTITY))
     monkeypatch.setattr(diagnostics, "_PACKAGE_DIR", source)
     monkeypatch.delenv("TMR_GATE_DEBUG", raising=False)
-    monkeypatch.delenv("TMR_GLOBAL_BANDS_UNROLL", raising=False)
+    monkeypatch.delenv("TMR_XCORR_PRECISION", raising=False)
     diagnostics._source_digest.cache_clear()
     drain_gate_refusals()
     yield str(where), source
@@ -131,7 +131,7 @@ def test_each_part_of_the_key_alone_asks_again(machine, monkeypatch, part):
         os.rename(os.path.join(source, "ops/kernel.py"),
                   os.path.join(source, "ops/kernel2.py"))
     elif part == "knob":  # a trace-time knob the digested source reads
-        monkeypatch.setenv("TMR_GLOBAL_BANDS_UNROLL", "2")
+        monkeypatch.setenv("TMR_XCORR_PRECISION", "bf16")
     else:
         monkeypatch.setattr(
             diagnostics, "_backend_identity",
@@ -381,8 +381,7 @@ def test_every_mosaic_gate_of_the_package_is_wrapped():
     gates = [
         flash_attn.flash_window_ok, flash_attn.flash_attention_ok,
         pallas_nms.pallas_nms_compiled_ok, pallas_attn.packed_window_ok,
-        pallas_attn.packed_global_ok, pallas_attn.pallas_global_ok,
-        pallas_attn.pallas_fused_ok, kda.kda_chunk_ok,
+        pallas_attn.packed_global_ok, kda.kda_chunk_ok,
         causal_attn.latent_kernel_ok, causal_attn.gqa_kernel_ok,
         pallas_int8.pallas_int8_ok,
         moe.pairs_kernels_ok, ssd.ssd_kernel_ok]
@@ -390,7 +389,7 @@ def test_every_mosaic_gate_of_the_package_is_wrapped():
         assert gate.cache_clear.__qualname__.startswith("mosaic_gate.")
         assert gate.cache_info().maxsize is None
     digest, knobs = diagnostics._source_digest(diagnostics._PACKAGE_DIR)
-    assert len(digest) == 64 and "TMR_PALLAS_ATTN_BQ" in knobs
+    assert len(digest) == 64 and "TMR_XCORR_IMPL" in knobs
 
 
 def test_what_a_check_traces_is_shared_with_no_later_trace():

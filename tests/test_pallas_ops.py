@@ -195,167 +195,6 @@ def test_pallas_xcorr_ok_gates(monkeypatch):
     assert not px.pallas_xcorr_ok(8, 64, 64, 17)
 
 
-# ---- fused rel-pos flash attention (global ViT blocks) ---------------------
-def _attn_inputs(gh, gw, D, B=1, H=2, seed=21, dtype=jnp.float32):
-    rng = np.random.default_rng(seed)
-    S = gh * gw
-    q = jnp.asarray(rng.standard_normal((B, H, S, D)), dtype)
-    k = jnp.asarray(rng.standard_normal((B, H, S, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((B, H, S, D)), dtype)
-    rh = jnp.asarray(rng.standard_normal((gh, gh, D)) * 0.2, jnp.float32)
-    rw = jnp.asarray(rng.standard_normal((gw, gw, D)) * 0.2, jnp.float32)
-    return q, k, v, rh, rw
-
-
-def test_pallas_fused_attention_matches_blockwise(monkeypatch):
-    """The fused-bias kernel (row+lane-aligned tiles, bias from block
-    offsets by broadcast alone — TMR_GLOBAL_ATTN=fused) vs the exact
-    blockwise oracle on the Pallas interpreter: forward values and
-    custom_vjp gradients, with tiles forced small enough that the online
-    softmax chains across multiple k blocks."""
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops.pallas_attn import (
-        effective_fused_tiles,
-        pallas_fused_attention,
-    )
-
-    # gw=8 -> lcm(8,128)=128; S=256 with 128-tile prefs -> 2 q x 2 k blocks
-    monkeypatch.setenv("TMR_PALLAS_ATTN_BQ", "128")
-    monkeypatch.setenv("TMR_PALLAS_ATTN_BK", "128")
-    gh, gw, D = 32, 8, 8
-    assert effective_fused_tiles(gh * gw, gw) == (128, 128)
-    q, k, v, rh, rw = _attn_inputs(gh, gw, D)
-    scale = D**-0.5
-
-    got = jax.jit(
-        lambda *a: pallas_fused_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    want = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-    # no-bias arity reuses the plain kernel — still blockwise-equal
-    got_nb = jax.jit(
-        lambda *a: pallas_fused_attention(*a, None, None, (gh, gw), scale)
-    )(q, k, v)
-    want_nb = jax.jit(
-        lambda *a: blockwise_decomposed_attention(
-            *a, None, None, (gh, gw), scale)
-    )(q, k, v)
-    np.testing.assert_allclose(np.asarray(got_nb), np.asarray(want_nb),
-                               rtol=2e-5, atol=2e-5)
-
-    # gradients: the custom_vjp backward recomputes through blockwise —
-    # this pins the plumbing (argument order, residuals)
-    def loss(fn):
-        return lambda a, b, c: jnp.sum(
-            fn(a, b, c, rh, rw, (gh, gw), scale) ** 2)
-
-    g_got = jax.jit(jax.grad(loss(pallas_fused_attention),
-                             argnums=(0, 1, 2)))(q, k, v)
-    g_want = jax.jit(jax.grad(loss(blockwise_decomposed_attention),
-                              argnums=(0, 1, 2)))(q, k, v)
-    for a, b in zip(g_got, g_want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_xla_flash_attention_matches_blockwise(monkeypatch):
-    """The pure-XLA online-softmax flash path (TMR_GLOBAL_ATTN=xlaflash)
-    vs the exact blockwise oracle — multi-k-block streaming forced via the
-    block-target knobs, bias on and off, non-square grid."""
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops.flash_attn import xla_flash_decomposed_attention
-
-    monkeypatch.setenv("TMR_XLA_FLASH_BQ", "64")
-    monkeypatch.setenv("TMR_XLA_FLASH_BK", "64")
-    for gh, gw in ((16, 8), (16, 16)):
-        D = 8
-        q, k, v, rh, rw = _attn_inputs(gh, gw, D, B=2, H=3)
-        scale = D**-0.5
-        got = jax.jit(
-            lambda *a, _g=(gh, gw): xla_flash_decomposed_attention(
-                *a, _g, scale)
-        )(q, k, v, rh, rw)
-        want = jax.jit(
-            lambda *a, _g=(gh, gw): blockwise_decomposed_attention(
-                *a, _g, scale)
-        )(q, k, v, rh, rw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-
-        got_nb = jax.jit(
-            lambda *a, _g=(gh, gw): xla_flash_decomposed_attention(
-                *a, None, None, _g, scale)
-        )(q, k, v)
-        want_nb = jax.jit(
-            lambda *a, _g=(gh, gw): blockwise_decomposed_attention(
-                *a, None, None, _g, scale)
-        )(q, k, v)
-        np.testing.assert_allclose(np.asarray(got_nb), np.asarray(want_nb),
-                                   rtol=2e-5, atol=2e-5)
-
-    # the knob contract: zero / non-integer targets are rejected
-    monkeypatch.setenv("TMR_XLA_FLASH_BK", "0")
-    with pytest.raises(ValueError, match="TMR_XLA_FLASH_BK"):
-        xla_flash_decomposed_attention(
-            q, k, v, rh, rw, (16, 16), 8**-0.5)
-
-
-def _max_intermediate_elems(jaxpr) -> int:
-    """Largest intermediate array (in elements) anywhere in a jaxpr,
-    sub-jaxprs (scan/pallas bodies) included."""
-    import math as _math
-
-    best = 0
-    for eqn in jaxpr.eqns:
-        for var in eqn.outvars:
-            aval = getattr(var, "aval", None)
-            if aval is not None and getattr(aval, "shape", None) is not None:
-                best = max(best, int(_math.prod(aval.shape)))
-        for val in eqn.params.values():
-            inner = getattr(val, "jaxpr", val)
-            if hasattr(inner, "eqns"):
-                best = max(best, _max_intermediate_elems(inner))
-    return best
-
-
-@pytest.mark.parametrize("gh,gw", [(64, 64), (96, 96)])
-def test_fused_paths_never_materialize_scores(gh, gw, monkeypatch):
-    """The acceptance check for both production geometries (1024 -> 64x64,
-    1536 -> 96x96): the fused Pallas kernel and the XLA flash path must
-    never materialize the (B, H, S, S) score tensor or the broadcast
-    rel-pos bias — asserted structurally on the traced jaxpr (every
-    intermediate in every sub-jaxpr stays below S*S elements). Trace-only:
-    nothing executes, so the full geometries are cheap here."""
-    from tmr_tpu.ops.flash_attn import xla_flash_decomposed_attention
-    from tmr_tpu.ops.pallas_attn import (
-        fused_supported,
-        pallas_fused_attention,
-    )
-
-    monkeypatch.delenv("TMR_PALLAS_ATTN_BQ", raising=False)
-    monkeypatch.delenv("TMR_PALLAS_ATTN_BK", raising=False)
-    monkeypatch.delenv("TMR_XLA_FLASH_BQ", raising=False)
-    monkeypatch.delenv("TMR_XLA_FLASH_BK", raising=False)
-    assert fused_supported(gh * gw, gw)
-    D = 64
-    S = gh * gw
-    q, k, v, rh, rw = _attn_inputs(gh, gw, D)
-    scale = D**-0.5
-    for fn in (pallas_fused_attention, xla_flash_decomposed_attention):
-        jaxpr = jax.make_jaxpr(
-            lambda *a, _f=fn: _f(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-        biggest = _max_intermediate_elems(jaxpr.jaxpr)
-        assert biggest < S * S, (
-            f"{fn.__name__} materializes a {biggest}-element intermediate "
-            f"(S^2 = {S * S}) at grid ({gh}, {gw})"
-        )
-
-
 def test_gate_refusal_records_structured_cause(monkeypatch):
     """Every kernel-gate refusal must leave a machine-readable cause in
     the diagnostics registry: category, exception class when one was
@@ -382,14 +221,14 @@ def test_gate_refusal_records_structured_cause(monkeypatch):
     monkeypatch.delenv("TMR_NO_FLASH_ATTN")
 
     # organic refusal off-TPU: the require_tpu gates record "backend",
-    # with the effective tile config in the cause record
-    pallas_attn.pallas_fused_ok.cache_clear()
-    assert pallas_attn.pallas_fused_ok(16, 8, 8, 128, 128) is False
+    # with the geometry the verdict keys on in the cause record
+    pallas_attn.packed_global_ok.cache_clear()
+    assert pallas_attn.packed_global_ok(16, 16, 64, 2) is False
     recs = drain_gate_refusals()
     assert [r["cause"] for r in recs] == ["backend"]
-    assert recs[0]["gate"] == "pallas_fused_ok"
-    assert recs[0]["config"]["bq"] == 128
-    assert recs[0]["config"]["bk"] == 128
+    assert recs[0]["gate"] == "packed_global_ok"
+    assert recs[0]["config"]["gh"] == 16
+    assert recs[0]["config"]["H"] == 2
 
     # the xcorr gate follows the same schema (its own config vocabulary)
     from tmr_tpu.ops import pallas_xcorr as px
@@ -400,28 +239,6 @@ def test_gate_refusal_records_structured_cause(monkeypatch):
     assert recs and recs[-1]["gate"] == "pallas_xcorr_ok"
     assert recs[-1]["cause"] == "kill-switch"
     assert recs[-1]["config"] == {"C": 8, "H": 64, "W": 64, "T": 17}
-
-
-def test_global_bands_unroll_zero_rejected(monkeypatch):
-    """TMR_GLOBAL_BANDS_UNROLL=0 must raise (the documented contract is a
-    positive integer), never silently clamp to 1 — a zero pin would
-    mislabel any A/B evidence recorded against it."""
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-
-    gh = gw = 8
-    D = 4
-    q, k, v, rh, rw = _attn_inputs(gh, gw, D)
-    monkeypatch.setenv("TMR_GLOBAL_BANDS_UNROLL", "0")
-    with pytest.raises(ValueError, match="TMR_GLOBAL_BANDS_UNROLL"):
-        jax.jit(
-            lambda *a: blockwise_decomposed_attention(*a, (gh, gw), D**-0.5)
-        )(q, k, v, rh, rw)
-    # a positive pin still works (and a beyond-band-count one clamps)
-    monkeypatch.setenv("TMR_GLOBAL_BANDS_UNROLL", "2")
-    out = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), D**-0.5)
-    )(q, k, v, rh, rw)
-    assert out.shape == q.shape
 
 
 def test_pallas_xcorr_big_bucket_falls_back_to_fft(monkeypatch):

@@ -1,6 +1,6 @@
 """Full-program A/B arbitration (scripts/pick_full_program.py): the
-one-block autotune sweep's ranking can disagree with the production
-program (round 4: flash won the sweep, lost the one-block profile), so the
+one-stage autotune sweep's ranking can disagree with the production
+program (round 4: a formulation won the sweep, lost a profile), so the
 battery's env-pinned whole-program benches decide — a decisive winner's
 knobs are pinned into the autotune seed with fresh variant stamps.
 """
@@ -36,8 +36,8 @@ def seed_file(tmp_path, monkeypatch):
     path = tmp_path / "seed.json"
     path.write_text(json.dumps({
         "TPU v5 lite|1024|128|4|512|vit_b": {
-            "TMR_GLOBAL_ATTN": "blockwise",
-            "_variants_TMR_GLOBAL_ATTN": "stale",
+            "TMR_XCORR_IMPL_SMALL": "conv",
+            "_variants_TMR_XCORR_IMPL_SMALL": "stale",
         }
     }))
     monkeypatch.setenv("TMR_AUTOTUNE_SEED", str(path))
@@ -53,51 +53,47 @@ def test_decisive_full_program_winner_pins_seed(tmp_path, seed_file, capsys):
     # autotuned (nothing externally pinned)
     (tmp_path / "bench_live.json").write_text(json.dumps(_rec(
         10.1,
-        knobs={"TMR_GLOBAL_ATTN": "blockwise"},
-        autotuned={"TMR_GLOBAL_ATTN": "blockwise"},
+        knobs={"TMR_XCORR_IMPL_SMALL": "conv"},
+        autotuned={"TMR_XCORR_IMPL_SMALL": "conv"},
     )))
-    # pinned run: the kernel's query tile forced in the env (absent from
-    # autotuned); the sweep of that run picked the formulation itself
-    (tmp_path / "bench_pallas.json").write_text(json.dumps(_rec(
-        27.4,
-        knobs={"TMR_GLOBAL_ATTN": "pallas", "TMR_PALLAS_ATTN_BQ": "256"},
-        autotuned={"TMR_GLOBAL_ATTN": "pallas"},
+    # pinned run: the correlation's formulation forced in the env (absent
+    # from autotuned)
+    (tmp_path / "bench_vmap.json").write_text(json.dumps(_rec(
+        27.4, knobs={"TMR_XCORR_IMPL_SMALL": "vmap"},
     )))
     rc = arb.main([str(tmp_path / "bench_live.json"),
-                   str(tmp_path / "bench_pallas.json")])
+                   str(tmp_path / "bench_vmap.json")])
     assert rc == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["updated"] is True and out["best"] == "bench_pallas.json"
+    assert out["updated"] is True and out["best"] == "bench_vmap.json"
 
     from tmr_tpu.utils.autotune import _load_validated, _variants_sig
 
     seed = json.loads(seed_file.read_text())
     entry = seed["TPU v5 lite|1024|128|4|512|vit_b"]
-    assert entry["TMR_PALLAS_ATTN_BQ"] == "256"
-    # the winning run's autotuned formulation is full-program-endorsed
-    assert entry["TMR_GLOBAL_ATTN"] == "pallas"
-    assert entry["_variants_TMR_GLOBAL_ATTN"] == _variants_sig(
-        "TMR_GLOBAL_ATTN"
+    assert entry["TMR_XCORR_IMPL_SMALL"] == "vmap"
+    assert entry["_variants_TMR_XCORR_IMPL_SMALL"] == _variants_sig(
+        "TMR_XCORR_IMPL_SMALL"
     )
     assert "_full_program_ab" in entry
     # and the written entry survives the loader's validation
     loaded = _load_validated(str(seed_file))
     assert loaded["TPU v5 lite|1024|128|4|512|vit_b"][
-        "TMR_GLOBAL_ATTN"] == "pallas"
+        "TMR_XCORR_IMPL_SMALL"] == "vmap"
 
 
 def test_non_decisive_win_leaves_seed_alone(tmp_path, seed_file, capsys):
     arb = _arbiter()
     before = seed_file.read_text()
     (tmp_path / "bench_live.json").write_text(json.dumps(_rec(
-        10.1, knobs={"TMR_GLOBAL_ATTN": "blockwise"},
-        autotuned={"TMR_GLOBAL_ATTN": "blockwise"},
+        10.1, knobs={"TMR_XCORR_IMPL_SMALL": "conv"},
+        autotuned={"TMR_XCORR_IMPL_SMALL": "conv"},
     )))
-    (tmp_path / "bench_pallas.json").write_text(json.dumps(_rec(
-        10.2, knobs={"TMR_GLOBAL_ATTN": "pallas"},
+    (tmp_path / "bench_vmap.json").write_text(json.dumps(_rec(
+        10.2, knobs={"TMR_XCORR_IMPL_SMALL": "vmap"},
     )))
     rc = arb.main([str(tmp_path / "bench_live.json"),
-                   str(tmp_path / "bench_pallas.json")])
+                   str(tmp_path / "bench_vmap.json")])
     assert rc == 3
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["updated"] is False
@@ -110,10 +106,10 @@ def test_no_baseline_refuses_to_pin(tmp_path, seed_file, capsys):
     to beat the autotuned program (review finding r5)."""
     arb = _arbiter()
     before = seed_file.read_text()
-    (tmp_path / "bench_pallas.json").write_text(json.dumps(_rec(
-        27.4, knobs={"TMR_GLOBAL_ATTN": "pallas"},
+    (tmp_path / "bench_vmap.json").write_text(json.dumps(_rec(
+        27.4, knobs={"TMR_XCORR_IMPL_SMALL": "vmap"},
     )))
-    rc = arb.main([str(tmp_path / "bench_pallas.json")])
+    rc = arb.main([str(tmp_path / "bench_vmap.json")])
     assert rc == 3
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["updated"] is False and "baseline" in out["reason"]
@@ -125,26 +121,26 @@ def test_pins_only_matching_batch_entries(tmp_path, seed_file, capsys):
     arb = _arbiter()
     seed = json.loads(seed_file.read_text())
     seed["TPU v5 lite|1024|128|8|512|vit_b"] = {
-        "TMR_GLOBAL_ATTN": "flash",
-        "_variants_TMR_GLOBAL_ATTN": "whatever",
+        "TMR_XCORR_IMPL_SMALL": "fft",
+        "_variants_TMR_XCORR_IMPL_SMALL": "whatever",
     }
     seed_file.write_text(json.dumps(seed))
     (tmp_path / "bench_live.json").write_text(json.dumps(_rec(
-        10.0, knobs={"TMR_GLOBAL_ATTN": "blockwise"},
-        autotuned={"TMR_GLOBAL_ATTN": "blockwise"},
+        10.0, knobs={"TMR_XCORR_IMPL_SMALL": "conv"},
+        autotuned={"TMR_XCORR_IMPL_SMALL": "conv"},
     )))
-    (tmp_path / "bench_pallas.json").write_text(json.dumps(_rec(
-        20.0, knobs={"TMR_GLOBAL_ATTN": "pallas"},
+    (tmp_path / "bench_vmap.json").write_text(json.dumps(_rec(
+        20.0, knobs={"TMR_XCORR_IMPL_SMALL": "vmap"},
     )))
     rc = arb.main([str(tmp_path / "bench_live.json"),
-                   str(tmp_path / "bench_pallas.json")])
+                   str(tmp_path / "bench_vmap.json")])
     assert rc == 0
     seed = json.loads(seed_file.read_text())
     assert seed["TPU v5 lite|1024|128|4|512|vit_b"][
-        "TMR_GLOBAL_ATTN"] == "pallas"
+        "TMR_XCORR_IMPL_SMALL"] == "vmap"
     # the batch-8 entry is untouched
     assert seed["TPU v5 lite|1024|128|8|512|vit_b"][
-        "TMR_GLOBAL_ATTN"] == "flash"
+        "TMR_XCORR_IMPL_SMALL"] == "fft"
 
 
 def test_size_match_is_positional_not_substring(tmp_path, seed_file, capsys):
@@ -152,23 +148,23 @@ def test_size_match_is_positional_not_substring(tmp_path, seed_file, capsys):
     substring-match the emb field of EVERY key (kind|image|up_hw|batch|emb|
     vit) — the match must compare the image field positionally."""
     arb = _arbiter()
-    base = _rec(10.0, knobs={"TMR_GLOBAL_ATTN": "blockwise"},
-                autotuned={"TMR_GLOBAL_ATTN": "blockwise"})
-    pin = _rec(20.0, knobs={"TMR_GLOBAL_ATTN": "pallas"})
+    base = _rec(10.0, knobs={"TMR_XCORR_IMPL_SMALL": "conv"},
+                autotuned={"TMR_XCORR_IMPL_SMALL": "conv"})
+    pin = _rec(20.0, knobs={"TMR_XCORR_IMPL_SMALL": "vmap"})
     for r in (base, pin):
         r["image_size"] = 512
         r["device_kind"] = "TPU v5 lite"
     (tmp_path / "bench_live.json").write_text(json.dumps(base))
-    (tmp_path / "bench_pallas.json").write_text(json.dumps(pin))
+    (tmp_path / "bench_vmap.json").write_text(json.dumps(pin))
     rc = arb.main([str(tmp_path / "bench_live.json"),
-                   str(tmp_path / "bench_pallas.json")])
+                   str(tmp_path / "bench_vmap.json")])
     assert rc == 0
     seed = json.loads(seed_file.read_text())
     # the 1024 entry is untouched; a NEW 512 key was created instead
     assert seed["TPU v5 lite|1024|128|4|512|vit_b"][
-        "TMR_GLOBAL_ATTN"] == "blockwise"
+        "TMR_XCORR_IMPL_SMALL"] == "conv"
     assert seed["TPU v5 lite|512|64|4|512|vit_b"][
-        "TMR_GLOBAL_ATTN"] == "pallas"
+        "TMR_XCORR_IMPL_SMALL"] == "vmap"
 
 
 def test_error_records_and_missing_files_are_skipped(tmp_path, seed_file,
@@ -185,9 +181,9 @@ def test_error_records_and_missing_files_are_skipped(tmp_path, seed_file,
 
 
 def test_pinned_tile_knobs_round_trip_the_cache(tmp_path, monkeypatch):
-    """Tile/unroll pins written by the arbiter must survive cache validation
-    and be exported to the env by autotune() as cached hits — the pallas
-    kernels read them at trace time."""
+    """A pinned formulation and the stored-int8 arm written into the seed
+    must survive cache validation (an off-contract value is dropped) and
+    be exported to the env by autotune() as cached hits."""
     import jax
 
     from tmr_tpu.utils import autotune as at
@@ -195,39 +191,33 @@ def test_pinned_tile_knobs_round_trip_the_cache(tmp_path, monkeypatch):
     seed = tmp_path / "seed.json"
     seed.write_text(json.dumps({
         "cpu|1024|128|4|512|vit_b": {
-            "TMR_GLOBAL_ATTN": "pallas",
-            "_variants_TMR_GLOBAL_ATTN": at._variants_sig("TMR_GLOBAL_ATTN"),
-            "TMR_PALLAS_ATTN_BQ": "256",
-            "TMR_PALLAS_ATTN_BK": "1024",
-            "TMR_GLOBAL_BANDS_UNROLL": "2",
-            "TMR_PALLAS_ATTN_BQ_bad": "300",  # not pow2: must be dropped
+            "TMR_XCORR_IMPL_SMALL": "vmap",
+            "_variants_TMR_XCORR_IMPL_SMALL": at._variants_sig(
+                "TMR_XCORR_IMPL_SMALL"),
+            "TMR_QUANT_STORAGE": "int8",
+            "_variants_TMR_QUANT_STORAGE": at._variants_sig(
+                "TMR_QUANT_STORAGE"),
+            "TMR_XCORR_PRECISION": "fp8",  # not a precision: dropped
         }
     }))
     monkeypatch.setenv("TMR_AUTOTUNE_SEED", str(seed))
     monkeypatch.setenv("TMR_AUTOTUNE_CACHE", str(tmp_path / "cache.json"))
     loaded = at._load_validated(str(seed))
     entry = loaded["cpu|1024|128|4|512|vit_b"]
-    assert entry["TMR_PALLAS_ATTN_BQ"] == "256"
-    assert entry["TMR_GLOBAL_BANDS_UNROLL"] == "2"
-    assert "TMR_PALLAS_ATTN_BQ_bad" not in entry
+    assert entry["TMR_XCORR_IMPL_SMALL"] == "vmap"
+    assert entry["TMR_QUANT_STORAGE"] == "int8"
+    assert "TMR_XCORR_PRECISION" not in entry
 
-    for k in ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL",
-              "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
-              "TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-              "TMR_GLOBAL_BANDS_UNROLL"):
+    for k in ("TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL",
+              "TMR_XCORR_PRECISION", "TMR_QUANT_STORAGE"):
         monkeypatch.delenv(k, raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(at, "measure_rtt_floor", lambda: 0.0)
     monkeypatch.setattr(
         at, "pick_xcorr_impl", lambda *a, **k: {"conv": 0.01}
     )
-    monkeypatch.setattr(
-        at, "pick_global_attn_impl", lambda *a, **k: {"blockwise": 0.01}
-    )
-    # the PR 6 decoder/quant stages are NOT what this test pins (tile
-    # knobs round-tripping the cache) — unmocked they compile real
-    # stage programs at the 1024 geometry and were silently charging
-    # ~5 minutes of tier-1 wall to an unrelated code path
+    # the decoder/quant stages are NOT what this test pins — unmocked they
+    # compile real stage programs at the 1024 geometry
     monkeypatch.setattr(
         at, "pick_decoder_impl", lambda *a, **k: {"xla": 0.01}
     )
@@ -245,13 +235,13 @@ def test_pinned_tile_knobs_round_trip_the_cache(tmp_path, monkeypatch):
                  batch_size=1)
     report = at.autotune(cfg, 1024, 4, tune_precision=False)
     try:
-        assert report["TMR_GLOBAL_ATTN"] == {"picked": "pallas",
-                                             "cached": True}
-        assert os.environ["TMR_PALLAS_ATTN_BQ"] == "256"
-        assert os.environ["TMR_PALLAS_ATTN_BK"] == "1024"
-        assert os.environ["TMR_GLOBAL_BANDS_UNROLL"] == "2"
+        assert report["TMR_XCORR_IMPL_SMALL"] == {"picked": "vmap",
+                                                  "cached": True}
+        assert report["TMR_QUANT_STORAGE"] == {"picked": "int8",
+                                               "cached": True}
+        assert os.environ["TMR_XCORR_IMPL_SMALL"] == "vmap"
+        assert os.environ["TMR_QUANT_STORAGE"] == "int8"
     finally:
-        for k in ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL_SMALL",
-                  "TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-                  "TMR_GLOBAL_BANDS_UNROLL", "TMR_XCORR_PRECISION"):
+        for k in ("TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
+                  "TMR_QUANT_STORAGE", "TMR_DECODER_IMPL"):
             os.environ.pop(k, None)

@@ -1,6 +1,6 @@
 """Promotion of user-cache autotune winners into the committed seed
 (scripts/promote_cache_to_seed.py): stamped-fresh winners are promoted,
-stale ones are not, and full-program pins (which outrank one-block sweep
+stale ones are not, and full-program pins (which outrank one-stage sweep
 winners) are preserved.
 """
 
@@ -38,28 +38,29 @@ def test_fresh_winners_promote_and_stale_do_not(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "pallas",
-        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
         "TMR_XCORR_IMPL_SMALL": "vmap",
-        "_variants_TMR_XCORR_IMPL_SMALL": "stale,old,set",  # must not move
+        "_variants_TMR_XCORR_IMPL_SMALL": _variants_sig(
+            "TMR_XCORR_IMPL_SMALL"),
+        "TMR_DECODER_IMPL": "fused",
+        "_variants_TMR_DECODER_IMPL": "stale,old,set",  # must not move
         "TMR_BENCH_BATCH": "8",
     }}))
     seed.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "blockwise",
-        "_variants_TMR_GLOBAL_ATTN": "old",
         "TMR_XCORR_IMPL_SMALL": "conv",
         "_variants_TMR_XCORR_IMPL_SMALL": "old",
+        "TMR_DECODER_IMPL": "xla",
+        "_variants_TMR_DECODER_IMPL": "old",
     }}))
     rc = _promoter().main([])
     assert rc == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_GLOBAL_ATTN"] == "pallas"
-    assert out["_variants_TMR_GLOBAL_ATTN"] == _variants_sig(
-        "TMR_GLOBAL_ATTN"
+    assert out["TMR_XCORR_IMPL_SMALL"] == "vmap"
+    assert out["_variants_TMR_XCORR_IMPL_SMALL"] == _variants_sig(
+        "TMR_XCORR_IMPL_SMALL"
     )
     # the stale-stamped correlation winner did NOT launder into the seed
-    assert out["TMR_XCORR_IMPL_SMALL"] == "conv"
-    assert out["_variants_TMR_XCORR_IMPL_SMALL"] == "old"
+    assert out["TMR_DECODER_IMPL"] == "xla"
+    assert out["_variants_TMR_DECODER_IMPL"] == "old"
     # measured batch rides along
     assert out["TMR_BENCH_BATCH"] == "8"
 
@@ -69,26 +70,26 @@ def test_full_program_pins_outrank_sweep_winners(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "flash",
-        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
-        "TMR_XCORR_IMPL_SMALL": "vmap",
+        "TMR_XCORR_IMPL_SMALL": "fft",
         "_variants_TMR_XCORR_IMPL_SMALL": _variants_sig(
-            "TMR_XCORR_IMPL_SMALL"
-        ),
+            "TMR_XCORR_IMPL_SMALL"),
+        "TMR_DECODER_IMPL": "fused",
+        "_variants_TMR_DECODER_IMPL": _variants_sig("TMR_DECODER_IMPL"),
     }}))
-    # seed entry written by pick_full_program: blockwise won the WHOLE-
-    # program A/B — the sweep's one-block flash pick must not overwrite it
+    # seed entry written by pick_full_program: conv won the WHOLE-program
+    # A/B — the sweep's one-stage fft pick must not overwrite it
     seed.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "blockwise",
-        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
+        "TMR_XCORR_IMPL_SMALL": "conv",
+        "_variants_TMR_XCORR_IMPL_SMALL": _variants_sig(
+            "TMR_XCORR_IMPL_SMALL"),
         "_full_program_ab": "{}",
     }}))
     rc = _promoter().main([])
     assert rc == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_GLOBAL_ATTN"] == "blockwise"         # preserved
+    assert out["TMR_XCORR_IMPL_SMALL"] == "conv"         # preserved
     assert out["_full_program_ab"] == "{}"         # marker intact
-    assert out["TMR_XCORR_IMPL_SMALL"] == "vmap"   # non-block knob promoted
+    assert out["TMR_DECODER_IMPL"] == "fused"   # non-pinnable knob promoted
 
 
 def test_stale_full_program_pin_does_not_block_promotion(paths, capsys):
@@ -100,18 +101,19 @@ def test_stale_full_program_pin_does_not_block_promotion(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "flash",
-        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
+        "TMR_XCORR_IMPL_SMALL": "fft",
+        "_variants_TMR_XCORR_IMPL_SMALL": _variants_sig(
+            "TMR_XCORR_IMPL_SMALL"),
     }}))
     seed.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "blockwise",
-        "_variants_TMR_GLOBAL_ATTN": "pre-revision,stale",
+        "TMR_XCORR_IMPL_SMALL": "conv",
+        "_variants_TMR_XCORR_IMPL_SMALL": "pre-revision,stale",
         "_full_program_ab": "{}",
     }}))
     rc = _promoter().main([])
     assert rc == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_GLOBAL_ATTN"] == "flash"
+    assert out["TMR_XCORR_IMPL_SMALL"] == "fft"
 
 
 def test_overwritten_stale_pin_loses_its_marker(paths, capsys):
@@ -123,17 +125,18 @@ def test_overwritten_stale_pin_loses_its_marker(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "flash",
-        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
+        "TMR_XCORR_IMPL_SMALL": "fft",
+        "_variants_TMR_XCORR_IMPL_SMALL": _variants_sig(
+            "TMR_XCORR_IMPL_SMALL"),
     }}))
     seed.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "blockwise",
-        "_variants_TMR_GLOBAL_ATTN": "pre-revision,stale",
+        "TMR_XCORR_IMPL_SMALL": "conv",
+        "_variants_TMR_XCORR_IMPL_SMALL": "pre-revision,stale",
         "_full_program_ab": "{}",
     }}))
     assert _promoter().main([]) == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_GLOBAL_ATTN"] == "flash"
+    assert out["TMR_XCORR_IMPL_SMALL"] == "fft"
     assert "_full_program_ab" not in out
 
 
@@ -167,23 +170,24 @@ def test_corrupt_seed_entry_degrades_gracefully(paths, capsys):
 
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "pallas",
-        "_variants_TMR_GLOBAL_ATTN": _variants_sig("TMR_GLOBAL_ATTN"),
+        "TMR_XCORR_IMPL_SMALL": "vmap",
+        "_variants_TMR_XCORR_IMPL_SMALL": _variants_sig(
+            "TMR_XCORR_IMPL_SMALL"),
     }}))
     seed.write_text(json.dumps({KEY: "corrupt-string-entry"}))
     rc = _promoter().main([])
     assert rc == 0
     out = json.loads(seed.read_text())[KEY]
-    assert out["TMR_GLOBAL_ATTN"] == "pallas"
+    assert out["TMR_XCORR_IMPL_SMALL"] == "vmap"
 
 
 def test_nothing_to_promote(paths, capsys):
     cache, seed = paths
     cache.write_text(json.dumps({KEY: {
-        "TMR_GLOBAL_ATTN": "flash",
-        "_variants_TMR_GLOBAL_ATTN": "stale",
+        "TMR_XCORR_IMPL_SMALL": "fft",
+        "_variants_TMR_XCORR_IMPL_SMALL": "stale",
     }}))
-    before = json.dumps({KEY: {"TMR_GLOBAL_ATTN": "blockwise"}})
+    before = json.dumps({KEY: {"TMR_XCORR_IMPL_SMALL": "conv"}})
     seed.write_text(before)
     rc = _promoter().main([])
     assert rc == 3

@@ -317,408 +317,6 @@ def test_blockwise_attention_matches_dense_at_global_grid():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.slow
-def test_blockfolded_attention_matches_blockwise():
-    """TMR_GLOBAL_ATTN=blockfolded (fold-into-QK + band scan, models/vit.py)
-    must equal the exact blockwise path in f32 — the fold is algebraically
-    exact there — at a grid that takes the global branch, bias on and off,
-    non-square grid included."""
-    import numpy as np
-
-    from tmr_tpu.models.vit import (
-        blockfolded_decomposed_attention,
-        blockwise_decomposed_attention,
-    )
-
-    rng = np.random.default_rng(11)
-    for gh, gw in ((32, 32), (16, 8)):
-        B, H, D = 2, 3, 8
-        S = gh * gw
-        q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-        rh = jnp.asarray(rng.standard_normal((gh, gh, D)), jnp.float32) * 0.2
-        rw = jnp.asarray(rng.standard_normal((gw, gw, D)), jnp.float32) * 0.2
-        scale = D**-0.5
-
-        got = jax.jit(
-            lambda *a: blockfolded_decomposed_attention(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-        want = jax.jit(
-            lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-        got_nb = jax.jit(
-            lambda *a: blockfolded_decomposed_attention(
-                *a, None, None, (gh, gw), scale)
-        )(q, k, v)
-        want_nb = jax.jit(
-            lambda *a: blockwise_decomposed_attention(
-                *a, None, None, (gh, gw), scale)
-        )(q, k, v)
-        np.testing.assert_allclose(np.asarray(got_nb), np.asarray(want_nb),
-                                   rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_densefolded_attention_matches_blockwise():
-    """TMR_GLOBAL_ATTN=densefolded (folded QK, no band scan) must equal the
-    exact blockwise path in f32, bias on and off, non-square grid included
-    — same contract as blockfolded, different XLA schedule."""
-    from tmr_tpu.models.vit import (
-        blockwise_decomposed_attention,
-        densefolded_decomposed_attention,
-    )
-
-    rng = np.random.default_rng(13)
-    for gh, gw in ((32, 32), (16, 8)):
-        B, H, D = 2, 3, 8
-        S = gh * gw
-        q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-        rh = jnp.asarray(rng.standard_normal((gh, gh, D)), jnp.float32) * 0.2
-        rw = jnp.asarray(rng.standard_normal((gw, gw, D)), jnp.float32) * 0.2
-        scale = D**-0.5
-
-        got = jax.jit(
-            lambda *a: densefolded_decomposed_attention(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-        want = jax.jit(
-            lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-        got_nb = jax.jit(
-            lambda *a: densefolded_decomposed_attention(
-                *a, None, None, (gh, gw), scale)
-        )(q, k, v)
-        want_nb = jax.jit(
-            lambda *a: blockwise_decomposed_attention(
-                *a, None, None, (gh, gw), scale)
-        )(q, k, v)
-        np.testing.assert_allclose(np.asarray(got_nb), np.asarray(want_nb),
-                                   rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_global_bands_unroll_invariance(monkeypatch):
-    """TMR_GLOBAL_BANDS_UNROLL is a schedule knob: unroll 2/4 (and a value
-    past the band count, which clamps) must match the default scan — the
-    bands compute the same ops either way. Tolerance instead of bit-equal:
-    rolled vs unrolled scan bodies are different XLA programs and the
-    compiler may legally reassociate the per-band reductions."""
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-
-    rng = np.random.default_rng(14)
-    gh = gw = 32
-    B, H, D = 2, 3, 8
-    S = gh * gw
-    q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    rh = jnp.asarray(rng.standard_normal((gh, gh, D)), jnp.float32) * 0.2
-    rw = jnp.asarray(rng.standard_normal((gw, gw, D)), jnp.float32) * 0.2
-    scale = D**-0.5
-
-    monkeypatch.delenv("TMR_GLOBAL_BANDS_UNROLL", raising=False)
-    want = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    for unroll in ("2", "4", "1000"):
-        monkeypatch.setenv("TMR_GLOBAL_BANDS_UNROLL", unroll)
-        got = jax.jit(
-            lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-6, atol=1e-7)
-
-    monkeypatch.setenv("TMR_GLOBAL_BANDS_UNROLL", "auto")
-    with pytest.raises(ValueError, match="TMR_GLOBAL_BANDS_UNROLL"):
-        jax.jit(
-            lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-
-
-@pytest.mark.slow
-def test_scores_dtype_bf16_matches_oracle(monkeypatch):
-    """TMR_GLOBAL_SCORES_DTYPE=bf16 (folded paths materialize the score
-    tiles in bf16 — half the HBM traffic) must stay within bf16-rounding
-    tolerance of the exact blockwise oracle, for both folded formulations;
-    f32 inputs must be untouched by the knob (bit-equal to f32 scores)."""
-    from tmr_tpu.models.vit import (
-        blockfolded_decomposed_attention,
-        blockwise_decomposed_attention,
-        densefolded_decomposed_attention,
-    )
-
-    rng = np.random.default_rng(16)
-    gh = gw = 16
-    B, H, D = 2, 3, 8
-    S = gh * gw
-    mk = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
-    q, k, v = mk(B, H, S, D), mk(B, H, S, D), mk(B, H, S, D)
-    rh, rw = mk(gh, gh, D) * 0.2, mk(gw, gw, D) * 0.2
-    scale = D**-0.5
-
-    monkeypatch.delenv("TMR_GLOBAL_SCORES_DTYPE", raising=False)
-    oracle = np.asarray(jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw), np.float32)
-
-    monkeypatch.setenv("TMR_GLOBAL_SCORES_DTYPE", "bf16")
-    for name, fn in (("blockfolded", blockfolded_decomposed_attention),
-                     ("densefolded", densefolded_decomposed_attention)):
-        got16 = np.asarray(jax.jit(
-            lambda *a, _f=fn: _f(*a, (gh, gw), scale)
-        )(*(t.astype(jnp.bfloat16) for t in (q, k, v)), rh, rw), np.float32)
-        rel = np.abs(got16 - oracle).max() / (np.abs(oracle).max() + 1e-6)
-        assert rel < 0.05, (name, rel)
-        # liveness: the knob must change the traced PROGRAM (bf16 score
-        # tiles where the f32 run had f32). Output inequality is the
-        # wrong pin at this tiny geometry — the post-softmax bf16
-        # rounding can absorb the score-tile rounding entirely (it does
-        # for densefolded on CPU) — so assert at the jaxpr level, the
-        # PR-1 no-S^2 technique.
-        trace = lambda _f=fn: str(jax.make_jaxpr(
-            lambda *a: _f(*a, (gh, gw), scale)
-        )(*(t.astype(jnp.bfloat16) for t in (q, k, v)), rh, rw))
-        jaxpr_on = trace()
-        monkeypatch.delenv("TMR_GLOBAL_SCORES_DTYPE", raising=False)
-        jaxpr_off = trace()
-        monkeypatch.setenv("TMR_GLOBAL_SCORES_DTYPE", "bf16")
-        assert jaxpr_on != jaxpr_off, f"{name}: knob is a silent no-op"
-
-        # f32 inputs: the knob must be inert (exact path untouched)
-        got_f32 = np.asarray(jax.jit(
-            lambda *a, _f=fn: _f(*a, (gh, gw), scale)
-        )(q, k, v, rh, rw), np.float32)
-        np.testing.assert_allclose(got_f32, oracle, rtol=1e-5, atol=1e-5)
-
-    # the PARITY ORACLE must ignore the env knob entirely: a bare
-    # blockwise call with rh=None (no-rel-pos models, and the pallas
-    # custom_vjp's backward oracle) under TMR_GLOBAL_SCORES_DTYPE=bf16
-    # must be bit-equal to the knob-unset run — the knob is plumbed as an
-    # explicit parameter only the gated folded formulations pass
-    qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))
-    bw16 = np.asarray(jax.jit(
-        lambda *a: blockwise_decomposed_attention(
-            *a, None, None, (gh, gw), scale)
-    )(qb, kb, vb), np.float32)
-    monkeypatch.delenv("TMR_GLOBAL_SCORES_DTYPE")
-    bw_ref = np.asarray(jax.jit(
-        lambda *a: blockwise_decomposed_attention(
-            *a, None, None, (gh, gw), scale)
-    )(qb, kb, vb), np.float32)
-    np.testing.assert_array_equal(bw16, bw_ref)
-
-    monkeypatch.setenv("TMR_GLOBAL_SCORES_DTYPE", "fp8")
-    with pytest.raises(ValueError, match="TMR_GLOBAL_SCORES_DTYPE"):
-        jax.jit(
-            lambda *a: blockfolded_decomposed_attention(
-                *a, (gh, gw), scale)
-        )(*(t.astype(jnp.bfloat16) for t in (q, k, v)), rh, rw)
-
-
-@pytest.mark.slow
-def test_scores_dtype_gate_keys_on_knob(monkeypatch):
-    """The blockfolded/densefolded numerics gates must cache their verdict
-    PER scores dtype — a verdict under f32 scores must never vouch for
-    bf16 score tiles (different checked numerics)."""
-    from tmr_tpu.ops import flash_attn
-
-    flash_attn.blockfolded_ok.cache_clear()
-    monkeypatch.delenv("TMR_GLOBAL_SCORES_DTYPE", raising=False)
-    v_f32 = flash_attn.blockfolded_ok(16, 16, 8, "f32")
-    monkeypatch.setenv("TMR_GLOBAL_SCORES_DTYPE", "bf16")
-    v_bf16 = flash_attn.blockfolded_ok(16, 16, 8, "bf16")
-    assert isinstance(v_f32, bool) and isinstance(v_bf16, bool)
-    info = flash_attn.blockfolded_ok.cache_info()
-    assert info.currsize >= 2  # two distinct cache entries, not one reused
-
-
-@pytest.mark.slow
-def test_global_attn_env_dispatch_densefolded(monkeypatch):
-    """Attention must dispatch to densefolded (blockwise-equal output)
-    when TMR_GLOBAL_ATTN=densefolded — the env plumbing, not just the
-    free function."""
-    from tmr_tpu.models.vit import Attention
-
-    rng = np.random.default_rng(15)
-    x = jnp.asarray(rng.standard_normal((1, 32, 32, 16)), jnp.float32)
-    attn = Attention(num_heads=2, rel_pos_size=(32, 32))
-    params = attn.init(jax.random.key(0), x)
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
-    want = jax.jit(attn.apply)(params, x)
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "densefolded")
-    got = jax.jit(attn.apply)(params, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_global_attn_env_dispatch_blockfolded(monkeypatch):
-    """The Attention module must actually dispatch to the blockfolded path
-    (and produce blockwise-equal output) when TMR_GLOBAL_ATTN=blockfolded —
-    guarding the env plumbing, not just the free function."""
-    import numpy as np
-
-    from tmr_tpu.models.vit import Attention
-
-    rng = np.random.default_rng(12)
-    x = jnp.asarray(rng.standard_normal((1, 32, 32, 16)), jnp.float32)
-    attn = Attention(num_heads=2, rel_pos_size=(32, 32))
-    params = attn.init(jax.random.key(0), x)
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
-    want = jax.jit(attn.apply)(params, x)
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockfolded")
-    got = jax.jit(attn.apply)(params, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "bogus")
-    with pytest.raises(ValueError, match="TMR_GLOBAL_ATTN"):
-        jax.jit(attn.apply)(params, x)
-
-    # an explicit pallas request whose gate refuses (always true off-TPU)
-    # must WARN about the blockwise fallback — a silent fallback corrupts
-    # A/B measurements by recording blockwise timings under another label
-    import warnings as _warnings
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "pallas")
-    with _warnings.catch_warnings(record=True) as rec:
-        _warnings.simplefilter("always")
-        got_p = jax.jit(attn.apply)(params, x)
-    assert any("blockwise fallback" in str(r.message) for r in rec)
-    np.testing.assert_allclose(np.asarray(got_p), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_pallas_decomposed_attention_matches_blockwise():
-    """The custom VMEM-resident global-attention kernel
-    (ops/pallas_attn.py, TMR_GLOBAL_ATTN=pallas) vs the exact blockwise
-    oracle — forward values and custom_vjp gradients, bias on and off, on
-    the Pallas interpreter (the TPU self-check gate runs the same
-    comparison compiled)."""
-    import numpy as np
-
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops.pallas_attn import pallas_decomposed_attention
-
-    rng = np.random.default_rng(13)
-    B, H, gh, gw, D = 1, 2, 16, 8, 8  # S=128: one 128-token block
-    S = gh * gw
-    q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    rh = jnp.asarray(rng.standard_normal((gh, gh, D)), jnp.float32) * 0.2
-    rw = jnp.asarray(rng.standard_normal((gw, gw, D)), jnp.float32) * 0.2
-    scale = D**-0.5
-
-    got = jax.jit(
-        lambda *a: pallas_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    want = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-    got_nb = jax.jit(
-        lambda *a: pallas_decomposed_attention(
-            *a, None, None, (gh, gw), scale)
-    )(q, k, v)
-    want_nb = jax.jit(
-        lambda *a: blockwise_decomposed_attention(
-            *a, None, None, (gh, gw), scale)
-    )(q, k, v)
-    np.testing.assert_allclose(np.asarray(got_nb), np.asarray(want_nb),
-                               rtol=2e-5, atol=2e-5)
-
-    # gradients: the custom_vjp backward recomputes through blockwise, so
-    # this pins the plumbing (argument order, None-bias arity)
-    def loss(fn):
-        return lambda a, b, c: jnp.sum(
-            fn(a, b, c, rh, rw, (gh, gw), scale) ** 2)
-
-    g_got = jax.jit(jax.grad(loss(pallas_decomposed_attention),
-                             argnums=(0, 1, 2)))(q, k, v)
-    g_want = jax.jit(jax.grad(loss(blockwise_decomposed_attention),
-                              argnums=(0, 1, 2)))(q, k, v)
-    for a, b in zip(g_got, g_want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("gh,gw,D", [(16, 32, 8), (16, 32, 80)])
-@pytest.mark.slow
-def test_pallas_attention_multiblock_seq(gh, gw, D):
-    """S=512 at block 256 forces a real multi-k-block online-softmax pass
-    (running max/denominator rescaling across iterations); D=80 is vit_h's
-    head dim — not lane-aligned, exercising the kernel's padded tiles."""
-    import numpy as np
-
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops import pallas_attn
-
-    rng = np.random.default_rng(14)
-    B, H = 1, 1
-    S = gh * gw
-    q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    rh = jnp.asarray(rng.standard_normal((gh, gh, D)), jnp.float32) * 0.2
-    rw = jnp.asarray(rng.standard_normal((gw, gw, D)), jnp.float32) * 0.2
-    scale = D**-0.5
-
-    orig = pallas_attn._pick_block
-    pallas_attn._pick_block = lambda s, preferred=256: orig(s, 256)
-    try:
-        got = jax.jit(
-            lambda *a: pallas_attn.pallas_decomposed_attention(
-                *a, (gh, gw), scale)
-        )(q, k, v, rh, rw)
-    finally:
-        pallas_attn._pick_block = orig
-    want = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_pallas_global_gate_keys_on_effective_tiles(monkeypatch):
-    """The gate verdict must be cached per EFFECTIVE (bq, bk) tile config:
-    TMR_PALLAS_ATTN_BQ/BK change the kernel the forward impl traces, so a
-    verdict reached under one tile config must never vouch for another
-    (ADVICE r4 medium). effective_global_tiles is the caller-side
-    resolution — env preference clamped to a power-of-two divisor of S,
-    identical to _pallas_attn_fwd_impl's."""
-    from tmr_tpu.ops import pallas_attn
-
-    monkeypatch.delenv("TMR_PALLAS_ATTN_BQ", raising=False)
-    monkeypatch.delenv("TMR_PALLAS_ATTN_BK", raising=False)
-    assert pallas_attn.effective_global_tiles(4096) == (512, 512)
-    monkeypatch.setenv("TMR_PALLAS_ATTN_BQ", "256")
-    monkeypatch.setenv("TMR_PALLAS_ATTN_BK", "1024")
-    assert pallas_attn.effective_global_tiles(4096) == (256, 1024)
-    # distinct tile configs -> distinct lru_cache entries (fresh keys so
-    # other tests' gate calls can't collide)
-    info0 = pallas_attn.pallas_global_ok.cache_info()
-    pallas_attn.pallas_global_ok(3, 3, 8, 512, 512)
-    pallas_attn.pallas_global_ok(3, 3, 8, 256, 1024)
-    pallas_attn.pallas_global_ok(3, 3, 8, 512, 512)  # hit, not a re-check
-    info1 = pallas_attn.pallas_global_ok.cache_info()
-    assert info1.misses - info0.misses == 2
-    assert info1.hits - info0.hits == 1
-
-
 def _packed_case(windows, heads, head_dim, dtype, seed=28):
     """qkv as ``nn.Dense(3 * dim)`` writes it on padded window rows, and
     the rel-pos tables, at the real 14 x 14 window."""
@@ -1037,19 +635,59 @@ def test_global_block_with_packed_equals_blockwise_module():
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("backend,dtype,gate,rel_pos,grid,taken", [
-    ("cpu", "bfloat16", "admits", True, 32, "blockwise"),
-    ("tpu", "bfloat16", "admits", True, 32, "packed"),
-    ("tpu", "float32", "admits", True, 32, "blockwise"),
-    ("tpu", "bfloat16", "partitioned", True, 32, "blockwise"),
-    ("tpu", "bfloat16", "refuses", True, 32, "flash"),
-    ("tpu", "bfloat16", "admits", False, 32, "flash"),
-    ("tpu", "bfloat16", "admits", True, 96, "flash"),
-], ids=["cpu-blockwise", "tpu-packed", "tpu-float32-blockwise",
-        "tpu-partitioned-blockwise", "tpu-gate-refuses-flash",
-        "tpu-no-rel-pos-flash", "tpu-192-projections-flash"])
+#: the knobs that once picked or tuned a global block's formulation, each
+#: set to a value the old code acted on or refused (the tile knobs were read
+#: only under an explicit formulation, so those cases name one too); the
+#: program reads none of them
+_DEAD = {
+    "dead-formulation": {"TMR_GLOBAL_ATTN": "blockwise"},
+    "dead-scores-dtype": {"TMR_GLOBAL_ATTN": "blockfolded",
+                          "TMR_GLOBAL_SCORES_DTYPE": "fp8"},
+    "dead-bands-unroll": {"TMR_GLOBAL_BANDS_UNROLL": "0"},
+    "dead-pallas-bq": {"TMR_GLOBAL_ATTN": "pallas",
+                       "TMR_PALLAS_ATTN_BQ": "100"},
+    "dead-pallas-bk": {"TMR_GLOBAL_ATTN": "fused",
+                       "TMR_PALLAS_ATTN_BK": "100"},
+    "dead-xla-flash-bq": {"TMR_GLOBAL_ATTN": "xlaflash",
+                          "TMR_XLA_FLASH_BQ": "0"},
+    "dead-xla-flash-bk": {"TMR_GLOBAL_ATTN": "xlaflash",
+                          "TMR_XLA_FLASH_BK": "0"},
+}
+
+
+@pytest.mark.parametrize(
+    "backend,dtype,gate,rel_pos,grid,head_dim,env,taken", [
+        ("cpu", "bfloat16", "admits", True, 32, 64, None, "blockwise"),
+        ("tpu", "bfloat16", "admits", True, 32, 64, None, "packed"),
+        ("tpu", "float32", "admits", True, 32, 64, None, "blockwise"),
+        ("tpu", "bfloat16", "partitioned", True, 32, 64, None, "blockwise"),
+        ("tpu", "bfloat16", "refuses", True, 32, 64, None, "flash"),
+        ("tpu", "bfloat16", "admits", False, 32, 64, None, "flash"),
+        ("tpu", "bfloat16", "admits", True, 96, 64, None, "flash"),
+        ("tpu", "bfloat16", "admits", True, 64, 80, None, "packed"),
+        ("tpu", "bfloat16", "mosaic-off", True, 96, 64, None, "blockwise"),
+        ("tpu", "float32", "admits", True, 96, 64, None, "blockwise"),
+        ("tpu", "bfloat16", "admits", True, 32, 64,
+         _DEAD["dead-formulation"], "packed"),
+        ("tpu", "bfloat16", "admits", True, 32, 64,
+         _DEAD["dead-scores-dtype"], "packed"),
+        ("cpu", "bfloat16", "admits", True, 32, 64,
+         _DEAD["dead-bands-unroll"], "blockwise"),
+        ("tpu", "bfloat16", "refuses", True, 32, 64,
+         _DEAD["dead-pallas-bq"], "flash"),
+        ("tpu", "bfloat16", "admits", False, 32, 64,
+         _DEAD["dead-pallas-bk"], "flash"),
+        ("tpu", "float32", "admits", True, 32, 64,
+         _DEAD["dead-xla-flash-bq"], "blockwise"),
+        ("tpu", "float32", "admits", True, 32, 64,
+         _DEAD["dead-xla-flash-bk"], "blockwise"),
+    ], ids=["cpu-blockwise", "tpu-packed", "tpu-float32-blockwise",
+            "tpu-partitioned-blockwise", "tpu-gate-refuses-flash",
+            "tpu-no-rel-pos-flash", "tpu-192-projections-flash",
+            "tpu-heads-of-80-packed", "tpu-mosaic-off-96-blockwise",
+            "tpu-float32-96-blockwise", *_DEAD])
 def test_global_blocks_are_counted_by_formulation(
-    backend, dtype, gate, rel_pos, grid, taken, monkeypatch
+    backend, dtype, gate, rel_pos, grid, head_dim, env, taken, monkeypatch
 ):
     """``ops/pallas_attn.global_formulation``'s truth table, and its
     record: ``vit.global_attn.<formulation>`` counts a trace's global
@@ -1060,16 +698,20 @@ def test_global_blocks_are_counted_by_formulation(
     answered before: ``flash`` where that gate passes (a chip's answer,
     given here; off a TPU it refuses for itself), else ``blockwise``. Both
     gates refuse with cause ``partitioned`` inside a program XLA
-    partitions. No environment variable is read."""
+    partitions and under ``diagnostics.mosaic_kernels_off``. No
+    environment variable is read: with a knob of the old formulations set,
+    the count and the traced program are those without it."""
     from tmr_tpu import diagnostics, obs
     from tmr_tpu.models.vit import Attention, SamViT
     from tmr_tpu.obs import metrics
     from tmr_tpu.ops import flash_attn, pallas_attn
     from tmr_tpu.parallel.compat import partitioned
 
-    monkeypatch.delenv("TMR_GLOBAL_ATTN", raising=False)
+    for name, value in (env or {}).items():
+        monkeypatch.setenv(name, value)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    if gate != "partitioned":  # there the real gates answer, unasked
+    real_gates = gate in ("partitioned", "mosaic-off")
+    if not real_gates:  # there the real gates answer, unasked
         monkeypatch.setattr(pallas_attn, "packed_global_ok",
                             lambda *a: gate == "admits")
         monkeypatch.setattr(pallas_attn, "packed_window_ok",
@@ -1078,32 +720,40 @@ def test_global_blocks_are_counted_by_formulation(
             monkeypatch.setattr(flash_attn, "flash_attention_ok",
                                 lambda *a: True)
     dtype = jnp.dtype(dtype)
+    heads = 2 if head_dim == 64 else 16
     px = grid * 16
     if rel_pos:
         blocks = 4
-        model = SamViT(embed_dim=128, depth=12, num_heads=2,
+        model = SamViT(embed_dim=heads * head_dim, depth=12, num_heads=heads,
                        global_attn_indexes=(2, 5, 8, 11), window_size=14,
                        out_chans=8, pretrain_img_size=px, dtype=dtype)
         x = jax.ShapeDtypeStruct((1, px, px, 3), jnp.float32)
     else:
         blocks = 1
-        model = Attention(num_heads=2, use_rel_pos=False, dtype=dtype)
-        x = jax.ShapeDtypeStruct((1, grid, grid, 128), dtype)
+        model = Attention(num_heads=heads, use_rel_pos=False, dtype=dtype)
+        x = jax.ShapeDtypeStruct((1, grid, grid, heads * head_dim), dtype)
     with diagnostics.mosaic_kernels_off("shapes only"):  # asks no gate
         params = jax.eval_shape(model.init, jax.random.key(0), x)
 
     def trace():
         jax.eval_shape(model.apply, params, x)
         return pallas_attn.global_formulation(
-            (grid, grid), 2, 64, dtype, rel_pos)
+            (grid, grid), heads, head_dim, dtype, rel_pos)
 
     if gate == "partitioned":
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("model",))
         trace = partitioned(trace, mesh)
+    elif gate == "mosaic-off":
+        bare = trace
+
+        def trace():
+            with diagnostics.mosaic_kernels_off("the test's"):
+                return bare()
     diagnostics.drain_gate_refusals()
     metrics.get_registry().reset("vit.global_attn.")
-    program = obs.track_compile(trace, "test_kind_global_attn",
-                                (backend, str(dtype), gate, rel_pos, grid))
+    program = obs.track_compile(
+        trace, "test_kind_global_attn",
+        (backend, str(dtype), gate, rel_pos, grid, head_dim, str(env)))
     assert program() == taken
     assert metrics.get_registry().counters("vit.global_attn.") == {
         taken: blocks}
@@ -1117,33 +767,52 @@ def test_global_blocks_are_counted_by_formulation(
         assert causes == {("packed_global_ok", "partitioned"),
                           ("flash_attention_ok", "partitioned"),
                           ("packed_window_ok", "partitioned")}
+    elif gate == "mosaic-off":  # no packed layout at 96 x 96: never asked
+        assert causes == {("flash_attention_ok", "partitioned"),
+                          ("packed_window_ok", "partitioned")}
     elif backend == "cpu":  # the flash gate's own no, unless cached
         assert causes <= {("flash_attention_ok", "backend")}
     else:
         assert causes == set()
+    if env:
+        with_env = str(jax.make_jaxpr(model.apply)(params, x))
+        for name in env:
+            monkeypatch.delenv(name)
+        assert str(jax.make_jaxpr(model.apply)(params, x)) == with_env
 
 
-def test_explicit_packed_refused_warns_and_runs_blockwise(monkeypatch):
-    """``TMR_GLOBAL_ATTN=packed`` is a legal value of the knob; where the
-    gate refuses (any backend but a TPU) the block says so once, at trace
-    time, runs blockwise and is counted as that. (The gate's no is given
-    here: its own depends on nothing but the backend.)"""
-    from tmr_tpu.diagnostics import FormulationFallbackWarning
-    from tmr_tpu.models.vit import Attention
-    from tmr_tpu.obs import metrics
+@pytest.mark.parametrize("module", [
+    "tmr_tpu.models.vit", "tmr_tpu.ops.pallas_attn", "tmr_tpu.ops.flash_attn",
+])
+def test_attention_modules_read_only_the_kill_switch_and_gate_debug(module):
+    """The modules on the ViT attention's path read no environment
+    variable but the flash family's kill-switch (``TMR_NO_FLASH_ATTN``)
+    and ``TMR_GATE_DEBUG``, each by its literal name: the formulation is
+    decided by what the choosers observe."""
+    import ast
+    import importlib
+    import inspect
 
-    from tmr_tpu.ops import pallas_attn
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "packed")
-    monkeypatch.setattr(pallas_attn, "packed_global_ok", lambda *a: False)
-    attn = Attention(num_heads=2, rel_pos_size=(32, 32), dtype=jnp.bfloat16)
-    x = jax.ShapeDtypeStruct((1, 32, 32, 128), jnp.bfloat16)
-    params = jax.eval_shape(attn.init, jax.random.key(0), x)
-    metrics.get_registry().reset("vit.global_attn.")
-    with pytest.warns(FormulationFallbackWarning, match="packed"):
-        jax.eval_shape(attn.apply, params, x)
-    assert metrics.get_registry().counters("vit.global_attn.") == {
-        "blockwise": 1}
+    tree = ast.parse(inspect.getsource(importlib.import_module(module)))
+    touches = sum(isinstance(n, ast.Attribute) and n.attr in (
+        "environ", "getenv") for n in ast.walk(tree))
+    names = []
+    for n in ast.walk(tree):
+        target = None
+        if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute):
+            f = n.func
+            if (f.attr == "get" and isinstance(f.value, ast.Attribute)
+                    and f.value.attr == "environ") or f.attr == "getenv":
+                target = n.args[0]
+        elif (isinstance(n, ast.Subscript)
+              and isinstance(n.value, ast.Attribute)
+              and n.value.attr == "environ"):
+            target = n.slice
+        if target is not None:
+            names.append(target.value if isinstance(target, ast.Constant)
+                         else ast.dump(target))
+    assert len(names) == touches, (module, names, touches)
+    assert set(names) <= {"TMR_NO_FLASH_ATTN", "TMR_GATE_DEBUG"}, names
 
 
 @pytest.mark.slow

@@ -465,100 +465,3 @@ def test_convert_cli_sam_hq_pth_recipe(tmp_path):
         np.asarray(got).transpose(0, 3, 1, 2), want, rtol=2e-4, atol=2e-5
     )
 
-
-# ---- fused-bias global attention at the PRODUCTION geometries --------------
-# The acceptance pins for the fused Pallas kernel (interpret mode on CPU)
-# and the fused-bias XLA flash path: oracle-equal to the exact blockwise
-# parity path at BOTH deployed token grids — 1024-input (64x64 tokens) and
-# the 1536 bucket (96x96) — at the existing parity tolerances. B/H are
-# reduced (geometry is what kernels key on); head_dim stays the real 64.
-def _global_attn_case(gh, gw, D=64, seed=31):
-    rng = np.random.default_rng(seed)
-    S = gh * gw
-    q = jnp.asarray(rng.standard_normal((1, 1, S, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, 1, S, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, 1, S, D)), jnp.float32)
-    rh = jnp.asarray(rng.standard_normal((gh, gh, D)) * 0.1, jnp.float32)
-    rw = jnp.asarray(rng.standard_normal((gw, gw, D)) * 0.1, jnp.float32)
-    return q, k, v, rh, rw
-
-
-@pytest.mark.parametrize("gh,gw", [(64, 64), (96, 96)])
-def test_fused_kernel_oracle_at_production_geometry(gh, gw, monkeypatch):
-    import jax
-
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops.pallas_attn import (
-        effective_fused_tiles,
-        pallas_fused_attention,
-    )
-
-    monkeypatch.delenv("TMR_PALLAS_ATTN_BQ", raising=False)
-    monkeypatch.delenv("TMR_PALLAS_ATTN_BK", raising=False)
-    bq, bk = effective_fused_tiles(gh * gw, gw)
-    assert (bq, bk) == ((512, 512) if gw == 64 else (384, 384))
-    q, k, v, rh, rw = _global_attn_case(gh, gw)
-    scale = 64**-0.5
-    got = jax.jit(
-        lambda *a: pallas_fused_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    want = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("gh,gw", [(64, 64), (96, 96)])
-def test_xla_flash_oracle_at_production_geometry(gh, gw, monkeypatch):
-    import jax
-
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-    from tmr_tpu.ops.flash_attn import xla_flash_decomposed_attention
-
-    monkeypatch.delenv("TMR_XLA_FLASH_BQ", raising=False)
-    monkeypatch.delenv("TMR_XLA_FLASH_BK", raising=False)
-    q, k, v, rh, rw = _global_attn_case(gh, gw)
-    scale = 64**-0.5
-    got = jax.jit(
-        lambda *a: xla_flash_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    want = jax.jit(
-        lambda *a: blockwise_decomposed_attention(*a, (gh, gw), scale)
-    )(q, k, v, rh, rw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_global_attn_env_dispatch_fused_variants(monkeypatch):
-    """TMR_GLOBAL_ATTN=xlaflash must dispatch through the Attention module
-    to the fused-bias XLA flash path (blockwise-equal output); =fused off-
-    TPU must WARN about the gate refusal and fall back blockwise-equal —
-    the env plumbing for both new variants, not just the free functions."""
-    import warnings
-
-    import jax
-
-    from tmr_tpu.models.vit import Attention
-
-    rng = np.random.default_rng(17)
-    x = jnp.asarray(rng.standard_normal((1, 32, 32, 16)), jnp.float32)
-    attn = Attention(num_heads=2, rel_pos_size=(32, 32))
-    params = attn.init(jax.random.key(0), x)
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "blockwise")
-    want = jax.jit(attn.apply)(params, x)
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "xlaflash")
-    got = jax.jit(attn.apply)(params, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-    monkeypatch.setenv("TMR_GLOBAL_ATTN", "fused")
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        got_f = jax.jit(attn.apply)(params, x)
-    if jax.default_backend() != "tpu":
-        assert any("blockwise fallback" in str(r.message) for r in rec)
-    np.testing.assert_allclose(np.asarray(got_f), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)

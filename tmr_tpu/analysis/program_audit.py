@@ -8,9 +8,8 @@ no compile, no execute, no device memory):
 - **no-S²** — every attention formulation that claims streaming/tiled
   semantics must never materialize the (B, H, S, S) score tensor or the
   broadcast rel-pos bias: largest intermediate anywhere in the traced
-  attention jaxpr stays below S*S elements (PR 1's fused/xlaflash
-  assert, generalized to all impls; ``densefolded`` is dense BY DESIGN
-  and exempt — its max is recorded informationally).
+  attention jaxpr stays below S*S elements, for every formulation a
+  global block can take.
 - **no-f64** — no equation output anywhere in a production program may
   be float64/complex128: on TPU a silent f64 upcast runs in emulation,
   on CPU it silently doubles bandwidth, and either way the oracle pins
@@ -40,15 +39,9 @@ import os
 import warnings
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-#: attention impls contractually bound to < S*S intermediates.
-#: ``densefolded`` is excluded: it is the dense small-grid formulation,
-#: S² materialization is its design point (the gate elects it only
-#: where that fits VMEM).
-NO_S2_ATTN_IMPLS = ("blockwise", "blockfolded", "flash", "xlaflash",
-                    "pallas", "fused", "packed")
-
-#: attention impls that trace without TPU hardware present; audited set
-DENSE_BY_DESIGN = ("densefolded",)
+#: the global-attention formulations (ops/pallas_attn.global_formulation's
+#: answers), each contractually bound to < S*S intermediates
+NO_S2_ATTN_IMPLS = ("blockwise", "flash", "packed")
 
 #: expected trace-time ``device_put`` count per production program: none.
 #: JAX 0.9 embeds the numpy constants a trace stages (the ViT rel-pos
@@ -284,30 +277,14 @@ def audit_jaxpr(
 
 
 def _attention_impl_fns() -> Dict[str, callable]:
-    from tmr_tpu.models.vit import (
-        blockfolded_decomposed_attention,
-        blockwise_decomposed_attention,
-        densefolded_decomposed_attention,
-    )
-    from tmr_tpu.ops.flash_attn import (
-        flash_decomposed_attention,
-        xla_flash_decomposed_attention,
-    )
-    from tmr_tpu.ops.pallas_attn import (
-        _packed_global_on_heads,
-        pallas_decomposed_attention,
-        pallas_fused_attention,
-    )
+    from tmr_tpu.models.vit import blockwise_decomposed_attention
+    from tmr_tpu.ops.flash_attn import flash_decomposed_attention
+    from tmr_tpu.ops.pallas_attn import _packed_global_on_heads
 
     return {
         "packed": _packed_global_on_heads,
         "blockwise": blockwise_decomposed_attention,
-        "blockfolded": blockfolded_decomposed_attention,
-        "densefolded": densefolded_decomposed_attention,
         "flash": flash_decomposed_attention,
-        "xlaflash": xla_flash_decomposed_attention,
-        "pallas": pallas_decomposed_attention,
-        "fused": pallas_fused_attention,
     }
 
 
@@ -316,8 +293,8 @@ def audit_attention_impls(
     head_dim: int = 64,
     impls: Optional[Iterable[str]] = None,
 ) -> dict:
-    """Trace every attention formulation at the given grids and apply
-    the no-S² bound to the contractually-streaming ones. Trace-only —
+    """Trace every global-attention formulation at the given grids and
+    apply the no-S² bound to each. Trace-only —
     the production 64x64 grid costs ~0.1 s per impl on CPU."""
     import jax
     import jax.numpy as jnp
@@ -334,7 +311,7 @@ def audit_attention_impls(
         for name in wanted:
             fn = fns[name]
             label = f"attn:{name}@{gh}x{gw}"
-            bound = S * S if name in NO_S2_ATTN_IMPLS else None
+            bound = S * S
             try:
                 jaxpr = jax.make_jaxpr(
                     lambda a, b, c, d, e, _f=fn: _f(
@@ -349,8 +326,7 @@ def audit_attention_impls(
             out[label] = rec
             ok = ok and rec["ok"]
     return {"grids": [list(g) for g in grids], "head_dim": head_dim,
-            "impls": out, "dense_by_design": list(DENSE_BY_DESIGN),
-            "ok": ok}
+            "impls": out, "ok": ok}
 
 
 # --------------------------------------------------------------------------

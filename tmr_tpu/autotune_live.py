@@ -73,12 +73,11 @@ DEMOTE_ANOMALIES = (
 BANK_PATH = os.path.join(STATE_DIR, "winner_bank.json")
 
 #: ``Predictor._compiled`` program kinds each live-tunable knob can
-#: change: None = every program embeds the formulation (backbone attn,
+#: change: None = every program embeds the formulation (matcher,
 #: quant numerics), a tuple = only those kinds re-trace. The
 #: promotion's invalidation scope — too narrow would serve a stale
 #: formulation, too wide only costs recompiles.
 KNOB_PROGRAM_KINDS: Dict[str, Optional[Tuple[str, ...]]] = {
-    "TMR_GLOBAL_ATTN": None,
     "TMR_XCORR_IMPL_SMALL": None,
     "TMR_QUANT": None,
     "TMR_QUANT_STORAGE": None,
@@ -162,7 +161,6 @@ def _winner_ok(knob: str, value: str) -> bool:
         return False
     sets = {
         "TMR_XCORR_IMPL_SMALL": set(_at.XCORR_VARIANTS) | {"auto"},
-        "TMR_GLOBAL_ATTN": set(_at.GLOBAL_ATTN_VARIANTS) | {"auto"},
         "TMR_DECODER_IMPL": set(_at.DECODER_IMPL_VARIANTS) | {"auto"},
         "TMR_QUANT": set(_at.QUANT_VARIANTS) | {"auto"},
         "TMR_QUANT_STORAGE": set(_at.STORAGE_VARIANTS),

@@ -172,15 +172,11 @@ def preset(name: str, **overrides) -> Config:
 #: long-form usage for the user-facing ones.
 ENV_KNOBS = {
     # formulation dispatch (trace-time; autotune exports winners here)
-    "TMR_GLOBAL_ATTN": "global ViT attention formulation: auto|blockwise|"
-        "blockfolded|densefolded|flash|xlaflash|pallas|fused|packed",
     "TMR_XCORR_IMPL": "template-correlation formulation: auto|conv|"
         "convnhwc|vmap|fft|pallas",
     "TMR_XCORR_IMPL_SMALL": "small-bucket override of TMR_XCORR_IMPL",
     "TMR_XCORR_PRECISION": "correlation MXU precision: highest|default|"
         "bf16 (decisive-win elected)",
-    "TMR_GLOBAL_SCORES_DTYPE": "global-attention score-tile dtype: "
-        "f32|bf16 (decisive-win elected)",
     "TMR_DECODER_IMPL": "decoder-tail formulation: auto|xla|fused "
         "(ops/fused_heads.py, oracle-gated)",
     "TMR_QUANT": "int8-weight quantized tail: off|int8|auto "
@@ -193,12 +189,6 @@ ENV_KNOBS = {
         "int8, tolerance-gated)",
     "TMR_DECODE_TAIL": "detection decode tail: host|device "
         "(device = on-device compaction, self-check-gated)",
-    # kernel tile / schedule parameters (validated, pinnable)
-    "TMR_PALLAS_ATTN_BQ": "Pallas global-attention query-tile rows",
-    "TMR_PALLAS_ATTN_BK": "Pallas global-attention key-tile rows",
-    "TMR_XLA_FLASH_BQ": "XLA flash-attention query-block rows",
-    "TMR_XLA_FLASH_BK": "XLA flash-attention key-block rows",
-    "TMR_GLOBAL_BANDS_UNROLL": "global-attention band-scan unroll factor",
     # kill-switches (gates refuse with a recorded cause)
     "TMR_NO_FLASH_ATTN": "force-disable the flash attention family",
     "TMR_NO_PALLAS_XCORR": "force-disable the Pallas correlation kernel",

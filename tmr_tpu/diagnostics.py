@@ -15,8 +15,7 @@ The gate-refusal REGISTRY is the machine-readable side of the same story
 (round-5 verdict #1: on the live TPU every require_tpu kernel fell back
 and the gates swallowed WHY). Every refusal inside the compiled
 self-checks (ops/flash_attn._self_check and the gates built on it —
-pallas_global_ok, pallas_fused_ok, packed_window_ok, flash_attention_ok,
-…) records a ``gate_probe.json``-schema cause here: refusal category,
+packed_window_ok, packed_global_ok, flash_attention_ok, …) records a ``gate_probe.json``-schema cause here: refusal category,
 exception class + message when one was swallowed, the tile/geometry
 config the verdict keys on, and the device kind. Consumers drain it:
 scripts/gate_probe.py --json emits the causes next to each probe, and the
@@ -2046,7 +2045,7 @@ class FormulationFallbackWarning(UserWarning):
     """An explicitly requested kernel formulation fell back at trace time.
 
     ``env_var`` names the knob whose request was refused (e.g.
-    "TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL")."""
+    "TMR_XCORR_IMPL", "TMR_XCORR_IMPL_SMALL", "TMR_QUANT")."""
 
     def __init__(self, env_var: str, message: str):
         super().__init__(message)

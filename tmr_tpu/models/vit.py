@@ -20,7 +20,6 @@ Weight layout intentionally mirrors the reference module tree so the
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -28,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tmr_tpu.diagnostics import FormulationFallbackWarning  # noqa: F401
 from tmr_tpu.models.common import LayerNorm2d, MLPBlock
 
 
@@ -84,19 +82,6 @@ def get_rel_pos(q_size: int, k_size: int, rel_pos: jnp.ndarray) -> jnp.ndarray:
     return rel[rel_coords.astype(np.int64)]
 
 
-def _scores_dtype() -> str:
-    """TMR_GLOBAL_SCORES_DTYPE: materialization dtype for the folded global
-    attention score tiles — 'f32' (default, exact) or 'bf16' (half the
-    HBM traffic of the bandwidth-bound stage; numerics-gated). Read at
-    trace time like every formulation knob."""
-    val = os.environ.get("TMR_GLOBAL_SCORES_DTYPE", "f32")
-    if val not in ("f32", "bf16"):
-        raise ValueError(
-            f"TMR_GLOBAL_SCORES_DTYPE={val!r}: expected f32|bf16"
-        )
-    return val
-
-
 def _q_block_rows(h: int, w: int, target_tokens: int = 512) -> int:
     """Largest divisor of ``h`` whose row-band holds <= target_tokens."""
     best = 1
@@ -114,7 +99,6 @@ def blockwise_decomposed_attention(
     rw: Optional[jnp.ndarray],
     grid_hw: Tuple[int, int],
     scale: float,
-    scores_dtype: Optional[str] = None,
 ) -> jnp.ndarray:
     """Attention with decomposed rel-pos bias, scanned over query row-bands.
 
@@ -135,20 +119,6 @@ def blockwise_decomposed_attention(
     rows = _q_block_rows(gh, gw)
     nb = gh // rows
     work = q.dtype
-    # scores_dtype="bf16" (EXPLICIT parameter — this parity oracle never
-    # reads the env knob itself, so the default blockwise path and the
-    # pallas custom_vjp's backward oracle stay exact): materialize each
-    # band's score tile in bf16 instead of f32, halving the dominant HBM
-    # traffic of this bandwidth-bound stage. Only the gated folded
-    # formulations pass it (bias already inside q/k — the einsum output IS
-    # the final logits). The MXU still accumulates in f32
-    # (preferred_element_type only rounds the OUTPUT) and softmax upcasts
-    # to f32 — a fused convert on the read path. Rounds logits to bf16
-    # (~0.4% rel), gated by flash_attn.blockfolded_ok/densefolded_ok,
-    # which key on the dtype.
-    score_pet = jnp.float32
-    if rh is None and work == jnp.bfloat16 and scores_dtype == "bf16":
-        score_pet = jnp.bfloat16
 
     q_g = q.reshape(B, H, nb, rows, gw, D)
     q_blocks = jnp.moveaxis(q_g, 2, 0)  # (nb, B, H, rows, gw, D)
@@ -161,9 +131,8 @@ def blockwise_decomposed_attention(
         qb, rhb = args  # (B, H, rows, gw, D), (rows, gh, D)
         s = jnp.einsum(
             "bhrwd,bhkd->bhrwk", qb, k,
-            preferred_element_type=score_pet,
-        ) * scale  # (B, H, rows, gw, S); python scale is weakly typed —
-        # the tile keeps score_pet (and the folded calls pass scale=1.0)
+            preferred_element_type=jnp.float32,
+        ) * scale  # (B, H, rows, gw, S)
         if rh is not None:
             qf = qb.astype(jnp.float32)
             rel_h = jnp.einsum(
@@ -175,125 +144,17 @@ def blockwise_decomposed_attention(
             s = s.reshape(B, H, rows, gw, gh, gw)
             s = s + rel_h[..., :, None] + rel_w[..., None, :]
             s = s.reshape(B, H, rows, gw, S)
-        # softmax always in f32: under bf16 score tiles the upcast is a
-        # convert fused into the softmax's read of the tile
-        p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
+        p = jax.nn.softmax(s, axis=-1)
         ob = jnp.einsum(
             "bhrwk,bhkd->bhrwd", p.astype(work), v,
             preferred_element_type=jnp.float32,
         )
         return ob.astype(work)
 
-    # Band schedule: lax.map == scan(unroll=1). TMR_GLOBAL_BANDS_UNROLL
-    # (trace-time, default 1 = the parity schedule) unrolls N bands per
-    # loop step so XLA can software-pipeline the next band's K/V and
-    # score-tile HBM traffic behind the current band's compute — same ops
-    # per band, same numerics, different schedule. Autotune measures it
-    # via the profile's sub-knob rows, like the Pallas tile sizes.
-    raw_unroll = os.environ.get("TMR_GLOBAL_BANDS_UNROLL", "1")
-    if (
-        not (raw_unroll.isascii() and raw_unroll.isdigit())
-        or int(raw_unroll) == 0
-    ):
-        # "0" is rejected, not clamped: the documented contract is a
-        # positive integer, and silently running unroll=1 under a zero pin
-        # would mislabel any A/B evidence recorded against it
-        raise ValueError(
-            f"TMR_GLOBAL_BANDS_UNROLL={raw_unroll!r}: expected a positive "
-            "integer unroll factor"
-        )
-    unroll = int(raw_unroll)
     out = jax.lax.scan(
-        lambda c, x: (c, one_band(x)), (), (q_blocks, rh_blocks),
-        unroll=min(unroll, nb),
-    )[1]  # (nb, B, H, rows, gw, Dv)
-    # output width comes from v: under the folded-QK variant q/k are
-    # augmented past v's head dim
-    return jnp.moveaxis(out, 0, 2).reshape(B, H, S, v.shape[-1])
-
-
-def blockfolded_decomposed_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    rh: Optional[jnp.ndarray],
-    rw: Optional[jnp.ndarray],
-    grid_hw: Tuple[int, int],
-    scale: float,
-) -> jnp.ndarray:
-    """The blockwise band scan with the bias folded into the QK contraction.
-
-    Same banded schedule as :func:`blockwise_decomposed_attention`, but q/k
-    are first augmented (ops/flash_attn.fold_rel_pos_into_qk: q' carries
-    [q*scale | q.RH | q.RW], k' carries [k | row one-hots | col one-hots]) so
-    each band's (rows*gw, S) f32 score tile arrives from ONE einsum with the
-    bias already inside. The two bias einsums and — the expensive part — the
-    two f32 broadcast-add passes over the score tile disappear; per-band HBM
-    traffic drops by roughly a third at ~2x the (tiny relative to bandwidth)
-    QK FLOPs. Algebraically exact in f32; under bf16 inputs the bias terms
-    round to bf16 before the f32-accumulated matmul, where the blockwise
-    path keeps them f32 — so this is an autotune-selected variant
-    (TMR_GLOBAL_ATTN=blockfolded), never the parity default.
-    """
-    if rh is None:
-        return blockwise_decomposed_attention(q, k, v, None, None, grid_hw, scale)
-    from tmr_tpu.ops.flash_attn import fold_rel_pos_into_qk
-
-    q_aug, k_aug = fold_rel_pos_into_qk(q, k, rh, rw, grid_hw, scale)
-    # v keeps the original head dim: the band einsum takes its output width
-    # from v, so the augmented contraction never widens the result.
-    # scores_dtype is resolved HERE (the gated formulation), not inside the
-    # blockwise oracle — the env knob must never touch the parity path.
-    return blockwise_decomposed_attention(
-        q_aug, k_aug, v, None, None, grid_hw, 1.0,
-        scores_dtype=_scores_dtype(),
-    )
-
-
-def densefolded_decomposed_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    rh: Optional[jnp.ndarray],
-    rw: Optional[jnp.ndarray],
-    grid_hw: Tuple[int, int],
-    scale: float,
-) -> jnp.ndarray:
-    """Folded-QK attention with NO band scan: one (B, H, S, S) einsum,
-    f32 softmax, one AV einsum, and XLA free to pick its own tiling.
-
-    The band scan exists to bound HBM high-water, but it also serializes
-    the schedule and hides the whole attention from XLA's fusion/tiling
-    autotuner. At the 4096-token global blocks the full f32 score tensor
-    is 3.2 GB per batch-4, 12-head block (4*12*4096^2*4 B) — it fits a
-    v5e's 16 GB for inference-shaped programs but is NOT free; selection
-    is by measurement only (TMR_GLOBAL_ATTN=densefolded, autotune-swept
-    like every formulation), and an OOM during the sweep's compile simply
-    loses the A/B to the banded variants.
-    Same math as blockfolded (identical fold; softmax over the full key
-    axis), so the same bf16 numerics gate applies.
-    """
-    if rh is None:
-        q_aug, k_aug = q * scale, k
-    else:
-        from tmr_tpu.ops.flash_attn import fold_rel_pos_into_qk
-
-        q_aug, k_aug = fold_rel_pos_into_qk(q, k, rh, rw, grid_hw, scale)
-    score_pet = (
-        jnp.bfloat16
-        if q.dtype == jnp.bfloat16 and _scores_dtype() == "bf16"
-        else jnp.float32
-    )
-    s = jnp.einsum(
-        "bhqd,bhkd->bhqk", q_aug, k_aug,
-        preferred_element_type=score_pet,
-    )
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-    out = jnp.einsum(
-        "bhqk,bhkd->bhqd", p.astype(q.dtype), v,
-        preferred_element_type=jnp.float32,
-    )
-    return out.astype(q.dtype)
+        lambda c, x: (c, one_band(x)), (), (q_blocks, rh_blocks)
+    )[1]  # (nb, B, H, rows, gw, D)
+    return jnp.moveaxis(out, 0, 2).reshape(B, H, S, D)
 
 
 class Attention(nn.Module):
@@ -340,232 +201,24 @@ class Attention(nn.Module):
 
     def _global_formulation(self, h: int, w: int, head_dim: int):
         """(name, attention function) a block of 1024 tokens or more
-        traces with, counted under ``vit.global_attn.<name>``; the function
-        takes head-major operands and is None for ``packed``, which takes
-        ``qkv`` itself."""
-        # global-attention blocks (4096+ tokens): never materialize the
-        # S x S scores or the (B, H, h, w, h, w) bias. TMR_GLOBAL_ATTN
-        # (trace-time A/B knob, measured by the autotune sweep) picks
-        # the formulation:
-        #   blockwise    exact XLA band scan (the f32-parity default)
-        #   blockfolded  band scan, bias folded into the QK contraction
-        #                (exact in f32; bf16 is numerics-self-checked
-        #                with blockwise fallback)
-        #   densefolded  folded QK with NO band scan — one dense
-        #                einsum/softmax/einsum, XLA picks the tiling
-        #                (same fold, same bf16 gate as blockfolded)
-        #   flash        stock Pallas flash over the 256-padded folded
-        #                QK (bf16 only; self-check gate -> blockwise)
-        #   pallas       custom decomposed-bias kernel, VMEM-resident
-        #                tiles at native head dim (ops/pallas_attn.py;
-        #                self-check gate -> blockwise)
-        #   fused        the rewritten fused-bias kernel: row+lane-
-        #                aligned v5e tiles, bias rebuilt per tile from
-        #                the (q, k) block offsets by broadcast alone —
-        #                no selector matmuls (ops/pallas_attn.py;
-        #                self-check gate -> blockwise)
-        #   xlaflash     pure-XLA online-softmax flash with the same
-        #                fused-bias tiling (ops/flash_attn.py) — the
-        #                Mosaic-independent form; largest live score
-        #                tile is (band, block_k), not (band, S)
-        #   packed       the kernel on ``qkv`` where the product wrote
-        #                it: no per-head operand between the two products
-        #                (ops/pallas_attn.py; bf16 with rel-pos tables;
-        #                self-check gate -> blockwise)
-        #   auto         what ops/pallas_attn.global_formulation observes:
-        #                packed, else flash, else blockwise
-        impl = os.environ.get("TMR_GLOBAL_ATTN", "auto")
-        if impl not in (
-            "auto", "blockwise", "flash", "blockfolded", "densefolded",
-            "pallas", "fused", "xlaflash", "packed",
-        ):
-            raise ValueError(
-                f"TMR_GLOBAL_ATTN={impl!r}: expected "
-                "auto|blockwise|flash|blockfolded|densefolded|pallas|"
-                "fused|xlaflash|packed"
-            )
-        attn_fn = blockwise_decomposed_attention
-        if impl in ("blockfolded", "densefolded"):
-            # exact in f32; under bf16 the folded bias rounds to bf16,
-            # so the selection is self-check-gated like every other
-            # formulation (PARITY.md contract). The gate is pure XLA
-            # (runs on any backend, Pallas kill-switch exempt).
-            attn_fn = (
-                blockfolded_decomposed_attention
-                if impl == "blockfolded"
-                else densefolded_decomposed_attention
-            )
-            if self.dtype == jnp.bfloat16:
-                from tmr_tpu.ops.flash_attn import (
-                    blockfolded_ok,
-                    densefolded_ok,
-                )
-
-                ok = (
-                    blockfolded_ok
-                    if impl == "blockfolded"
-                    else densefolded_ok
-                )
-                if not ok(h, w, head_dim, _scores_dtype()):
-                    import warnings
-
-                    warnings.warn(FormulationFallbackWarning(
-                        "TMR_GLOBAL_ATTN",
-                        f"TMR_GLOBAL_ATTN={impl}: bf16 numerics "
-                        f"self-check failed at grid ({h}, {w}, "
-                        f"head_dim {head_dim}); running blockwise "
-                        "fallback"
-                    ))
-                    attn_fn = blockwise_decomposed_attention
-        elif impl == "pallas":
-            # the custom decomposed-bias kernel (ops/pallas_attn.py):
-            # VMEM-resident online-softmax tiles, native head-dim
-            # contraction; self-checked per geometry with fallback
-            from tmr_tpu.ops.pallas_attn import (
-                effective_global_tiles,
-                pallas_decomposed_attention,
-                pallas_global_ok,
-                pallas_supported,
-            )
-
-            bq, bk = effective_global_tiles(h * w)
-            if pallas_supported(h * w) and pallas_global_ok(
-                h, w, head_dim, bq, bk
-            ):
-                attn_fn = pallas_decomposed_attention
-            else:
-                # explicit request refused by the gate: an A/B number
-                # measured now would silently be blockwise — say so
-                # once, at trace time
-                import warnings
-
-                warnings.warn(FormulationFallbackWarning(
-                    "TMR_GLOBAL_ATTN",
-                    "TMR_GLOBAL_ATTN=pallas: self-check gate refused "
-                    f"grid ({h}, {w}, head_dim {head_dim}); running "
-                    "blockwise fallback"
-                ))
-        elif impl == "fused":
-            # the fused-bias kernel: row+lane-aligned tiles, bias
-            # rebuilt per tile from the (q, k) block offsets —
-            # self-checked per (geometry, tile config) with fallback
-            from tmr_tpu.ops.pallas_attn import (
-                effective_fused_tiles,
-                fused_supported,
-                pallas_fused_attention,
-                pallas_fused_ok,
-            )
-
-            bq, bk = effective_fused_tiles(h * w, w)
-            if fused_supported(h * w, w) and pallas_fused_ok(
-                h, w, head_dim, bq, bk
-            ):
-                attn_fn = pallas_fused_attention
-            else:
-                import warnings
-
-                warnings.warn(FormulationFallbackWarning(
-                    "TMR_GLOBAL_ATTN",
-                    "TMR_GLOBAL_ATTN=fused: self-check gate refused "
-                    f"grid ({h}, {w}, head_dim {head_dim}); running "
-                    "blockwise fallback"
-                ))
-        elif impl == "xlaflash":
-            # pure-XLA online-softmax flash, fused bias tiles: exact
-            # in f32 up to reassociation (ungated there, like the
-            # folded formulations); bf16 is numerics-self-checked
-            # with blockwise fallback
-            from tmr_tpu.ops.flash_attn import (
-                xla_flash_decomposed_attention,
-                xlaflash_ok,
-            )
-
-            attn_fn = xla_flash_decomposed_attention
-            if self.dtype == jnp.bfloat16 and not xlaflash_ok(
-                h, w, head_dim
-            ):
-                import warnings
-
-                warnings.warn(FormulationFallbackWarning(
-                    "TMR_GLOBAL_ATTN",
-                    "TMR_GLOBAL_ATTN=xlaflash: bf16 numerics "
-                    f"self-check failed at grid ({h}, {w}, head_dim "
-                    f"{head_dim}); running blockwise fallback"
-                ))
-                attn_fn = blockwise_decomposed_attention
-        elif impl == "packed":
-            # the kernel on ``qkv`` where the product wrote it
-            # (ops/pallas_attn.packed_global_attention): bf16 with rel-pos
-            # tables on a TPU, self-check gate -> blockwise
-            from tmr_tpu.ops.pallas_attn import (
-                packed_global_ok,
-                packed_global_supported,
-            )
-
-            if (self.use_rel_pos and self.dtype == jnp.bfloat16
-                    and packed_global_supported(
-                        (h, w), self.num_heads, head_dim)
-                    and packed_global_ok(h, w, head_dim, self.num_heads)):
-                attn_fn = None
-            else:
-                import warnings
-
-                warnings.warn(FormulationFallbackWarning(
-                    "TMR_GLOBAL_ATTN",
-                    "TMR_GLOBAL_ATTN=packed: no packed layout or gate "
-                    f"refused grid ({h}, {w}, head_dim {head_dim}, dtype "
-                    f"{jnp.dtype(self.dtype).name}); running blockwise "
-                    "fallback"
-                ))
-        elif impl == "auto":
-            from tmr_tpu.ops.pallas_attn import global_formulation
-
-            impl = global_formulation(
-                (h, w), self.num_heads, head_dim, self.dtype,
-                self.use_rel_pos)
-            if impl == "packed":
-                attn_fn = None
-            elif impl == "flash":
-                from tmr_tpu.ops.flash_attn import flash_decomposed_attention
-
-                attn_fn = flash_decomposed_attention
-        elif impl == "flash" and self.dtype == jnp.bfloat16:
-            from tmr_tpu.ops.flash_attn import (
-                flash_attention_ok,
-                flash_decomposed_attention,
-                flash_supported,
-            )
-
-            if flash_supported(h * w) and flash_attention_ok(
-                h, w, head_dim
-            ):
-                attn_fn = flash_decomposed_attention
-            else:
-                import warnings
-
-                warnings.warn(FormulationFallbackWarning(
-                    "TMR_GLOBAL_ATTN",
-                    "TMR_GLOBAL_ATTN=flash: gate refused grid "
-                    f"({h}, {w}, head_dim {head_dim}); running "
-                    "blockwise fallback"
-                ))
-        elif impl == "flash":
-            # explicit flash on a non-bf16 model: the kernel is
-            # bf16-only, so the request silently lands on blockwise —
-            # say so or an A/B records blockwise timings labeled flash
-            import warnings
-
-            warnings.warn(FormulationFallbackWarning(
-                "TMR_GLOBAL_ATTN",
-                f"TMR_GLOBAL_ATTN=flash needs bf16 (model dtype "
-                f"{self.dtype}); running blockwise fallback"
-            ))
-        if attn_fn is blockwise_decomposed_attention:
-            impl = "blockwise"
+        traces with (ops/pallas_attn.global_formulation: ``packed``, else
+        ``flash``, else ``blockwise``), counted under
+        ``vit.global_attn.<name>``; the function takes head-major operands
+        and is None for ``packed``, which takes ``qkv`` itself."""
         from tmr_tpu.obs import metrics
+        from tmr_tpu.ops.pallas_attn import global_formulation
 
-        metrics.counter(f"vit.global_attn.{impl}").inc()
-        return impl, attn_fn
+        got = global_formulation(
+            (h, w), self.num_heads, head_dim, self.dtype, self.use_rel_pos)
+        attn_fn = None
+        if got == "flash":
+            from tmr_tpu.ops.flash_attn import flash_decomposed_attention
+
+            attn_fn = flash_decomposed_attention
+        elif got == "blockwise":
+            attn_fn = blockwise_decomposed_attention
+        metrics.counter(f"vit.global_attn.{got}").inc()
+        return got, attn_fn
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:

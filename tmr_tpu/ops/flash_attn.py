@@ -21,14 +21,18 @@ the plain path, a few extra ms at v5e peak, in exchange for ZERO S x S HBM
 traffic inside jax.experimental.pallas's TPU flash kernel (VMEM-resident
 tiles, online softmax).
 
-Used by models/vit.py on the TPU bf16 path behind a per-geometry compiled
-self-check (the pallas_nms pattern); every other configuration takes the
-exact XLA blockwise path.
+Used by models/vit.py on the TPU bf16 path where the packed kernel
+(ops/pallas_attn.py) has no layout or its gate refuses and this path's own
+per-geometry compiled self-check passes (``flash_attention_ok``): the 96 x
+96 grid of the 1536 bucket, and blocks without rel-pos tables. Every other
+configuration takes the packed kernel or the exact XLA blockwise path.
+
+``_self_check`` here is the one compiled self-check every attention kernel's
+gate runs against that blockwise oracle.
 """
 
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional, Tuple
 
@@ -211,150 +215,6 @@ def flash_windowed_attention(
     return out[..., :S, :D].astype(q.dtype)
 
 
-def _band_rows(h: int, w: int, target_tokens: int) -> int:
-    """Largest divisor of ``h`` whose row-band holds <= target_tokens
-    (floor 1). Local copy of models/vit._q_block_rows: this module and
-    vit.py import each other lazily, and the XLA flash schedule must not
-    depend on the model layer at import time."""
-    best = 1
-    for rows in range(1, h + 1):
-        if h % rows == 0 and rows * w <= target_tokens:
-            best = rows
-    return best
-
-
-def _env_tokens(name: str, default: int) -> int:
-    """Positive-integer token-count knob, read at trace time."""
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    if not (raw.isascii() and raw.isdigit()) or int(raw) == 0:
-        raise ValueError(
-            f"{name}={raw!r}: expected a positive integer token count"
-        )
-    return int(raw)
-
-
-def xla_flash_decomposed_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    rh: Optional[jnp.ndarray],
-    rw: Optional[jnp.ndarray],
-    grid_hw: Tuple[int, int],
-    scale: float,
-) -> jnp.ndarray:
-    """Pure-XLA ONLINE-SOFTMAX flash attention with the decomposed rel-pos
-    bias fused per tile (TMR_GLOBAL_ATTN=xlaflash) — the Mosaic-independent
-    form of the fused Pallas kernel (ops/pallas_attn.pallas_fused_attention),
-    so the no-S^2 restructuring survives on backends where Pallas refuses.
-
-    Where blockwise holds a full (band, S) score strip (softmax over the
-    whole key axis at once), this streams over k in row-aligned blocks with
-    running (m, l, acc) f32 state — the StreamFlow/FastFlow trade (PAPERS.md)
-    of a little recomputation (the exp rescale) for HBM high-water: the
-    largest live score tile is (band_q, block_k), not (band_q, S). The bias
-    tile is rebuilt per (q-band, k-block) from the SMALL f32 q-projections
-    rel_h_q (B, H, S, gh) / rel_w_q (B, H, S, gw) by broadcast + reshape
-    over the row-aligned block structure — no (S, S) score tensor, no
-    broadcast (B, H, h, w, h, w) bias, no one-hot expansion matmuls, ever.
-
-    q/k/v: (B, H, S, D) on the (gh, gw) token grid; rh/rw the get_rel_pos
-    tables (None skips the bias). Exact online softmax: equal to the dense
-    softmax up to float reassociation (the same freedom XLA already has),
-    f32 accumulators throughout; under bf16 inputs the probability matrix
-    rounds to bf16 for the AV contraction exactly like the blockwise oracle.
-    Block targets: TMR_XLA_FLASH_BQ/BK (tokens, default 512), clamped to
-    whole grid rows.
-
-    Schedule: the q-band loop is a ROLLED lax.scan (blockwise's band
-    structure — one compiled body); the k-block loop inside each band is a
-    STATIC UNROLL: the unrolled inner body is what lets XLA
-    software-pipeline the next block's K/V fetch behind the current tile's
-    compute — the measured TMR_GLOBAL_BANDS_UNROLL lesson applied here by
-    construction.
-    """
-    B, H, S, D = q.shape
-    gh, gw = grid_hw
-    work = q.dtype
-    rows_q = _band_rows(gh, gw, _env_tokens("TMR_XLA_FLASH_BQ", 512))
-    rows_k = _band_rows(gh, gw, _env_tokens("TMR_XLA_FLASH_BK", 512))
-    nqb, nkb = gh // rows_q, gh // rows_k
-    bq, bk = rows_q * gw, rows_k * gw
-    neg = jnp.float32(-1e30)
-
-    q_blocks = jnp.moveaxis(q.reshape(B, H, nqb, bq, D), 2, 0)
-
-    if rh is not None:
-        qf = q.reshape(B, H, gh, gw, D).astype(jnp.float32)
-        rel_h_q = jnp.einsum(
-            "bhywd,ykd->bhywk", qf, rh.astype(jnp.float32)
-        ).reshape(B, H, nqb, bq, gh)
-        rel_w_q = jnp.einsum(
-            "bhywd,wkd->bhywk", qf, rw.astype(jnp.float32)
-        ).reshape(B, H, nqb, bq, gw)
-        rel_h_blocks = jnp.moveaxis(rel_h_q, 2, 0)  # (nqb, B, H, bq, gh)
-        rel_w_blocks = jnp.moveaxis(rel_w_q, 2, 0)  # (nqb, B, H, bq, gw)
-    else:
-        rel_h_blocks = jnp.zeros((nqb, 0), jnp.float32)
-        rel_w_blocks = jnp.zeros((nqb, 0), jnp.float32)
-
-    def one_band(args):
-        qb, rhb, rwb = args  # (B, H, bq, D) + the band's bias projections
-        m = jnp.full((B, H, bq, 1), neg, jnp.float32)
-        l = jnp.zeros((B, H, bq, 1), jnp.float32)
-        acc = jnp.zeros((B, H, bq, v.shape[-1]), jnp.float32)
-        for ikb in range(nkb):
-            # static slices of the raw q/k/v arguments
-            kb = k[:, :, ikb * bk:(ikb + 1) * bk]
-            s = jnp.einsum(
-                "bhqd,bhkd->bhqk", qb, kb,
-                preferred_element_type=jnp.float32,
-            ) * scale  # (B, H, bq, bk) f32
-            if rh is not None:
-                # bias tile from the block index offsets: key token
-                # j = ky*gw + kx, so over the row-aligned block the rel-h
-                # column repeats gw-wide and the rel-w row tiles rows_k
-                # times — broadcast + reshape, no gather, no one-hots.
-                # This block's keys cover rows [ikb*rows_k, (ikb+1)*rows_k)
-                # of the rel-h projection — a static column slice.
-                rhk = rhb[..., ikb * rows_k:(ikb + 1) * rows_k]
-                s = s.reshape(B, H, bq, rows_k, gw)
-                s = s + rhk[..., :, None] + rwb[..., None, :]
-                s = s.reshape(B, H, bq, bk)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)  # (B, H, bq, bk) f32
-            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            acc = acc * alpha + jnp.einsum(
-                "bhqk,bhkd->bhqd", p.astype(work),
-                v[:, :, ikb * bk:(ikb + 1) * bk],
-                preferred_element_type=jnp.float32,
-            )
-            m = m_new
-        return (acc / l).astype(work)
-
-    # the same rolled scan spelling as blockwise
-    out = jax.lax.scan(
-        lambda c, x: (c, one_band(x)), (),
-        (q_blocks, rel_h_blocks, rel_w_blocks),
-    )[1]
-    return jnp.moveaxis(out, 0, 2).reshape(B, H, S, v.shape[-1])
-
-
-@functools.lru_cache(maxsize=None)
-def xlaflash_ok(gh: int, gw: int, head_dim: int) -> bool:
-    """Per-geometry compiled self-check of the XLA online-softmax flash
-    path. Pure XLA — any backend, Pallas kill-switch exempt — and gated
-    only under bf16 models (in f32 the online softmax differs from the
-    oracle by float reassociation alone, the same freedom the compiler
-    already has over the blockwise schedule). Same PARITY.md contract as
-    blockfolded_ok: every selectable formulation pins to the blockwise
-    oracle before it can trace."""
-    return _self_check(xla_flash_decomposed_attention, 1, 2, gh, gw,
-                       head_dim, require_tpu=False, gate="xlaflash_ok")
-
-
 def _self_check(
     attn_fn, B: int, H: int, gh: int, gw: int, D: int,
     require_tpu: bool = True,
@@ -369,9 +229,9 @@ def _self_check(
     (Mosaic lowering, unsupported backend) or disagreement beyond bf16
     tolerance -> False. TMR_NO_FLASH_ATTN=1 force-disables.
 
-    ``require_tpu=False`` is for pure-XLA formulations (blockfolded): the
-    comparison runs on any backend and the Pallas kill-switch does not
-    apply — there is no kernel to kill, only numerics to pin.
+    ``require_tpu=False`` lifts the backend requirement and the
+    kill-switch, so that a test can run a kernel's check in the Pallas
+    interpreter.
 
     Callers invoke this while TRACING the model (Attention.__call__ only
     ever runs under jit), so the whole check runs outside the ambient trace
@@ -518,42 +378,6 @@ def _self_check(
             traceback.print_exc()
         return _refused(f"{type(e).__name__}: {e}", cause="exception",
                         exception=type(e).__name__)
-
-
-@functools.lru_cache(maxsize=None)
-def blockfolded_ok(
-    gh: int, gw: int, head_dim: int, scores: str = "f32"
-) -> bool:
-    """Per-geometry compiled self-check of the blockfolded formulation
-    under bf16 (the folded bias rounds to bf16; in f32 the fold is
-    algebraically exact and needs no gate). Pure XLA — runs on any backend
-    and ignores the Pallas kill-switch. Keeps the PARITY.md contract:
-    every selectable formulation is pinned to the blockwise oracle.
-
-    ``scores`` must be the resolved TMR_GLOBAL_SCORES_DTYPE the model will
-    trace with (the knob changes the checked numerics — bf16 score tiles
-    round the logits — so a verdict under one dtype must never vouch for
-    the other; same pattern as pallas_global_ok's tile params)."""
-    from tmr_tpu.models.vit import blockfolded_decomposed_attention
-
-    return _self_check(blockfolded_decomposed_attention, 1, 2, gh, gw,
-                       head_dim, require_tpu=False, gate="blockfolded_ok",
-                       config={"scores": scores})
-
-
-@functools.lru_cache(maxsize=None)
-def densefolded_ok(
-    gh: int, gw: int, head_dim: int, scores: str = "f32"
-) -> bool:
-    """blockfolded_ok's twin for the scan-free densefolded formulation —
-    same fold, same bf16 rounding surface (including the ``scores`` cache
-    key), separately compiled/checked because the dense schedule is a
-    different XLA program."""
-    from tmr_tpu.models.vit import densefolded_decomposed_attention
-
-    return _self_check(densefolded_decomposed_attention, 1, 2, gh, gw,
-                       head_dim, require_tpu=False, gate="densefolded_ok",
-                       config={"scores": scores})
 
 
 @mosaic_gate

@@ -1,35 +1,23 @@
-"""Custom Pallas TPU kernel: global attention with decomposed rel-pos bias.
+"""Custom Pallas TPU kernels for the ViT's attention blocks, and the
+choosers that decide which form a block traces with.
 
-The 4 global-attention blocks dominate the flagship program's runtime
-(PROFILE_LIVE: ~55 ms/block of the 394 ms batch-4 budget at 1024, vs ~1 ms
-of pure matmul FLOPs). The XLA blockwise path (models/vit.py) is bandwidth-
-bound: every band's (rows*gw, S) f32 score tile makes ~5 HBM passes
-(write, bias adds, softmax reductions). The stock Pallas flash kernel with
-the bias folded into a 256-lane-padded contraction measured *worse*
-(~68 ms). This kernel keeps scores resident in VMEM:
+Both kernels read ``qkv`` where the product wrote it, a row a token with
+q | k | v along the row and heads within each, and write what ``proj``
+reads: no head-major operand, transpose, pad or concatenate between the
+two products.
 
-- grid (B*H, S/BQ, S/BK), k-axis innermost ("arbitrary" semantics), online
-  softmax with running (m, l, acc) f32 scratch — no score tensor ever
-  reaches HBM;
-- the decomposed bias (reference sam_ViT.py:325-361 semantics:
-  bias[q=(y,x), k=(ky,kx)] = (q.RH)[y,ky] + (q.RW)[x,kx]) is applied per
-  tile from the SMALL precomputed projections rel_h_q (B*H, S, gh) and
-  rel_w_q (B*H, S, gw), expanded to the (BQ, BK) tile by two one-hot
-  selector matmuls built from iota — MXU work on (BQ, gh)x(gh, BK), no
-  dynamic lane slicing, no (S, S) bias materialization;
-- qk/av contractions stay at the native head dim (64/80), f32 accumulate.
+- ``packed_windowed_attention``: one window, all of its heads, a grid
+  step; ``window_formulation`` answers ``packed`` for it, else ``dense``.
+- ``packed_global_attention``: one image's group of heads a grid step;
+  ``global_formulation`` answers ``packed`` for it, else ``flash``
+  (ops/flash_attn.py), else ``blockwise`` (models/vit.py).
 
-Exactness: identical math to blockwise_decomposed_attention to
-float-associativity — the bias projections are computed and consumed in
-full f32 regardless of the input dtype (bf16 deployment rounds only the
-qk/av contraction inputs, exactly like the blockwise path). Gated like
-every Pallas path here: per-geometry compiled self-check against the exact
-blockwise oracle, fallback on any failure (ops/flash_attn._self_check).
-
-Training: a ``jax.custom_vjp`` whose backward recomputes gradients through
-the exact blockwise formulation — the forward speed is what matters for the
-eval/deploy path, and the backward stays bit-identical to the parity
-implementation (no handwritten flash backward to validate).
+Each choice reads only what it can observe: the backend, the dtype, the
+rel-pos tables, the grid and heads, and the kernel's own self-check
+against the exact blockwise oracle (``packed_window_ok`` /
+``packed_global_ok``, compiled, forward and gradients; ops/flash_attn.
+_self_check). Both kernels are differentiable by a ``jax.custom_vjp``
+whose backward recomputes through that oracle.
 """
 
 from __future__ import annotations
@@ -48,276 +36,17 @@ from tmr_tpu.diagnostics import mosaic_gate
 _NEG_INF = -1e30
 
 
-def _attn_kernel(
-    q_ref, k_ref, v_ref, rhq_ref, rwq_ref, out_ref,
-    m_ref, l_ref, acc_ref,
-    *, scale: float, gw: int, bk: int, nk: int, has_bias: bool,
-):
-    """One (batch*head, q-block, k-block) step of online-softmax attention.
-
-    Refs (VMEM blocks): q (1, BQ, D), k/v (1, BK, D), rhq (1, BQ, gh),
-    rwq (1, BQ, gw), out (1, BQ, D); scratch m/l (BQ, 128) f32 running
-    max/denominator (lane-broadcast), acc (BQ, D) f32 running numerator.
-    """
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0]
-    k = k_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # (BQ, BK)
-
-    if has_bias:
-        # decomposed bias for this tile. k-token j of block ik sits at grid
-        # (ky, kx) = divmod(ik*BK + j, gw); select the matching columns of
-        # the precomputed q-projections with one-hot matmuls (iota-built,
-        # MXU-fed).
-        gh = rhq_ref.shape[-1]
-        k_tok = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        ky = k_tok // gw  # (1, BK)
-        kx = k_tok % gw
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (gh, 1), 0)
-        col_ids = jax.lax.broadcasted_iota(jnp.int32, (gw, 1), 0)
-        sel_h = (row_ids == ky).astype(jnp.float32)  # (gh, BK)
-        sel_w = (col_ids == kx).astype(jnp.float32)  # (gw, BK)
-        s += jax.lax.dot_general(
-            rhq_ref[0].astype(jnp.float32), sel_h, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s += jax.lax.dot_general(
-            rwq_ref[0].astype(jnp.float32), sel_w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    m_prev = m_ref[:, :1]  # (BQ, 1)
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)  # (BQ, 1)
-    p = jnp.exp(s - m_new)  # (BQ, BK) f32
-    l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        out_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(out_ref.dtype)
-
-
-def _attn_kernel_nobias(
-    q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref,
-    *, scale: float, gw: int, bk: int, nk: int,
-):
-    """use_rel_pos=False arity: no bias-projection inputs, no selector
-    matmuls — the has_bias=False specialization drops them statically."""
-    _attn_kernel(
-        q_ref, k_ref, v_ref, None, None, out_ref, m_ref, l_ref, acc_ref,
-        scale=scale, gw=gw, bk=bk, nk=nk, has_bias=False,
-    )
-
-
-def _bias_projections(
-    q: jnp.ndarray, rh: jnp.ndarray, rw: jnp.ndarray,
-    grid_hw: Tuple[int, int],
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(B, H, S, D) q + (gh, gh, D)/(gw, gw, D) tables -> the small f32
-    q-projections rel_h_q (B*H, S, gh), rel_w_q (B*H, S, gw) the kernel
-    rebuilds bias tiles from. The f32 cast and layout here are part of the
-    kernel's exactness contract with the blockwise oracle."""
-    B, H, S, D = q.shape
-    gh, gw = grid_hw
-    qf = q.reshape(B, H, gh, gw, D).astype(jnp.float32)
-    rel_h_q = jnp.einsum(
-        "bhywd,ykd->bhywk", qf, rh.astype(jnp.float32)
-    ).reshape(B * H, S, gh)
-    rel_w_q = jnp.einsum(
-        "bhywd,wkd->bhywk", qf, rw.astype(jnp.float32)
-    ).reshape(B * H, S, gw)
-    return rel_h_q, rel_w_q
-
-
 def _pick_block(s: int, preferred: int = 512) -> Optional[int]:
-    # delegates to the one block-selection rule (flash_attn._block_for) so
-    # the flash and pallas gates can never diverge; kept as a module-level
-    # name so tests can monkeypatch the preferred size
+    # the one block-selection rule, the flash path's (flash_attn._block_for)
     from tmr_tpu.ops.flash_attn import _block_for
 
     return _block_for(s, preferred)
 
 
-def pallas_supported(seq_len: int) -> bool:
-    return _pick_block(seq_len) is not None
-
-
-def effective_global_tiles(
-    seq_len: int,
-) -> Tuple[Optional[int], Optional[int]]:
-    """The (bq, bk) tile sizes the global kernel will actually trace with:
-    the TMR_PALLAS_ATTN_BQ/BK preferences clamped to the largest
-    power-of-two divisor of ``seq_len`` — the same resolution
-    ``_pallas_attn_fwd_impl`` performs. Callers of ``pallas_global_ok``
-    MUST pass these so the gate verdict is cached under the tile config it
-    actually vouches for."""
-    return (
-        _pick_block(seq_len, _env_tile("TMR_PALLAS_ATTN_BQ", 512)),
-        _pick_block(seq_len, _env_tile("TMR_PALLAS_ATTN_BK", 512)),
-    )
-
-
-def _fused_block(seq_len: int, gw: int, preferred: int) -> Optional[int]:
-    """Tile size for the FUSED kernel: the largest multiple of
-    lcm(gw, 128) at or below ``preferred`` that divides ``seq_len``.
-
-    Double alignment is the kernel's whole trick: 128 keeps every tile
-    edge on a v5e lane boundary, and gw keeps every tile edge on a token-
-    grid ROW boundary — so within one (bq, bk) tile the key row index is
-    ``block_index * rk + (lane // gw)`` and the key column cycles
-    0..gw-1, letting the decomposed bias assemble from the (q, k) block
-    offsets by broadcast + reshape alone (no selector one-hot matmuls, no
-    gathers). None when no such tile exists (gate on fused_supported)."""
-    base = gw * 128 // math.gcd(gw, 128)
-    b = (preferred // base) * base
-    while b >= base:
-        if seq_len % b == 0:
-            return b
-        b -= base
-    return None
-
-
-def fused_supported(seq_len: int, gw: int) -> bool:
-    """True when row+lane-aligned tiles exist for this grid (production:
-    4096 tokens @ gw 64 -> 512-token tiles; 9216 @ 96 -> 384)."""
-    if seq_len % max(gw, 1):
-        return False
-    return (
-        _fused_block(seq_len, gw, _env_tile("TMR_PALLAS_ATTN_BQ", 512))
-        is not None
-        and _fused_block(seq_len, gw, _env_tile("TMR_PALLAS_ATTN_BK", 512))
-        is not None
-    )
-
-
-def effective_fused_tiles(
-    seq_len: int, gw: int
-) -> Tuple[Optional[int], Optional[int]]:
-    """effective_global_tiles' sibling for the fused kernel: the (bq, bk)
-    the fused forward will actually trace with under the current
-    TMR_PALLAS_ATTN_BQ/BK preferences. Callers of ``pallas_fused_ok`` MUST
-    pass these — the gate verdict is cached per tile config."""
-    return (
-        _fused_block(seq_len, gw, _env_tile("TMR_PALLAS_ATTN_BQ", 512)),
-        _fused_block(seq_len, gw, _env_tile("TMR_PALLAS_ATTN_BK", 512)),
-    )
-
-
-def pallas_decomposed_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    rh: Optional[jnp.ndarray],
-    rw: Optional[jnp.ndarray],
-    grid_hw: Tuple[int, int],
-    scale: float,
-) -> jnp.ndarray:
-    """Drop-in for blockwise_decomposed_attention (q/k/v (B, H, S, D),
-    rh (gh, gh, D) / rw (gw, gw, D) tables or None) running the VMEM-resident
-    kernel above. Differentiable: backward recomputes through the exact
-    blockwise path (module docstring). Off-TPU the kernel runs in the Pallas
-    interpreter (CPU tests); the production gate (pallas_global_ok) already
-    refuses off-TPU backends, so only tests reach that mode."""
-    return _pallas_attn_vjp(q, k, v, rh, rw, grid_hw, scale)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _pallas_attn_vjp(q, k, v, rh, rw, grid_hw, scale):
-    return _pallas_attn_fwd_impl(q, k, v, rh, rw, grid_hw, scale)
-
-
-def _env_tile(name: str, default: int) -> int:
-    """Preferred tile size from the env: a power of two >= 128 (the actual
-    tile is still the largest such divisor of S at or below it)."""
-    import os
-
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        raise ValueError(f"{name}={raw!r}: expected an integer tile size")
-    if val < 128 or val & (val - 1):
-        raise ValueError(f"{name}={val}: expected a power of two >= 128")
-    return val
-
-
-def _pallas_attn_fwd_impl(q, k, v, rh, rw, grid_hw, scale):
-    B, H, S, D = q.shape
-    gh, gw = grid_hw
-    # TMR_PALLAS_ATTN_BQ/BK: preferred tile sizes for on-hardware block
-    # sweeps (still clamped to the largest power-of-two divisor of S)
-    bq = _pick_block(S, _env_tile("TMR_PALLAS_ATTN_BQ", 512))
-    bk = _pick_block(S, _env_tile("TMR_PALLAS_ATTN_BK", 512))
-    if bq is None or bk is None:
-        raise ValueError(
-            f"sequence length {S} has no power-of-two block >= 128; gate "
-            "callers on pallas_supported()"
-        )
-    bh = B * H
-    nq = S // bq
-    nk = S // bk
-    qkv_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, iq, ik: (b, ik, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, iq, ik: (b, ik, 0)),
-    ]
-    inputs = [q.reshape(bh, S, D), k.reshape(bh, S, D), v.reshape(bh, S, D)]
-    if rh is not None:
-        inputs.extend(_bias_projections(q, rh, rw, grid_hw))
-        in_specs = qkv_specs + [
-            pl.BlockSpec((1, bq, gh), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bq, gw), lambda b, iq, ik: (b, iq, 0)),
-        ]
-        kernel = functools.partial(
-            _attn_kernel, scale=scale, gw=gw, bk=bk, nk=nk, has_bias=True
-        )
-    else:
-        in_specs = qkv_specs
-        kernel = functools.partial(
-            _attn_kernel_nobias, scale=scale, gw=gw, bk=bk, nk=nk
-        )
-    out = pl.pallas_call(
-        kernel,
-        grid=(bh, nq, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, S, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=jax.default_backend() != "tpu",
-    )(*inputs)
-    return out.reshape(B, H, S, D)
-
-
 # --------------------------------------------------------------------------
 # Packed windowed attention (``window_formulation``: a TPU's bf16 path).
 #
-# The kernels above take q/k/v head-major, (B', H, S, D): between the
+# The head-major forms take q/k/v as (B', H, S, D): between the
 # ``qkv`` product, which writes (B', S, 3*dim) token-major, and ``proj``,
 # which reads (B', S, dim), that costs a transpose of every operand, and
 # pads and concatenates besides: of the 11.1 ms an image that the 8
@@ -441,8 +170,7 @@ def _packed_projections(
 ) -> jnp.ndarray:
     """(B'*S, 3*dim) qkv + (gh, gh, D)/(gw, gw, D) tables -> (B'*S, P)
     float32: for token t = (y, x) of its window and head h, lanes
-    [32h, 32h + gh) hold q.RH[y] and the next gw q.RW[x], the numbers of
-    ``_bias_projections``.
+    [32h, 32h + gh) hold q.RH[y] and the next gw q.RW[x].
 
     The tables become block-diagonal weights over the heads, (dim, P) for
     each grid row and each grid column, so that the two products contract
@@ -1108,25 +836,21 @@ def packed_global_ok(
     )
 
 
-#: what ``global_formulation`` can answer; ``utils/autotune.py``'s sweep
-#: of ``TMR_GLOBAL_ATTN`` has to hold every one (tests/test_autotune.py)
-GLOBAL_FORMULATIONS = ("packed", "flash", "blockwise")
-
-
 def global_formulation(
     grid_hw: Tuple[int, int], num_heads: int, head_dim: int, dtype,
     use_rel_pos: bool = True,
 ) -> str:
-    """What a block of 1024 tokens or more traces with when
-    ``TMR_GLOBAL_ATTN`` is unset or ``auto``, by what can be observed: on a
-    TPU in bfloat16, with rel-pos tables, at a grid and heads that have a
-    packed layout and where the kernel's self-check says yes (it says no
-    inside a trace XLA partitions and under
+    """What a block of 1024 tokens or more traces with, by what can be
+    observed: on a TPU in bfloat16, with rel-pos tables, at a grid and
+    heads that have a packed layout and where the kernel's self-check says
+    yes (it says no inside a trace XLA partitions and under
     ``diagnostics.mosaic_kernels_off``), the kernel above (``packed``);
     else in bfloat16 the stock flash kernel on folded operands where its
     gate passes (``flash``); else the exact band scan (``blockwise``):
     float32, the CPU, a partitioned trace. The 96 x 96 grid of the 1536
-    bucket (192 projections a token) keeps ``flash``."""
+    bucket (192 projections a token) keeps ``flash``. No knob has a say:
+    on the v5e ``packed`` beat ``flash`` end to end in both SAM cells
+    (PERF.md section 6)."""
     gh, gw = grid_hw
     if dtype != jnp.bfloat16:
         return "blockwise"
@@ -1139,264 +863,3 @@ def global_formulation(
     if flash_supported(gh * gw) and flash_attention_ok(gh, gw, head_dim):
         return "flash"
     return "blockwise"
-
-
-@mosaic_gate
-def pallas_global_ok(
-    gh: int, gw: int, head_dim: int, bq: int, bk: int
-) -> bool:
-    """Per-geometry compiled self-check of this kernel against the exact
-    blockwise oracle (forward AND backward — the backward here IS blockwise,
-    so the grad half guards only the custom_vjp plumbing). Same policy as
-    flash_attention_ok: reduced batch/heads, full grid/blocks/head-dim.
-
-    ``(bq, bk)`` must be the EFFECTIVE tile sizes the kernel will trace
-    with (callers resolve them via ``effective_global_tiles`` — the same
-    env + clamp resolution the forward impl performs). The self-check
-    below reads the same env at trace time, so its compiled program runs
-    exactly those tiles; the lru_cache keys on them so a verdict reached
-    under one tile config is never reused for another (a tile-specific
-    Mosaic lowering failure or VMEM overflow must trip here, inside the
-    gate)."""
-    from tmr_tpu.ops.flash_attn import _self_check
-
-    # (bq, bk) are cache key only — the env the caller resolved them from
-    # is live during the check — but they also label the refusal record
-    return _self_check(pallas_decomposed_attention, 1, 2, gh, gw, head_dim,
-                       gate="pallas_global_ok", config={"bq": bq, "bk": bk})
-
-
-def _vjp_fwd(q, k, v, rh, rw, grid_hw, scale):
-    return _pallas_attn_fwd_impl(q, k, v, rh, rw, grid_hw, scale), (
-        q, k, v, rh, rw,
-    )
-
-
-def _vjp_bwd(grid_hw, scale, res, g):
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-
-    q, k, v, rh, rw = res
-    if rh is None:
-        _, pull = jax.vjp(
-            lambda a, b, c: blockwise_decomposed_attention(
-                a, b, c, None, None, grid_hw, scale),
-            q, k, v,
-        )
-        dq, dk, dv = pull(g)
-        return dq, dk, dv, None, None
-    _, pull = jax.vjp(
-        lambda a, b, c, d, e: blockwise_decomposed_attention(
-            a, b, c, d, e, grid_hw, scale),
-        q, k, v, rh, rw,
-    )
-    return pull(g)
-
-
-_pallas_attn_vjp.defvjp(_vjp_fwd, _vjp_bwd)
-
-
-# --------------------------------------------------------------------------
-# Fused rel-pos flash kernel (TMR_GLOBAL_ATTN=fused): v5e-shaped tiles.
-#
-# The original kernel above expands the bias per tile with TWO one-hot
-# selector matmuls, (BQ, gh)x(gh, BK) + (BQ, gw)x(gw, BK) — at the
-# production shape (BQ=BK=512, gh=gw=64, D=64) that is 2x the MXU work of
-# the actual QK contraction, i.e. the bias expansion TRIPLES the matmul
-# FLOPs of a kernel whose problem is already ~4% MXU efficiency. This
-# variant makes the expansion free: tiles are aligned to BOTH the 128-lane
-# boundary and the token-grid rows (_fused_block), so inside a (bq, bk)
-# tile the key's grid position is a pure function of the (q, k) BLOCK
-# OFFSETS — key row = ik*rk + (lane // gw), key column = lane % gw — and
-# the decomposed bias assembles from the small f32 q-projections by
-# broadcast + reshape ONLY. No selector matmuls, no gathers, no iota, no
-# (S, S) anything; the only MXU work is the native-head-dim QK and AV.
-#
-# The rel-h projection's gh axis is block-sliced BY THE K INDEX (BlockSpec
-# (1, bq, rk) indexed (b, iq, ik)), so Pallas's own block pipeline delivers
-# exactly the rk bias columns this tile needs — the "(q, k) index offsets"
-# are the block indices themselves.
-# --------------------------------------------------------------------------
-#: Retired from what a TPU can select (PR 23): the chip's compiler takes
-#: neither the (1, bq, rk) bias strip ("the last two dimensions of your
-#: block shape [must be] divisible by 8 and 128 respectively, or be equal
-#: to the respective dimensions of the overall array": rk is 8 of gh 64)
-#: nor, with the strip laid out to satisfy that, the kernel's one idea —
-#: the (bq, bk) -> (bq, rk, gw) view that splits the lane axis below 128.
-#: The kernel stays for the interpreter tests; ROADMAP Design item 3b decides
-#: whether it is rewritten or deleted.
-_FUSED_MOSAIC_REFUSAL = (
-    "Mosaic (jaxlib 0.9.0, v5e): infer-vector-layout: unsupported shape "
-    "cast — tpu.reshape vector<512x512xf32> -> vector<512x8x64xf32>"
-)
-
-
-def _fused_attn_kernel(
-    q_ref, k_ref, v_ref, rhq_ref, rwq_ref, out_ref,
-    m_ref, l_ref, acc_ref,
-    *, scale: float, gw: int, nk: int,
-):
-    """One (batch*head, q-block, k-block) step, row+lane-aligned tiles.
-
-    Refs (VMEM blocks): q (1, BQ, D), k/v (1, BK, D), rhq (1, BQ, rk) —
-    the ik-th rk-wide column strip of the rel-h projection — rwq
-    (1, BQ, gw), out (1, BQ, D); scratch m/l (BQ, 128) f32 running
-    max/denominator (lane-broadcast), acc (BQ, D) f32 running numerator.
-    """
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0]
-    k = k_ref[0]
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
-    rk = rhq_ref.shape[-1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # (BQ, BK)
-    # bias tile by broadcast alone: key j of this block sits at grid row
-    # (ik*rk + j//gw) — column j//gw of the rhq strip — and grid column
-    # j % gw — column j % gw of rwq. Both index patterns are the row-major
-    # layout itself, so a (BQ, rk, gw) view lines them up exactly.
-    s = s.reshape(bq, rk, gw)
-    s = s + rhq_ref[0].astype(jnp.float32)[:, :, None]
-    s = s + rwq_ref[0].astype(jnp.float32)[:, None, :]
-    s = s.reshape(bq, bk)
-
-    m_prev = m_ref[:, :1]  # (BQ, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)  # (BQ, 1)
-    p = jnp.exp(s - m_new)  # (BQ, BK) f32
-    l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        out_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(out_ref.dtype)
-
-
-def pallas_fused_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    rh: Optional[jnp.ndarray],
-    rw: Optional[jnp.ndarray],
-    grid_hw: Tuple[int, int],
-    scale: float,
-) -> jnp.ndarray:
-    """Drop-in for blockwise_decomposed_attention running the fused-bias
-    kernel above (q/k/v (B, H, S, D), rh (gh, gh, D) / rw (gw, gw, D)
-    tables). bf16 inputs keep f32 accumulators and a full-f32 bias path,
-    exactly like the blockwise oracle. Differentiable: the backward
-    recomputes through the exact blockwise formulation (module docstring).
-    With ``rh`` None there is no bias to fuse — the original no-bias
-    kernel is already optimal and is reused. Off-TPU the kernel runs in
-    the Pallas interpreter (CPU tests); production gates on
-    ``fused_supported`` + ``pallas_fused_ok``."""
-    if rh is None:
-        return pallas_decomposed_attention(q, k, v, None, None, grid_hw,
-                                           scale)
-    return _pallas_fused_vjp(q, k, v, rh, rw, grid_hw, scale)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _pallas_fused_vjp(q, k, v, rh, rw, grid_hw, scale):
-    return _pallas_fused_fwd_impl(q, k, v, rh, rw, grid_hw, scale)
-
-
-def _pallas_fused_fwd_impl(q, k, v, rh, rw, grid_hw, scale):
-    B, H, S, D = q.shape
-    gh, gw = grid_hw
-    bq = _fused_block(S, gw, _env_tile("TMR_PALLAS_ATTN_BQ", 512))
-    bk = _fused_block(S, gw, _env_tile("TMR_PALLAS_ATTN_BK", 512))
-    if bq is None or bk is None:
-        raise ValueError(
-            f"grid ({gh}, {gw}) has no row+lane-aligned tile; gate callers "
-            "on fused_supported()"
-        )
-    bh = B * H
-    nq, nk = S // bq, S // bk
-    rk = bk // gw  # grid rows per k block; gh == nk * rk by construction
-    rel_h_q, rel_w_q = _bias_projections(q, rh, rw, grid_hw)
-    out = pl.pallas_call(
-        functools.partial(_fused_attn_kernel, scale=scale, gw=gw, nk=nk),
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, iq, ik: (b, ik, 0)),
-            # the k index slices the PROJECTION's gh axis: strip ik holds
-            # bias columns for exactly the grid rows k-block ik covers
-            pl.BlockSpec((1, bq, rk), lambda b, iq, ik: (b, iq, ik)),
-            pl.BlockSpec((1, bq, gw), lambda b, iq, ik: (b, iq, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, iq, ik: (b, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, S, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=jax.default_backend() != "tpu",
-    )(
-        q.reshape(bh, S, D), k.reshape(bh, S, D), v.reshape(bh, S, D),
-        rel_h_q, rel_w_q,
-    )
-    return out.reshape(B, H, S, D)
-
-
-def _fused_vjp_fwd(q, k, v, rh, rw, grid_hw, scale):
-    return _pallas_fused_fwd_impl(q, k, v, rh, rw, grid_hw, scale), (
-        q, k, v, rh, rw,
-    )
-
-
-def _fused_vjp_bwd(grid_hw, scale, res, g):
-    from tmr_tpu.models.vit import blockwise_decomposed_attention
-
-    q, k, v, rh, rw = res
-    _, pull = jax.vjp(
-        lambda a, b, c, d, e: blockwise_decomposed_attention(
-            a, b, c, d, e, grid_hw, scale),
-        q, k, v, rh, rw,
-    )
-    return pull(g)
-
-
-_pallas_fused_vjp.defvjp(_fused_vjp_fwd, _fused_vjp_bwd)
-
-
-@mosaic_gate
-def pallas_fused_ok(
-    gh: int, gw: int, head_dim: int, bq: int, bk: int
-) -> bool:
-    """Per-geometry compiled self-check of the fused kernel against the
-    exact blockwise oracle — pallas_global_ok's twin for the fused
-    variant, with the same contract: ``(bq, bk)`` must be the EFFECTIVE
-    tiles (effective_fused_tiles) so a verdict under one tile config never
-    vouches for another, and a tile-specific Mosaic failure trips here
-    with a structured cause, not in the model trace."""
-    from tmr_tpu.ops.flash_attn import _self_check
-
-    if jax.default_backend() == "tpu":
-        from tmr_tpu.diagnostics import gate_refused
-
-        return gate_refused(
-            "pallas_fused_ok", _FUSED_MOSAIC_REFUSAL, "unsupported-shape",
-            config={"gh": gh, "gw": gw, "head_dim": head_dim,
-                    "bq": bq, "bk": bk},
-        )
-    return _self_check(pallas_fused_attention, 1, 2, gh, gw, head_dim,
-                       gate="pallas_fused_ok", config={"bq": bq, "bk": bk})

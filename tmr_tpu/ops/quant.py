@@ -1,8 +1,8 @@
 """int8-weight / bf16-activation quantization for the inference tail.
 
-The seed's relaxed-numerics playbook (TMR_XCORR_PRECISION=bf16,
-TMR_GLOBAL_SCORES_DTYPE) trades rounding error for MXU passes only when a
-measured, decisive win justifies it. This module extends that playbook one
+The seed's relaxed-numerics playbook (TMR_XCORR_PRECISION=bf16) trades
+rounding error for MXU passes only when a measured, decisive win justifies
+it. This module extends that playbook one
 tier further for the post-attention tail — the matcher correlation and the
 decoder conv stacks + heads, the PR-6 MFU targets: weights are rounded to
 the **int8 grid with a per-output-channel f32 scale** (symmetric,
@@ -27,7 +27,7 @@ Two storage tiers share the int8 grid:
   operand int8 ``dot_general``/Pallas MXU kernels) behind their own
   tolerance gates; see ops/fused_heads.py.
 
-Election contract (the TMR_GLOBAL_SCORES_DTYPE pattern, one tier deeper):
+Election contract (the TMR_XCORR_PRECISION pattern, one tier deeper):
 
 - ``TMR_QUANT=off`` (default) — exact path, knob inert.
 - ``TMR_QUANT=int8`` — explicit request; refused by the tiered oracle gate
@@ -81,7 +81,7 @@ OUTPUT_TIER_REL = 5e-2
 
 def quant_mode() -> str:
     """Resolve TMR_QUANT at trace time (autotune exports the elected
-    winner through the same env knob, the TMR_GLOBAL_SCORES_DTYPE
+    winner through the same env knob, the TMR_XCORR_PRECISION
     mechanism). "auto" without an autotune election means off: quantized
     numerics must never be the accidental default."""
     mode = os.environ.get("TMR_QUANT", "off")

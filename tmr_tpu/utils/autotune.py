@@ -1,10 +1,10 @@
 """Measured kernel-formulation selection (cuDNN-autotune philosophy, TPU-
 style): some ops have several semantically identical lowerings whose relative
 speed depends on the hardware/compiler pair — the matcher correlation
-(ops/xcorr.py: grouped conv / vmap'd depthwise conv / FFT) and the ViT
-global attention (models/vit.py: blockwise / folded-QK / Pallas flash).
-(Windowed attention is not swept: ops/pallas_attn.window_formulation
-decides it from what it observes.)
+(ops/xcorr.py: grouped conv / vmap'd depthwise conv / FFT) and the decoder
+tail (ops/fused_heads.py, ops/quant.py). (The ViT's attention is not swept:
+ops/pallas_attn.window_formulation and global_formulation decide it from
+what they observe.)
 Rather than hardcoding a winner, ``autotune(cfg, ...)`` microbenchmarks each
 variant ON DEVICE at the production shapes derived from the config and
 exports the winners via the env knobs the modules read at trace time:
@@ -12,10 +12,9 @@ exports the winners via the env knobs the modules read at trace time:
 - ``TMR_XCORR_IMPL_SMALL`` — the small-bucket correlation winner. Scoped:
   ops/xcorr.py consults it only below FFT_CAPACITY_THRESHOLD, so the
   capacity-17 winner can never drag the 127/191 buckets off the FFT path.
-- ``TMR_GLOBAL_ATTN`` — the global-attention formulation.
 
 The microbenchmarks are small isolated programs (one correlation, one
-transformer block) timed with the bench.py methodology via the shared
+decoder tail) timed with the bench.py methodology via the shared
 helpers in utils/profiling.py (device-staged inputs, scalar-chained
 iterations, one closing fetch, RTT floor subtracted). Explicitly set env
 knobs are respected and never overridden. Off-TPU the defaults stand
@@ -31,16 +30,7 @@ from tmr_tpu.utils.cache import REPO_ROOT, STATE_DIR
 from tmr_tpu.utils.profiling import chained_seconds_per_iter, measure_rtt_floor
 
 XCORR_VARIANTS = ("conv", "convnhwc", "vmap", "fft", "pallas")
-#: every formulation ``ops/pallas_attn.global_formulation`` can answer is
-#: in the sweep (``packed`` since PR 32): ``pick_global_attn_impl`` exports
-#: the fastest of these whenever the knob is unset, so a formulation the
-#: default takes and the sweep lacks would be displaced by a slower one
-GLOBAL_ATTN_VARIANTS = (
-    "blockwise", "flash", "blockfolded", "densefolded", "pallas",
-    "fused", "xlaflash", "packed",
-)
 XCORR_PRECISIONS = ("highest", "default", "bf16")
-GLOBAL_SCORES_DTYPES = ("f32", "bf16")
 DECODER_IMPL_VARIANTS = ("xla", "fused")
 QUANT_VARIANTS = ("off", "int8")
 
@@ -67,9 +57,9 @@ def _attach_refusals(
 
 #: suffix marking a sweep entry whose timing measured a gate-refused
 #: variant's FALLBACK formulation, not the labeled one. Single source of
-#: truth for producer (_sweep_block_env), consumer (the winner filter in
-#: autotune()), and tests — the three must never desynchronize or fallback
-#: rows become electable again.
+#: truth for producers (the _sweep_*_env harnesses), consumer (the winner
+#: filter in autotune()), and tests — the three must never desynchronize or
+#: fallback rows become electable again.
 FALLBACK_SUFFIX = " (fallback)"
 
 #: bumped when a sweep harness changes in a way that invalidates
@@ -77,10 +67,8 @@ FALLBACK_SUFFIX = " (fallback)"
 #: entry re-sweeps at the next hardware window). History: "fallback-label"
 #: — pre-revision sweeps could record a gate-refused variant's fallback
 #: timing under the requested label and crown it. "fused-relpos" — the
-#: fused Pallas kernel and the XLA online-softmax flash path joined
-#: GLOBAL_ATTN_VARIANTS, and the jax-version CompilerParams fix plus the
-#: off-trace gate repair (flash_attn._self_check) mean every previously
-#: refused kernel row may now genuinely compile: stale cached winners must
+#: off-trace gate repair (flash_attn._self_check) meant every previously
+#: refused kernel row might genuinely compile: stale cached winners had to
 #: re-record at the next hardware window. "decoder-tail" — the decoder
 #: tail joined the swept surface (TMR_DECODER_IMPL fused formulation,
 #: TMR_QUANT int8 weights) and the full-program tail changed shape
@@ -155,7 +143,7 @@ def _sweep_xcorr_env(
                     y = match_templates(f + fb, e, capacity=capacity)
                     return y, jnp.sum(y) * 0.0
 
-            # same fallback-labeling contract as _sweep_block_env: a
+            # same fallback-labeling contract as _sweep_tail_env: a
             # gate-refused variant (pallas off-gate -> conv/fft) warns at
             # trace time and its timing is recorded annotated
             t = None
@@ -203,7 +191,7 @@ def _decisive_pick(
     knob: str,
 ) -> str:
     """Relaxed-numerics selection policy, single-sourced for the
-    TMR_XCORR_PRECISION and TMR_GLOBAL_SCORES_DTYPE stages: pick the
+    TMR_XCORR_PRECISION and TMR_QUANT stages: pick the
     fastest electable row, but keep the exact ``baseline`` unless the win
     is decisive (>10%) — only a clear speedup justifies changed numerics —
     and fall back to the baseline when no exact row was measured (gate
@@ -279,117 +267,6 @@ def pick_xcorr_precision(
     )
     if seed_highest is not None:
         times["highest"] = seed_highest
-    return times
-
-
-def _sweep_block_env(
-    env_var: str, variants,
-    batch: int, grid: int, embed_dim: int, num_heads: int,
-    rtt: Optional[float], log: Callable[[str], None],
-    train: bool = False,
-    also_fallback_envs: tuple = (),
-) -> Dict[str, float]:
-    """Shared microbenchmark harness for the trace-time transformer-block
-    knobs: pin ``env_var`` to each variant, jit one global Block (window 0,
-    the full grid as keys) at the production grid (bf16, the deployment
-    dtype), time it chained. One harness for the formulation and the
-    score-dtype sweeps so staging / step / failure handling can never
-    diverge between them (the _sweep_xcorr_env principle).
-
-    ``train=True`` times forward + backward (value_and_grad through the
-    block): the Pallas kernels' backward RECOMPUTES through the blockwise
-    path, so a forward-only sweep would systematically mis-pick them for
-    training runs — the training sweep must measure what a train step pays.
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tmr_tpu.diagnostics import (
-        FormulationFallbackWarning,
-        drain_gate_refusals,
-    )
-    from tmr_tpu.models.vit import Block
-
-    import warnings
-
-    rng = np.random.default_rng(0)
-    tokens = jnp.asarray(
-        rng.standard_normal((batch, grid, grid, embed_dim)), jnp.bfloat16
-    )
-    rtt = measure_rtt_floor() if rtt is None else rtt
-    times: Dict[str, float] = {}
-    refusals = LAST_SWEEP_REFUSALS.setdefault(env_var, {})
-    refusals.clear()
-    prev = os.environ.get(env_var)
-    try:
-        for impl in variants:
-            os.environ[env_var] = impl
-            drain_gate_refusals()  # discard causes from earlier traces
-            blk = Block(num_heads=num_heads, window_size=0,
-                        rel_pos_size=(grid, grid), dtype=jnp.bfloat16)
-
-            # a gate-refused request silently traces the fallback
-            # formulation (vit.py warns at trace time): capture those
-            # warnings so the timing is labeled with what was MEASURED —
-            # an entry recorded under the requested name would poison the
-            # cached winner and the exported A/B evidence
-            t = None
-            with warnings.catch_warnings(record=True) as caught:
-                warnings.simplefilter("always")
-                try:
-                    params = jax.jit(blk.init)(
-                        jax.random.key(1), tokens
-                    )["params"]
-
-                    if train:
-                        def loss_fn(p, x, _blk=blk):
-                            y = _blk.apply({"params": p}, x)
-                            return jnp.sum(y.astype(jnp.float32) ** 2)
-
-                        @jax.jit
-                        def step(p, x, fb):
-                            l, g = jax.value_and_grad(loss_fn)(
-                                p, x + fb.astype(x.dtype)
-                            )
-                            return g, l * 0.0
-                    else:
-                        @jax.jit
-                        def step(p, x, fb):
-                            y = blk.apply(
-                                {"params": p}, x + fb.astype(x.dtype)
-                            )
-                            return y, jnp.sum(y).astype(jnp.float32) * 0.0
-
-                    t = chained_seconds_per_iter(
-                        step, params, tokens, rtt=rtt
-                    )
-                except Exception as e:
-                    log(f"autotune: {env_var}[{impl}] failed: "
-                        f"{type(e).__name__}: {e}")
-            _reemit_unrelated(caught, env_var)
-            caused = drain_gate_refusals()
-            if t is None:
-                continue
-            # ``also_fallback_envs``: a sub-knob sweep (scores dtype under
-            # a pinned TMR_GLOBAL_ATTN) must also treat the FORMULATION
-            # knob's refusal as a fallback — its timing would otherwise be
-            # recorded under the sub-knob value while measuring blockwise
-            fell_back = any(
-                isinstance(w.message, FormulationFallbackWarning)
-                and w.message.env_var in (env_var,) + tuple(also_fallback_envs)
-                for w in caught
-            )
-            if fell_back:
-                log(f"autotune: {env_var}[{impl}] gate-refused; timed the "
-                    "fallback formulation — recording annotated")
-                times[impl + FALLBACK_SUFFIX] = t
-                if caused:
-                    refusals[impl + FALLBACK_SUFFIX] = caused
-            else:
-                times[impl] = t
-    finally:
-        _restore(prev, env_var)
     return times
 
 
@@ -564,43 +441,6 @@ def pick_quant(
     return combined
 
 
-def pick_global_attn_impl(
-    batch: int, grid: int, embed_dim: int, num_heads: int,
-    rtt: Optional[float] = None,
-    log: Callable[[str], None] = lambda s: None,
-    train: bool = False,
-) -> Dict[str, float]:
-    """Time one GLOBAL transformer block (window 0, the full grid as keys,
-    bf16) per TMR_GLOBAL_ATTN formulation — the 4 global blocks were the one
-    formulation chosen by static gates instead of measurement. Off-TPU the
-    flash gate falls back to blockwise, so both variants time the same
-    program (harmless; selection only runs on TPU). Returns
-    {variant: sec/iter}."""
-    return _sweep_block_env(
-        "TMR_GLOBAL_ATTN", GLOBAL_ATTN_VARIANTS,
-        batch, grid, embed_dim, num_heads, rtt, log, train=train,
-    )
-
-
-def pick_global_scores_dtype(
-    batch: int, grid: int, embed_dim: int, num_heads: int,
-    rtt: Optional[float] = None,
-    log: Callable[[str], None] = lambda s: None,
-    train: bool = False,
-) -> Dict[str, float]:
-    """Time one GLOBAL block at each TMR_GLOBAL_SCORES_DTYPE under the
-    CURRENTLY exported global formulation (run after the formulation sweep,
-    like the xcorr precision stage). Only the gated folded formulations
-    read the knob; a TMR_GLOBAL_ATTN gate refusal during the sweep is
-    annotated as a fallback row so a blockwise timing can never masquerade
-    as bf16-scores evidence. Returns {dtype: sec/iter}."""
-    return _sweep_block_env(
-        "TMR_GLOBAL_SCORES_DTYPE", GLOBAL_SCORES_DTYPES,
-        batch, grid, embed_dim, num_heads, rtt, log, train=train,
-        also_fallback_envs=("TMR_GLOBAL_ATTN",),
-    )
-
-
 def _vit_kind(cfg):
     """backbone -> sweep geometry family (None for non-ViT backbones) —
     single source for autotune() and stale_winners(), whose cache keys
@@ -640,8 +480,7 @@ def stale_winners(
     pre-sweep bank can measure under the last known-good configuration
     instead of the library defaults. Without this, growing a variant set
     silently downgrades the banked wedge-fallback number to whatever the
-    ungated default formulation happens to be (e.g. the 21 img/s
-    blockfolded headline banking at ~11 img/s under blockwise)."""
+    ungated default formulation happens to be."""
     key = _cache_key(cfg, image_size, batch, _vit_kind(cfg), train)
     cached = _cache_load().get(key, {})
     out: Dict[str, str] = {}
@@ -828,8 +667,7 @@ def _cache_load() -> Dict[str, dict]:
 #: new kernel) must get its chance at the next hardware sweep instead of
 #: being silently locked out by an older pick.
 _VERSIONED_KNOBS = (
-    "TMR_XCORR_IMPL_SMALL", "TMR_GLOBAL_ATTN",
-    "TMR_XCORR_PRECISION", "TMR_GLOBAL_SCORES_DTYPE",
+    "TMR_XCORR_IMPL_SMALL", "TMR_XCORR_PRECISION",
     "TMR_DECODER_IMPL", "TMR_QUANT", "TMR_QUANT_STORAGE",
 )
 
@@ -837,16 +675,14 @@ _VERSIONED_KNOBS = (
 def _variants_sig(knob: str) -> str:
     sets = {
         "TMR_XCORR_IMPL_SMALL": XCORR_VARIANTS,
-        "TMR_GLOBAL_ATTN": GLOBAL_ATTN_VARIANTS,
         "TMR_XCORR_PRECISION": XCORR_PRECISIONS,
-        "TMR_GLOBAL_SCORES_DTYPE": GLOBAL_SCORES_DTYPES,
         "TMR_DECODER_IMPL": DECODER_IMPL_VARIANTS,
         "TMR_QUANT": QUANT_VARIANTS,
         "TMR_QUANT_STORAGE": STORAGE_VARIANTS,
     }
     sig = ",".join(sets[knob])
-    if knob in ("TMR_GLOBAL_ATTN", "TMR_XCORR_IMPL_SMALL",
-                "TMR_DECODER_IMPL", "TMR_QUANT", "TMR_QUANT_STORAGE"):
+    if knob in ("TMR_XCORR_IMPL_SMALL", "TMR_DECODER_IMPL", "TMR_QUANT",
+                "TMR_QUANT_STORAGE"):
         # formulation-sweep winners are additionally versioned by the
         # harness revision: a winner picked by a pre-revision sweep may be
         # a mislabeled fallback timing (see _SWEEP_REV) and must go stale
@@ -859,16 +695,7 @@ def _variants_sig(knob: str) -> str:
 def _validate_cache_obj(obj: dict) -> Dict[str, dict]:
     valid = {
         "TMR_XCORR_IMPL_SMALL": set(XCORR_VARIANTS) | {"auto"},
-        "TMR_GLOBAL_ATTN": set(GLOBAL_ATTN_VARIANTS) | {"auto"},
         "TMR_XCORR_PRECISION": set(XCORR_PRECISIONS),
-        "TMR_GLOBAL_SCORES_DTYPE": set(GLOBAL_SCORES_DTYPES),
-        # metadata, not an env knob: which global formulation the scores-
-        # dtype winner was measured under (evidence is impl-specific).
-        # "auto" is a legal pairing — a TMR_GLOBAL_ATTN=auto run records
-        # its scores-dtype evidence under that resolution, and dropping it
-        # here would strip the stamp on reload and re-record the pairing
-        # forever (cache churn on every launch)
-        "_scores_global_impl": set(GLOBAL_ATTN_VARIANTS) | {"auto"},
         # metadata, not an env knob: which impl the precision winner was
         # measured under (its decisive-win evidence is impl-specific)
         "_precision_impl": set(XCORR_VARIANTS),
@@ -879,28 +706,15 @@ def _validate_cache_obj(obj: dict) -> Dict[str, dict]:
         # decisive-win evidence was measured under
         "_quant_decoder_impl": set(DECODER_IMPL_VARIANTS) | {"auto"},
     }
-    # measured throughput-optimal eval batch (bench_extra's batch sweep),
-    # the band-scan unroll, and the XLA flash block targets — positive
-    # ints as strings
+    # measured throughput-optimal eval batch (bench_extra's batch sweep)
+    # — positive ints as strings
     digit_keys = {
         "TMR_BENCH_BATCH",
-        "TMR_GLOBAL_BANDS_UNROLL", "TMR_XLA_FLASH_BQ", "TMR_XLA_FLASH_BK",
         # gallery sweep winners (scripts/gallery_bench.py writes them,
         # serve/gallery.py reads): the N-bucket ladder cap + the
         # elected prefilter top-k
         "TMR_GALLERY_NMAX", "TMR_GALLERY_PREFILTER_TOPK",
     }
-    # global-kernel tile preferences: powers of two >= 128 (the contract
-    # _env_tile enforces at read time — an off-contract seed value would
-    # otherwise crash the next trace instead of being dropped here)
-    tile_keys = {"TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK"}
-
-    def _tile_ok(vv: str) -> bool:
-        if not (vv.isascii() and vv.isdigit()):
-            return False
-        n = int(vv)
-        return n >= 128 and not (n & (n - 1))
-
     # per-knob filtering: one invalid/unknown winner drops only itself —
     # the valid sibling survives (and all-or-nothing would let the next
     # _cache_store rewrite erase it from disk permanently)
@@ -915,7 +729,6 @@ def _validate_cache_obj(obj: dict) -> Dict[str, dict]:
                 vv in valid.get(kk, ())
                 or (kk in digit_keys and vv.isascii() and vv.isdigit()
                     and int(vv) > 0)
-                or (kk in tile_keys and _tile_ok(vv))
                 # variant-set version stamps: free-form comma-joined
                 # names, compared verbatim against _variants_sig()
                 or kk.startswith("_variants_")
@@ -1021,8 +834,6 @@ def autotune(
     """
     import jax
 
-    from tmr_tpu.models.vit import VIT_CONFIGS
-
     if jax.default_backend() != "tpu":
         return {}
     vit_kind = _vit_kind(cfg)
@@ -1043,21 +854,14 @@ def autotune(
             log(f"autotune: cached {knob} predates the current variant "
                 "set; re-measuring")
 
-    # Schedule sub-knobs pinned by a full-program A/B — Pallas tiles/group
-    # plus the band-scan unroll (scripts/pick_full_program.py writes them
-    # into the seed next to the formulation they tuned): export when
-    # present and not user-set. Must run BEFORE the everything-pinned
-    # early return below — a fully env-pinned A/B rerun still needs the
-    # endorsed values. Each is read only by the formulation it tunes
-    # (pallas kernels / the blockwise-family band scan), so exporting
-    # alongside a different winner is inert.
-    for knob in ("TMR_PALLAS_ATTN_BQ", "TMR_PALLAS_ATTN_BK",
-                 "TMR_GLOBAL_BANDS_UNROLL", "TMR_XLA_FLASH_BQ",
-                 "TMR_XLA_FLASH_BK", "TMR_QUANT_STORAGE"):
-        if knob in cached and knob not in os.environ:
-            os.environ[knob] = cached[knob]
-            report[knob] = {"picked": cached[knob], "cached": True}
-            log(f"autotune: {knob}={cached[knob]} (cached, {key})")
+    # the stored-int8 arm rides the TMR_QUANT sweep's evidence: export it
+    # when cached and not user-set, BEFORE the everything-pinned early
+    # return below (a fully env-pinned rerun still needs it)
+    knob = "TMR_QUANT_STORAGE"
+    if knob in cached and knob not in os.environ:
+        os.environ[knob] = cached[knob]
+        report[knob] = {"picked": cached[knob], "cached": True}
+        log(f"autotune: {knob}={cached[knob]} (cached, {key})")
 
     wanted = set()
     if (
@@ -1065,20 +869,8 @@ def autotune(
         and "TMR_XCORR_IMPL_SMALL" not in os.environ
     ):
         wanted.add("TMR_XCORR_IMPL_SMALL")
-    if "TMR_GLOBAL_ATTN" not in os.environ and vit_kind is not None:
-        wanted.add("TMR_GLOBAL_ATTN")
     if tune_precision and "TMR_XCORR_PRECISION" not in os.environ:
         wanted.add("TMR_XCORR_PRECISION")
-    if (
-        tune_precision
-        and "TMR_GLOBAL_SCORES_DTYPE" not in os.environ
-        and vit_kind is not None
-        and cfg.compute_dtype == "bfloat16"
-    ):
-        # same relaxed-numerics policy as the precision sweep: inference
-        # sweeps only (tune_precision=False for training), bf16 models only
-        # (the knob is inert elsewhere)
-        wanted.add("TMR_GLOBAL_SCORES_DTYPE")
     if not train and "TMR_DECODER_IMPL" not in os.environ and cfg.box_reg:
         # the fused formulation covers the two-stack tail; single-stack
         # (box-regression-ablated) models stay on the module path. The
@@ -1119,21 +911,6 @@ def autotune(
         # arm's evidence rides the same sweep, so it drops with it.
         cached = {k: v for k, v in cached.items()
                   if k not in ("TMR_QUANT", "TMR_QUANT_STORAGE")}
-    active_global = os.environ.get(
-        "TMR_GLOBAL_ATTN", cached.get("TMR_GLOBAL_ATTN")
-    )
-    if "TMR_GLOBAL_SCORES_DTYPE" in cached and (
-        "TMR_GLOBAL_ATTN" in wanted
-        or cached.get("_scores_global_impl") != active_global
-    ):
-        # the scores-dtype record — a bf16 win AND the f32 "nothing to
-        # sweep" no-op alike — is evidence about ONE global formulation:
-        # drop it when the formulation it was recorded under changes or is
-        # about to be re-swept (re-decided after the fresh pick instead),
-        # else a no-op recorded under blockwise would permanently suppress
-        # the sweep after blockfolded starts winning
-        cached = {k: v for k, v in cached.items()
-                  if k != "TMR_GLOBAL_SCORES_DTYPE"}
     # export every cached wanted knob up front; only the remainder is
     # measured. A seed file (AUTOTUNE_SEED.json) typically covers the big
     # knobs, so a fresh container sweeps just the unseeded ones instead of
@@ -1143,25 +920,9 @@ def autotune(
         report[knob] = {"picked": cached[knob], "cached": True}
         log(f"autotune: {knob}={cached[knob]} (cached, {key})")
     wanted -= set(cached)
-    if (
-        "TMR_GLOBAL_SCORES_DTYPE" in wanted
-        and "TMR_GLOBAL_ATTN" not in wanted
-        and os.environ.get("TMR_GLOBAL_ATTN", "auto")
-        not in ("blockfolded", "densefolded")
-    ):
-        # the active formulation is settled and not folded: the stage
-        # resolves to the f32 no-op with zero measurements — record it
-        # here so an otherwise-pinned run skips the rtt round trip too
-        os.environ["TMR_GLOBAL_SCORES_DTYPE"] = "f32"
-        report["TMR_GLOBAL_SCORES_DTYPE"] = {"picked": "f32", "times": {}}
-        wanted.discard("TMR_GLOBAL_SCORES_DTYPE")
     if not wanted:
         if report:
             extra = {}
-            if "TMR_GLOBAL_SCORES_DTYPE" in report:
-                extra["_scores_global_impl"] = os.environ.get(
-                    "TMR_GLOBAL_ATTN", "auto"
-                )
             for knob in _VERSIONED_KNOBS:
                 if knob in report:
                     extra[f"_variants_{knob}"] = _variants_sig(knob)
@@ -1223,43 +984,6 @@ def autotune(
                                                  "times": times}
                 _attach_refusals(report, "TMR_XCORR_PRECISION")
 
-    if "TMR_GLOBAL_ATTN" in wanted:
-        vc = VIT_CONFIGS[vit_kind]
-        times = pick_global_attn_impl(
-            batch, grid, vc["embed_dim"], vc["num_heads"], rtt=rtt, log=log,
-            train=train,
-        )
-        pickable = _electable(times)
-        if pickable:
-            best = min(pickable, key=pickable.get)
-            os.environ["TMR_GLOBAL_ATTN"] = best
-            report["TMR_GLOBAL_ATTN"] = {"picked": best, "times": times}
-            _attach_refusals(report, "TMR_GLOBAL_ATTN")
-            log(f"autotune: TMR_GLOBAL_ATTN={best} {times}")
-
-    if "TMR_GLOBAL_SCORES_DTYPE" in wanted:
-        # sweep AFTER the formulation pick (the knob only matters to the
-        # folded formulations, and its win is paired to the one active)
-        active = os.environ.get("TMR_GLOBAL_ATTN", "auto")
-        if active not in ("blockfolded", "densefolded"):
-            # no folded formulation active: record the no-op so the cache
-            # entry is complete and later runs skip the sweep
-            os.environ["TMR_GLOBAL_SCORES_DTYPE"] = "f32"
-            report["TMR_GLOBAL_SCORES_DTYPE"] = {"picked": "f32",
-                                                 "times": {}}
-        else:
-            vc = VIT_CONFIGS[vit_kind]
-            times = pick_global_scores_dtype(
-                batch, grid, vc["embed_dim"], vc["num_heads"], rtt=rtt,
-                log=log, train=train,
-            )
-            best = _decisive_pick(times, "f32", log,
-                                  "TMR_GLOBAL_SCORES_DTYPE")
-            os.environ["TMR_GLOBAL_SCORES_DTYPE"] = best
-            report["TMR_GLOBAL_SCORES_DTYPE"] = {"picked": best,
-                                                 "times": times}
-            _attach_refusals(report, "TMR_GLOBAL_SCORES_DTYPE")
-
     c_cat = cfg.emb_dim * 2 if cfg.fusion else cfg.emb_dim
     if "TMR_DECODER_IMPL" in wanted:
         times = pick_decoder_impl(
@@ -1313,10 +1037,6 @@ def autotune(
         extra = {}
         if "TMR_XCORR_PRECISION" in report:
             extra["_precision_impl"] = _active_small_impl({})
-        if "TMR_GLOBAL_SCORES_DTYPE" in report:
-            extra["_scores_global_impl"] = os.environ.get(
-                "TMR_GLOBAL_ATTN", "auto"
-            )
         if "TMR_QUANT" in report:
             extra["_quant_decoder_impl"] = os.environ.get(
                 "TMR_DECODER_IMPL", "auto"
