@@ -534,6 +534,66 @@ def _vit_heads(size: Size) -> tuple:
     return vc["num_heads"], vc["embed_dim"] // vc["num_heads"]
 
 
+def check_swa_kernel(size: Size, seed: int, batch: int = 2) -> bool:
+    """Grouped-query attention's kernel under the backbone's causal sliding
+    window (``gqa_attention_kernel(..., window=W)``), held to the blocked
+    XLA form at the backbone's own shape (its heads, their width, its
+    tokens, its window) with its q/k norms, once with its rotary and once
+    without, and the count of key blocks a query block visits
+    (``causal_attn.key_blocks``) against the band the window needs. Returns
+    whether a trace of the backbone's sliding layers takes the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS
+    from tmr_tpu.ops import causal_attn
+
+    z = TRUNK_CONFIGS[size.backbone]
+    if not any(mixer == "swa" for mixer, _ in z["layers"]):
+        say("  sliding-window attention: the backbone has no swa layer, "
+            "nothing to hold")
+        return False
+    h, hkv, d, w = z["num_heads"], z["kv_heads"], z["head_dim"], \
+        z["sliding_window"]
+    theta, qk_norm = z.get("sliding_rope_theta"), bool(z.get("qk_norm"))
+    seq, bf = (size.image_size // 16) ** 2, jnp.bfloat16
+    formulation = causal_attn.gqa_formulation(
+        seq, h, hkv, d, bf, 1, qk_norm, theta is not None, window=w)
+    bq, bk = causal_attn._WINDOW_BLOCK
+    visited = sum(end - first for first, end in (
+        causal_attn.key_blocks(i, bq, bk, w) for i in range(seq // bq)))
+    say(f"  sliding-window attention: gqa_formulation({seq}, {h}, {hkv}, "
+        f"{d}, bfloat16, 1, qk_norm={qk_norm}, rope={theta is not None}, "
+        f"window={w}) = {formulation}; blocks {bq} x {bk}, {visited} key "
+        f"blocks visited a head of the {(seq // bq) * (seq // bk)} a full "
+        f"grid holds")
+    if formulation != "causal_kernel":
+        return False
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q = jax.random.normal(ks[0], (batch, seq, h * d)).astype(bf)
+    k = jax.random.normal(ks[1], (batch, seq, hkv * d)).astype(bf)
+    v = jax.random.normal(ks[2], (batch, seq, hkv * d)).astype(bf)
+    weight = lambda key: jax.random.uniform(key, (d,), minval=1.25,
+                                            maxval=2.25).astype(bf)
+    norms = ((weight(ks[3]), weight(ks[4]), float(z.get("norm_eps", 1e-5)))
+             if qk_norm else None)
+    for turn in (theta, None):
+        run = lambda fn: np.asarray(jax.jit(
+            lambda *a: fn(*a, h, hkv, z["attn_scale"], norms, turn,
+                          window=w))(q, k, v), np.float32)
+        got = run(causal_attn.gqa_attention_kernel)
+        want = run(causal_attn.gqa_attention_blocked)
+        gap = float(np.abs(got - want).max() / np.abs(want).max())
+        say(f"  sliding-window attention, {batch} x {seq} tokens x {h} heads "
+            f"on {hkv} of {d}, window {w}, q/k norms {qk_norm}, rotary theta "
+            f"{turn}: the kernel against the blocked form, widest gap "
+            f"{gap:.5f} of the range (bfloat16 on both sides)")
+        check(np.isfinite(got).all() and gap < 0.02,
+              "the windowed kernel equals the blocked form at the backbone's "
+              f"shape (rotary {turn})")
+    return True
+
+
 def decide_gates(cfg, size: Size) -> dict:
     """Ask, outside any trace, every gate the ``auto`` path of this
     configuration consults; the model's traces then hit their caches."""
@@ -552,6 +612,7 @@ def decide_gates(cfg, size: Size) -> dict:
         verdicts["kda_chunk_ok"] = check_kda_kernel(size, seed=0)
         verdicts["latent_kernel_ok"] = check_mla_kernel(size, seed=0)
         verdicts["gqa_kernel_ok"] = check_gqa_kernel(size, seed=0)
+        verdicts["swa_kernel_ok"] = check_swa_kernel(size, seed=0)
         verdicts["ssd_kernel_ok"] = check_ssd_scan(size, seed=0)
         report_gates("decide")
         check_grouped_products(size, seed=0)
@@ -778,9 +839,14 @@ def phase_predict(pred, size: Size, seed: int, verdicts: dict) -> None:
             gqa += f"_block{z['diffusion_block']}"
         ssm = ("chunk_kernel" if verdicts.get("ssd_kernel_ok")
                else "chunked_xla")
+        swa = ("causal_kernel" + ("_qknorm" if z.get("qk_norm") else "")
+               if verdicts.get("swa_kernel_ok") else "blocked_xla") + (
+                   "_rope" if z.get("sliding_rope_theta") else "") + (
+                       f"_w{z.get('sliding_window')}")
         for kind, want in (
                 ("trunk_ssm", f"{ssm} x{layers('ssm')}"),
                 ("trunk_gqa", f"{gqa} x{layers('gqa')}"),
+                ("trunk_swa", f"{swa} x{layers('swa')}"),
                 ("trunk_short_conv", f"xla x{layers('short_conv')}")):
             if kind in kinds:
                 check(all(t[kind] == want for t in traced),
@@ -1179,7 +1245,8 @@ def main(argv=None) -> int:
                     help="a name build_backbone knows; a trunk of typed "
                          "layers (kimi_linear_a3b_share2, xing4_a4b_stage6, "
                          "granite4_h_small_share2, lfm2_24b_a2b_share8, "
-                         "sdar_30b_a3b_share8) runs the predict and serve "
+                         "sdar_30b_a3b_share8, k_exaone_236b_a23b_share8) "
+                         "runs the predict and serve "
                          "phases")
     args = ap.parse_args(argv)
 

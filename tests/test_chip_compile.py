@@ -207,18 +207,18 @@ def _latent_case(rope):
     return case
 
 
-def _gqa_case(batch, seq, head_dim, hkv=8, diffusion_block=1):
+def _gqa_case(batch, seq, head_dim, hkv=8, diffusion_block=1, window=None):
     """Grouped-query attention's kernel on what ``GQAMixer`` holds: 32
     query heads on ``hkv`` key-value heads of ``head_dim``, q, k and v as
     the projections (and the mixer's norms and rotation) write them, under
-    the mask of ``diffusion_block``."""
+    the mask of ``diffusion_block`` or the sliding ``window``."""
     from tmr_tpu.ops.causal_attn import gqa_attention_kernel
 
     def case(sds):
         h = 32
         return (lambda *a: gqa_attention_kernel(
             *a, h, hkv, head_dim ** -0.5,
-            diffusion_block=diffusion_block)), (
+            diffusion_block=diffusion_block, window=window)), (
             sds((batch, seq, h * head_dim), jnp.bfloat16),
             sds((batch, seq, hkv * head_dim), jnp.bfloat16),
             sds((batch, seq, hkv * head_dim), jnp.bfloat16))
@@ -296,6 +296,9 @@ CASES = {
     # sdar30b_fscd147.eval: 4 images, groups of 8 heads of 128, diffusion
     # blocks of 4
     "gqa_kernel_sdar": _gqa_case(4, 4096, 128, hkv=4, diffusion_block=4),
+    # kexaone_fscd147.eval: 4 images, groups of 8 heads of 128, a sliding
+    # window of 128
+    "gqa_kernel_kexaone_swa": _gqa_case(4, 4096, 128, hkv=4, window=128),
     # a cell's tokens a batch, choices a token and hidden width; and one
     # image of the 1536 bucket, which no cell runs
     "pairs_kimi": _pairs_case(4 * 4096, 8, 2304),
@@ -304,6 +307,7 @@ CASES = {
     "pairs_granite_1536": _pairs_case(9216, 10, 4096),
     "pairs_lfm2": _pairs_case(4 * 4096, 4, 2048),
     "pairs_sdar": _pairs_case(4 * 4096, 8, 2048),  # 131,072 rows, the most
+    "pairs_kexaone": _pairs_case(4 * 4096, 8, 6144),  # the widest rows
     "nms": _case_nms,
     "int8_matmul": _case_int8_matmul,
     "predict_program": _case_predict_program,

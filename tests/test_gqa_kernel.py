@@ -459,3 +459,177 @@ def test_a_trunk_traced_with_the_kernel_names_it_where_the_metrics_read(
     for layer in gqa_layers:  # and nothing of the blocked form is left
         assert f"layers_{layer}/attn/softmax/bqgrd" not in text
     assert not re.search(prologue, text)
+
+
+# ------------------------------------------------ the causal sliding window
+def _band_softmax(q, k, v, heads, kv_heads, window):
+    """Float32, all S x S scores a head at once, query ``i`` on keys
+    ``i - W < j <= i``."""
+    b, s, _ = q.shape
+    f32 = lambda t, h: np.asarray(t, np.float32).reshape(b, s, h, -1)
+    q, k, v = f32(q, heads), f32(k, kv_heads), f32(v, kv_heads)
+    k, v = (np.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
+    scores = np.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    scores = np.where((j <= i) & (j > i - window), scores, -np.inf)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, s, -1)
+
+
+def _windowed(form, q, k, v, heads, kv_heads, window, **kw):
+    scale = (q.shape[-1] // heads) ** -0.5
+    fn = gqa_attention_kernel if form == "kernel" else gqa_attention_blocked
+    return np.asarray(fn(q, k, v, heads, kv_heads, scale, window=window,
+                         **kw), np.float32)
+
+
+@pytest.mark.parametrize("window", [1, 128, 1024], ids=["w1", "w128",
+                                                        "w_at_least_s"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("form", ["kernel", "blocked"])
+def test_a_window_is_the_band_and_at_least_the_sequence_is_causal(
+        form, head_dim, window):
+    """1,024 tokens, groups of 8, rotated operands: under a window of 1
+    each query sees itself alone (its key-value head's value), of 128 the
+    band ``i - 128 < j <= i`` (the kernel in two query blocks of
+    ``_WINDOW_BLOCK``, the second of which skips the first key block), and
+    of the whole sequence the causal softmax, the very output of the form
+    without a window (the kernel's in the same blocks); against the float32
+    band softmax, 5e-3 of outputs of order 1."""
+    q, k, v, heads, kv_heads = _operands(1024, head_dim, 8, rotated=True,
+                                         seed=window)
+    got = _windowed(form, q, k, v, heads, kv_heads, window)
+    want = _band_softmax(q, k, v, heads, kv_heads, window)
+    span = np.abs(want).max()
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < 5e-3 * span
+    if window == 1:
+        v_own = np.repeat(np.asarray(v, np.float32).reshape(
+            1, 1024, kv_heads, head_dim), 8, axis=2).reshape(1, 1024, -1)
+        np.testing.assert_array_equal(got, v_own)
+    if window == 1024:  # the causal form, the kernel in the same blocks
+        same = ({"block": causal_attn._WINDOW_BLOCK} if form == "kernel"
+                else {})
+        causal = _windowed(form, q, k, v, heads, kv_heads, None, **same)
+        np.testing.assert_array_equal(got, causal)
+    else:
+        assert np.abs(want - _masked_softmax(q, k, v, heads, kv_heads)
+                      ).max() > 0.05 * span
+
+
+@pytest.mark.parametrize("form", ["kernel", "blocked"])
+def test_a_key_wholly_before_every_window_of_a_row_block_is_never_read(form):
+    """Values of NaN in the first 256 keys (the kernel's first key block):
+    under a window of 128 the rows from 512 on (the kernel's second and
+    third query blocks of 512, the blocked form's third to sixth row blocks
+    of 256) neither multiply nor read them, so they stay finite and as they
+    were; a masked key that was read would carry its NaN through the
+    values product."""
+    q, k, v, heads, kv_heads = _operands(1536, 128, 8, rotated=True)
+    got = _windowed(form, q, k, v, heads, kv_heads, 128)
+    poisoned = _windowed(form, q, k, v.at[:, :256].set(jnp.nan), heads,
+                         kv_heads, 128)
+    np.testing.assert_array_equal(poisoned[:, 512:], got[:, 512:])
+    assert np.isnan(poisoned[:, :256]).all()
+
+
+@pytest.mark.parametrize("block,window,want", [
+    ((256, 128), 128, 2 + 15 * 3), ((128, 128), 128, 1 + 31 * 2),
+    ((512, 512), 128, 1 + 7 * 2), ((256, 128), 1, 2 * 16),
+    ((256, 128), None, sum(2 * (i + 1) for i in range(16))),
+    ((512, 256), 128, 2 + 7 * 3)])
+def test_the_key_blocks_a_query_block_visits_are_pinned(block, window,
+                                                        want):
+    """At 4,096 tokens a head visits, over its query blocks, the key blocks
+    from the one that holds the block's first query's first key to the
+    diagonal's last: under the cell's window of 128 in blocks of
+    ``_WINDOW_BLOCK`` (512, 256), 23 of the 128 a full grid holds and the
+    72 the causal triangle visits in those blocks."""
+    bq, bk = block
+    spans = [causal_attn.key_blocks(i, bq, bk, window)
+             for i in range(4096 // bq)]
+    assert sum(end - first for first, end in spans) == want
+    assert all(0 <= first < end for first, end in spans)
+    if window is not None:  # no key block lies wholly before a window
+        assert all(first * bk + bk > i * bq - window + 1 or first == 0
+                   for i, (first, _) in enumerate(spans))
+    assert causal_attn._WINDOW_BLOCK == (512, 256)
+
+
+def test_the_windowed_kernel_differentiates_through_the_blocked_form():
+    """With a window, the q/k norms and the rotary, the gradients are the
+    blocked form's under the same window."""
+    q, k, v, heads, kv_heads = _operands(512, 128, 8, batch=2)
+    wq, wk, eps = _norms(128, 0)
+    w = jax.random.normal(jax.random.key(9), q.shape)
+    scale = 128 ** -0.5
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, heads, kv_heads, scale, (wq, wk, eps), 1e6,
+               window=128).astype(jnp.float32) * w)
+
+    got = jax.grad(loss(gqa_attention_kernel), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(gqa_attention_blocked), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("form", ["kernel", "blocked"])
+def test_no_window_lowers_as_the_causal_call(form):
+    """``window`` None is the causal text as it was: the same program as a
+    call that does not name it; a window of 128 lowers to another."""
+    q, k, v, heads, kv_heads = _operands(512, 128, 8)
+    scale = 128 ** -0.5
+    fn = gqa_attention_kernel if form == "kernel" else gqa_attention_blocked
+    text = lambda **kw: str(jax.make_jaxpr(
+        lambda q, k, v: fn(q, k, v, heads, kv_heads, scale, **kw))(q, k, v))
+    causal = text()
+    assert text(window=None) == causal
+    assert text(window=128) != causal
+
+
+@pytest.mark.parametrize("case,want", [
+    ("kexaone_sliding", "causal_kernel"), ("window_of_one", "causal_kernel"),
+    ("window_under_block4", "blocked_xla"), ("window_zero", "blocked_xla"),
+    ("float32", "blocked_xla"), ("odd_length", "blocked_xla")])
+def test_a_windowed_formulation_by_what_it_observes(case, want, monkeypatch):
+    """The kernel where the type, the head geometry, a length of whole
+    ``_WINDOW_BLOCK`` blocks and a window of at least one token allow it and
+    the gate, asked with the window, says yes; no window under a diffusion
+    block."""
+    asked = []
+    monkeypatch.setattr(causal_attn, "gqa_kernel_ok",
+                        lambda *a: asked.append(a) or True)
+    window = {"window_of_one": 1, "window_zero": 0}.get(case, 128)
+    seq = 4000 if case == "odd_length" else 4096
+    dtype = jnp.float32 if case == "float32" else jnp.bfloat16
+    block = 4 if case == "window_under_block4" else 1
+    got = gqa_formulation(seq, 64, 8, 128, dtype, block, True, True,
+                          window=window)
+    assert got == want
+    if want == "causal_kernel":
+        assert asked == [(128, 1, True, True, window)]
+
+
+def test_the_gate_is_asked_with_the_window_and_the_full_layer_without():
+    """K-EXAONE's sliding layers ask the gate at heads of 128 with q/k
+    norms, rotary and the window of 128; its full layer with q/k norms and
+    neither rotary nor window, the four arguments the other trunks ask."""
+    from tmr_tpu.models.lm_trunk import TRUNK_CONFIGS, GQAMixer
+
+    z = TRUNK_CONFIGS["k_exaone_236b_a23b_share8"]
+    asked = []
+    x = jax.ShapeDtypeStruct((1, 4096, z["hidden"]), jnp.bfloat16)
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(causal_attn, "gqa_kernel_ok",
+                  lambda *a: asked.append(a) or False)
+        for window, theta in ((z["sliding_window"], z["sliding_rope_theta"]),
+                              (None, None)):
+            mixer = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
+                             z["attn_scale"], dtype=jnp.bfloat16,
+                             qk_norm=True, rope_theta=theta, window=window)
+            jax.eval_shape(mixer.init, jax.random.key(0), x)
+    assert asked == [(128, 1, True, True, 128), (128, 1, True, False)]

@@ -1,11 +1,12 @@
 """A language model's trunk as the detector's backbone: a list of typed
-decoder layers (mixer ``kda`` | ``mla`` | ``ssm`` | ``gqa`` | ``short_conv``,
-feed-forward ``dense`` | ``moe``) between SAM's patch embedding and SAM's
-neck.
+decoder layers (mixer ``kda`` | ``mla`` | ``ssm`` | ``gqa`` | ``swa`` |
+``short_conv``, feed-forward ``dense`` | ``moe``) between SAM's patch
+embedding and SAM's neck.
 
-Five published families are built from it (``TRUNK_CONFIGS``), each as
-published, with no bias on a linear layer, SiLU, pre-norm sub-layers and
-causal over the patches in raster order (position = raster index):
+Six published families are built from it (``TRUNK_CONFIGS``), each as
+published, with no bias on a linear layer, SiLU, pre-norm sub-layers (but
+the last family's) and causal over the patches in raster order (position =
+raster index):
 
 - Kimi-Linear's: gated delta-rule linear attention 3 : 1 latent attention
   without rotary (no positional encoding anywhere), the plain residual add,
@@ -32,14 +33,22 @@ causal over the patches in raster order (position = raster index):
   ``ops/causal_attn.py``), experts chosen by the largest logits and
   weighed by a softmax over the chosen (the published softmax over all,
   renormalised over the chosen, is the same weights), no shared expert,
-  the plain residual add, RMSNorm eps 1e-6.
+  the plain residual add, RMSNorm eps 1e-6;
+- K-EXAONE's (EXAONE 4.0's layer with experts): grouped-query attention
+  with per-head RMSNorm on q and k in every layer, three layers of a
+  causal sliding window of 128 patches (``swa``, ``sliding_window``) with
+  default rotary (``sliding_rope_theta``) to one of full causal attention
+  without rotary (``gqa``, NoPE), a norm *after* each sub-layer and none
+  ahead of it (``post_norms``: ``x + norm(f(x))``), a leading dense layer
+  and then sigmoid-routed experts with a selection bias and a shared
+  expert, RMSNorm eps 1e-5.
 
-The first two's feed-forwards are dense or sigmoid-routed experts with a
-shared expert. The token embedding and the output head are on no path of a
-detector: the patch embedding stands in the embedding's place and the neck
-in the head's. A layer that has experts is told which it holds
-(``expert_offset``, ``experts_held``): it routes over all ``num_experts``
-and computes its own experts' part (``ops/moe.py``).
+The first two's and the last one's feed-forwards are dense or
+sigmoid-routed experts with a shared expert. The token embedding and the
+output head are on no path of a detector: the patch embedding stands in the
+embedding's place and the neck in the head's. A layer that has experts is
+told which it holds (``expert_offset``, ``experts_held``): it routes over
+all ``num_experts`` and computes its own experts' part (``ops/moe.py``).
 
 The trunk's leaves are bfloat16 (``param_dtype``), as the model is published
 and served; the router's and the gates' arithmetic is float32; the rest runs
@@ -75,13 +84,14 @@ from tmr_tpu.ops.kda import (HI, causal_conv, kda_chunk_kernel, kda_chunked,
 STATS = "trunk_stats"
 
 #: what each mechanism traces with (counters ``trunk.<kind>.<formulation>``,
-#: copied onto the ``compile`` span). All seven choose theirs by device, type
-#: and sizes: the recurrence (``ops/kda.py:kda_formulation``), latent
+#: copied onto the ``compile`` span). All eight choose theirs by device,
+#: type and sizes: the recurrence (``ops/kda.py:kda_formulation``), latent
 #: attention (``ops/causal_attn.py:mla_formulation``, with ``_rope`` after
 #: the name where the trunk has rotary), the state-space recurrence
 #: (``ops/ssd.py:ssd_formulation``), grouped-query attention
 #: (``ops/causal_attn.py:gqa_formulation``, ``_rope`` likewise, and
-#: ``_qknorm`` ahead of it where the kernel norms q and k), the gated
+#: ``_qknorm`` ahead of it where the kernel norms q and k) and its sliding
+#: window (``trunk.swa.*``, the same names and ``_w<window>``), the gated
 #: short convolution (``ops/short_conv.py:short_conv_formulation``), the
 #: experts' grouped products
 #: (``ops/moe.py:grouped_formulation``) and the hyper-connections
@@ -379,7 +389,9 @@ class GQAMixer(nn.Module):
     ``RMSNorm(weight_only=True)``), and the counter's name gains
     ``_qknorm`` where the norms went in. ``diffusion_block`` L over 1:
     block diffusion's block-causal mask in place of the causal one (the
-    counter's name ends in ``_block<L>``)."""
+    counter's name ends in ``_block<L>``). ``window`` W: the causal sliding
+    window, query ``i`` on keys ``i - W < j <= i``, counted as
+    ``trunk.swa.<formulation>...`` with ``_w<W>`` at the end."""
 
     num_heads: int
     kv_heads: int
@@ -391,6 +403,7 @@ class GQAMixer(nn.Module):
     rope_theta: Optional[float] = None
     norm_eps: float = 1e-5
     diffusion_block: int = 1
+    window: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -401,14 +414,18 @@ class GQAMixer(nn.Module):
         norm = lambda name: RMSNorm(self.norm_eps, self.param_dtype,
                                     name=name)
         n, rope = self.diffusion_block, self.rope_theta is not None
+        # a window is named only where there is one: the other trunks'
+        # calls stay as they were
+        wide = {} if self.window is None else {"window": self.window}
         formulation = gqa_formulation(s, h, hkv, d, self.dtype, n,
-                                      self.qk_norm, rope)
+                                      self.qk_norm, rope, **wide)
         # the kernel norms and turns q and k itself, where they were written
         kernel = formulation == "causal_kernel"
         metrics.counter(
-            f"trunk.gqa.{formulation}"
+            f"trunk.{'swa' if wide else 'gqa'}.{formulation}"
             + ("_qknorm" if kernel and self.qk_norm else "")
             + ("_rope" if rope else "") + (f"_block{n}" if n > 1 else "")
+            + (f"_w{self.window}" if wide else "")
         ).inc()
         shape = (b, s, -1) if kernel else (b, s, -1, d)
         q = lin(h * d, "q_proj")(x).reshape(shape)
@@ -424,7 +441,7 @@ class GQAMixer(nn.Module):
             with jax.named_scope("softmax"):
                 o = gqa_attention_kernel(q, k, v, h, hkv, self.scale,
                                          qk_norm, self.rope_theta,
-                                         diffusion_block=n)
+                                         diffusion_block=n, **wide)
         else:
             if self.qk_norm:
                 q, k = norm("q_norm")(q), norm("k_norm")(k)
@@ -435,7 +452,7 @@ class GQAMixer(nn.Module):
                     k = rope_ops.rotate(k, jnp.arange(s), inv_freq)
             # at L = 1 the four-argument call, which the benchmark's granite
             # fault replaces by a function of four arguments
-            mask = {"diffusion_block": n} if n > 1 else {}
+            mask = dict({"diffusion_block": n} if n > 1 else {}, **wide)
             with jax.named_scope("softmax"):
                 o = causal_attention_blocked(q, k, v, self.scale, **mask)
         return lin(x.shape[-1], "o_proj")(o.reshape(b, s, h * d))
@@ -570,16 +587,25 @@ class HyperConn(nn.Module):
             b_res=_weight(self, "b_res", (n, n), eye))
 
 
+def post_normed(x, f, norm):
+    """A sub-layer under a post-norm: ``x + norm(f(x))``, no norm ahead of
+    ``f``. A function of the module's own, looked up as a trunk is traced,
+    so that the benchmark's fault "pre-norm in place" reaches every path."""
+    return x + norm(f(x))
+
+
 class TrunkLayer(nn.Module):
     """A mixer and a feed-forward, each on ``norm(x)``. With ``hc_mult`` 0
     the residual is the plain add, ``x + f(norm(x))`` on (B, S, C), or with
     a ``residual_multiplier`` ``r``, ``x + r f(norm(x))``; else
     ``x`` is ``hc_mult`` streams (n, B, S, C) and each sub-layer reads their
     ``H_pre`` mix and writes back through ``H_res`` and ``H_post``
-    (``ops/hyper_conn.py``; one stream is *not* the plain add). ``sizes``
-    is the trunk's own (``TRUNK_CONFIGS``)."""
+    (``ops/hyper_conn.py``; one stream is *not* the plain add). With
+    ``post_norms`` each sub-layer is :func:`post_normed` instead, the
+    norms named ``post_attn_norm`` and ``post_ffn_norm``. ``sizes`` is the
+    trunk's own (``TRUNK_CONFIGS``)."""
 
-    mixer: str  # "kda" | "mla" | "ssm" | "gqa" | "short_conv"
+    mixer: str  # "kda" | "mla" | "ssm" | "gqa" | "swa" | "short_conv"
     ffn: str  # "dense" | "moe"
     sizes: Any
     dtype: Any = jnp.float32
@@ -608,6 +634,12 @@ class TrunkLayer(nn.Module):
                             norm_eps=z["norm_eps"],
                             diffusion_block=z["diffusion_block"],
                             name="attn", **kw)
+        elif self.mixer == "swa":
+            attn = GQAMixer(z["num_heads"], z["kv_heads"], z["head_dim"],
+                            z["attn_scale"], qk_norm=z["qk_norm"],
+                            rope_theta=z["sliding_rope_theta"],
+                            norm_eps=z["norm_eps"],
+                            window=z["sliding_window"], name="attn", **kw)
         elif self.mixer == "short_conv":
             attn = ShortConvMixer(z["conv_size"], name="attn", **kw)
         else:
@@ -623,6 +655,12 @@ class TrunkLayer(nn.Module):
             raise KeyError(f"unknown feed-forward kind {self.ffn!r}")
         norm = lambda name: RMSNorm(z["norm_eps"], self.param_dtype,
                                     name=name)
+        if z.get("post_norms"):
+            if z["hc_mult"] or z.get("residual_multiplier") is not None:
+                raise ValueError("post-norms beside hyper-connections or a "
+                                 "residual multiplier are not written")
+            x = post_normed(x, attn, norm("post_attn_norm"))
+            return post_normed(x, mlp, norm("post_ffn_norm"))
         if not z["hc_mult"]:
             r = z.get("residual_multiplier")  # a size newer than some callers
             scaled = (lambda y: y) if r is None else (lambda y: r * y)
@@ -686,10 +724,13 @@ class LMTrunkBackbone(nn.Module):
     qk_norm: bool = False  # "gqa" layers: q and k normed a head
     rope_theta: Optional[float] = None  # "gqa" layers: None, NoPE
     diffusion_block: int = 1  # "gqa" layers: 1, causal; else block-causal
+    sliding_window: Optional[int] = None  # "swa" layers: the window
+    sliding_rope_theta: Optional[float] = None  # "swa" layers: None, NoPE
     router: str = "sigmoid_bias"  # or "softmax_topk"
     router_eps: Optional[float] = None  # None: ops/moe.py:route's own
     shared_width: Optional[int] = None  # None: an expert's own; 0: none
     residual_multiplier: Optional[float] = None  # None: x + f(norm(x))
+    post_norms: bool = False  # True: x + norm(f(x)), no norm ahead of f
     embedding_multiplier: Optional[float] = None
     q_rank: Optional[int] = None  # None: one q_proj
     rope: Any = None  # None: NoPE; else YaRN's group and "theta"
@@ -715,7 +756,8 @@ class LMTrunkBackbone(nn.Module):
             "hc_eps", "hc_clamp", "norm_eps", "ssm_heads", "ssm_head_dim",
             "ssm_state", "ssm_groups", "ssm_chunk", "kv_heads", "head_dim",
             "attn_scale", "qk_norm", "rope_theta", "diffusion_block",
-            "router", "router_eps", "shared_width", "residual_multiplier")}
+            "sliding_window", "sliding_rope_theta", "router", "router_eps",
+            "shared_width", "residual_multiplier", "post_norms")}
         self._layers = [
             TrunkLayer(mixer, ffn, sizes, self.dtype, self.param_dtype,
                        name=f"layers_{i}")
@@ -756,10 +798,12 @@ def _pattern(n_layers: int, mla_every: int = 4, first_dense: int = 1):
 
 def _from_layer_types(layer_types: Sequence[str], first_dense: int):
     """A pattern from a published ``layer_types`` list (``conv`` is the
-    gated short convolution, ``full_attention`` grouped-query attention);
+    gated short convolution, ``full_attention`` grouped-query attention,
+    ``sliding_attention`` the same under a sliding window);
     the first ``first_dense`` layers have a dense feed-forward, the others
     experts."""
-    kind = {"conv": "short_conv", "full_attention": "gqa"}
+    kind = {"conv": "short_conv", "full_attention": "gqa",
+            "sliding_attention": "swa"}
     return tuple((kind[t], "dense" if i < first_dense else "moe")
                  for i, t in enumerate(layer_types))
 
@@ -767,6 +811,11 @@ def _from_layer_types(layer_types: Sequence[str], first_dense: int):
 #: LFM2-24B-A2B's published ``layer_types``: attention at 2, 6, ..., 38
 _LFM2_24B_LAYER_TYPES = tuple(
     "full_attention" if i % 4 == 2 else "conv" for i in range(40))
+
+#: K-EXAONE-236B-A23B's published ``layer_types``, ``LLLG`` twelve times
+_K_EXAONE_LAYER_TYPES = tuple(
+    "full_attention" if i % 4 == 3 else "sliding_attention"
+    for i in range(48))
 
 #: name -> sizes. ``kimi_linear_a3b_share2``: the published widths of
 #: Kimi-Linear-48B-A3B, layers 1 to 5, the 128 of 256 experts that one of
@@ -785,7 +834,12 @@ _LFM2_24B_LAYER_TYPES = tuple(
 #: published widths and all 48 layers of SDAR-30B-A3B (grouped-query
 #: attention under a block-causal mask of 4 patches, 128 experts, 8 a
 #: token, in every layer), the 16 of 128 experts that one of the eight
-#: chips sharing every layer holds. (The tests and the benchmark's
+#: chips sharing every layer holds. ``k_exaone_236b_a23b_share8``: the
+#: published widths of K-EXAONE-236B-A23B, one pipeline stage of published
+#: layers 0 to 4 (the leading dense layer and four expert layers, sliding,
+#: sliding, full, sliding: a whole period of ``LLLG`` among the expert
+#: layers), the 16 of 128 experts that one of the eight chips sharing every
+#: layer holds. (The tests and the benchmark's
 #: rehearsals add the same patterns at tiny widths under names of their
 #: own.)
 TRUNK_CONFIGS = {
@@ -822,6 +876,13 @@ TRUNK_CONFIGS = {
         rope_theta=1000000.0, diffusion_block=4, expert_width=768,
         shared_width=0, num_experts=128, experts_held=16, expert_offset=0,
         top_k=8, router="softmax_topk", norm_eps=1e-6),
+    "k_exaone_236b_a23b_share8": dict(
+        hidden=6144, layers=_from_layer_types(_K_EXAONE_LAYER_TYPES[:5], 1),
+        num_heads=64, kv_heads=8, head_dim=128, attn_scale=128 ** -0.5,
+        qk_norm=True, sliding_window=128, sliding_rope_theta=1000000.0,
+        post_norms=True, dense_width=18432, expert_width=2048,
+        num_experts=128, experts_held=16, expert_offset=0, top_k=8,
+        routed_scale=2.5),
 }
 
 

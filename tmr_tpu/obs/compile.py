@@ -27,8 +27,8 @@ blocks took it (``win_attn_blocks``), from the ``vit.win_attn.*`` counters,
 the same of the global blocks (``global_attn``, ``global_attn_blocks``, from
 ``vit.global_attn.*``), and for a trunk of typed layers
 (``models/lm_trunk.py``) ``trunk_kda``, ``trunk_mla``, ``trunk_ssm``,
-``trunk_gqa``, ``trunk_short_conv``, ``trunk_moe``, ``trunk_hc`` and
-``experts_held`` from ``trunk.*``.
+``trunk_gqa``, ``trunk_swa``, ``trunk_short_conv``, ``trunk_moe``,
+``trunk_hc`` and ``experts_held`` from ``trunk.*``.
 
 :func:`track_compile`'s wrapper is also the one seam every Predictor
 program is called through, so it records each call as a
@@ -64,7 +64,7 @@ _WIN_ATTN = "vit.win_attn."
 _GLOBAL_ATTN = "vit.global_attn."
 
 #: prefix of the counters of trunk layers traced (models/lm_trunk.py):
-#: ``trunk.<kda|mla|ssm|gqa|short_conv|moe|hc>.<formulation>`` and
+#: ``trunk.<kda|mla|ssm|gqa|swa|short_conv|moe|hc>.<formulation>`` and
 #: ``trunk.experts_held``
 _TRUNK = "trunk."
 #: the run-time routing counters under the same prefix (inference.py)
@@ -134,8 +134,9 @@ def record_compile_event(kind: str, key: Any, t0: float, t1: float,
 
 def _trunk_attrs(before: dict, after: dict) -> dict:
     """The ``compile`` span's ``trunk_kda`` / ``trunk_mla`` / ``trunk_ssm`` /
-    ``trunk_gqa`` / ``trunk_short_conv`` / ``trunk_moe`` / ``trunk_pairs``
-    (formulation x layers traced), ``trunk_hc`` (x sub-layers) and
+    ``trunk_gqa`` / ``trunk_swa`` / ``trunk_short_conv`` / ``trunk_moe`` /
+    ``trunk_pairs`` (formulation x layers traced), ``trunk_hc`` (x
+    sub-layers) and
     ``experts_held``, from what the trace-time counters ``trunk.*`` gained
     over a program's first call."""
     traced = {n: v - before.get(n, 0)  # names come without the prefix
@@ -143,7 +144,7 @@ def _trunk_attrs(before: dict, after: dict) -> dict:
     attrs = {}
     for name, count in sorted(traced.items()):
         kind, _, formulation = name.partition(".")
-        if (kind in ("kda", "mla", "ssm", "gqa", "short_conv", "moe",
+        if (kind in ("kda", "mla", "ssm", "gqa", "swa", "short_conv", "moe",
                      "pairs", "hc")
                 and formulation not in _RUN_TIME):
             attrs[f"trunk_{kind}"] = f"{formulation} x{count}"

@@ -5,7 +5,10 @@ heads, of 64 or 128). Softmax in float32, over the true row maximum.
 Grouped-query attention also takes block diffusion's block-causal mask
 (``diffusion_block`` L: query ``i`` sees key ``j`` where
 ``j // L <= i // L``); at L = 1 every form is the causal one, traced as it
-was before the mask existed.
+was before the mask existed. It takes a causal sliding window too
+(``window`` W: query ``i`` sees key ``j`` where ``i - W < j <= i``), whose
+forms never multiply a key that lies wholly before a row block's windows;
+``window`` None is the causal text as it was.
 
 :func:`causal_attention_blocked` is plain XLA: one block of query rows at a
 time against the keys at or before it, so the scores of a step are
@@ -48,14 +51,20 @@ from tmr_tpu.ops.kda import rms_norm
 
 
 def causal_attention_blocked(q, k, v, scale: float, block: int = 256,
-                             diffusion_block: int = 1):
+                             diffusion_block: int = 1, window=None):
     """``q`` (B, S, H, dqk), ``k`` (B, S, Hkv, dqk), ``v`` (B, S, Hkv, dv)
     -> (B, S, H, dv) in ``v``'s dtype. With fewer key-value heads than query
     heads (grouped-query attention) query head ``j`` reads key-value head
     ``j // (H / Hkv)``; the key-value heads are never written out again.
     ``diffusion_block`` ``L``: the block-causal mask of block diffusion,
     query ``i`` sees key ``j`` where ``j // L <= i // L`` (bidirectional
-    within a block of ``L`` tokens, causal across blocks); 1 is causal."""
+    within a block of ``L`` tokens, causal across blocks); 1 is causal.
+    ``window`` ``W``: the causal sliding window, query ``i`` sees key ``j``
+    where ``i - W < j <= i``; a row block multiplies only the keys from its
+    first query's window on."""
+    if window is not None and diffusion_block != 1:
+        raise ValueError("a sliding window under a block-causal mask is not "
+                         "written")
     b, s, h, _ = q.shape
     hkv = k.shape[2]
     if hkv == h:
@@ -70,11 +79,15 @@ def causal_attention_blocked(q, k, v, scale: float, block: int = 256,
         # query of the row block
         end = hi if diffusion_block == 1 else min(
             s, -(-hi // diffusion_block) * diffusion_block)
-        scores = jnp.einsum(to_scores, q[:, lo:hi], k[:, :end],
+        # the first key the row block's first query sees
+        first = 0 if window is None else max(0, lo - window + 1)
+        scores = jnp.einsum(to_scores, q[:, lo:hi], k[:, first:end],
                             preferred_element_type=jnp.float32) * scale
-        after = _after(lo, hi, end, diffusion_block)
-        probs = jax.nn.softmax(jnp.where(after, -jnp.inf, scores), axis=-1)
-        outs.append(jnp.einsum(to_out, probs.astype(v.dtype), v[:, :end]))
+        hidden = (_after(lo, hi, end, diffusion_block) if window is None
+                  else _outside(lo, hi, first, end, window))
+        probs = jax.nn.softmax(jnp.where(hidden, -jnp.inf, scores), axis=-1)
+        outs.append(jnp.einsum(to_out, probs.astype(v.dtype),
+                               v[:, first:end]))
     return jnp.concatenate(outs, axis=1).reshape(b, s, h, -1)
 
 
@@ -87,16 +100,26 @@ def _after(lo: int, hi: int, end: int, diffusion_block: int):
             > jnp.arange(lo, hi)[:, None] // diffusion_block)
 
 
+def _outside(lo: int, hi: int, first: int, end: int, window: int):
+    """(hi - lo, end - first): where key ``first + j`` lies outside query
+    ``lo + i``'s sliding window, after it or ``window`` or more before it."""
+    col = jnp.arange(first, end)[None, :]
+    row = jnp.arange(lo, hi)[:, None]
+    return (col > row) | (col <= row - window)
+
+
 def gqa_attention_blocked(q, k, v, heads: int, kv_heads: int,
                           scale: float, qk_norm=None, theta=None,
-                          diffusion_block: int = 1):
+                          diffusion_block: int = 1, window=None):
     """:func:`causal_attention_blocked` on the projections' own outputs (the
     kernel's signature, :func:`gqa_attention_kernel`): ``q`` (B, S, H x d),
     ``k`` and ``v`` (B, S, Hkv x d) -> (B, S, H x d). ``qk_norm`` (q's
     weight, k's weight, eps): an RMSNorm over each head's dims on q and on k
     first, and ``theta``: the default rotary on both after it, in float32
     and rounded to the operands' dtype once, as the chip's XLA computes
-    ``GQAMixer``'s path (its fusion keeps the norm's output in float32)."""
+    ``GQAMixer``'s path (its fusion keeps the norm's output in float32).
+    ``window``: the causal sliding window, as
+    :func:`causal_attention_blocked`'s."""
     b, s, _ = q.shape
     d = q.shape[-1] // heads
     q, k = q.reshape(b, s, heads, d), k.reshape(b, s, kv_heads, d)
@@ -111,7 +134,8 @@ def gqa_attention_blocked(q, k, v, heads: int, kv_heads: int,
                     for t in (q, k))
         q, k = q.astype(dtype), k.astype(dtype)
     o = causal_attention_blocked(q, k, v.reshape(b, s, kv_heads, d), scale,
-                                 diffusion_block=diffusion_block)
+                                 diffusion_block=diffusion_block,
+                                 window=window)
     return o.reshape(b, s, -1)
 
 
@@ -192,9 +216,20 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
+def key_blocks(iq, bq: int, bk: int, window=None, maximum=max):
+    """The key blocks ``[first, end)`` query block ``iq`` visits: causal,
+    from the first to the diagonal's last; under a sliding ``window``, from
+    the one that holds its first query's first key. ``maximum``: ``max`` of
+    Python integers, or ``jnp.maximum`` of a kernel's program id."""
+    end = (iq + 1) * (bq // bk)
+    if window is None:
+        return 0, end
+    return maximum(iq * bq - window + 1, 0) // bk, end
+
+
 def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
                     *, scale: float, bq: int, bk: int, dtype,
-                    diffusion_block: int = 1):
+                    diffusion_block: int = 1, window=None):
     """The two passes every causal kernel of this file makes for query
     block ``iq``, whatever its operands: ``scores_of(cols)`` is the block's
     (bq, bk) float32 scores against the keys ``cols`` before ``scale``,
@@ -203,7 +238,10 @@ def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
     ``diffusion_block`` ``L`` (a power of two that divides ``bk``): the
     block-causal mask, ``col // L > row // L`` masked. A query block then
     sees the same key blocks as under the causal mask, so only the diagonal
-    blocks' mask changes."""
+    blocks' mask changes. ``window`` ``W``: the causal sliding window, only
+    the key blocks :func:`key_blocks` names are visited (a key block wholly
+    before every window of the query block is neither multiplied nor read)
+    and each is masked, ``col > row`` or ``col <= row - W``."""
     per = bq // bk
 
     def folded(op, t):  # (bq, bk) -> (bq, 128), lane on lane
@@ -220,7 +258,10 @@ def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
                 # the first key of col's diffusion block, (col // L) L,
                 # passes row exactly where col // L > row // L
                 col = col & -diffusion_block
-            s = jnp.where(col > row, _NEG, s)
+            hidden = col > row
+            if window is not None:
+                hidden = hidden | (col <= row - window)
+            s = jnp.where(hidden, _NEG, s)
         s_ref[:, cols] = s
         m_ref[...] = jnp.maximum(m_ref[...], folded(jnp.maximum, s))
 
@@ -228,10 +269,18 @@ def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
         scores(ik, False)
         return c
 
+    def in_window(ik, c):
+        scores(ik, True)
+        return c
+
     m_ref[...] = jnp.full_like(m_ref, _NEG)
-    lax.fori_loop(0, iq * per, before_diagonal, 0)
-    for d in range(per):  # the key blocks the diagonal crosses
-        scores(iq * per + d, True)
+    if window is None:
+        lax.fori_loop(0, iq * per, before_diagonal, 0)
+        for d in range(per):  # the key blocks the diagonal crosses
+            scores(iq * per + d, True)
+    else:
+        first, end = key_blocks(iq, bq, bk, window, jnp.maximum)
+        lax.fori_loop(first, end, in_window, 0)
     m_ref[...] = jnp.broadcast_to(
         jnp.max(m_ref[...], axis=1, keepdims=True), m_ref.shape)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -244,7 +293,9 @@ def _causal_softmax(iq, scores_of, values_of, s_ref, m_ref, l_ref, acc_ref,
         acc_ref[...] += _dot(p.astype(dtype), values_of(cols))
         return c
 
-    lax.fori_loop(0, (iq + 1) * per, weighted, 0)
+    if window is None:
+        first, end = key_blocks(iq, bq, bk)
+    lax.fori_loop(first, end, weighted, 0)
     return acc_ref[...] / jnp.sum(l_ref[...], axis=1, keepdims=True)
 
 
@@ -489,7 +540,22 @@ def mla_formulation(seq: int, heads: int, dn: int, dp: int, dv: int, dtype,
 #   diffusion block of L tokens (block diffusion's mask: bidirectional
 #   within a block, causal across blocks) only the diagonal blocks' mask
 #   differs, since L divides a key block.
+# - with a sliding window of W tokens a query block visits only the key
+#   blocks from the one that holds its first query's first key
+#   (:func:`key_blocks`), each masked on both edges, in blocks of
+#   ``_WINDOW_BLOCK``: under a window of at most a key block, a
+#   (bq, bq + bk) band of scores, no more.
 # --------------------------------------------------------------------------
+#: tokens a query block and a key block hold under a sliding window: of
+#: the shapes timed on a v5e at a window of 128 over 4,096 tokens, 64 heads
+#: on 8 of 128 (PERF.md section 6), the fastest with rotary and without
+_WINDOW_BLOCK = (512, 256)
+
+
+def _block_for(window):
+    return _BLOCK if window is None else _WINDOW_BLOCK
+
+
 def _norm_turn(x, w, cos, sin, *, eps, head_dim: int, dtype):
     """A (rows, 128) slab of heads as ``GQAMixer``'s XLA path leaves it on
     the chip, in float32 holding ``dtype``'s values: with ``eps``, each
@@ -543,14 +609,15 @@ def _turn_tables(seq: int, head_dim: int, theta: float):
 
 def _gqa_kernel_body(q_ref, k_ref, v_ref, *rest, scale: float, bq: int,
                      bk: int, head_dim: int, group: int,
-                     diffusion_block: int, eps=None, rope: bool = False):
+                     diffusion_block: int, eps=None, rope: bool = False,
+                     window=None):
     """Refs: q (1, bq, 128) a slab of query heads, k and v (1, S, 128) the
     slab that holds the step's key-value head, with ``eps`` w (2, 128) q's
     and k's norm weights over a slab, float32, with ``rope`` the (S, 128)
     cos and sin of :func:`_turn_tables`, out (1, bq, 128); scratch as
     :func:`_latent_kernel_body`'s and, with either, k's slab turned
     (S, 128) and at heads of 64 the q slab turned (bq, 128). ``group``
-    query heads share a key-value head."""
+    query heads share a key-value head; ``window``: the sliding window."""
     prologue = eps is not None or rope
     w_ref = cos_ref = sin_ref = None
     if eps is not None:
@@ -611,7 +678,7 @@ def _gqa_kernel_body(q_ref, k_ref, v_ref, *rest, scale: float, bq: int,
     o = _causal_softmax(
         iq, lambda cols: _dot(q, keys(cols), _NT),
         lambda cols: v_ref[0, cols, :], *scratch, scale=scale, bq=bq, bk=bk,
-        dtype=dtype, diffusion_block=diffusion_block)
+        dtype=dtype, diffusion_block=diffusion_block, window=window)
     if head_dim == _LANES:
         o_ref[0] = o.astype(o_ref.dtype)
     else:
@@ -620,14 +687,18 @@ def _gqa_kernel_body(q_ref, k_ref, v_ref, *rest, scale: float, bq: int,
 
 
 def gqa_kernel_supported(seq: int, heads: int, kv_heads: int, head_dim: int,
-                         block=_BLOCK, diffusion_block: int = 1) -> bool:
+                         block=None, diffusion_block: int = 1,
+                         window=None) -> bool:
     """Heads of 128, or of 64 where the two heads of a slab of q read one
     slab of k and v (a group of one or of an even count); whole slabs of k
-    and v; a sequence that is whole query blocks (of whole key blocks) and
-    that VMEM holds; a diffusion block that is a power of two and divides
-    the key block."""
-    bq, bk = block
+    and v; a sequence that is whole query blocks (of whole key blocks, by
+    default ``_BLOCK``, under a window ``_WINDOW_BLOCK``) and that VMEM
+    holds; a diffusion block that is a power of two and divides the key
+    block; a window of at least one token, not under a diffusion block."""
+    bq, bk = block or _block_for(window)
     if head_dim not in (64, _LANES) or heads % kv_heads:
+        return False
+    if window is not None and (window < 1 or diffusion_block != 1):
         return False
     group, n = heads // kv_heads, diffusion_block
     return ((kv_heads * head_dim) % _LANES == 0
@@ -636,21 +707,21 @@ def gqa_kernel_supported(seq: int, heads: int, kv_heads: int, head_dim: int,
             and n >= 1 and n & (n - 1) == 0 and bk % n == 0)
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(4, 11)))
+@functools.partial(jax.jit, static_argnums=tuple(range(4, 12)))
 def _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale, eps,
-                         theta, block, diffusion_block):
+                         theta, block, diffusion_block, window):
     # a jit of its own: a trunk's attention layers are one shape, and share
     # one traced and lowered function in the enclosing program
     b, s, _ = q.shape
     d = q.shape[-1] // heads
     if not (gqa_kernel_supported(s, heads, kv_heads, d, block,
-                                 diffusion_block)
+                                 diffusion_block, window)
             and q.shape[-1] == heads * d and k.shape[-1] == kv_heads * d
             and v.shape == k.shape):
         raise ValueError(
             f"q {q.shape}, k {k.shape}, v {v.shape} at {heads} / {kv_heads} "
-            f"heads, diffusion block {diffusion_block}, have no kernel; gate "
-            "callers on gqa_formulation()")
+            f"heads, diffusion block {diffusion_block}, window {window}, "
+            "have no kernel; gate callers on gqa_formulation()")
     bq, bk = block
     per, group = _LANES // d, heads // kv_heads
     slab = pl.BlockSpec((1, bq, _LANES), lambda i, j, n, r: (i, n, j))
@@ -684,7 +755,7 @@ def _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale, eps,
         functools.partial(_gqa_kernel_body, scale=scale, bq=bq, bk=bk,
                           head_dim=d, group=group,
                           diffusion_block=diffusion_block, eps=eps,
-                          rope=theta is not None),
+                          rope=theta is not None, window=window),
         grid=(b, heads // per, s // bq, per),
         in_specs=in_specs,
         out_specs=slab,
@@ -699,14 +770,16 @@ def _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale, eps,
 
 
 def gqa_attention_kernel(q, k, v, heads: int, kv_heads: int, scale: float,
-                         qk_norm=None, theta=None, block=_BLOCK,
-                         diffusion_block: int = 1):
+                         qk_norm=None, theta=None, block=None,
+                         diffusion_block: int = 1, window=None):
     """Causal grouped-query attention as one Pallas TPU kernel, on the
     projections' own outputs: ``q`` (B, S, H x d), ``k`` and ``v``
     (B, S, Hkv x d), query head ``j`` on key-value head ``j // (H / Hkv)``,
     heads of 128 or of 64 (:func:`gqa_kernel_supported`); with a
     ``diffusion_block`` over 1, block-causal as
-    :func:`causal_attention_blocked`. ``qk_norm`` (q's weight, k's weight,
+    :func:`causal_attention_blocked`; with a ``window`` W, causal over the
+    last W tokens, in blocks of ``_WINDOW_BLOCK`` unless ``block`` says
+    otherwise. ``qk_norm`` (q's weight, k's weight,
     each of a head's width, and eps) and ``theta``: the mixer's norms and
     default rotary on q and k, which the kernel applies itself. Returns
     (B, S, H x d) in ``v``'s dtype, what ``o_proj`` reads. Same mathematics
@@ -717,29 +790,30 @@ def gqa_attention_kernel(q, k, v, heads: int, kv_heads: int, scale: float,
     weights, eps = (None, None) if qk_norm is None else (qk_norm[:2],
                                                          qk_norm[2])
     return _gqa_kernel_vjp(q, k, v, weights, heads, kv_heads, scale, eps,
-                           theta, block, diffusion_block)
+                           theta, block or _block_for(window),
+                           diffusion_block, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 11)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 12)))
 def _gqa_kernel_vjp(q, k, v, weights, heads, kv_heads, scale, eps, theta,
-                    block, diffusion_block):
+                    block, diffusion_block, window):
     return _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale,
-                                eps, theta, block, diffusion_block)
+                                eps, theta, block, diffusion_block, window)
 
 
 def _gqa_vjp_fwd(q, k, v, weights, heads, kv_heads, scale, eps, theta, block,
-                 diffusion_block):
+                 diffusion_block, window):
     return _gqa_kernel_fwd_impl(q, k, v, weights, heads, kv_heads, scale,
-                                eps, theta, block, diffusion_block), (
+                                eps, theta, block, diffusion_block, window), (
         q, k, v, weights)
 
 
 def _gqa_vjp_bwd(heads, kv_heads, scale, eps, theta, block, diffusion_block,
-                 res, ct):
+                 window, res, ct):
     def blocked(q, k, v, weights):
         qk_norm = None if weights is None else (*weights, eps)
         return gqa_attention_blocked(q, k, v, heads, kv_heads, scale,
-                                     qk_norm, theta, diffusion_block)
+                                     qk_norm, theta, diffusion_block, window)
 
     return jax.vjp(blocked, *res)[1](ct)
 
@@ -749,7 +823,8 @@ _gqa_kernel_vjp.defvjp(_gqa_vjp_fwd, _gqa_vjp_bwd)
 
 @mosaic_gate
 def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1,
-                  qk_norm: bool = False, rope: bool = False) -> bool:
+                  qk_norm: bool = False, rope: bool = False,
+                  window=None) -> bool:
     """Compiled self-check of :func:`gqa_attention_kernel` as the mixer
     calls it, once a machine: two query blocks (a full key block and the
     diagonal), a batch of two and two slabs of k and v (sixteen query heads:
@@ -757,19 +832,24 @@ def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1,
     groups of four, whose pairs roll and whose pairs do not), masked with
     the ``diffusion_block`` it is asked for, with the q/k norms (weights
     drawn about the benchmark's 1.75) and the rotary (theta 1e6) where
-    asked, against :func:`gqa_attention_blocked` under the same mask and
-    prologue. One program, its inputs drawn on the host (``chip_smoke.py``
-    holds the kernel to the blocked form at the backbones' shapes)."""
+    asked, under the sliding ``window`` where asked (then eight query blocks
+    of ``_WINDOW_BLOCK``, most of whose key blocks lie before the window),
+    against :func:`gqa_attention_blocked` under the same mask and prologue.
+    One program, its inputs drawn on the host (``chip_smoke.py`` holds the
+    kernel to the blocked form at the backbones' shapes)."""
     from tmr_tpu.diagnostics import gate_refused, run_outside_trace
 
     config = {"head_dim": head_dim, "diffusion_block": diffusion_block,
               "qk_norm": qk_norm, "rope": rope}
+    if window is not None:
+        config["window"] = window
     if jax.default_backend() != "tpu":
         return gate_refused(
             "gqa_kernel_ok", f"backend {jax.default_backend()!r} != 'tpu'",
             "backend", config)
 
-    b, s, heads = 2, 2 * _BLOCK[0], 16
+    b, heads = 2, 16
+    s = 2 * _BLOCK[0] if window is None else 8 * _WINDOW_BLOCK[0]
     kv_heads, scale = 2 * _LANES // head_dim, head_dim ** -0.5
     theta = 1e6 if rope else None
 
@@ -777,9 +857,10 @@ def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1,
     def gap(q, k, v, wq, wk):
         norms = (wq, wk, 1e-6) if qk_norm else None
         got = gqa_attention_kernel(q, k, v, heads, kv_heads, scale, norms,
-                                   theta, diffusion_block=diffusion_block)
+                                   theta, diffusion_block=diffusion_block,
+                                   window=window)
         want = gqa_attention_blocked(q, k, v, heads, kv_heads, scale, norms,
-                                     theta, diffusion_block)
+                                     theta, diffusion_block, window)
         gap = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
         return gap.max() / jnp.abs(want.astype(jnp.float32)).max()
 
@@ -806,7 +887,7 @@ def gqa_kernel_ok(head_dim: int, diffusion_block: int = 1,
 
 def gqa_formulation(seq: int, heads: int, kv_heads: int, head_dim: int,
                     dtype, diffusion_block: int = 1, qk_norm: bool = False,
-                    rope: bool = False) -> str:
+                    rope: bool = False, window=None) -> str:
     """What grouped-query attention traces with (counter
     ``trunk.gqa.<formulation>``), by what can be observed: in bfloat16, at
     heads of 128, or of 64 whose pairs share a slab of k and v, a sequence
@@ -814,14 +895,18 @@ def gqa_formulation(seq: int, heads: int, kv_heads: int, head_dim: int,
     with the prologue's tables and turned k, the 1536 bucket's 9,216 43 MiB
     and 54; over 16,384 not) and a diffusion block that divides a key block,
     where the kernel's self-check at that diffusion block and with the
-    mixer's q/k norms and rotary (``qk_norm``, ``rope``) says yes (it says
+    mixer's q/k norms and rotary (``qk_norm``, ``rope``) and under its
+    sliding ``window`` (in blocks of ``_WINDOW_BLOCK``) says yes (it says
     no off a TPU and inside a trace XLA partitions), the Pallas kernel
     (``causal_kernel``); else ``blocked_xla``,
     :func:`causal_attention_blocked`."""
+    # the gate asked as it was where there is no window
+    wide = () if window is None else (window,)
     if (dtype == jnp.bfloat16
             and gqa_kernel_supported(seq, heads, kv_heads, head_dim,
-                                     diffusion_block=diffusion_block)
+                                     diffusion_block=diffusion_block,
+                                     window=window)
             and gqa_kernel_ok(head_dim, diffusion_block, bool(qk_norm),
-                              bool(rope))):
+                              bool(rope), *wide)):
         return "causal_kernel"
     return "blocked_xla"
